@@ -1369,7 +1369,7 @@ def _run_trace_merge(args) -> int:
 
 def _run(args) -> int:
     if args.cmd == "supervise":
-        # Before force_platform/jax: the supervisor process never
+        # Before anything imports jax: the supervisor process never
         # touches a device.
         return _run_supervise(args)
     if args.cmd == "serve-fleet":
@@ -1388,21 +1388,26 @@ def _run(args) -> int:
         # supervisor shell; only rank subprocesses load the tables.
         return _run_transform_fleet(args)
 
-    from glint_word2vec_tpu.utils.platform import force_platform
+    if args.cmd == "train" and (args.coordinator or args.num_processes):
+        # First: nothing may initialise the backend before the gang joins,
+        # and the cache rule below reads the backend.
+        from glint_word2vec_tpu.parallel import distributed as dist
 
-    force_platform()  # a plain `JAX_PLATFORMS=cpu` must always work
+        dist.initialize(
+            coordinator_address=args.coordinator,
+            num_processes=args.num_processes,
+            process_id=args.process_id,
+        )
+
+    from glint_word2vec_tpu.utils.platform import enable_compile_cache
+
+    # A server start compiles ~50 small programs, the same for every start
+    # of one table shape: keep them all. Other commands keep JAX's floor.
+    enable_compile_cache(0.0 if args.cmd == "serve" else 1.0)
 
     from glint_word2vec_tpu import FastTextWord2Vec, Word2Vec, load_model
 
     if args.cmd == "train":
-        if args.coordinator or args.num_processes:
-            from glint_word2vec_tpu.parallel import distributed as dist
-
-            dist.initialize(
-                coordinator_address=args.coordinator,
-                num_processes=args.num_processes,
-                process_id=args.process_id,
-            )
         kw = dict(
             vector_size=args.vector_size,
             window=args.window,

@@ -1217,6 +1217,8 @@ class Word2Vec:
         if steptime:
             model.training_metrics["steptime"] = steptime
         model.training_metrics["batch_packing"] = p.batch_packing
+        model.training_metrics["step_body"] = engine.step_body(packed)
+        model.training_metrics["pallas_mode"] = engine.pallas_mode
         if exchanger is not None:
             model.training_metrics["exchange_mode"] = p.exchange
             model.training_metrics["exchange_wire"] = p.exchange_wire
@@ -1230,9 +1232,6 @@ class Word2Vec:
             model.training_metrics.update(
                 packed_pairs=packed_pairs,
                 packed_mask_density=round(packed_pairs / packed_slots, 4),
-                # Whether the dispatches rode the fused Pallas megakernel
-                # (ops/pallas_sgns) instead of the composed XLA pair step.
-                pallas_fused=bool(getattr(engine, "_pallas_fused", False)),
             )
         return model
 
@@ -1606,7 +1605,11 @@ class Word2Vec:
             obs_run.close()
         logger.info("training done: %s", metrics.summary())
         model = self._make_model(vocab, engine)
-        model.training_metrics = {**metrics.summary(), "pipeline": "host"}
+        model.training_metrics = {
+            **metrics.summary(), "pipeline": "host",
+            "step_body": engine.step_body(False),
+            "pallas_mode": engine.pallas_mode,
+        }
         steptime = obs_run.steptime_totals()
         if steptime:
             model.training_metrics["steptime"] = steptime

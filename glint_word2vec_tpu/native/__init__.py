@@ -13,6 +13,7 @@ import ctypes
 import hashlib
 import logging
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional, Tuple
@@ -24,40 +25,59 @@ logger = logging.getLogger(__name__)
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "host_ops.cpp")
 _SO = os.path.join(_HERE, "_host_ops.so")
-_STAMP = _SO + ".sha256"  # source hash the .so was built from
+_STAMP = _SO + ".sha256"  # what the .so was built from, and where
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _src_hash() -> str:
+def _build_stamp() -> str:
+    """Hash of the source AND of this machine's CPU: the build uses
+    ``-march=native``, so a library that arrived with a copy of the
+    working tree from another host must be rebuilt, not loaded — it can
+    SIGILL."""
+    h = hashlib.sha256()
     with open(_SRC, "rb") as f:
-        return hashlib.sha256(f.read()).hexdigest()
+        h.update(f.read())
+    h.update(platform.machine().encode())
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features", "model name")):
+                    h.update(line.encode())
+                elif not line.strip():
+                    break  # first processor's block is enough
+    except OSError:
+        h.update(platform.processor().encode())
+    return h.hexdigest()
 
 
 def _is_fresh() -> bool:
-    """A .so is usable only if built from the current source ON this machine
-    (-march=native output from another host can SIGILL); the build stamp
-    records the source hash, and a missing stamp forces a rebuild."""
+    """A .so is usable only if its stamp matches :func:`_build_stamp`; a
+    missing stamp forces a rebuild."""
     if not os.path.exists(_SO) or not os.path.exists(_STAMP):
         return False
     try:
         with open(_STAMP) as f:
-            return f.read().strip() == _src_hash()
+            return f.read().strip() == _build_stamp()
     except OSError:
         return False
 
 
 def _build() -> bool:
+    # Link to a private name, then rename: concurrent first users (xdist
+    # workers on a fresh checkout) never load a half-written library.
+    tmp = f"{_SO}.tmp{os.getpid()}"
     cmd = [
         "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-        "-pthread", _SRC, "-o", _SO,
+        "-pthread", _SRC, "-o", tmp,
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
         # graftlint: ignore[atomic-persist] best-effort build stamp: a torn stamp only fails the hash check and forces one rebuild
         with open(_STAMP, "w") as f:
-            f.write(_src_hash())
+            f.write(_build_stamp())
         return True
     except Exception as e:  # compiler missing, read-only fs, ...
         logger.warning("native host_ops build failed (%s); using Python fallbacks", e)

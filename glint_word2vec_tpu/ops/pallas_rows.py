@@ -25,10 +25,14 @@ sequentially on a core and every write DMA is waited before the step ends,
 so a run spanning two blocks is just two ordered read-modify-writes of the
 same row — still a sum.
 
-These kernels are OPT-IN (engine flag / GLINT_W2V_PALLAS env var): XLA's
-native lowering is the default until per-hardware measurement says
-otherwise (scripts/pallas_bench.py is the measurement harness). On CPU they
-run in interpret mode, which is how the unit tests exercise them.
+These kernels are OPT-IN (engine flag / GLINT_W2V_PALLAS env var) and
+INTERPRET-ONLY today: the TPU's compiler refuses all three ("Slice shape
+along dimension 0 must be aligned to tiling (8), but is 1" — the per-row
+``make_async_copy`` of a (1, d) slice of the tiled HBM table is not a legal
+DMA; tests/test_tpu_compile.py keeps the verdicts as strict xfails), and the
+engine raises on ``use_pallas`` under a tpu backend. On CPU they run in
+interpret mode, which is how the unit tests exercise them. Keep or delete is
+ROADMAP S5/D1.
 """
 
 from __future__ import annotations

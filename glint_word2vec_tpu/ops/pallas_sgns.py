@@ -3,9 +3,8 @@
 The packed pair step (ops/sgns.train_step_pairs through the engine's
 packed corpus scan) is XLA-composed: gather h/u rows -> dot -> sigmoid
 -> rank-1 outer products -> scatter-add. Every touched row round-trips
-HBM *between* those ops, and with bf16 tables the XLA lowering is so
-gather/scatter-unfriendly that halving the row bytes HALVES throughput
-instead of doubling it (BENCH_r05: 5,955 vs 12,577 words/sec per-pair).
+HBM *between* those ops, and whether bf16 tables pay off under the XLA
+lowering has not been measured on a chip (ROADMAP S4).
 This module fuses the whole pair update into Pallas kernels that move
 each touched row across the HBM<->VMEM boundary once per phase and do
 ALL arithmetic in fp32 VMEM registers regardless of the table's storage
@@ -55,9 +54,15 @@ on :func:`fused_pair_step`). The composed path materializes those PLUS
 u_pos (P, d), u_neg (P, n, d), and both expanded rank-1 payloads.
 
 Like ops/pallas_rows.py these kernels are OPT-IN (engine flag /
-``GLINT_W2V_PALLAS``) and run in interpret mode off-TPU, which is how
-the parity tests (tests/test_pallas_sgns.py, 3-way vs the composed XLA
-step and a host-NumPy oracle) exercise them on the CPU mesh.
+``GLINT_W2V_PALLAS``) and INTERPRET-ONLY today, which is how the parity
+tests (tests/test_pallas_sgns.py, 3-way vs the composed XLA step and a
+host-NumPy oracle) exercise them on the CPU mesh. The TPU's compiler
+refuses every entry point (tests/test_tpu_compile.py, strict xfails):
+the scatters for the same unaligned (1, d) row DMA as ops/pallas_rows.py,
+``pair_forward`` with "Cannot store scalars to VMEM", and
+``pair_forward_shared`` with "Unimplemented primitive in Pallas TPU
+lowering for KernelType.TC: scatter"; the engine raises on ``use_pallas``
+under a tpu backend. Nothing above has been timed on a chip.
 """
 
 from __future__ import annotations

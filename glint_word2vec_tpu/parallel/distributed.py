@@ -60,26 +60,19 @@ def initialize(
 
     import jax
 
-    if _is_initialized(jax):  # already up
+    if jax.distributed.is_initialized():  # already up
         logger.info("jax.distributed already initialized; skipping")
         return
     # Multi-process CPU runs (the supervisor's gang mode on dev boxes /
     # CI) need an explicit cross-host collectives backend: without it
     # jaxlib raises "Multiprocess computations aren't implemented on
     # the CPU backend" at the first psum. Opt into gloo when the run is
-    # pinned to CPU and the operator hasn't chosen an implementation
-    # (older jax versions without the option just skip this).
-    platforms = (
-        jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS", "")
-    )
+    # pinned to CPU and the operator hasn't chosen an implementation.
     if (
-        "cpu" in (platforms or "")
+        "cpu" in (jax.config.jax_platforms or "")
         and "JAX_CPU_COLLECTIVES_IMPLEMENTATION" not in os.environ
     ):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # pragma: no cover - jax without the option
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kwargs = {}
     if coordinator_address is not None:
         kwargs["coordinator_address"] = coordinator_address
@@ -97,25 +90,6 @@ def initialize(
         jax.local_device_count(),
         jax.device_count(),
     )
-
-
-def _is_initialized(jax) -> bool:
-    """Best-effort "is the distributed runtime already up?" check, using the
-    public API where this JAX version has one and falling back to the
-    private global state otherwise (the private attribute may move across
-    releases; the fallback failing open just means jax.distributed.initialize
-    itself reports the duplicate initialization)."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if callable(is_init):
-        try:
-            return bool(is_init())
-        except Exception:  # pragma: no cover - defensive
-            pass
-    try:
-        state = getattr(jax._src.distributed, "global_state", None)
-        return state is not None and state.client is not None
-    except Exception:  # pragma: no cover - defensive
-        return False
 
 
 def make_global_mesh(

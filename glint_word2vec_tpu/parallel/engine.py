@@ -51,13 +51,8 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from glint_word2vec_tpu.corpus.alias import build_unigram_alias
 from glint_word2vec_tpu.obs import events as obs_events
@@ -92,22 +87,36 @@ def _host_or_device(a, dtype=None):
     return np.asarray(a) if dtype is None else np.asarray(a, dtype=dtype)
 
 
-def _pull_rows(table_l, idx, start, rows_per_shard, pallas_mode=0):
+def _score(table_l, q):
+    """``table_l @ q`` in fp32 for the similarity programs (multiply, top-k,
+    batch top-k). ``HIGHEST`` because the TPU's default rounds f32 operands
+    to bf16 on the MXU: a coalesced (Q > 1) query then scored 5.6e-4 off the
+    same word asked alone and swapped near-ties (chip run, PR 22), so a
+    served rank depended on how the request was batched. The pass stays
+    bound by reading the table once: at 1M x 300 f32 score + top-k took
+    0.2% longer at Q=8 and 1.8% at Q=64, Q=1 the same (same run)."""
+    return jnp.matmul(
+        table_l.astype(jnp.float32), q, precision=lax.Precision.HIGHEST
+    )
+
+
+def _pull_rows(table_l, idx, start, rows_per_shard, pallas=False):
     """Gather global rows from a shard-local table: contribute owned rows,
     zeros elsewhere, then psum over the model axis. The TPU analogue of the
     servers each answering a pull with their slice (SURVEY.md §2.2 pull).
 
-    ``pallas_mode``: 0 = XLA gather (default), 1 = Pallas row pipeline
-    (ops/pallas_rows.py), 2 = Pallas in interpret mode (CPU tests).
+    ``pallas``: False = XLA gather (default), True = the Pallas row
+    pipeline (ops/pallas_rows.py) in interpret mode, the only mode it has
+    (see PALLAS_TPU_REFUSAL).
     """
     loc = idx - start
     own = (loc >= 0) & (loc < rows_per_shard)
     clipped = jnp.clip(loc, 0, rows_per_shard - 1)
-    if pallas_mode:
+    if pallas:
         from glint_word2vec_tpu.ops.pallas_rows import gather_rows
 
         rows = gather_rows(
-            table_l, clipped, interpret=pallas_mode == 2
+            table_l, clipped, interpret=True
         ).astype(jnp.float32)
     else:
         rows = table_l[clipped].astype(jnp.float32)
@@ -159,16 +168,16 @@ def _bf16_safe_scatter_add(table_l, idx, upd):
     return table_l.at[sid].add(summed.astype(table_l.dtype))
 
 
-def _scatter_rows(table_l, idx, upd, start, rows_per_shard, pallas_mode=0):
+def _scatter_rows(table_l, idx, upd, start, rows_per_shard, pallas=False):
     """Apply global rank-1 updates to the owned slice of a sharded table
     (the servers' half of ``adjust``, SURVEY.md §2.2). Disowned updates are
-    zeroed and land harmlessly on a clipped row. ``pallas_mode`` as in
+    zeroed and land harmlessly on a clipped row. ``pallas`` as in
     :func:`_pull_rows`."""
     loc = idx - start
     own = (loc >= 0) & (loc < rows_per_shard)
     upd = jnp.where(own[:, None], upd, 0.0)
     clipped = jnp.clip(loc, 0, rows_per_shard - 1)
-    if pallas_mode:
+    if pallas:
         from glint_word2vec_tpu.ops.pallas_rows import scatter_add_rows
 
         if jnp.dtype(table_l.dtype).itemsize < 4:
@@ -177,9 +186,7 @@ def _scatter_rows(table_l, idx, upd, start, rows_per_shard, pallas_mode=0):
             # rounds each row's batch total once (same contract as the
             # XLA branch below and the fused kernels).
             clipped, upd = _dup_sum_f32(clipped, upd)
-        return scatter_add_rows(
-            table_l, clipped, upd, interpret=pallas_mode == 2
-        )
+        return scatter_add_rows(table_l, clipped, upd, interpret=True)
     return _bf16_safe_scatter_add(table_l, clipped, upd)
 
 
@@ -250,6 +257,22 @@ def _query_memo_put(key, fn):
     _QUERY_MEMO[key] = fn
     return fn
 
+#: Why ``use_pallas`` is an error on a tpu backend. The verdicts are the
+#: chip compiler's own (v5e, libtpu 0.0.34, every kernel at d=300, f32 and
+#: bf16; tests/test_tpu_compile.py keeps them as strict xfails).
+PALLAS_TPU_REFUSAL = (
+    "use_pallas / GLINT_W2V_PALLAS=1 cannot run on a tpu backend: the TPU "
+    "compiler refuses every kernel of ops/pallas_rows.py and "
+    "ops/pallas_sgns.py. gather_rows, scatter_add_rank1, scatter_add_rows, "
+    "scatter_add_rows_f32, scatter_add_rank1_hbm: 'Slice shape along "
+    "dimension 0 must be aligned to tiling (8), but is 1' (a (1, d) row "
+    "of the tiled HBM table is not a legal DMA slice); pair_forward, "
+    "fused_pair_step: 'Cannot store scalars to VMEM'; pair_forward_shared, "
+    "fused_pair_step_shared: 'Unimplemented primitive in Pallas TPU "
+    "lowering for KernelType.TC: scatter'. They run in interpret mode "
+    "off-TPU only; leave the flag off (the default XLA step) on the chip."
+)
+
 #: Floor of the top-k k-bucket family. Requested k is rounded up to
 #: ``max(next_pow2(k), TOPK_MIN_K_BUCKET)`` (capped at padded_vocab) and
 #: the result truncated to k, so every small-k request — num defaults,
@@ -319,7 +342,7 @@ def _apply_rank1_updates(
             coefs = jnp.where(own, coefs, 0.0)
             ids = jnp.clip(loc, 0, Vs - 1)
         syn1_l = scatter_add_rank1(
-            syn1_l, ids, coefs, h_g, hidx, interpret=pm == 2
+            syn1_l, ids, coefs, h_g, hidx, interpret=True
         )
         return syn1_l, None
     d = h_g.shape[-1]
@@ -391,12 +414,65 @@ class EmbeddingEngine:
         slices). Both train bit-equivalently up to reduction order, and
         checkpoints re-home across layouts, so the choice is reversible.
         """
+        self._configure(
+            mesh, vocab_size, dim, num_negatives=num_negatives,
+            unigram_power=unigram_power,
+            unigram_table_size=unigram_table_size, seed=seed, dtype=dtype,
+            extra_rows=extra_rows, shared_negatives=shared_negatives,
+            use_pallas=use_pallas, compute_dtype=compute_dtype,
+            layout=layout,
+        )
+        if counts.shape != (vocab_size,):
+            raise ValueError("counts must have shape (vocab_size,)")
+
+        # Noise distribution over the *unpadded* vocab — draws are therefore
+        # identical for every mesh shape (padding never enters sampling),
+        # and padded rows can never be drawn as negatives.
+        self._counts = np.asarray(counts, dtype=np.int64).copy()
+        table = build_unigram_alias(
+            self._counts, power=unigram_power, table_size=unigram_table_size
+        )
+        repl = NamedSharding(mesh, P())
+        self._prob = jax.device_put(table.prob, repl)
+        self._alias = jax.device_put(table.alias, repl)
+
+        # Initialize tables directly sharded on-device (no host round-trip):
+        # syn0 ~ U[-0.5/d, 0.5/d), syn1 = 0 (word2vec standard, ops/sgns.py).
+        # Randoms are drawn for the unpadded rows/cols only, then
+        # zero-padded, so initial values are layout- and mesh-shape-
+        # invariant (a "dims" engine starts bitwise-equal to a "rows" one).
+        # That rests on partitionable threefry (JAX's default): the legacy
+        # lowering produces sharding-DEPENDENT random values when GSPMD
+        # partitions the draw.
+        tsh = self._table_sharding()
+        V, Vp, d, dp = self.num_rows, self.padded_vocab, self.dim, self.padded_dim
+
+        def _init(key):
+            s0, s1 = sgns.init_tables(key, V, d, self._dtype)
+            pad = ((0, Vp - V), (0, dp - d))
+            return jnp.pad(s0, pad), jnp.pad(s1, pad)
+
+        self.syn0, self.syn1 = jax.jit(_init, out_shardings=(tsh, tsh))(
+            jax.random.PRNGKey(seed)
+        )
+        self._build_jitted_fns()
+
+    def _configure(
+        self, mesh, vocab_size: int, dim: int, *, num_negatives: int,
+        unigram_power: float, unigram_table_size: Optional[int], seed: int,
+        dtype: str, extra_rows: int, shared_negatives: int,
+        use_pallas: Optional[bool], compute_dtype: Optional[str],
+        layout: str,
+    ) -> None:
+        """The host-only half of construction: validate, and derive every
+        attribute the jitted closures capture (geometry, dtypes, step
+        path). Places nothing on a device, so :meth:`_build_jitted_fns`
+        can follow it on a mesh of described devices that hold no array
+        (tests/test_tpu_compile.py)."""
         if vocab_size <= 0 or dim <= 0:
             raise ValueError("vocab_size and dim must be > 0")
         if layout not in ("rows", "dims"):
             raise ValueError("layout must be 'rows' or 'dims'")
-        if counts.shape != (vocab_size,):
-            raise ValueError("counts must have shape (vocab_size,)")
         if extra_rows < 0:
             raise ValueError("extra_rows must be >= 0")
         if shared_negatives < 0:
@@ -425,13 +501,14 @@ class EmbeddingEngine:
             jnp.bfloat16 if compute_dtype == "bfloat16" else jnp.float32
         )
         # Pallas row kernels for the sparse table traffic: opt-in per
-        # engine or via GLINT_W2V_PALLAS=1; interpret mode off-TPU so the
-        # same flag is testable on the CPU mesh.
+        # engine or via GLINT_W2V_PALLAS=1. Interpret mode only: the
+        # chip's compiler refuses every kernel (see PALLAS_TPU_REFUSAL),
+        # so on a tpu backend the flag is an error, not a Mosaic trace.
         if use_pallas is None:
             use_pallas = os.environ.get("GLINT_W2V_PALLAS", "0") == "1"
-        self._pallas_mode = 0
-        if use_pallas:
-            self._pallas_mode = 1 if jax.default_backend() == "tpu" else 2
+        if use_pallas and jax.default_backend() == "tpu":
+            raise NotImplementedError(PALLAS_TPU_REFUSAL)
+        self._pallas_interpret = bool(use_pallas)
         self.num_data = mesh.shape[DATA_AXIS]
         self.num_model = mesh.shape[MODEL_AXIS]
         self.layout = layout
@@ -445,7 +522,7 @@ class EmbeddingEngine:
         # GLINT_W2V_PALLAS_FUSED=0 keeps the row kernels but not the
         # fused step.
         fused = (
-            self._pallas_mode != 0
+            self._pallas_interpret
             and layout == "rows"
             and self.num_model == 1
             and os.environ.get("GLINT_W2V_PALLAS_FUSED", "1") == "1"
@@ -456,7 +533,8 @@ class EmbeddingEngine:
             )
 
             # The shared-pool forward pins the pool (storage + fp32) in
-            # VMEM; an oversized pool falls back to the composed step.
+            # VMEM; an oversized pool takes the composed step instead —
+            # which one ran is on record in :meth:`step_body`.
             fused = shared_pool_vmem_ok(
                 self.shared_negatives, self.dim, self._dtype
             )
@@ -472,49 +550,30 @@ class EmbeddingEngine:
             self.padded_dim = pad_to_multiple(self.dim, self.num_model)
             self.cols_per_shard = self.padded_dim // self.num_model
 
-        # Noise distribution over the *unpadded* vocab — draws are therefore
-        # identical for every mesh shape (padding never enters sampling),
-        # and padded rows can never be drawn as negatives.
-        self._counts = np.asarray(counts, dtype=np.int64).copy()
-        table = build_unigram_alias(
-            self._counts, power=unigram_power, table_size=unigram_table_size
-        )
-        repl = NamedSharding(mesh, P())
-        self._prob = jax.device_put(jnp.asarray(table.prob), repl)
-        self._alias = jax.device_put(jnp.asarray(table.alias), repl)
+    @property
+    def pallas_mode(self) -> str:
+        """``"off"`` (XLA gathers and scatters) or ``"interpret"`` — what
+        the Pallas flag resolved to. There is no compiled mode until a
+        kernel gets past the chip's compiler (PALLAS_TPU_REFUSAL)."""
+        return "interpret" if self._pallas_interpret else "off"
 
-        # Initialize tables directly sharded on-device (no host round-trip):
-        # syn0 ~ U[-0.5/d, 0.5/d), syn1 = 0 (word2vec standard, ops/sgns.py).
-        # Randoms are drawn for the unpadded rows/cols only, then
-        # zero-padded, so initial values are layout- and mesh-shape-
-        # invariant (a "dims" engine starts bitwise-equal to a "rows" one).
-        # The init MUST trace with partitionable threefry: the legacy
-        # (non-partitionable) lowering produces sharding-DEPENDENT random
-        # values when GSPMD partitions the draw — on meshes with data > 1
-        # and certain model-axis sizes the tables came up different from
-        # every other mesh shape, breaking the seed -> identical-tables
-        # contract (the two round-0 mesh-invariance test failures). Scoped
-        # to this one jit so every other RNG stream (negatives, window
-        # shrink) keeps its existing draws.
-        tsh = self._table_sharding()
-        V, Vp, d, dp = self.num_rows, self.padded_vocab, self.dim, self.padded_dim
-
-        def _init(key):
-            s0, s1 = sgns.init_tables(key, V, d, self._dtype)
-            pad = ((0, Vp - V), (0, dp - d))
-            return jnp.pad(s0, pad), jnp.pad(s1, pad)
-
-        prev_partitionable = jax.config.jax_threefry_partitionable
-        jax.config.update("jax_threefry_partitionable", True)
-        try:
-            self.syn0, self.syn1 = jax.jit(_init, out_shardings=(tsh, tsh))(
-                jax.random.PRNGKey(seed)
-            )
-        finally:
-            jax.config.update(
-                "jax_threefry_partitionable", prev_partitionable
-            )
-        self._build_jitted_fns()
+    def step_body(self, pair_form: bool) -> str:
+        """Which step body a dispatch of this engine traces, recorded in
+        ``training_metrics`` so no fit hides a fallback:
+        ``<layout>/<per_pair|shared_pool>/<xla|pallas_rows|pallas_fused>``.
+        ``pair_form`` says whether the dispatches are dense pair batches
+        (the packed scan) — the only shape the fused kernels take.
+        ``pallas_rows`` is the composed step over the Pallas row kernels,
+        which is also what a requested fused shared-pool step becomes
+        when its pool does not fit VMEM."""
+        if self._pallas_fused and pair_form:
+            kernels = "pallas_fused"
+        elif self._pallas_interpret:
+            kernels = "pallas_rows"
+        else:
+            kernels = "xla"
+        estimator = "shared_pool" if self.shared_negatives else "per_pair"
+        return f"{self.layout}/{estimator}/{kernels}"
 
     def _table_sharding(self):
         return (
@@ -528,21 +587,15 @@ class EmbeddingEngine:
     # ------------------------------------------------------------------
 
     def _shard_map(self, f, in_specs, out_specs):
-        try:
-            return shard_map(
-                f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        except TypeError:  # older jax spells the flag check_rep
-            return shard_map(
-                f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=False,
-            )
+        return shard_map(
+            f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
+        )
 
     def _build_jitted_fns(self) -> None:
         mesh = self.mesh
         Vs = self.rows_per_shard
-        pm = self._pallas_mode
+        pm = self._pallas_interpret
         n = self.num_negatives
         if self._pallas_fused:
             from glint_word2vec_tpu.ops import pallas_sgns
@@ -568,7 +621,6 @@ class EmbeddingEngine:
             # d_center rows the forward pass already materialized.
             Bl = centers.shape[0]
             drank = lax.axis_index(DATA_AXIS)
-            interp = pm == 2
             a32 = alpha.astype(jnp.float32)
             cen_g = lax.all_gather(centers, DATA_AXIS, tiled=True)
             if self.shared_negatives:
@@ -580,7 +632,7 @@ class EmbeddingEngine:
                 )
                 fw = pallas_sgns.pair_forward_shared(
                     syn0_l, syn1_l, centers, contexts, mask, pool, a32,
-                    n, interpret=interp,
+                    n, interpret=True,
                 )
                 cpos_g = lax.all_gather(fw.c_pos, DATA_AXIS, tiled=True)
                 h_g = lax.all_gather(fw.h, DATA_AXIS, tiled=True)
@@ -592,10 +644,10 @@ class EmbeddingEngine:
                 P = cen_g.shape[0]
                 syn1_l = pallas_sgns.scatter_add_rank1_hbm(
                     syn1_l, ctx_g, cpos_g, h_g,
-                    jnp.arange(P, dtype=jnp.int32), interpret=interp,
+                    jnp.arange(P, dtype=jnp.int32), interpret=True,
                 )
                 syn1_l = pallas_sgns.scatter_add_rows_f32(
-                    syn1_l, pool, dpool_g, interpret=interp
+                    syn1_l, pool, dpool_g, interpret=True
                 )
             else:
                 # Per-pair negatives, keyed by GLOBAL pair row — the
@@ -609,7 +661,7 @@ class EmbeddingEngine:
                 )
                 fw = pallas_sgns.pair_forward(
                     syn0_l, syn1_l, centers, contexts, mask,
-                    negs[:, 0, :], nmask[:, 0, :], a32, interpret=interp,
+                    negs[:, 0, :], nmask[:, 0, :], a32, interpret=True,
                 )
                 cpos_g = lax.all_gather(fw.c_pos, DATA_AXIS, tiled=True)
                 cneg_g = lax.all_gather(fw.c_neg, DATA_AXIS, tiled=True)
@@ -627,10 +679,10 @@ class EmbeddingEngine:
                     jnp.concatenate([cpos_g, cneg_g.reshape(-1)]),
                     h_g,
                     jnp.concatenate([rows_p, jnp.repeat(rows_p, n)]),
-                    interpret=interp,
+                    interpret=True,
                 )
             syn0_l = pallas_sgns.scatter_add_rows_f32(
-                syn0_l, cen_g, dcen_g, interpret=interp
+                syn0_l, cen_g, dcen_g, interpret=True
             )
             # Same global masked-mean as the composed body: the kernel
             # returns the SUM form directly.
@@ -1174,12 +1226,10 @@ class EmbeddingEngine:
             if dims:
                 # Partial dot over local columns -> psum: exactly the
                 # reference servers' partial-dot-product contract.
-                return lax.psum(
-                    table_l.astype(jnp.float32) @ _local_cols(v), MODEL_AXIS
-                )
+                return lax.psum(_score(table_l, _local_cols(v)), MODEL_AXIS)
             # Distributed matvec: each shard scores its own rows (the TP
             # matvec noted in SURVEY.md §2.3); output model-sharded.
-            return table_l.astype(jnp.float32) @ v
+            return _score(table_l, v)
 
         self._multiply = shared_query_program("multiply", lambda: jax.jit(
             self._shard_map(
@@ -1220,8 +1270,7 @@ class EmbeddingEngine:
                     # cosine scores (replicated), then ranked. The psum
                     # moves V floats of scalars — never rows.
                     scores = lax.psum(
-                        table_l.astype(jnp.float32) @ _local_cols(v),
-                        MODEL_AXIS,
+                        _score(table_l, _local_cols(v)), MODEL_AXIS
                     )  # (V,)
                     inv, neg = _mask_terms(norms_l, 0, nq)
                     val, idx = lax.top_k(
@@ -1234,7 +1283,7 @@ class EmbeddingEngine:
                 # driver-side scan (mllib:601-617).
                 start = lax.axis_index(MODEL_AXIS) * Vs
                 kk = min(k, Vs)
-                scores = table_l.astype(jnp.float32) @ v
+                scores = _score(table_l, v)
                 inv, neg = _mask_terms(norms_l, start, nq)
                 val, idx = lax.top_k(scores * inv + neg, kk)
                 cand_val = lax.all_gather(val, MODEL_AXIS, tiled=True)
@@ -1265,7 +1314,7 @@ class EmbeddingEngine:
                         q, mrank * dcols, dcols, axis=1
                     )
                     scores = lax.psum(
-                        (table_l.astype(jnp.float32) @ q_l.T).T, MODEL_AXIS
+                        _score(table_l, q_l.T).T, MODEL_AXIS
                     )  # (Q, V)
                     inv, neg = _mask_terms(norms_l, 0, nq)
                     val, idx = lax.top_k(
@@ -1275,10 +1324,10 @@ class EmbeddingEngine:
                     return val, idx
                 # q: (Q, d) replicated query batch. Same candidate-merge
                 # scheme as the single-vector kernel, vectorized over Q —
-                # one MXU matmul scores all queries against this shard.
+                # one matmul scores all queries against this shard.
                 start = lax.axis_index(MODEL_AXIS) * Vs
                 kk = min(k, Vs)
-                scores = (table_l.astype(jnp.float32) @ q.T).T  # (Q, Vs)
+                scores = _score(table_l, q.T).T  # (Q, Vs)
                 inv, neg = _mask_terms(norms_l, start, nq)
                 val, idx = lax.top_k(
                     scores * inv[None, :] + neg[None, :], kk
@@ -1732,7 +1781,7 @@ class EmbeddingEngine:
             tuple(self.mesh.shape.items()),
             self.layout,
             str(self._dtype), str(self._compute_dtype),
-            self._pallas_mode, self._pallas_fused,
+            self._pallas_interpret, self._pallas_fused,
             self.num_negatives, self.shared_negatives,
             self.rows_per_shard, self.cols_per_shard,
             self.padded_vocab, self.padded_dim,
@@ -1753,7 +1802,7 @@ class EmbeddingEngine:
             tuple(self.mesh.shape.items()),
             self.layout,
             str(self._dtype),
-            self._pallas_mode,
+            self._pallas_interpret,
             self.rows_per_shard, self.cols_per_shard,
             self.padded_vocab, self.padded_dim, self.dim,
         )
@@ -3553,10 +3602,15 @@ class EmbeddingEngine:
             (0, self.padded_dim - self.dim),
         )
         tsh = self._table_sharding()
-        full0 = np.pad(syn0, pad).astype(np.float32)
-        full1 = np.pad(syn1, pad).astype(np.float32)
-        self.syn0 = jax.device_put(jnp.asarray(full0, dtype=self._dtype), tsh)
-        self.syn1 = jax.device_put(jnp.asarray(full1, dtype=self._dtype), tsh)
+
+        def put(host):
+            # Host array straight to its shards: going through
+            # jnp.asarray first would land the whole table on the
+            # default device.
+            full = np.pad(host, pad).astype(np.float32, copy=False)
+            return jax.device_put(full.astype(self._dtype, copy=False), tsh)
+
+        self.syn0, self.syn1 = put(syn0), put(syn1)
         self._tick_tables("set_tables")
 
     def resident_bytes(self) -> int:

@@ -502,9 +502,9 @@ class StreamTrainer:
         model.training_metrics = {
             **metrics.summary(),
             "pipeline": "stream",
-            # The stream drains through the packed pair scan, so it
-            # rides the fused Pallas megakernel whenever the engine does.
-            "pallas_fused": bool(getattr(engine, "_pallas_fused", False)),
+            # The stream drains through the packed pair scan.
+            "step_body": engine.step_body(True),
+            "pallas_mode": engine.pallas_mode,
             "rounds": self.rounds,
             "words_trained": self.words_trained,
             "vocab_size": sv.size,

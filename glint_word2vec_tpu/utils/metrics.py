@@ -46,6 +46,10 @@ class TrainingMetrics:
     #: Most recent per-step loss as an UNSYNCED device array; float()ed
     #: only at log points and in summary().
     _last_loss_lazy: Optional[object] = None
+    #: The first recorded step's loss, kept unsynced the same way: with
+    #: ``final_loss`` it says whether the run learned anything.
+    first_loss: Optional[float] = None
+    _first_loss_lazy: Optional[object] = None
     _t_start: float = field(default_factory=time.time)
     _t_window: float = field(default_factory=time.time)
     _words_window: int = -1  # sentinel: initialized on first record_step
@@ -70,6 +74,8 @@ class TrainingMetrics:
             # the dispatch pipeline, so it happens only at log points and
             # in summary() — never per step.
             self._last_loss_lazy = loss
+            if self.steps == 1:
+                self._first_loss_lazy = loss
         if self.steps % self.log_every == 0:
             now = time.time()
             wps = (words_done - self._words_window) / max(now - self._t_window, 1e-9)
@@ -140,6 +146,10 @@ class TrainingMetrics:
                     "stale): %s", self.last_loss, e,
                 )
             self._last_loss_lazy = None
+        if self._first_loss_lazy is not None:
+            # graftlint: ignore[sync-point] end-of-fit sync of a loss the device finished long ago
+            self.first_loss = float(self._first_loss_lazy)
+            self._first_loss_lazy = None
         return {
             "steps": self.steps,
             "words_done": self.words_done,
@@ -148,6 +158,7 @@ class TrainingMetrics:
             "host_time": round(self.host_time, 2),
             "step_time": round(self.step_time, 2),
             "device_stall_seconds": round(self.stall_time, 3),
+            "first_loss": self.first_loss,
             "final_loss": self.last_loss,
         }
 

@@ -316,9 +316,6 @@ def main() -> int:
 
     quality = {}
     if checks["completed"]:
-        from glint_word2vec_tpu.utils.platform import force_platform
-
-        force_platform()
         from glint_word2vec_tpu import load_model
 
         m = load_model(model_dir)

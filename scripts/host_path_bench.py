@@ -1,8 +1,8 @@
 """Measure the host data path end-to-end (round-3 directive #6).
 
-The chip consumes ~4M+ trained words/sec (BENCH_r03), so the host pipeline
-— subsample + shrunk-window context/mask generation + batch assembly —
-must sustain at least that to keep a real ``fit_file()`` device-bound
+The host pipeline — subsample + shrunk-window context/mask generation +
+batch assembly — must outrun the chip's step rate (not measured on the
+current code) to keep a host-batched ``fit_file()`` device-bound
 (SURVEY.md §7 hard part 5). This measures, on this machine:
 
   * native epoch pass (C++ window_batch_epoch, native/host_ops.cpp)

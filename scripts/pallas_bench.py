@@ -3,9 +3,10 @@
 The decision record VERDICT asked for: per-hardware step times for the
 sparse row traffic (gather / scatter-add) and the full fused train step
 with the engine's ``use_pallas`` flag off vs on. The winner should be the
-engine default; the loser stays opt-in. Run on the real TPU when available:
+engine default; the loser stays opt-in. Today only the CPU form runs: the
+TPU's compiler refuses every kernel (engine.PALLAS_TPU_REFUSAL,
+tests/test_tpu_compile.py), and ``use_pallas`` raises on a tpu backend.
 
-    python scripts/pallas_bench.py            # current default backend
     GLINT_PB_PLATFORM=cpu python scripts/pallas_bench.py   # CPU (interpret)
 
 Prints one JSON line per measurement and a final summary line, and

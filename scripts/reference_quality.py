@@ -88,10 +88,6 @@ def _mean_sd(xs):
 
 
 def run(corpus: str, out_path: str, n_seeds: int = 5) -> dict:
-    from glint_word2vec_tpu.utils.platform import force_platform
-
-    force_platform()
-
     from glint_word2vec_tpu import Word2Vec
     from glint_word2vec_tpu.eval import evaluate_analogies
     from glint_word2vec_tpu.parallel.mesh import make_mesh

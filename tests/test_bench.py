@@ -1,10 +1,11 @@
-"""Unit tests for bench.py's partial-salvage orchestration (round-5
-hardening): merging per-attempt flush files, headline protection, and
-mask-density-scaled FLOPs accounting."""
+"""bench.py's contract with the device (it measures an accelerator or
+fails: no CPU fallback, no unknown peak, a JAX-free parent), its
+mask-density-scaled FLOPs accounting, and the trace summarizer."""
 
 import importlib.util
-import json
 import os
+import subprocess
+import sys
 
 import pytest
 
@@ -17,51 +18,49 @@ bench = importlib.util.module_from_spec(spec)
 spec.loader.exec_module(bench)
 
 
-def _write(path, modes):
-    with open(path, "w") as f:
-        json.dump(
-            {"platform": "tpu", "device_kind": "v5e", "config": {},
-             "modes": modes},
-            f,
-        )
+def test_peak_table_is_keyed_by_exact_device_kind():
+    assert bench._peak_for("TPU v5 lite", "bfloat16") == 197e12
+    assert bench._peak_for("TPU v5 lite", "float32") == 197e12 / 2
+    # No substring match ("v5" once also caught v5p) and no default: a
+    # device without a published peak on record is an error.
+    for kind in ("TPU v5", "TPU v5p", "cpu", "TPU v9 lite"):
+        with pytest.raises(ValueError, match="no published peak"):
+            bench._peak_for(kind, "bfloat16")
 
 
-def test_salvage_merges_attempts_finished_mode_wins(tmp_path):
-    a1 = str(tmp_path / "p.a1")
-    a2 = str(tmp_path / "p.a2")
-    _write(a1, {"per_pair": {"words_per_sec": 100.0}})
-    # Retry died fast: error entry for the same mode must NOT clobber
-    # the default attempt's finished measurement.
-    _write(a2, {"per_pair": {"error": "dead tunnel"},
-                "shared": {"words_per_sec": 50.0}})
-    out = bench._salvage_partial([a1, a2], [], require_per_pair=True)
-    assert out is not None
-    assert out["value"] == 100.0
-    assert out["estimator"] == "per_pair"
-    assert out["salvaged_partial"] is True
-    assert out["modes"]["shared"]["words_per_sec"] == 50.0
+def test_bench_fails_without_an_accelerator():
+    """No CPU fallback, no salvaged line, no exit 0: with JAX held to the
+    CPU (tests/conftest.py exports JAX_PLATFORMS=cpu) the one worker
+    raises and bench.py passes its failure on."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "bench.py")],
+        capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, BENCH_MODES="per_pair", BENCH_VOCAB="1000"),
+    )
+    assert proc.returncode != 0
+    assert "found only the CPU" in proc.stderr
+    assert '"metric"' not in proc.stdout
 
 
-def test_salvage_declines_without_headline_mode(tmp_path):
-    a1 = str(tmp_path / "p.a1")
-    # Only a non-comparable estimator finished; with per_pair requested
-    # the salvage must decline (same protection the worker enforces by
-    # raising) so the orchestrator falls through to the CPU fallback.
-    _write(a1, {"shared": {"words_per_sec": 50.0},
-                "per_pair": {"error": "OOM"}})
-    assert bench._salvage_partial([a1], [], require_per_pair=True) is None
-    out = bench._salvage_partial([a1], [], require_per_pair=False)
-    assert out is not None and out["estimator"] == "shared"
-
-
-def test_salvage_handles_missing_and_garbage_files(tmp_path):
-    missing = str(tmp_path / "nope")
-    garbage = str(tmp_path / "bad")
-    with open(garbage, "w") as f:
-        f.write("not json{")
-    assert bench._salvage_partial(
-        [missing, garbage], [], require_per_pair=False
-    ) is None
+def test_bench_parent_stays_off_jax():
+    """The chip belongs to one process: the parent that starts the
+    worker must never import JAX (or the package, which does)."""
+    code = (
+        "import runpy, sys, subprocess\n"
+        "subprocess.run = lambda *a, **k: type('P', (), {'returncode': 0})()\n"
+        "try:\n"
+        f"    runpy.run_path({os.path.join(ROOT, 'bench.py')!r}, run_name='__main__')\n"
+        "except SystemExit as e:\n"
+        "    assert e.code == 0, e.code\n"
+        "assert 'jax' not in sys.modules, 'bench.py parent imported jax'\n"
+        "assert 'glint_word2vec_tpu' not in sys.modules\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "BENCH_WORKER"}
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=60, env=env,
+    )
+    assert proc.returncode == 0, proc.stderr
 
 
 def test_flops_scale_with_measured_mask_density():

@@ -16,7 +16,7 @@ Contracts pinned here:
   * ENGINE SELECTION — pallas engines ride the fused path for the pair
     form on data-parallel meshes and match the composed engine's
     tables; model-sharded meshes fall back to the composed step.
-  * FIT INTEGRATION — a fused packed fit reports ``pallas_fused`` and a
+  * FIT INTEGRATION — a fused packed fit reports its ``step_body`` and a
     mid-epoch checkpoint/resume reproduces the uninterrupted fused run
     bit-for-bit (slow; the pallas-interpret CI leg runs it).
 """
@@ -332,7 +332,7 @@ def test_bf16_pallas_row_scatter_gets_f32_dup_sums():
     tb = jnp.asarray(table, dtype=jnp.bfloat16)
     ids = jnp.full((8,), 5, jnp.int32)
     upd = jnp.full((8, D), 0.5, jnp.float32)
-    out = _scatter_rows(tb, ids, upd, 0, V, pallas_mode=2)
+    out = _scatter_rows(tb, ids, upd, 0, V, pallas=True)
     np.testing.assert_array_equal(
         np.asarray(out[5], np.float32), np.full(D, 260.0, np.float32)
     )
@@ -404,7 +404,7 @@ def test_engine_fused_shared_pool_matches_composed():
 @pytest.mark.slow
 def test_engine_fused_falls_back_when_model_sharded():
     eng = _mk_engine((2, 4), use_pallas=True)
-    assert eng._pallas_mode == 2 and not eng._pallas_fused
+    assert eng._pallas_interpret and not eng._pallas_fused
     ref = _mk_engine((1, 1))
     _run_packed(ref)
     _run_packed(eng)  # composed path, still correct
@@ -418,7 +418,7 @@ def test_engine_fused_falls_back_when_model_sharded():
 def test_engine_fused_env_escape_hatch(monkeypatch):
     monkeypatch.setenv("GLINT_W2V_PALLAS_FUSED", "0")
     eng = _mk_engine((1, 1), use_pallas=True)
-    assert eng._pallas_mode == 2 and not eng._pallas_fused
+    assert eng._pallas_interpret and not eng._pallas_fused
 
 
 # ---------------- fit integration (pallas-interpret CI leg) -------------
@@ -449,7 +449,8 @@ def test_fused_fit_reports_and_learns(monkeypatch):
     tm = m.training_metrics
     assert tm["pipeline"] == "device_corpus"
     assert tm["batch_packing"] == "dense"
-    assert tm["pallas_fused"] is True
+    assert tm["step_body"] == "rows/per_pair/pallas_fused"
+    assert tm["pallas_mode"] == "interpret"
     assert tm["packed_mask_density"] >= 0.9
     assert len(m.find_synonyms("quick", 3)) == 3
 
@@ -469,7 +470,7 @@ def test_fused_fit_mid_epoch_resume_bit_parity(tmp_path, monkeypatch):
     assert state["position"] > 0 and state["batch_packing"] == "dense"
     m_resumed = _w2v().fit(CORPUS, checkpoint_dir=ck)
     m_full = _w2v().fit(CORPUS)
-    assert m_resumed.training_metrics["pallas_fused"] is True
+    assert m_resumed.training_metrics["step_body"].endswith("pallas_fused")
     np.testing.assert_array_equal(
         np.asarray(m_resumed.engine.syn0, np.float32),
         np.asarray(m_full.engine.syn0, np.float32),

@@ -1,0 +1,317 @@
+"""The main path's programs, put to the TPU v5e's own compiler at 1M x 300.
+
+Nothing here runs: the chip is *described* (``v5e:2x2``), programs are
+lowered from ``ShapeDtypeStruct``s and compiled by the installed TPU
+compiler, which raises what the chip's compiler would raise — a kernel it
+refuses, a program that does not fit 16 GB. A compile that passes is not a
+chip run and is never reported as one; ``python chip_smoke.py`` is the run.
+
+* The XLA programs ``chip_smoke.py`` drives (the packed corpus scan with
+  per-pair and shared-pool negatives, ``subsample_compact``, the query
+  family serving warm-up compiles) must compile and fit, on one chip and,
+  for the sharded path, on the 1x4 mesh of ``chip_smoke.py --chips 4``.
+* Every public Pallas entry point is a strict xfail carrying the compiler's
+  own words: today all are refused (so ``use_pallas`` raises on a tpu
+  backend, engine.PALLAS_TPU_REFUSAL). The day one is repaired its mark
+  must come off.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU's library, and every xdist worker imports
+every test file. Keep these tests in this one file for the same reason.
+"""
+
+import numpy as np
+import pytest
+
+V, D, NEG = 1_000_000, 300, 5
+# chip_smoke.py's training geometry (bench.py's headline shape).
+BATCH, WINDOW, STEPS_PER_CALL = 8192, 5, 32
+CORPUS_WORDS, CORPUS_SENTENCES = 4_000_000, 100_000
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def engines(topo):
+    """Engines over DESCRIBED devices, built once per (chips, pool):
+    ``_configure`` derives the geometry and ``_build_jitted_fns`` the
+    programs; the placing half of the constructor is skipped because a
+    described device holds no array."""
+    from jax.sharding import Mesh
+
+    from glint_word2vec_tpu.parallel.engine import EmbeddingEngine
+
+    built = {}
+
+    def get(chips: int, shared_negatives: int = 0):
+        key = (chips, shared_negatives)
+        if key not in built:
+            mesh = Mesh(
+                np.asarray(topo.devices[:chips]).reshape(1, chips),
+                ("data", "model"),
+            )
+            eng = EmbeddingEngine.__new__(EmbeddingEngine)
+            eng._configure(
+                mesh, V, D, num_negatives=NEG, unigram_power=0.75,
+                unigram_table_size=None, seed=1, dtype="float32",
+                extra_rows=0, shared_negatives=shared_negatives,
+                use_pallas=False, compute_dtype=None, layout="rows",
+            )
+            eng._build_jitted_fns()
+            built[key] = eng
+        return built[key]
+
+    return get
+
+
+def _shapes(eng):
+    """``sds(shape, dtype, *spec)``: an abstract array sharded over the
+    engine's mesh (replicated with no spec)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(eng.mesh, P(*spec))
+        )
+
+    return sds
+
+
+def _fits(compiled, chips: int = 1) -> dict:
+    """Per-device bytes of one compiled program against the chip's HBM
+    (it counts this program alone, not what else the process holds)."""
+    m = compiled.memory_analysis()
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+    mem = {"total": total, "args": m.argument_size_in_bytes,
+           "temp": m.temp_size_in_bytes,
+           "aliased": m.alias_size_in_bytes}
+    print(f"memory_analysis per device ({chips} chip(s)): {mem}")  # -s shows it
+    assert total < HBM_BYTES, mem
+    return mem
+
+
+@pytest.mark.parametrize(
+    "chips,shared_negatives",
+    [(1, 0), (1, 4096), (4, 0)],
+    ids=["1chip-per_pair", "1chip-shared_pool", "4chips-per_pair"],
+)
+def test_packed_corpus_scan_compiles(engines, chips, shared_negatives):
+    import jax.numpy as jnp
+
+    from glint_word2vec_tpu.corpus.batching import (
+        context_width,
+        packed_pair_batch,
+    )
+
+    eng = engines(chips, shared_negatives)
+    sds = _shapes(eng)
+    P_ = packed_pair_batch(BATCH, WINDOW, 1)
+    span = -(-3 * P_ // context_width(WINDOW))
+    fn = eng._make_packed_corpus_scan(
+        P_, WINDOW, BATCH, span, STEPS_PER_CALL
+    )
+    table = sds((eng.padded_vocab, D), jnp.float32, "model", None)
+    offs = sds((CORPUS_SENTENCES + 1,), jnp.int32)
+    i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
+    compiled = fn.lower(
+        table, table, sds((V,), jnp.float32), sds((V,), jnp.int32),
+        sds((CORPUS_WORDS,), jnp.int32), offs, offs, i32, i32,
+        sds((2,), jnp.uint32), u32, u32, f32, f32, f32,
+    ).compile()
+    mem = _fits(compiled, chips)
+    # The tables are donated: the program must not hold a second pair.
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        2 * eng.rows_per_shard * D * 4
+    ), mem
+    if chips > 1:
+        # Rows really are spread: each device is handed 1/chips of them.
+        assert mem["args"] < 2 * V * D * 4 / chips + 64 * 10**6, mem
+        assert "all-reduce" in compiled.as_text()
+
+
+def test_subsample_compact_compiles(engines):
+    import jax
+    import jax.numpy as jnp
+
+    from glint_word2vec_tpu.ops.device_batching import subsample_compact
+
+    sds = _shapes(engines(1))
+    compiled = jax.jit(subsample_compact).lower(
+        sds((CORPUS_WORDS,), jnp.int32),
+        sds((CORPUS_SENTENCES + 1,), jnp.int32),
+        sds((V,), jnp.float32), sds((2,), jnp.uint32),
+    ).compile()
+    _fits(compiled)
+
+
+# The serving warm-up family (ModelServer defaults: Q buckets 1..64, k
+# buckets 16/32, sentence grid 16 x 64): its corners, not all ~50 shapes —
+# the single-query top-k alone takes the compiler half a minute at V=1M.
+@pytest.mark.parametrize(
+    "chips,op,shape",
+    [
+        (1, "topk", (16,)),
+        (1, "topk_batch", (1, 16)),
+        (1, "topk_batch", (64, 32)),
+        (4, "topk_batch", (8, 32)),
+        (1, "pull", (64,)),
+        (1, "pull_average", (16, 64)),
+        (4, "pull_average", (16, 64)),
+        (1, "norms", ()),
+    ],
+    ids=lambda v: (
+        v if isinstance(v, str)
+        else f"{v}chip" if isinstance(v, int)
+        else "x".join(map(str, v)) or "-"
+    ),
+)
+def test_query_program_compiles(engines, chips, op, shape):
+    import jax.numpy as jnp
+
+    eng = engines(chips)
+    sds = _shapes(eng)
+    table = sds((eng.padded_vocab, D), jnp.float32, "model", None)
+    norms = sds((eng.padded_vocab,), jnp.float32, "model")
+    nq = sds((), jnp.int32)
+    if op == "topk":
+        lowered = eng._make_topk(shape[0]).lower(
+            table, sds((D,), jnp.float32), norms, nq
+        )
+    elif op == "topk_batch":
+        lowered = eng._make_topk_batch(shape[1]).lower(
+            table, sds((shape[0], D), jnp.float32), norms, nq
+        )
+    elif op == "pull":
+        lowered = eng._pull.lower(table, sds(shape, jnp.int32))
+    elif op == "pull_average":
+        lowered = eng._pull_average.lower(
+            table, sds(shape, jnp.int32), sds(shape, jnp.float32)
+        )
+    else:
+        lowered = eng._norms.lower(table)
+    _fits(lowered.compile(), chips)
+
+
+# ----------------------------------------------------------------------
+# Pallas entry points: the compiler's verdicts, kept loud
+# ----------------------------------------------------------------------
+
+# Few pairs: the verdicts depend on d and the table, not on the batch.
+P_PAIRS, POOL = 256, 1024
+# (exception class name, the compiler's words: any one of them, the reason)
+_ROW_DMA = (
+    "MosaicError",
+    ("Slice shape along dimension 0 must be aligned to tiling (8), but is 1",
+     # the bf16 scatters
+     "cannot statically prove that index in dimension 0 is a multiple of 8"),
+    "the per-row make_async_copy of a (1, d) slice of the tiled HBM table "
+    "is not a legal DMA",
+)
+_SCALAR_STORE = ("ValueError", ("Cannot store scalars to VMEM",), "")
+_NO_SCATTER = (
+    "NotImplementedError",
+    ("Unimplemented primitive in Pallas TPU lowering for KernelType.TC: "
+     "scatter",),
+    "",
+)
+_PALLAS_VERDICTS = {
+    "pallas_rows.gather_rows": _ROW_DMA,
+    "pallas_rows.scatter_add_rank1": _ROW_DMA,
+    "pallas_rows.scatter_add_rows": _ROW_DMA,
+    "pallas_sgns.pair_forward": _SCALAR_STORE,
+    "pallas_sgns.pair_forward_shared": _NO_SCATTER,
+    "pallas_sgns.scatter_add_rows_f32": _ROW_DMA,
+    "pallas_sgns.scatter_add_rank1_hbm": _ROW_DMA,
+    # The two fused steps only compose the above; each dies in its
+    # forward kernel.
+    "pallas_sgns.fused_pair_step": _SCALAR_STORE,
+    "pallas_sgns.fused_pair_step_shared": _NO_SCATTER,
+}
+
+
+def _pallas_call(name: str, table_dtype, sds):
+    """(fn, abstract args) for one public Pallas entry point at d=300."""
+    import jax.numpy as jnp
+
+    from glint_word2vec_tpu.ops import pallas_rows, pallas_sgns
+
+    mod, fn_name = name.split(".")
+    fn = getattr({"pallas_rows": pallas_rows,
+                  "pallas_sgns": pallas_sgns}[mod], fn_name)
+    t = sds((V, D), table_dtype)
+    n_upd = P_PAIRS * (1 + NEG)
+    pairs_i, pairs_f = sds((P_PAIRS,), jnp.int32), sds((P_PAIRS,), jnp.float32)
+    upd_i, upd_f = sds((n_upd,), jnp.int32), sds((n_upd,), jnp.float32)
+    h = sds((P_PAIRS, D), jnp.float32)
+    negs = sds((P_PAIRS, NEG), jnp.int32)
+    nmask = sds((P_PAIRS, NEG), jnp.float32)
+    alpha = sds((), jnp.float32)
+    pool = sds((POOL,), jnp.int32)
+    per_pair = (t, t, pairs_i, pairs_i, pairs_f, negs, nmask, alpha)
+    shared = (t, t, pairs_i, pairs_i, pairs_f, pool, alpha)
+    args = {
+        "gather_rows": (t, pairs_i),
+        "scatter_add_rank1": (t, upd_i, upd_f, h, upd_i),
+        "scatter_add_rows": (t, pairs_i, sds((P_PAIRS, D), table_dtype)),
+        "pair_forward": per_pair,
+        "pair_forward_shared": shared,
+        "scatter_add_rows_f32": (t, pairs_i, h),
+        "scatter_add_rank1_hbm": (t, upd_i, upd_f, h, upd_i),
+        "fused_pair_step": per_pair,
+        "fused_pair_step_shared": shared,
+    }[fn_name]
+    if fn_name.endswith("_shared"):
+        return (lambda *a: fn(*a, NEG)), args
+    return fn, args
+
+
+class CompilerRefused(Exception):
+    """The chip's compiler refused the kernel in the words on record. Any
+    other exception (a wrongly built argument list, a new refusal) is not
+    this one, so it fails the strict xfail instead of satisfying it."""
+
+
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "name",
+    [
+        pytest.param(name, marks=pytest.mark.xfail(
+            strict=True, raises=CompilerRefused,
+            reason=f"{kind}: {' / '.join(words)}"
+            + (f" — {why}" if why else ""),
+        ))
+        for name, (kind, words, why) in _PALLAS_VERDICTS.items()
+    ],
+)
+def test_pallas_entry_point_compiles(engines, name, table_dtype):
+    import jax
+    import jax.numpy as jnp
+
+    fn, args = _pallas_call(
+        name, jnp.dtype(table_dtype), _shapes(engines(1))
+    )
+    kind, words, _ = _PALLAS_VERDICTS[name]
+    try:
+        jax.jit(fn).lower(*args).compile()
+    except Exception as e:
+        if type(e).__name__ == kind and any(w in str(e) for w in words):
+            raise CompilerRefused(f"{kind}: {e}".splitlines()[0]) from e
+        raise
