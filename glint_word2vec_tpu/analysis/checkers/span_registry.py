@@ -4,7 +4,7 @@ registered span must have at least one live call site.
 
 The end-to-end trace (ISSUE 18) is only stitchable because the balancer,
 the replica request threads, and the coalescer leader all tag their
-phases with the SAME eight names; ``scripts/trace_summarize.py`` and the
+phases with the SAME registered names; ``scripts/trace_summarize.py`` and the
 Perfetto track grouping key on them. A typo'd name at one hop would
 silently drop that phase from every per-span latency rollup. The
 registry (name -> docstring) is the single source of truth; this checker

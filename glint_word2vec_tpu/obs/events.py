@@ -7,7 +7,10 @@ mutations, query-shape compiles) record spans and instant events into a
 thread-safe bounded ring with an optional JSONL sink, and the ring
 re-exports as a Chrome-trace (``chrome://tracing`` / Perfetto) JSON so
 host spans can be eyeballed against the device xplane traces
-``scripts/trace_summarize.py`` parses.
+``scripts/trace_summarize.py`` parses. In a process that has JAX loaded,
+every ring-direct span is also a ``jax.profiler.TraceAnnotation``
+(``glint.<name>``, see :class:`_Span`): a profiler capture shows the
+spans beside the device's ops, on its own clock.
 
 Three usage layers:
 
@@ -36,6 +39,7 @@ import itertools
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -56,7 +60,11 @@ REQUEST_SPANS = {
     "req.admission": "admission gate: inflight-slot acquire or shed",
     "req.queue": "coalescer queue wait, enqueue to leader drain",
     "req.hop": "balancer -> replica proxy attempt (one per retry hop)",
+    "req.grace": "coalescer leader's straggler-absorbing sleeps before a "
+                 "dispatch (args: batch before and after)",
     "req.dispatch": "warm-bucket device dispatch of one coalesced batch",
+    "req.pull": "the dispatch's row pull, device program and read-back "
+                "(child of req.dispatch; args: rows)",
     "req.query": "engine query path (args carry mode=ann|exact)",
     "req.readback": "device result harvest / host materialization",
     "req.serialize": "response serialization + socket write",
@@ -108,7 +116,16 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_rec", "_name", "_args", "_t0")
+    """A ring-direct span. While it is open it is also a
+    ``jax.profiler.TraceAnnotation`` named ``glint.<name>`` whose
+    ``t0_us`` stat is the ``ts`` its ring event will carry: inside a
+    profiler capture every such annotation is one reading of (trace
+    clock - ring clock), which puts the whole ring, the request phases
+    stamped after the fact included, on the device trace's clock. JAX is
+    taken from ``sys.modules``: a process that never imported it (the
+    balancer) records its spans and annotates nothing."""
+
+    __slots__ = ("_rec", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, rec: "EventRecorder", name: str, args: dict):
         self._rec = rec
@@ -117,10 +134,20 @@ class _Span:
 
     def __enter__(self):
         self._t0 = time.perf_counter()
+        jax = sys.modules.get("jax")
+        if jax is None:
+            self._ann = None
+        else:
+            self._ann = jax.profiler.TraceAnnotation(
+                "glint." + self._name, t0_us=self._rec._ts(self._t0)
+            )
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._rec._record(self._name, "X", self._t0, t1 - self._t0, self._args)
         return False
 
@@ -352,12 +379,16 @@ class EventRecorder:
         self.sink_rotations += 1
         self._write_anchor_locked()
 
+    def _ts(self, t: float) -> float:
+        """``perf_counter`` seconds -> this recorder's ``ts``."""
+        return round((t - self._t0) * 1e6, 1)
+
     def _record(self, name: str, ph: str, t0: float, dur: float,
                 args: dict) -> None:
         ev = {
             "name": name,
             "ph": ph,
-            "ts": round((t0 - self._t0) * 1e6, 1),
+            "ts": self._ts(t0),
             "pid": os.getpid(),
             "tid": threading.get_ident(),
         }

@@ -713,20 +713,33 @@ class EmbeddingEngine:
             start = lax.axis_index(MODEL_AXIS) * Vs
             drank = lax.axis_index(DATA_AXIS)
 
-            h_rows = _pull_rows(syn0_l, centers.reshape(-1), start, Vs, pm)
-            h_rows = h_rows.reshape(Bl, S, -1)
-            cnt = jnp.maximum(cmask.sum(axis=1, keepdims=True), 1.0)  # (Bl,1)
-            h = (h_rows * cmask[..., None]).sum(axis=1) / cnt
-            u_pos = _pull_rows(syn1_l, contexts.reshape(-1), start, Vs, pm)
-            u_pos = u_pos.reshape(Bl, C, -1)
+            # The glint.* scopes (here, in step_body_dims and in the packed
+            # scan's body) name the step's five phases in every op's
+            # metadata, and nothing else: the device trace is split by
+            # them (benchmark/program_trace.py). A fusion is filed under
+            # its root's scope.
+            with jax.named_scope("glint.gather"):
+                h_rows = _pull_rows(
+                    syn0_l, centers.reshape(-1), start, Vs, pm
+                )
+                h_rows = h_rows.reshape(Bl, S, -1)
+                cnt = jnp.maximum(
+                    cmask.sum(axis=1, keepdims=True), 1.0
+                )  # (Bl,1)
+                h = (h_rows * cmask[..., None]).sum(axis=1) / cnt
+                u_pos = _pull_rows(
+                    syn1_l, contexts.reshape(-1), start, Vs, pm
+                )
+                u_pos = u_pos.reshape(Bl, C, -1)
 
-            # The data-axis exchange ships ONLY h (B, d), scalar gradient
-            # coefficients, and int32 indices — the TPU restatement of the
-            # reference's defining ship-scalars property (gPlus/gMinus,
-            # mllib:422-425). The O(B*C*(1+n)*d) rank-1 payloads are never
-            # exchanged: every consuming shard re-forms coef x h outer
-            # products locally, where XLA fuses them into the scatter-add.
-            h_g = lax.all_gather(h, DATA_AXIS, tiled=True)  # (B, d)
+                # The data-axis exchange ships ONLY h (B, d), scalar
+                # gradient coefficients, and int32 indices — the TPU
+                # restatement of the reference's defining ship-scalars
+                # property (gPlus/gMinus, mllib:422-425). The
+                # O(B*C*(1+n)*d) rank-1 payloads are never exchanged: every
+                # consuming shard re-forms coef x h outer products locally,
+                # where XLA fuses them into the scatter-add.
+                h_g = lax.all_gather(h, DATA_AXIS, tiled=True)  # (B, d)
 
             if self.shared_negatives:
                 # Shared-pool mode: ONE pool of P negatives per step,
@@ -734,79 +747,107 @@ class EmbeddingEngine:
                 # mesh-invariance contract needs no slicing here), scored
                 # and updated by dense MXU matmuls instead of B*C*n sparse
                 # row accesses (ops.sgns.shared_sgns_grads).
-                pool = sample_negatives(
-                    key, prob, alias, (self.shared_negatives,)
-                )
-                u_pool = _pull_rows(syn1_l, pool, start, Vs, pm)
-                collide = sgns.pool_collision_mask(pool, contexts, mask)
-                g = sgns.shared_sgns_grads(
-                    h, u_pos, u_pool, mask, collide,
-                    alpha.astype(jnp.float32), n,
-                    compute_dtype=self._compute_dtype,
-                )
-                # The pool update sums contributions from every data rank;
-                # after the psum it is identical everywhere, so each model
-                # shard applies its owned slice exactly once per replica.
-                d_pool = lax.psum(g.d_pool, DATA_AXIS)
-                ids1 = lax.all_gather(
-                    contexts.reshape(-1), DATA_AXIS, tiled=True
-                )
-                cpos_g = lax.all_gather(g.c_pos, DATA_AXIS, tiled=True)
-                d_upos = cpos_g[..., None] * h_g[:, None, :]
-                ids1_g = jnp.concatenate([ids1, pool])
-                upd1_g = jnp.concatenate(
-                    [d_upos.reshape(-1, d_upos.shape[-1]), d_pool]
-                )
+                with jax.named_scope("glint.sample"):
+                    pool = sample_negatives(
+                        key, prob, alias, (self.shared_negatives,)
+                    )
+                with jax.named_scope("glint.gather"):
+                    u_pool = _pull_rows(syn1_l, pool, start, Vs, pm)
+                with jax.named_scope("glint.sample"):
+                    collide = sgns.pool_collision_mask(pool, contexts, mask)
+                with jax.named_scope("glint.grads"):
+                    g = sgns.shared_sgns_grads(
+                        h, u_pos, u_pool, mask, collide,
+                        alpha.astype(jnp.float32), n,
+                        compute_dtype=self._compute_dtype,
+                    )
+                    # The pool update sums contributions from every data
+                    # rank; after the psum it is identical everywhere, so
+                    # each model shard applies its owned slice exactly once
+                    # per replica.
+                    d_pool = lax.psum(g.d_pool, DATA_AXIS)
+                    ids1 = lax.all_gather(
+                        contexts.reshape(-1), DATA_AXIS, tiled=True
+                    )
+                    cpos_g = lax.all_gather(g.c_pos, DATA_AXIS, tiled=True)
+                with (jax.named_scope("glint.scatter"),
+                      jax.named_scope("syn1")):
+                    d_upos = cpos_g[..., None] * h_g[:, None, :]
+                    ids1_g = jnp.concatenate([ids1, pool])
+                    upd1_g = jnp.concatenate(
+                        [d_upos.reshape(-1, d_upos.shape[-1]), d_pool]
+                    )
             else:
                 # Per-pair mode (reference semantics): n fresh negatives
                 # per (center, context) pair, keyed by GLOBAL row index so
                 # draws are mesh-invariant while each rank samples only its
                 # own Bl rows (ops.sampling.sample_negatives_per_row).
-                rows_g = drank * Bl + jnp.arange(Bl, dtype=jnp.int32)
-                negs = sample_negatives_per_row(
-                    key, prob, alias, rows_g, (C, n)
-                )
-                u_neg = _pull_rows(syn1_l, negs.reshape(-1), start, Vs, pm)
-                u_neg = u_neg.reshape(Bl, C, n, -1)
-                nmask = sgns.negative_mask(negs, contexts, mask)
-                g = sgns.sgns_grads(h, u_pos, u_neg, mask, nmask,
-                                    alpha.astype(jnp.float32),
-                                    compute_dtype=self._compute_dtype)
+                with jax.named_scope("glint.sample"):
+                    rows_g = drank * Bl + jnp.arange(Bl, dtype=jnp.int32)
+                    negs = sample_negatives_per_row(
+                        key, prob, alias, rows_g, (C, n)
+                    )
+                with jax.named_scope("glint.gather"):
+                    u_neg = _pull_rows(
+                        syn1_l, negs.reshape(-1), start, Vs, pm
+                    )
+                    u_neg = u_neg.reshape(Bl, C, n, -1)
+                with jax.named_scope("glint.sample"):
+                    nmask = sgns.negative_mask(negs, contexts, mask)
+                with jax.named_scope("glint.grads"):
+                    g = sgns.sgns_grads(h, u_pos, u_neg, mask, nmask,
+                                        alpha.astype(jnp.float32),
+                                        compute_dtype=self._compute_dtype)
 
-                ctx_g = lax.all_gather(contexts, DATA_AXIS, tiled=True)
-                negs_g = lax.all_gather(negs, DATA_AXIS, tiled=True)
-                cpos_g = lax.all_gather(g.c_pos, DATA_AXIS, tiled=True)
-                cneg_g = lax.all_gather(g.c_neg, DATA_AXIS, tiled=True)
-                ids1_g = jnp.concatenate(
-                    [ctx_g.reshape(-1), negs_g.reshape(-1)]
-                )
-                # Fused Pallas scatter (payload formed in VMEM) when
-                # eligible, else consumer-side outer products; ownership
-                # masking for this rows layout via own_range.
-                syn1_l, upd1_g = _apply_rank1_updates(
-                    syn1_l, ids1_g, cpos_g, cneg_g, h_g, C, n, pm,
-                    own_range=(start, Vs),
-                )
+                    ctx_g = lax.all_gather(contexts, DATA_AXIS, tiled=True)
+                    negs_g = lax.all_gather(negs, DATA_AXIS, tiled=True)
+                    cpos_g = lax.all_gather(g.c_pos, DATA_AXIS, tiled=True)
+                    cneg_g = lax.all_gather(g.c_neg, DATA_AXIS, tiled=True)
+                with (jax.named_scope("glint.scatter"),
+                      jax.named_scope("syn1")):
+                    ids1_g = jnp.concatenate(
+                        [ctx_g.reshape(-1), negs_g.reshape(-1)]
+                    )
+                    # Fused Pallas scatter (payload formed in VMEM) when
+                    # eligible, else consumer-side outer products;
+                    # ownership masking for this rows layout via own_range.
+                    syn1_l, upd1_g = _apply_rank1_updates(
+                        syn1_l, ids1_g, cpos_g, cneg_g, h_g, C, n, pm,
+                        own_range=(start, Vs),
+                    )
 
             # The center gradient is distributed over the group's rows
             # (d mean / d row = 1/count): ship the (Bl, d) gradient + the
             # (Bl, S) group mask, expand to rows at the consumer.
-            dcen_g = lax.all_gather(g.d_center / cnt, DATA_AXIS, tiled=True)
-            cmask_g = lax.all_gather(cmask, DATA_AXIS, tiled=True)
-            ids0_g = lax.all_gather(centers.reshape(-1), DATA_AXIS, tiled=True)
-            upd0_g = (dcen_g[:, None, :] * cmask_g[..., None]).reshape(
-                -1, dcen_g.shape[-1]
-            )
-            syn0_l = _scatter_rows(syn0_l, ids0_g, upd0_g, start, Vs, pm)
-            if upd1_g is not None:
-                syn1_l = _scatter_rows(syn1_l, ids1_g, upd1_g, start, Vs, pm)
+            with jax.named_scope("glint.grads"):
+                dcen_g = lax.all_gather(
+                    g.d_center / cnt, DATA_AXIS, tiled=True
+                )
+                cmask_g = lax.all_gather(cmask, DATA_AXIS, tiled=True)
+                ids0_g = lax.all_gather(
+                    centers.reshape(-1), DATA_AXIS, tiled=True
+                )
+            with jax.named_scope("glint.scatter"):
+                with jax.named_scope("syn0"):
+                    upd0_g = (
+                        dcen_g[:, None, :] * cmask_g[..., None]
+                    ).reshape(-1, dcen_g.shape[-1])
+                    syn0_l = _scatter_rows(
+                        syn0_l, ids0_g, upd0_g, start, Vs, pm
+                    )
+                if upd1_g is not None:
+                    with jax.named_scope("syn1"):
+                        syn1_l = _scatter_rows(
+                            syn1_l, ids1_g, upd1_g, start, Vs, pm
+                        )
 
             # Masked-mean loss over the global batch.
-            denom = mask.sum()
-            loss_sum = g.loss * jnp.maximum(denom, 1.0)
-            loss = lax.psum(loss_sum, DATA_AXIS) / jnp.maximum(
-                lax.psum(denom, DATA_AXIS), 1.0
-            )
+            with jax.named_scope("glint.grads"):
+                denom = mask.sum()
+                loss_sum = g.loss * jnp.maximum(denom, 1.0)
+                loss = lax.psum(loss_sum, DATA_AXIS) / jnp.maximum(
+                    lax.psum(denom, DATA_AXIS), 1.0
+                )
             return syn0_l, syn1_l, loss
 
         def step_body_dims(syn0_l, syn1_l, prob, alias, centers, cmask,
@@ -824,114 +865,136 @@ class EmbeddingEngine:
             drank = lax.axis_index(DATA_AXIS)
             cd = self._compute_dtype
 
-            h_rows = syn0_l[centers.reshape(-1)].astype(jnp.float32)
-            h_rows = h_rows.reshape(Bl, S, -1)
-            cnt = jnp.maximum(cmask.sum(axis=1, keepdims=True), 1.0)
-            h = (h_rows * cmask[..., None]).sum(axis=1) / cnt  # (Bl, dl)
-            u_pos = syn1_l[contexts.reshape(-1)].astype(jnp.float32)
-            u_pos = u_pos.reshape(Bl, C, -1)
+            with jax.named_scope("glint.gather"):
+                h_rows = syn0_l[centers.reshape(-1)].astype(jnp.float32)
+                h_rows = h_rows.reshape(Bl, S, -1)
+                cnt = jnp.maximum(cmask.sum(axis=1, keepdims=True), 1.0)
+                h = (h_rows * cmask[..., None]).sum(axis=1) / cnt  # (Bl, dl)
+                u_pos = syn1_l[contexts.reshape(-1)].astype(jnp.float32)
+                u_pos = u_pos.reshape(Bl, C, -1)
 
-            h_g = lax.all_gather(h, DATA_AXIS, tiled=True)  # (B, dl)
+                h_g = lax.all_gather(h, DATA_AXIS, tiled=True)  # (B, dl)
 
             if self.shared_negatives:
-                pool = sample_negatives(
-                    key, prob, alias, (self.shared_negatives,)
-                )
-                u_pool = syn1_l[pool].astype(jnp.float32)  # (S, dl)
-                collide = sgns.pool_collision_mask(pool, contexts, mask)
-                f_pos = lax.psum(
-                    jnp.einsum(
-                        "bd,bcd->bc", h.astype(cd), u_pos.astype(cd),
-                        preferred_element_type=jnp.float32,
-                    ),
-                    MODEL_AXIS,
-                )
-                f_pool = lax.psum(
-                    jnp.dot(
-                        h.astype(cd), u_pool.astype(cd).T,
-                        preferred_element_type=jnp.float32,
-                    ),
-                    MODEL_AXIS,
-                )
-                co = sgns.shared_sgns_coefs(
-                    f_pos, f_pool, mask, collide,
-                    alpha.astype(jnp.float32), n,
-                )
-                d_center_l, d_pool_l = sgns.shared_sgns_updates(
-                    co.c_pos, co.c_pool, h, u_pos, u_pool, cd
-                )
-                d_pool_g = lax.psum(d_pool_l, DATA_AXIS)  # (S, dl)
-                ids1 = lax.all_gather(
-                    contexts.reshape(-1), DATA_AXIS, tiled=True
-                )
-                cpos_g = lax.all_gather(co.c_pos, DATA_AXIS, tiled=True)
-                d_upos = cpos_g[..., None] * h_g[:, None, :]
-                ids1_g = jnp.concatenate([ids1, pool])
-                upd1_g = jnp.concatenate(
-                    [d_upos.reshape(-1, d_upos.shape[-1]), d_pool_g]
-                )
+                with jax.named_scope("glint.sample"):
+                    pool = sample_negatives(
+                        key, prob, alias, (self.shared_negatives,)
+                    )
+                with jax.named_scope("glint.gather"):
+                    u_pool = syn1_l[pool].astype(jnp.float32)  # (S, dl)
+                with jax.named_scope("glint.sample"):
+                    collide = sgns.pool_collision_mask(pool, contexts, mask)
+                with jax.named_scope("glint.grads"):
+                    f_pos = lax.psum(
+                        jnp.einsum(
+                            "bd,bcd->bc", h.astype(cd), u_pos.astype(cd),
+                            preferred_element_type=jnp.float32,
+                        ),
+                        MODEL_AXIS,
+                    )
+                    f_pool = lax.psum(
+                        jnp.dot(
+                            h.astype(cd), u_pool.astype(cd).T,
+                            preferred_element_type=jnp.float32,
+                        ),
+                        MODEL_AXIS,
+                    )
+                    co = sgns.shared_sgns_coefs(
+                        f_pos, f_pool, mask, collide,
+                        alpha.astype(jnp.float32), n,
+                    )
+                    d_center_l, d_pool_l = sgns.shared_sgns_updates(
+                        co.c_pos, co.c_pool, h, u_pos, u_pool, cd
+                    )
+                    d_pool_g = lax.psum(d_pool_l, DATA_AXIS)  # (S, dl)
+                    ids1 = lax.all_gather(
+                        contexts.reshape(-1), DATA_AXIS, tiled=True
+                    )
+                    cpos_g = lax.all_gather(co.c_pos, DATA_AXIS, tiled=True)
+                with (jax.named_scope("glint.scatter"),
+                      jax.named_scope("syn1")):
+                    d_upos = cpos_g[..., None] * h_g[:, None, :]
+                    ids1_g = jnp.concatenate([ids1, pool])
+                    upd1_g = jnp.concatenate(
+                        [d_upos.reshape(-1, d_upos.shape[-1]), d_pool_g]
+                    )
                 loss_local = co.loss
             else:
-                rows_g = drank * Bl + jnp.arange(Bl, dtype=jnp.int32)
-                negs = sample_negatives_per_row(
-                    key, prob, alias, rows_g, (C, n)
-                )
-                u_neg = syn1_l[negs.reshape(-1)].astype(jnp.float32)
-                u_neg = u_neg.reshape(Bl, C, n, -1)
-                nmask = sgns.negative_mask(negs, contexts, mask)
-                f_pos = lax.psum(
-                    jnp.einsum(
-                        "bd,bcd->bc", h.astype(cd), u_pos.astype(cd),
-                        preferred_element_type=jnp.float32,
-                    ),
-                    MODEL_AXIS,
-                )
-                f_neg = lax.psum(
-                    jnp.einsum(
-                        "bd,bcnd->bcn", h.astype(cd), u_neg.astype(cd),
-                        preferred_element_type=jnp.float32,
-                    ),
-                    MODEL_AXIS,
-                )
-                co = sgns.sgns_coefs(
-                    f_pos, f_neg, mask, nmask, alpha.astype(jnp.float32)
-                )
-                d_center_l = sgns.sgns_d_center(
-                    co.c_pos, co.c_neg, u_pos, u_neg, cd
-                )
-                ctx_g = lax.all_gather(contexts, DATA_AXIS, tiled=True)
-                negs_g = lax.all_gather(negs, DATA_AXIS, tiled=True)
-                cpos_g = lax.all_gather(co.c_pos, DATA_AXIS, tiled=True)
-                cneg_g = lax.all_gather(co.c_neg, DATA_AXIS, tiled=True)
-                ids1_g = jnp.concatenate(
-                    [ctx_g.reshape(-1), negs_g.reshape(-1)]
-                )
-                # Every row is local under dims: no own_range masking.
-                syn1_l, upd1_g = _apply_rank1_updates(
-                    syn1_l, ids1_g, cpos_g, cneg_g, h_g, C, n, pm
-                )
+                with jax.named_scope("glint.sample"):
+                    rows_g = drank * Bl + jnp.arange(Bl, dtype=jnp.int32)
+                    negs = sample_negatives_per_row(
+                        key, prob, alias, rows_g, (C, n)
+                    )
+                with jax.named_scope("glint.gather"):
+                    u_neg = syn1_l[negs.reshape(-1)].astype(jnp.float32)
+                    u_neg = u_neg.reshape(Bl, C, n, -1)
+                with jax.named_scope("glint.sample"):
+                    nmask = sgns.negative_mask(negs, contexts, mask)
+                with jax.named_scope("glint.grads"):
+                    f_pos = lax.psum(
+                        jnp.einsum(
+                            "bd,bcd->bc", h.astype(cd), u_pos.astype(cd),
+                            preferred_element_type=jnp.float32,
+                        ),
+                        MODEL_AXIS,
+                    )
+                    f_neg = lax.psum(
+                        jnp.einsum(
+                            "bd,bcnd->bcn", h.astype(cd), u_neg.astype(cd),
+                            preferred_element_type=jnp.float32,
+                        ),
+                        MODEL_AXIS,
+                    )
+                    co = sgns.sgns_coefs(
+                        f_pos, f_neg, mask, nmask, alpha.astype(jnp.float32)
+                    )
+                    d_center_l = sgns.sgns_d_center(
+                        co.c_pos, co.c_neg, u_pos, u_neg, cd
+                    )
+                    ctx_g = lax.all_gather(contexts, DATA_AXIS, tiled=True)
+                    negs_g = lax.all_gather(negs, DATA_AXIS, tiled=True)
+                    cpos_g = lax.all_gather(co.c_pos, DATA_AXIS, tiled=True)
+                    cneg_g = lax.all_gather(co.c_neg, DATA_AXIS, tiled=True)
+                with (jax.named_scope("glint.scatter"),
+                      jax.named_scope("syn1")):
+                    ids1_g = jnp.concatenate(
+                        [ctx_g.reshape(-1), negs_g.reshape(-1)]
+                    )
+                    # Every row is local under dims: no own_range masking.
+                    syn1_l, upd1_g = _apply_rank1_updates(
+                        syn1_l, ids1_g, cpos_g, cneg_g, h_g, C, n, pm
+                    )
                 loss_local = co.loss
 
-            dcen_g = lax.all_gather(d_center_l / cnt, DATA_AXIS, tiled=True)
-            cmask_g = lax.all_gather(cmask, DATA_AXIS, tiled=True)
-            ids0_g = lax.all_gather(
-                centers.reshape(-1), DATA_AXIS, tiled=True
-            )
-            upd0_g = (dcen_g[:, None, :] * cmask_g[..., None]).reshape(
-                -1, dcen_g.shape[-1]
-            )
+            with jax.named_scope("glint.grads"):
+                dcen_g = lax.all_gather(
+                    d_center_l / cnt, DATA_AXIS, tiled=True
+                )
+                cmask_g = lax.all_gather(cmask, DATA_AXIS, tiled=True)
+                ids0_g = lax.all_gather(
+                    centers.reshape(-1), DATA_AXIS, tiled=True
+                )
             # Every row is local: plain scatter-adds, no ownership masks
             # (fp32 duplicate-row sums under bf16 storage, see
             # _bf16_safe_scatter_add).
-            syn0_l = _bf16_safe_scatter_add(syn0_l, ids0_g, upd0_g)
-            if upd1_g is not None:
-                syn1_l = _bf16_safe_scatter_add(syn1_l, ids1_g, upd1_g)
+            with jax.named_scope("glint.scatter"):
+                with jax.named_scope("syn0"):
+                    upd0_g = (
+                        dcen_g[:, None, :] * cmask_g[..., None]
+                    ).reshape(-1, dcen_g.shape[-1])
+                    syn0_l = _bf16_safe_scatter_add(syn0_l, ids0_g, upd0_g)
+                if upd1_g is not None:
+                    with jax.named_scope("syn1"):
+                        syn1_l = _bf16_safe_scatter_add(
+                            syn1_l, ids1_g, upd1_g
+                        )
 
-            denom = mask.sum()
-            loss_sum = loss_local * jnp.maximum(denom, 1.0)
-            loss = lax.psum(loss_sum, DATA_AXIS) / jnp.maximum(
-                lax.psum(denom, DATA_AXIS), 1.0
-            )
+            with jax.named_scope("glint.grads"):
+                denom = mask.sum()
+                loss_sum = loss_local * jnp.maximum(denom, 1.0)
+                loss = lax.psum(loss_sum, DATA_AXIS) / jnp.maximum(
+                    lax.psum(denom, DATA_AXIS), 1.0
+                )
             return syn0_l, syn1_l, loss
 
         step_body = (
@@ -1097,25 +1160,26 @@ class EmbeddingEngine:
 
                 def body(carry, i):
                     s0, s1, pos = carry
-                    key = jax.random.fold_in(base_key, step0 + i)
-                    pc, px, pm, n_cons, n_pairs = pack_window_pairs(
-                        ids, soffs, pos, base_key, grid_step0,
-                        window=W, span=S, pair_batch=P, grid_batch=B_grid,
-                        n_valid=n_valid,
-                    )
-                    pos_end = pos + n_cons
-                    done = device_words_done(
-                        orig_offs, soffs, pos_end, n_valid
-                    )
-                    wd = words_base + done.astype(jnp.float32)
-                    alpha = jnp.maximum(
-                        step_size * (1.0 - wd * inv_total_words),
-                        step_size * 1e-4,
-                    )
-                    c_l = lax.dynamic_slice_in_dim(pc, drank * Pl, Pl)
-                    x_l = lax.dynamic_slice_in_dim(px, drank * Pl, Pl)
-                    m_l = lax.dynamic_slice_in_dim(pm, drank * Pl, Pl)
-                    cmask = jnp.ones((Pl, 1), jnp.float32)
+                    with jax.named_scope("glint.batch"):
+                        key = jax.random.fold_in(base_key, step0 + i)
+                        pc, px, pm, n_cons, n_pairs = pack_window_pairs(
+                            ids, soffs, pos, base_key, grid_step0,
+                            window=W, span=S, pair_batch=P,
+                            grid_batch=B_grid, n_valid=n_valid,
+                        )
+                        pos_end = pos + n_cons
+                        done = device_words_done(
+                            orig_offs, soffs, pos_end, n_valid
+                        )
+                        wd = words_base + done.astype(jnp.float32)
+                        alpha = jnp.maximum(
+                            step_size * (1.0 - wd * inv_total_words),
+                            step_size * 1e-4,
+                        )
+                        c_l = lax.dynamic_slice_in_dim(pc, drank * Pl, Pl)
+                        x_l = lax.dynamic_slice_in_dim(px, drank * Pl, Pl)
+                        m_l = lax.dynamic_slice_in_dim(pm, drank * Pl, Pl)
+                        cmask = jnp.ones((Pl, 1), jnp.float32)
                     s0, s1, loss = step_body(
                         s0, s1, prob, alias, c_l[:, None], cmask,
                         x_l[:, None], m_l[:, None], key, alpha,
@@ -2616,7 +2680,7 @@ class EmbeddingEngine:
              for p in (*nprobes, self._ann_conf["nprobe"])}
         )
         d = self.dim
-        with obs_events.span("engine_warmup_ann"):
+        with obs_events.span("engine_warmup_ann") as warm:
             for p in nps:
                 for q in sorted(
                     {min(self._q_bucket(q), ANN_MAX_Q)
@@ -2636,8 +2700,8 @@ class EmbeddingEngine:
                 jnp.zeros(_ann.INCREMENTAL_BLOCK, jnp.int32),
                 idx.centroids,
             )
-        n = self.query_compiles - before
-        obs_events.emit("warmup_ann_done", shapes_compiled=n)
+            n = self.query_compiles - before
+            warm.update(shapes_compiled=n)
         return n
 
     def ann_recall_at_k(
@@ -2740,7 +2804,7 @@ class EmbeddingEngine:
         requests, so a warmed bucket can never re-compile. Returns the
         number of shapes this call compiled (0 = already warm)."""
         before = self.query_compiles
-        with obs_events.span("engine_warmup"):
+        with obs_events.span("engine_warmup") as warm:
             d = self.dim
             ks = sorted({self._k_bucket(int(k)) for k in k_buckets})
             for k in ks:
@@ -2757,8 +2821,8 @@ class EmbeddingEngine:
                         np.zeros((s, L), np.int32),
                         np.zeros((s, L), np.float32),
                     )
-        n = self.query_compiles - before
-        obs_events.emit("warmup_done", shapes_compiled=n)
+            n = self.query_compiles - before
+            warm.update(shapes_compiled=n)
         return n
 
     # ------------------------------------------------------------------

@@ -402,16 +402,20 @@ class _SynonymCoalescer:
                             # dispatch. A request missing the drain
                             # costs a FULL extra device round; the
                             # worst-case grace (16ms) is well under one.
-                            for _ in range(8):
-                                n0 = len(batch)
-                                time.sleep(self.batch_grace)
-                                with self._mu:
-                                    if self._pending:
-                                        batch += self._pending
-                                        self._pending = []
-                                if (len(batch) == n0
-                                        or len(batch) >= self.max_batch):
-                                    break
+                            with obs_events.phase_span(
+                                "req.grace", batch=len(batch)
+                            ) as grace:
+                                for _ in range(8):
+                                    n0 = len(batch)
+                                    time.sleep(self.batch_grace)
+                                    with self._mu:
+                                        if self._pending:
+                                            batch += self._pending
+                                            self._pending = []
+                                    if (len(batch) == n0
+                                            or len(batch) >= self.max_batch):
+                                        break
+                                grace.update(batch_after=len(batch))
                         if batch:
                             self._process(batch)
                 finally:
@@ -547,10 +551,11 @@ class _SynonymCoalescer:
         ):
             word_rows = [r for r in chunk if "idx" in r]
             if word_rows:
-                pulled = _pull_coalesced(
-                    m.engine,
-                    np.asarray([r["idx"] for r in word_rows], np.int32),
-                )
+                with obs_events.phase_span("req.pull", rows=len(word_rows)):
+                    pulled = _pull_coalesced(
+                        m.engine,
+                        np.asarray([r["idx"] for r in word_rows], np.int32),
+                    )
                 for r, v in zip(word_rows, pulled):
                     r["vec"] = v
             k = max(

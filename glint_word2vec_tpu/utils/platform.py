@@ -66,6 +66,13 @@ def enable_compile_cache(min_compile_secs: float = 1.0) -> Optional[str]:
     every start of one table shape; nothing evicts, so a directory that
     has seen many shapes is cleared by hand (``rm -rf``).
 
+    Op metadata is part of the cache key. JAX leaves it out by default, and
+    a hit then hands back the executable of whoever compiled first, with
+    THAT program's op names and source lines: a profile of this version
+    would show the ``glint.*`` scopes (parallel/engine.py) of the version
+    that filled the cache, or none. The price is a recompile when a line
+    on a traced call's stack moves.
+
     Call from an entry point before the first compile, never at import;
     initialises the backend.
     """
@@ -83,6 +90,7 @@ def enable_compile_cache(min_compile_secs: float = 1.0) -> Optional[str]:
         min(jax.config.jax_persistent_cache_min_compile_time_secs,
             min_compile_secs),
     )
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env

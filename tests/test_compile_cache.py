@@ -33,6 +33,8 @@ print(json.dumps({{
     "config_dir": jax.config.jax_compilation_cache_dir,
     "enabled": jax.config.jax_enable_compilation_cache,
     "floor": jax.config.jax_persistent_cache_min_compile_time_secs,
+    "metadata_in_key":
+        jax.config.jax_compilation_cache_include_metadata_in_key,
 }}))
 """
 
@@ -65,6 +67,9 @@ FIXED = os.path.join(ROOT, ".jax_cache")  # in the checkout, never temp/pid/time
 )
 def test_compile_cache_rule(backend, env_dir, unpin):
     got = _child(backend, env_dir, unpin)
+    # A hit must never hand back another version's op names (the scopes a
+    # profile is split by): where the cache is on, metadata is in its key.
+    assert got["metadata_in_key"] is (backend != "cpu")
     if backend == "cpu":
         # Off, whatever the environment says.
         assert got["returned"] is None and got["enabled"] is False
