@@ -124,70 +124,130 @@ def _pull_rows(table_l, idx, start, rows_per_shard, pallas=False):
     return lax.psum(rows, MODEL_AXIS)
 
 
-def _dup_sum_f32(idx, upd):
-    """Collapse duplicate target rows to ONE fp32-summed update row per
-    id run (the remaining duplicate slots carry exact zeros), so a
-    low-precision table's scatter-add rounds each row's BATCH TOTAL
-    once instead of once per duplicate — the XLA restatement of the
-    fused kernel's fp32 VMEM run accumulation (ops/pallas_sgns), used
-    by :func:`_bf16_safe_scatter_add` whenever storage is narrower than
-    fp32. Without it the dense pair form is quality-lossy on bf16
-    tables: a center's per-context d_center contributions (summed in
-    the fp32 einsum under the grid shape) would each round against the
-    table separately, and sub-ulp contributions vanish entirely (the
-    dense+bf16 quality regression pinned in tests/test_pallas_sgns.py).
+#: Update slots one trip of the row writer walks. XLA's TPU scatter into a
+#: table costs about 100 ns for every slot it is handed, live or dropped,
+#: and no promise changes that (PERF.md, PR 26's probes), so the writer
+#: hands it the distinct rows a chunk at a time and stops after the last
+#: live chunk: half a chunk a table is walked for nothing.
+_SCATTER_CHUNK = 4096
 
-    Sorted-run form: sort ids (duplicates become adjacent), fp32
-    inclusive cumsum over the sorted updates, per-run total = cum at
-    the run end minus cum just before the run start."""
-    N = idx.shape[0]
-    sid, order = lax.sort_key_val(
-        idx.astype(jnp.int32), jnp.arange(N, dtype=jnp.int32)
-    )
-    su = upd[order].astype(jnp.float32)
-    cum = jnp.cumsum(su, axis=0)
+
+def _writer_chunk(n: int) -> int:
+    return min(_SCATTER_CHUNK, n)
+
+
+def _run_ends(sid, n_rows):
+    """``(is_start, live_end)`` over sorted keys ``sid``: where a run of
+    equal keys starts, and where a run of a key below ``n_rows`` (a row of
+    the table) ends. The live ends are the distinct rows a scatter
+    writes."""
     change = sid[1:] != sid[:-1]
-    is_start = jnp.concatenate([jnp.ones(1, bool), change])
-    is_end = jnp.concatenate([change, jnp.ones(1, bool)])
-    pos = jnp.arange(N, dtype=jnp.int32)
-    run_start = lax.cummax(jnp.where(is_start, pos, 0))
-    prev_cum = jnp.where(
-        (run_start > 0)[:, None], cum[jnp.maximum(run_start - 1, 0)], 0.0
+    one = jnp.ones(1, bool)
+    return (
+        jnp.concatenate([one, change]),
+        jnp.concatenate([change, one]) & (sid < n_rows),
     )
-    return sid, jnp.where(is_end[:, None], cum - prev_cum, 0.0)
 
 
-def _bf16_safe_scatter_add(table_l, idx, upd):
-    """``table_l.at[idx].add(upd)`` with fp32 duplicate-row sums when
-    the table stores less than fp32 (see :func:`_dup_sum_f32`); the
-    fp32 path keeps the plain scatter-add (exactness-tested numerics,
-    no extra sort/cumsum work)."""
-    if jnp.dtype(table_l.dtype).itemsize >= 4:
-        return table_l.at[idx].add(upd.astype(table_l.dtype))
-    sid, summed = _dup_sum_f32(idx, upd)
-    return table_l.at[sid].add(summed.astype(table_l.dtype))
+def _run_totals(key, coefs, src, hidx, n_rows):
+    """Sort the N update slots by target row and total each run of equal
+    rows once, in float32: slot k adds ``coefs[k] * src[hidx[k]]`` to row
+    ``key[k]``; a key of ``n_rows`` marks a slot whose row another shard
+    owns. Returns ``(u, tot, n_u)``: the ``n_u`` distinct owned rows in
+    rising order with their totals in ``tot[:n_u]``, then sentinels (all
+    different, all past ``n_rows``) whose ``tot`` rows no writer reads.
+    Both are padded to a whole number of writer chunks.
+
+    The payload is formed in sorted order (coefficient and source index
+    ride through the sort, the source ROW is gathered after it), never in
+    batch order. A run is totalled by a sorted scatter-add into
+    consecutive rows of a fresh buffer, a pass over that buffer and 17 ns
+    a slot: it adds a run in the order it stood in the batch, so its error
+    is that of the plain sum, where a difference of prefix sums carries
+    the whole prefix's (PERF.md, PR 26: 1.2 against 3.8e8 units of
+    eps * sum|x|)."""
+    n = key.shape[0]
+    chunk = _writer_chunk(n)
+    n_pad = -(-n // chunk) * chunk
+    sid, coefs, hidx = lax.sort(
+        (key.astype(jnp.int32), coefs.astype(jnp.float32), hidx), num_keys=1
+    )
+    rows = coefs[:, None] * src[hidx].astype(jnp.float32)
+    is_start, live_end = _run_ends(sid, n_rows)
+    slot = jnp.cumsum(is_start.astype(jnp.int32)) - 1
+    tot = jnp.zeros((n_pad, rows.shape[1]), jnp.float32).at[slot].add(
+        rows, indices_are_sorted=True
+    )
+    pad = (0, n_pad - n)
+    u = lax.sort(jnp.where(
+        jnp.pad(live_end, pad), jnp.pad(sid, pad),
+        n_rows + jnp.arange(n_pad, dtype=jnp.int32),
+    ))
+    return u, tot, live_end.sum(dtype=jnp.int32)
 
 
-def _scatter_rows(table_l, idx, upd, start, rows_per_shard, pallas=False):
+def _scatter_add_rows(table_l, key, coefs, src, hidx):
+    """``table_l[key[k]] += coefs[k] * src[hidx[k]]`` for every slot whose
+    key is a row of ``table_l``; any other key is dropped. Every table
+    dtype and layout takes this one path: the slots are sorted, each
+    row's updates are summed once in float32 (:func:`_run_totals`), and
+    each distinct row is written once, its total rounded once to the
+    table's dtype. The scatter is told what is then true of its rows (no
+    two alike, strays dropped) but not that they are sorted: that flag
+    selects XLA's other TPU emitter, which passes over the whole table
+    (9.4 ms at 2M x 300) before it adds a slot. Returns ``(table_l, rows
+    written)``."""
+    u, tot, n_u = _run_totals(key, coefs, src, hidx, table_l.shape[0])
+    chunk = _writer_chunk(key.shape[0])
+
+    def write(k, t):
+        return t.at[lax.dynamic_slice_in_dim(u, k * chunk, chunk)].add(
+            lax.dynamic_slice_in_dim(tot, k * chunk, chunk).astype(t.dtype),
+            unique_indices=True, mode="drop",
+        )
+
+    return lax.fori_loop(0, -(-n_u // chunk), write, table_l), n_u
+
+
+def _scatter_rows(table_l, idx, coefs, src, hidx, start, pallas=False):
     """Apply global rank-1 updates to the owned slice of a sharded table
-    (the servers' half of ``adjust``, SURVEY.md §2.2). Disowned updates are
-    zeroed and land harmlessly on a clipped row. ``pallas`` as in
-    :func:`_pull_rows`."""
-    loc = idx - start
-    own = (loc >= 0) & (loc < rows_per_shard)
-    upd = jnp.where(own[:, None], upd, 0.0)
-    clipped = jnp.clip(loc, 0, rows_per_shard - 1)
-    if pallas:
-        from glint_word2vec_tpu.ops.pallas_rows import scatter_add_rows
+    (the servers' half of ``adjust``, SURVEY.md §2.2): slot k adds
+    ``coefs[k] * src[hidx[k]]`` to global row ``idx[k]``. Updates of rows
+    another shard owns are dropped, not walked. Returns ``(table_l, rows
+    written)``.
 
-        if jnp.dtype(table_l.dtype).itemsize < 4:
-            # The pallas_rows run accumulator is TABLE dtype; pre-sum
-            # duplicate rows in fp32 so low-precision storage still
-            # rounds each row's batch total once (same contract as the
-            # XLA branch below and the fused kernels).
-            clipped, upd = _dup_sum_f32(clipped, upd)
-        return scatter_add_rows(table_l, clipped, upd, interpret=True)
-    return _bf16_safe_scatter_add(table_l, clipped, upd)
+    ``pallas``: False = the XLA path; ``"rows"`` / ``"rank1"`` = the
+    Pallas kernels of ops/pallas_rows.py in interpret mode, the only mode
+    they have (see PALLAS_TPU_REFUSAL): the row pipeline, or the fused
+    rank-1 scatter (payload formed in VMEM) where ``src`` fits its budget
+    and the table is float32, else the row pipeline."""
+    Vs = table_l.shape[0]
+    loc = idx - start
+    own = (loc >= 0) & (loc < Vs)
+    key = jnp.where(own, loc, Vs)
+    if not pallas:
+        return _scatter_add_rows(table_l, key, coefs, src, hidx)
+    from glint_word2vec_tpu.ops import pallas_rows
+
+    # Both kernels accumulate a run in TABLE dtype, so the row pipeline
+    # is handed float32 run totals, one per distinct row (what bf16
+    # storage needs to round a row's batch total once; the sentinels
+    # land as zero rows on the last row), and the fused one is kept to
+    # float32 tables.
+    u, tot, n_u = _run_totals(key, coefs, src, hidx, Vs)
+    if (
+        pallas == "rank1"
+        and src.shape[0] * src.shape[1] * 4 <= _RANK1_FUSE_VMEM_BYTES
+        and table_l.dtype == jnp.float32
+    ):
+        return pallas_rows.scatter_add_rank1(
+            table_l, jnp.minimum(key, Vs - 1), jnp.where(own, coefs, 0.0),
+            src, hidx, interpret=True,
+        ), n_u
+    return pallas_rows.scatter_add_rows(
+        table_l, jnp.minimum(u, Vs - 1),
+        jnp.where((u < Vs)[:, None], tot, 0.0), interpret=True,
+    ), n_u
 
 
 #: VMEM budget for pinning h_g whole in the fused rank-1 scatter kernel
@@ -296,62 +356,38 @@ TOPK_MIN_Q_BUCKET = 8
 ANN_MAX_Q = 16
 
 
-def _rank1_payload(cpos_g, cneg_g, C: int, n: int):
-    """(coefs, hidx) for the fused rank-1 scatter, matching the update
-    ordering ids1_g = [contexts.flat | negs.flat] (rank-major batch axis).
-    Shared by both layouts' step bodies — the ordering contract lives in
-    exactly one place."""
-    B = cpos_g.shape[0]
-    coefs = jnp.concatenate([cpos_g.reshape(-1), cneg_g.reshape(-1)])
-    hidx = jnp.concatenate([
-        jnp.repeat(jnp.arange(B, dtype=jnp.int32), C),
-        jnp.repeat(jnp.arange(B, dtype=jnp.int32), C * n),
-    ])
-    return coefs, hidx
-
-
-def _apply_rank1_updates(
-    syn1_l, ids1_g, cpos_g, cneg_g, h_g, C, n, pm, own_range=None
-):
-    """Apply the per-pair syn1 rank-1 updates, choosing between the fused
-    Pallas scatter (Pallas mode on AND h_g fits the VMEM budget) and the
-    dense outer-product payload. Returns (syn1_l, upd1_g) where upd1_g is
-    None when the update was already applied (fused path) or the (N, d)
-    payload for the caller's scatter otherwise. ``own_range=(start, Vs)``
-    applies the rows layout's ownership masking; None = every row local
-    (dims layout). ONE implementation for both step bodies — the fuse
-    gate, payload ordering, and fallback stay in lockstep by construction.
-    """
-    fuse = (
-        pm
-        and h_g.shape[0] * h_g.shape[1] * 4 <= _RANK1_FUSE_VMEM_BYTES
-        # scatter_add_rank1 accumulates runs in TABLE dtype; under bf16
-        # storage take the payload path instead, whose scatter pre-sums
-        # duplicates in fp32 (_dup_sum_f32) — round-once semantics.
-        and jnp.dtype(syn1_l.dtype).itemsize >= 4
+def _pair_payload(ctx_g, negs_g, cpos_g, cneg_g, h_g):
+    """``(ids, coefs, src, hidx)`` of the per-pair syn1 update for
+    :func:`_scatter_rows`: slot order [contexts.flat | negs.flat]
+    (rank-major batch axis), each slot its coefficient times its
+    centre's row of ``h_g``. Shared by both layouts' step bodies — the
+    ordering contract lives in exactly one place."""
+    B, C = cpos_g.shape
+    n = cneg_g.shape[-1]
+    rows = jnp.arange(B, dtype=jnp.int32)
+    return (
+        jnp.concatenate([ctx_g.reshape(-1), negs_g.reshape(-1)]),
+        jnp.concatenate([cpos_g.reshape(-1), cneg_g.reshape(-1)]),
+        h_g,
+        jnp.concatenate([jnp.repeat(rows, C), jnp.repeat(rows, C * n)]),
     )
-    if fuse:
-        from glint_word2vec_tpu.ops.pallas_rows import scatter_add_rank1
 
-        coefs, hidx = _rank1_payload(cpos_g, cneg_g, C, n)
-        ids = ids1_g
-        if own_range is not None:
-            start, Vs = own_range
-            loc = ids1_g - start
-            own = (loc >= 0) & (loc < Vs)
-            coefs = jnp.where(own, coefs, 0.0)
-            ids = jnp.clip(loc, 0, Vs - 1)
-        syn1_l = scatter_add_rank1(
-            syn1_l, ids, coefs, h_g, hidx, interpret=True
-        )
-        return syn1_l, None
-    d = h_g.shape[-1]
-    d_upos = cpos_g[..., None] * h_g[:, None, :]
-    d_uneg = cneg_g[..., None] * h_g[:, None, None, :]
-    upd1_g = jnp.concatenate(
-        [d_upos.reshape(-1, d), d_uneg.reshape(-1, d)]
+
+def _pool_payload(ctx_ids, pool, cpos_g, h_g, d_pool):
+    """The same for the shared-pool step: the positives as in
+    :func:`_pair_payload`, then the pool's rows, whose dense update
+    ``d_pool`` rides behind ``h_g`` in the source with coefficient one."""
+    B, C = cpos_g.shape
+    S = pool.shape[0]
+    return (
+        jnp.concatenate([ctx_ids, pool]),
+        jnp.concatenate([cpos_g.reshape(-1), jnp.ones(S, jnp.float32)]),
+        jnp.concatenate([h_g, d_pool]),
+        jnp.concatenate([
+            jnp.repeat(jnp.arange(B, dtype=jnp.int32), C),
+            B + jnp.arange(S, dtype=jnp.int32),
+        ]),
     )
-    return syn1_l, upd1_g
 
 
 class EmbeddingEngine:
@@ -642,6 +678,7 @@ class EmbeddingEngine:
                 # psum the dense payload is identical everywhere.
                 dpool_g = lax.psum(fw.d_pool, DATA_AXIS)
                 P = cen_g.shape[0]
+                ids1_g = jnp.concatenate([ctx_g, pool])
                 syn1_l = pallas_sgns.scatter_add_rank1_hbm(
                     syn1_l, ctx_g, cpos_g, h_g,
                     jnp.arange(P, dtype=jnp.int32), interpret=True,
@@ -673,9 +710,10 @@ class EmbeddingEngine:
                 )
                 P = cen_g.shape[0]
                 rows_p = jnp.arange(P, dtype=jnp.int32)
+                ids1_g = jnp.concatenate([ctx_g, negs_g.reshape(-1)])
                 syn1_l = pallas_sgns.scatter_add_rank1_hbm(
                     syn1_l,
-                    jnp.concatenate([ctx_g, negs_g.reshape(-1)]),
+                    ids1_g,
                     jnp.concatenate([cpos_g, cneg_g.reshape(-1)]),
                     h_g,
                     jnp.concatenate([rows_p, jnp.repeat(rows_p, n)]),
@@ -690,7 +728,13 @@ class EmbeddingEngine:
             loss = lax.psum(fw.loss_sum, DATA_AXIS) / jnp.maximum(
                 lax.psum(denom, DATA_AXIS), 1.0
             )
-            return syn0_l, syn1_l, loss
+            # The kernels sum their runs in VMEM; the rows they write are
+            # counted here as the composed body's scatters count theirs.
+            written = jnp.stack([
+                _run_ends(lax.sort(ids), Vs)[1].sum(dtype=jnp.int32)
+                for ids in (cen_g, ids1_g)
+            ])
+            return syn0_l, syn1_l, loss, written
 
         def step_body_rows(syn0_l, syn1_l, prob, alias, centers, cmask,
                            contexts, mask, key, alpha):
@@ -770,13 +814,11 @@ class EmbeddingEngine:
                         contexts.reshape(-1), DATA_AXIS, tiled=True
                     )
                     cpos_g = lax.all_gather(g.c_pos, DATA_AXIS, tiled=True)
+                # The pool's dense update rides as rows of the source with
+                # coefficient one, after h_g.
                 with (jax.named_scope("glint.scatter"),
                       jax.named_scope("syn1")):
-                    d_upos = cpos_g[..., None] * h_g[:, None, :]
-                    ids1_g = jnp.concatenate([ids1, pool])
-                    upd1_g = jnp.concatenate(
-                        [d_upos.reshape(-1, d_upos.shape[-1]), d_pool]
-                    )
+                    scat1 = _pool_payload(ids1, pool, cpos_g, h_g, d_pool)
             else:
                 # Per-pair mode (reference semantics): n fresh negatives
                 # per (center, context) pair, keyed by GLOBAL row index so
@@ -805,16 +847,7 @@ class EmbeddingEngine:
                     cneg_g = lax.all_gather(g.c_neg, DATA_AXIS, tiled=True)
                 with (jax.named_scope("glint.scatter"),
                       jax.named_scope("syn1")):
-                    ids1_g = jnp.concatenate(
-                        [ctx_g.reshape(-1), negs_g.reshape(-1)]
-                    )
-                    # Fused Pallas scatter (payload formed in VMEM) when
-                    # eligible, else consumer-side outer products;
-                    # ownership masking for this rows layout via own_range.
-                    syn1_l, upd1_g = _apply_rank1_updates(
-                        syn1_l, ids1_g, cpos_g, cneg_g, h_g, C, n, pm,
-                        own_range=(start, Vs),
-                    )
+                    scat1 = _pair_payload(ctx_g, negs_g, cpos_g, cneg_g, h_g)
 
             # The center gradient is distributed over the group's rows
             # (d mean / d row = 1/count): ship the (Bl, d) gradient + the
@@ -827,19 +860,21 @@ class EmbeddingEngine:
                 ids0_g = lax.all_gather(
                     centers.reshape(-1), DATA_AXIS, tiled=True
                 )
+            # The outer products coef x row are formed at the consumer, in
+            # the scatter's sorted order (_run_totals), never exchanged.
             with jax.named_scope("glint.scatter"):
                 with jax.named_scope("syn0"):
-                    upd0_g = (
-                        dcen_g[:, None, :] * cmask_g[..., None]
-                    ).reshape(-1, dcen_g.shape[-1])
-                    syn0_l = _scatter_rows(
-                        syn0_l, ids0_g, upd0_g, start, Vs, pm
+                    syn0_l, w0 = _scatter_rows(
+                        syn0_l, ids0_g, cmask_g.reshape(-1), dcen_g,
+                        jnp.repeat(jnp.arange(dcen_g.shape[0]), S),
+                        start, pm and "rows",
                     )
-                if upd1_g is not None:
-                    with jax.named_scope("syn1"):
-                        syn1_l = _scatter_rows(
-                            syn1_l, ids1_g, upd1_g, start, Vs, pm
-                        )
+                with jax.named_scope("syn1"):
+                    syn1_l, w1 = _scatter_rows(
+                        syn1_l, *scat1, start,
+                        pm and ("rows" if self.shared_negatives else "rank1"),
+                    )
+                    written = lax.psum(jnp.stack([w0, w1]), MODEL_AXIS)
 
             # Masked-mean loss over the global batch.
             with jax.named_scope("glint.grads"):
@@ -848,7 +883,7 @@ class EmbeddingEngine:
                 loss = lax.psum(loss_sum, DATA_AXIS) / jnp.maximum(
                     lax.psum(denom, DATA_AXIS), 1.0
                 )
-            return syn0_l, syn1_l, loss
+            return syn0_l, syn1_l, loss, written
 
         def step_body_dims(syn0_l, syn1_l, prob, alias, centers, cmask,
                            contexts, mask, key, alpha):
@@ -913,11 +948,7 @@ class EmbeddingEngine:
                     cpos_g = lax.all_gather(co.c_pos, DATA_AXIS, tiled=True)
                 with (jax.named_scope("glint.scatter"),
                       jax.named_scope("syn1")):
-                    d_upos = cpos_g[..., None] * h_g[:, None, :]
-                    ids1_g = jnp.concatenate([ids1, pool])
-                    upd1_g = jnp.concatenate(
-                        [d_upos.reshape(-1, d_upos.shape[-1]), d_pool_g]
-                    )
+                    scat1 = _pool_payload(ids1, pool, cpos_g, h_g, d_pool_g)
                 loss_local = co.loss
             else:
                 with jax.named_scope("glint.sample"):
@@ -957,13 +988,7 @@ class EmbeddingEngine:
                     cneg_g = lax.all_gather(co.c_neg, DATA_AXIS, tiled=True)
                 with (jax.named_scope("glint.scatter"),
                       jax.named_scope("syn1")):
-                    ids1_g = jnp.concatenate(
-                        [ctx_g.reshape(-1), negs_g.reshape(-1)]
-                    )
-                    # Every row is local under dims: no own_range masking.
-                    syn1_l, upd1_g = _apply_rank1_updates(
-                        syn1_l, ids1_g, cpos_g, cneg_g, h_g, C, n, pm
-                    )
+                    scat1 = _pair_payload(ctx_g, negs_g, cpos_g, cneg_g, h_g)
                 loss_local = co.loss
 
             with jax.named_scope("glint.grads"):
@@ -974,20 +999,20 @@ class EmbeddingEngine:
                 ids0_g = lax.all_gather(
                     centers.reshape(-1), DATA_AXIS, tiled=True
                 )
-            # Every row is local: plain scatter-adds, no ownership masks
-            # (fp32 duplicate-row sums under bf16 storage, see
-            # _bf16_safe_scatter_add).
+            # Every row is local (start 0, no row is another shard's), and
+            # every shard writes the same rows of its own columns.
             with jax.named_scope("glint.scatter"):
                 with jax.named_scope("syn0"):
-                    upd0_g = (
-                        dcen_g[:, None, :] * cmask_g[..., None]
-                    ).reshape(-1, dcen_g.shape[-1])
-                    syn0_l = _bf16_safe_scatter_add(syn0_l, ids0_g, upd0_g)
-                if upd1_g is not None:
-                    with jax.named_scope("syn1"):
-                        syn1_l = _bf16_safe_scatter_add(
-                            syn1_l, ids1_g, upd1_g
-                        )
+                    syn0_l, w0 = _scatter_rows(
+                        syn0_l, ids0_g, cmask_g.reshape(-1), dcen_g,
+                        jnp.repeat(jnp.arange(dcen_g.shape[0]), S), 0,
+                    )
+                with jax.named_scope("syn1"):
+                    syn1_l, w1 = _scatter_rows(
+                        syn1_l, *scat1, 0,
+                        pm and not self.shared_negatives and "rank1",
+                    )
+                    written = jnp.stack([w0, w1])
 
             with jax.named_scope("glint.grads"):
                 denom = mask.sum()
@@ -995,7 +1020,7 @@ class EmbeddingEngine:
                 loss = lax.psum(loss_sum, DATA_AXIS) / jnp.maximum(
                     lax.psum(denom, DATA_AXIS), 1.0
                 )
-            return syn0_l, syn1_l, loss
+            return syn0_l, syn1_l, loss, written
 
         step_body = (
             step_body_rows if self.layout == "rows" else step_body_dims
@@ -1003,7 +1028,7 @@ class EmbeddingEngine:
 
         self._train_step = jax.jit(
             self._shard_map(
-                step_body,
+                lambda *a: step_body(*a)[:3],
                 in_specs=(tspec, tspec, rep, rep, P(DATA_AXIS, None),
                           P(DATA_AXIS, None), P(DATA_AXIS, None),
                           P(DATA_AXIS, None), rep, rep),
@@ -1024,7 +1049,7 @@ class EmbeddingEngine:
                 s0, s1 = carry
                 centers, cmask, contexts, mask, i, alpha = xs
                 key = jax.random.fold_in(base_key, step0 + i)
-                s0, s1, loss = step_body(
+                s0, s1, loss, _ = step_body(
                     s0, s1, prob, alias, centers, cmask, contexts, mask,
                     key, alpha,
                 )
@@ -1092,7 +1117,7 @@ class EmbeddingEngine:
                         n_valid=n_valid,
                     )
                     cmask = jnp.ones((Bl, 1), jnp.float32)
-                    s0, s1, loss = step_body(
+                    s0, s1, loss, _ = step_body(
                         s0, s1, prob, alias, centers[:, None], cmask,
                         contexts, mask, key, alpha,
                     )
@@ -1180,25 +1205,26 @@ class EmbeddingEngine:
                         x_l = lax.dynamic_slice_in_dim(px, drank * Pl, Pl)
                         m_l = lax.dynamic_slice_in_dim(pm, drank * Pl, Pl)
                         cmask = jnp.ones((Pl, 1), jnp.float32)
-                    s0, s1, loss = step_body(
+                    s0, s1, loss, written = step_body(
                         s0, s1, prob, alias, c_l[:, None], cmask,
                         x_l[:, None], m_l[:, None], key, alpha,
                     )
-                    return (s0, s1, pos_end), (loss, n_pairs, pos_end, alpha)
+                    return (s0, s1, pos_end), (
+                        loss, n_pairs, pos_end, alpha, written
+                    )
 
                 (syn0_l, syn1_l, _), ys = lax.scan(
                     body,
                     (syn0_l, syn1_l, pstart),
                     jnp.arange(K, dtype=jnp.uint32),
                 )
-                losses, pair_counts, pos_ends, alphas = ys
-                return syn0_l, syn1_l, losses, pair_counts, pos_ends, alphas
+                return (syn0_l, syn1_l) + ys
 
             return jax.jit(
                 self._shard_map(
                     local_packed_scan,
                     in_specs=(tspec, tspec) + (rep,) * 13,
-                    out_specs=(tspec, tspec, rep, rep, rep, rep),
+                    out_specs=(tspec, tspec, rep, rep, rep, rep, rep),
                 ),
                 donate_argnums=(0, 1),
             )
@@ -1943,9 +1969,11 @@ class EmbeddingEngine:
         grid path's (like host-vs-device RNG divergence, documented).
 
         Returns ``(losses (K,), pair_counts (K,), pos_ends (K,),
-        alphas (K,))`` — per-step loss, live pairs packed, consumed
-        position after the step, and the device-computed alpha. The
-        caller reads ``pos_ends[-1]`` to schedule the next dispatch
+        alphas (K,), rows_written (K, 2))`` — per-step loss, live pairs
+        packed, consumed position after the step, the device-computed
+        alpha, and the distinct rows the step's scatters wrote into
+        (syn0, syn1), of the :meth:`packed_scatter_slots` they were handed.
+        The caller reads ``pos_ends[-1]`` to schedule the next dispatch
         (one scalar readback per K steps).
         """
         if getattr(self, "_corpus", None) is None:
@@ -1985,9 +2013,7 @@ class EmbeddingEngine:
         else:
             ids, soffs = self._corpus
             n_valid = getattr(self, "_corpus_n_valid", ids.shape[0])
-        (
-            self.syn0, self.syn1, losses, pair_counts, pos_ends, alphas,
-        ) = fn(
+        self.syn0, self.syn1, *per_step = fn(
             self.syn0, self.syn1, self._prob, self._alias, ids, soffs,
             self._corpus[1], jnp.int32(n_valid),
             jnp.int32(start_position), base_key, jnp.uint32(step0),
@@ -1996,7 +2022,15 @@ class EmbeddingEngine:
             jnp.float32(words_base),
         )
         self._tick_tables("train_steps_corpus_packed")
-        return losses, pair_counts, pos_ends, alphas
+        return tuple(per_step)
+
+    def packed_scatter_slots(self, pair_batch: int) -> Tuple[int, int]:
+        """Update slots one packed step hands the (syn0, syn1) scatters:
+        a centre a pair, and a context plus its negatives (or the shared
+        pool once) a pair."""
+        if self.shared_negatives:
+            return pair_batch, pair_batch + self.shared_negatives
+        return pair_batch, pair_batch * (1 + self.num_negatives)
 
     # ------------------------------------------------------------------
     # Serving ops (the BigWord2VecMatrix query surface)

@@ -17,7 +17,7 @@ layers three independently-gated optimizations on that wire:
     base costs one extra table pair of HBM, halved by bf16 storage);
   * after the dispatch group, a jitted diff+encode harvest dedupes
     touched rows BY CONSTRUCTION (one row = one delta, the table-diff
-    restatement of the sorted-run-sum dedupe in ``engine._dup_sum_f32``)
+    restatement of the sorted-run-sum dedupe in ``engine._run_totals``)
     and compacts their ids into a FIXED-CAPACITY padded buffer via the
     same prefix-sum scatter trick as
     ``ops/device_batching.subsample_compact`` — every traced shape is
@@ -106,7 +106,7 @@ from glint_word2vec_tpu.utils import faults, next_pow2
 #: Wire dtype of exact (fp32-wire / dense / flush) delta payloads:
 #: accumulation dtype, not storage dtype — deltas of bf16 tables still
 #: travel and sum in fp32 so the reconstruction rounds each row total
-#: once (same contract as ``engine._bf16_safe_scatter_add``).
+#: once (same contract as ``engine._scatter_add_rows``).
 _WIRE_DTYPE = np.float32
 
 #: Supported sparse payload encodings (``--exchange-wire``).

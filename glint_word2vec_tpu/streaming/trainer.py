@@ -444,7 +444,7 @@ class StreamTrainer:
                     with metrics.timing("step"), obs_run.span(
                         "device_steps", step0=self.steps, n=spc, packed=True
                     ):
-                        losses, pair_counts, pos_ends, alphas = (
+                        losses, pair_counts, pos_ends, alphas, _ = (
                             engine.train_steps_corpus_packed(
                                 pos, pair_batch, W, B, base_key, spc,
                                 step0=self.steps, grid_step0=self.steps,

@@ -236,6 +236,7 @@ def test_canary_abort_on_device_corpus_path(tiny_corpus, monkeypatch):
             np.full(K, int(pair_batch), np.int64),
             np.full(K, 10**9, np.int64),
             np.full(K, 0.025, np.float32),
+            np.zeros((K, 2), np.int32),
         )
 
     monkeypatch.setattr(

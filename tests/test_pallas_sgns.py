@@ -322,8 +322,8 @@ def test_fused_shared_small_pool_drain():
 def test_bf16_pallas_row_scatter_gets_f32_dup_sums():
     # The pallas-but-NOT-fused scatter path (model-sharded meshes, the
     # fused escape hatch) must keep the fp32 duplicate-sum contract on
-    # bf16 tables: _scatter_rows pre-sums runs in fp32 before the
-    # pallas_rows kernel (whose accumulator is table dtype). Same
+    # bf16 tables: _scatter_rows hands the pallas_rows kernel (whose
+    # accumulator is table dtype) fp32 run totals (_run_totals). Same
     # sub-ulp construction as the f32-scatter test above.
     from glint_word2vec_tpu.parallel.engine import _scatter_rows
 
@@ -332,10 +332,14 @@ def test_bf16_pallas_row_scatter_gets_f32_dup_sums():
     tb = jnp.asarray(table, dtype=jnp.bfloat16)
     ids = jnp.full((8,), 5, jnp.int32)
     upd = jnp.full((8, D), 0.5, jnp.float32)
-    out = _scatter_rows(tb, ids, upd, 0, V, pallas=True)
+    out, written = _scatter_rows(
+        tb, ids, jnp.ones(8, jnp.float32), upd, jnp.arange(8), 0,
+        pallas="rows",
+    )
     np.testing.assert_array_equal(
         np.asarray(out[5], np.float32), np.full(D, 260.0, np.float32)
     )
+    assert int(written) == 1
 
 
 def test_shared_pool_vmem_gate():

@@ -57,8 +57,8 @@ def engines(topo):
 
     built = {}
 
-    def get(chips: int, shared_negatives: int = 0):
-        key = (chips, shared_negatives)
+    def get(chips: int, shared_negatives: int = 0, vocab: int = V):
+        key = (chips, shared_negatives, vocab)
         if key not in built:
             mesh = Mesh(
                 np.asarray(topo.devices[:chips]).reshape(1, chips),
@@ -66,7 +66,7 @@ def engines(topo):
             )
             eng = EmbeddingEngine.__new__(EmbeddingEngine)
             eng._configure(
-                mesh, V, D, num_negatives=NEG, unigram_power=0.75,
+                mesh, vocab, D, num_negatives=NEG, unigram_power=0.75,
                 unigram_table_size=None, seed=1, dtype="float32",
                 extra_rows=0, shared_negatives=shared_negatives,
                 use_pallas=False, compute_dtype=None, layout="rows",
@@ -108,12 +108,9 @@ def _fits(compiled, chips: int = 1) -> dict:
     return mem
 
 
-@pytest.mark.parametrize(
-    "chips,shared_negatives",
-    [(1, 0), (1, 4096), (4, 0)],
-    ids=["1chip-per_pair", "1chip-shared_pool", "4chips-per_pair"],
-)
-def test_packed_corpus_scan_compiles(engines, chips, shared_negatives):
+def _compile_packed_scan(eng):
+    """The packed corpus scan at chip_smoke.py's training geometry (26,215
+    pairs a step, 5 negatives), compiled for the engine's described mesh."""
     import jax.numpy as jnp
 
     from glint_word2vec_tpu.corpus.batching import (
@@ -121,21 +118,31 @@ def test_packed_corpus_scan_compiles(engines, chips, shared_negatives):
         packed_pair_batch,
     )
 
-    eng = engines(chips, shared_negatives)
     sds = _shapes(eng)
     P_ = packed_pair_batch(BATCH, WINDOW, 1)
     span = -(-3 * P_ // context_width(WINDOW))
     fn = eng._make_packed_corpus_scan(
         P_, WINDOW, BATCH, span, STEPS_PER_CALL
     )
+    vocab = eng.vocab_size
     table = sds((eng.padded_vocab, D), jnp.float32, "model", None)
     offs = sds((CORPUS_SENTENCES + 1,), jnp.int32)
     i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
-    compiled = fn.lower(
-        table, table, sds((V,), jnp.float32), sds((V,), jnp.int32),
+    return fn.lower(
+        table, table, sds((vocab,), jnp.float32), sds((vocab,), jnp.int32),
         sds((CORPUS_WORDS,), jnp.int32), offs, offs, i32, i32,
         sds((2,), jnp.uint32), u32, u32, f32, f32, f32,
     ).compile()
+
+
+@pytest.mark.parametrize(
+    "chips,shared_negatives",
+    [(1, 0), (1, 4096), (4, 0)],
+    ids=["1chip-per_pair", "1chip-shared_pool", "4chips-per_pair"],
+)
+def test_packed_corpus_scan_compiles(engines, chips, shared_negatives):
+    eng = engines(chips, shared_negatives)
+    compiled = _compile_packed_scan(eng)
     mem = _fits(compiled, chips)
     # The tables are donated: the program must not hold a second pair.
     assert compiled.memory_analysis().alias_size_in_bytes >= (
@@ -145,6 +152,18 @@ def test_packed_corpus_scan_compiles(engines, chips, shared_negatives):
         # Rows really are spread: each device is handed 1/chips of them.
         assert mem["args"] < 2 * V * D * 4 / chips + 64 * 10**6, mem
         assert "all-reduce" in compiled.as_text()
+
+
+def test_packed_corpus_scan_at_the_benchmark_size(engines):
+    # The training cell's step (benchmark/configs/w2v-300-2m.json): 2M x
+    # 300 is the most rows the step program left room for, so what the
+    # step holds beside its donated tables must not grow past the 7.13 GB
+    # of the unsorted scatter (PR 25's tree: 7,125,079,040 bytes; the
+    # sorted triples and the run totals, padded to whole writer chunks,
+    # take the room the batch-order payload had and 4.3 MB more).
+    compiled = _compile_packed_scan(engines(1, vocab=2_000_000))
+    mem = _fits(compiled)
+    assert mem["temp"] <= 7.13e9, mem
 
 
 def test_subsample_compact_compiles(engines):

@@ -877,13 +877,15 @@ def test_new_request_spans_leave_graftlint_clean():
     assert {"req.grace", "req.pull"} <= set(obs_events.REQUEST_SPANS)
 
 
-@pytest.mark.parametrize(
+_PACKED_CASES = pytest.mark.parametrize(
     "shared_negatives,layout", [(0, "rows"), (16, "rows"), (0, "dims")],
     ids=["per_pair", "shared_pool", "per_pair-dims"],
 )
-def test_packed_scan_ops_carry_the_phase_scopes(shared_negatives, layout):
-    import re
 
+
+def _lowered_packed_scan(shared_negatives, layout):
+    """(text, table type) of the packed scan lowered on the CPU, with the
+    ops' locations in the text."""
     import jax
     import jax.numpy as jnp
 
@@ -904,8 +906,49 @@ def test_packed_scan_ops_carry_the_phase_scopes(shared_negatives, layout):
         ).as_text(debug_info=True)
     finally:
         model.stop()
+    rows, cols = table.shape
+    return text, f"tensor<{rows}x{cols}xf32>"
+
+
+@_PACKED_CASES
+def test_packed_scan_ops_carry_the_phase_scopes(shared_negatives, layout):
+    import re
+
+    text, _ = _lowered_packed_scan(shared_negatives, layout)
     scopes = set(re.findall(r"glint\.\w+(?:/syn[01])?", text))
     assert scopes == {
         "glint.batch", "glint.sample", "glint.gather", "glint.grads",
         "glint.scatter/syn0", "glint.scatter/syn1",
     }
+
+
+@_PACKED_CASES
+def test_packed_scan_writes_each_distinct_row_once(shared_negatives, layout):
+    """What the step's scatters promise the compiler, and where the work
+    that earns the promise is filed: each table is written by ONE scatter,
+    told its rows are distinct (``unique_indices``; not that they are
+    sorted, though they are: that flag picks XLA's other TPU emitter, a
+    pass over the whole table, PERF.md PR 26), and the sorts and the run
+    totals that make them distinct lie under the table's own scope, so
+    ``step.scatter_ms`` counts them."""
+    import re
+
+    text, table = _lowered_packed_scan(shared_negatives, layout)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    ops = re.findall(
+        r'"stablehlo\.(scatter|sort)"\(.*?\) <\{(.*?)\}> \(\{.*?\n\s*\}\) : '
+        r'\(.*?\) -> \(?(tensor<[^>]*>).*? loc\((#loc\d+)\)', text, re.S)
+    under = {"syn0": [], "syn1": []}
+    for op, attrs, result, loc in ops:
+        scope = re.match(r"glint\.scatter/(syn[01])/", names[loc])
+        if op == "scatter" and result == table:
+            assert scope, names[loc]
+            assert "unique_indices = true" in attrs
+            assert "indices_are_sorted = false" in attrs
+        if scope:
+            sorted_ = "indices_are_sorted = true" in attrs
+            under[scope.group(1)].append(
+                "write" if result == table else
+                "totals" if op == "scatter" and sorted_ else op)
+    for table_ops in under.values():
+        assert sorted(table_ops) == ["sort", "sort", "totals", "write"]
