@@ -1237,6 +1237,8 @@ class Word2Vec:
             model.training_metrics.update(
                 packed_pairs=packed_pairs,
                 packed_mask_density=round(packed_pairs / packed_slots, 4),
+                exchange_bytes_per_step=engine.packed_exchange_bytes(
+                    pair_batch),
             )
             if rows_written.any():
                 # Rows the scatters wrote over the update slots they were

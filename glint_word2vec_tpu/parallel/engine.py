@@ -105,23 +105,36 @@ def _pull_rows(table_l, idx, start, rows_per_shard, pallas=False):
     zeros elsewhere, then psum over the model axis. The TPU analogue of the
     servers each answering a pull with their slice (SURVEY.md §2.2 pull).
 
+    Opens its own scopes, so callers keep it OUT of theirs (a device trace
+    files an op under its outermost ``glint.`` scope): the own-row gather
+    and its mask under ``glint.gather``, the psum, which is the only part
+    that crosses chips, under its sibling ``glint.exchange``.
+
     ``pallas``: False = XLA gather (default), True = the Pallas row
     pipeline (ops/pallas_rows.py) in interpret mode, the only mode it has
     (see PALLAS_TPU_REFUSAL).
     """
-    loc = idx - start
-    own = (loc >= 0) & (loc < rows_per_shard)
-    clipped = jnp.clip(loc, 0, rows_per_shard - 1)
-    if pallas:
-        from glint_word2vec_tpu.ops.pallas_rows import gather_rows
+    with jax.named_scope("glint.gather"):
+        loc = idx - start
+        own = (loc >= 0) & (loc < rows_per_shard)
+        clipped = jnp.clip(loc, 0, rows_per_shard - 1)
+        if pallas:
+            from glint_word2vec_tpu.ops.pallas_rows import gather_rows
 
-        rows = gather_rows(
-            table_l, clipped, interpret=True
-        ).astype(jnp.float32)
-    else:
-        rows = table_l[clipped].astype(jnp.float32)
-    rows = jnp.where(own[:, None], rows, 0.0)
-    return lax.psum(rows, MODEL_AXIS)
+            rows = gather_rows(
+                table_l, clipped, interpret=True
+            ).astype(jnp.float32)
+        else:
+            rows = table_l[clipped].astype(jnp.float32)
+        rows = jnp.where(own[:, None], rows, 0.0)
+    return _exchange_sum(rows)
+
+
+def _exchange_sum(x):
+    """The step's model-axis exchange: one psum, under ``glint.exchange``
+    (``EmbeddingEngine.packed_exchange_bytes`` counts what it is handed)."""
+    with jax.named_scope("glint.exchange"):
+        return lax.psum(x, MODEL_AXIS)
 
 
 #: Update slots one trip of the row writer walks. XLA's TPU scatter into a
@@ -757,23 +770,21 @@ class EmbeddingEngine:
             start = lax.axis_index(MODEL_AXIS) * Vs
             drank = lax.axis_index(DATA_AXIS)
 
-            # The glint.* scopes (here, in step_body_dims and in the packed
-            # scan's body) name the step's five phases in every op's
-            # metadata, and nothing else: the device trace is split by
-            # them (benchmark/program_trace.py). A fusion is filed under
-            # its root's scope.
+            # The glint.* scopes (here, in step_body_dims, in _pull_rows
+            # and in the packed scan's body) name the step's five phases
+            # and its model-axis exchange in every op's metadata, and
+            # nothing else: the device trace is split by them
+            # (benchmark/program_trace.py). A fusion is filed under its
+            # root's scope, an op under its OUTERMOST one: _pull_rows
+            # opens its own two and is called outside any other.
+            h_rows = _pull_rows(syn0_l, centers.reshape(-1), start, Vs, pm)
+            u_pos = _pull_rows(syn1_l, contexts.reshape(-1), start, Vs, pm)
             with jax.named_scope("glint.gather"):
-                h_rows = _pull_rows(
-                    syn0_l, centers.reshape(-1), start, Vs, pm
-                )
                 h_rows = h_rows.reshape(Bl, S, -1)
                 cnt = jnp.maximum(
                     cmask.sum(axis=1, keepdims=True), 1.0
                 )  # (Bl,1)
                 h = (h_rows * cmask[..., None]).sum(axis=1) / cnt
-                u_pos = _pull_rows(
-                    syn1_l, contexts.reshape(-1), start, Vs, pm
-                )
                 u_pos = u_pos.reshape(Bl, C, -1)
 
                 # The data-axis exchange ships ONLY h (B, d), scalar
@@ -795,8 +806,7 @@ class EmbeddingEngine:
                     pool = sample_negatives(
                         key, prob, alias, (self.shared_negatives,)
                     )
-                with jax.named_scope("glint.gather"):
-                    u_pool = _pull_rows(syn1_l, pool, start, Vs, pm)
+                u_pool = _pull_rows(syn1_l, pool, start, Vs, pm)
                 with jax.named_scope("glint.sample"):
                     collide = sgns.pool_collision_mask(pool, contexts, mask)
                 with jax.named_scope("glint.grads"):
@@ -829,10 +839,8 @@ class EmbeddingEngine:
                     negs = sample_negatives_per_row(
                         key, prob, alias, rows_g, (C, n)
                     )
+                u_neg = _pull_rows(syn1_l, negs.reshape(-1), start, Vs, pm)
                 with jax.named_scope("glint.gather"):
-                    u_neg = _pull_rows(
-                        syn1_l, negs.reshape(-1), start, Vs, pm
-                    )
                     u_neg = u_neg.reshape(Bl, C, n, -1)
                 with jax.named_scope("glint.sample"):
                     nmask = sgns.negative_mask(negs, contexts, mask)
@@ -920,20 +928,16 @@ class EmbeddingEngine:
                 with jax.named_scope("glint.sample"):
                     collide = sgns.pool_collision_mask(pool, contexts, mask)
                 with jax.named_scope("glint.grads"):
-                    f_pos = lax.psum(
-                        jnp.einsum(
-                            "bd,bcd->bc", h.astype(cd), u_pos.astype(cd),
-                            preferred_element_type=jnp.float32,
-                        ),
-                        MODEL_AXIS,
+                    f_pos = jnp.einsum(
+                        "bd,bcd->bc", h.astype(cd), u_pos.astype(cd),
+                        preferred_element_type=jnp.float32,
                     )
-                    f_pool = lax.psum(
-                        jnp.dot(
-                            h.astype(cd), u_pool.astype(cd).T,
-                            preferred_element_type=jnp.float32,
-                        ),
-                        MODEL_AXIS,
+                    f_pool = jnp.dot(
+                        h.astype(cd), u_pool.astype(cd).T,
+                        preferred_element_type=jnp.float32,
                     )
+                f_pos, f_pool = _exchange_sum(f_pos), _exchange_sum(f_pool)
+                with jax.named_scope("glint.grads"):
                     co = sgns.shared_sgns_coefs(
                         f_pos, f_pool, mask, collide,
                         alpha.astype(jnp.float32), n,
@@ -962,20 +966,16 @@ class EmbeddingEngine:
                 with jax.named_scope("glint.sample"):
                     nmask = sgns.negative_mask(negs, contexts, mask)
                 with jax.named_scope("glint.grads"):
-                    f_pos = lax.psum(
-                        jnp.einsum(
-                            "bd,bcd->bc", h.astype(cd), u_pos.astype(cd),
-                            preferred_element_type=jnp.float32,
-                        ),
-                        MODEL_AXIS,
+                    f_pos = jnp.einsum(
+                        "bd,bcd->bc", h.astype(cd), u_pos.astype(cd),
+                        preferred_element_type=jnp.float32,
                     )
-                    f_neg = lax.psum(
-                        jnp.einsum(
-                            "bd,bcnd->bcn", h.astype(cd), u_neg.astype(cd),
-                            preferred_element_type=jnp.float32,
-                        ),
-                        MODEL_AXIS,
+                    f_neg = jnp.einsum(
+                        "bd,bcnd->bcn", h.astype(cd), u_neg.astype(cd),
+                        preferred_element_type=jnp.float32,
                     )
+                f_pos, f_neg = _exchange_sum(f_pos), _exchange_sum(f_neg)
+                with jax.named_scope("glint.grads"):
                     co = sgns.sgns_coefs(
                         f_pos, f_neg, mask, nmask, alpha.astype(jnp.float32)
                     )
@@ -2031,6 +2031,23 @@ class EmbeddingEngine:
         if self.shared_negatives:
             return pair_batch, pair_batch + self.shared_negatives
         return pair_batch, pair_batch * (1 + self.num_negatives)
+
+    def packed_exchange_bytes(self, pair_batch: int) -> int:
+        """Bytes one device hands the model-axis collectives of one packed
+        step (the psums under ``glint.exchange``), from shapes alone: the
+        float32 rows it pulls in the ``rows`` layout (a centre, a context
+        and the negatives, or the shared pool once, for each of its
+        pairs), the logit partials in ``dims``; 0 where the model axis
+        has one shard."""
+        if self.num_model == 1:
+            return 0
+        pairs = pair_batch // self.num_data
+        if self.layout == "dims":
+            return 4 * pairs * (
+                1 + (self.shared_negatives or self.num_negatives))
+        rows = (2 * pairs + self.shared_negatives if self.shared_negatives
+                else pairs * (2 + self.num_negatives))
+        return 4 * rows * self.dim
 
     # ------------------------------------------------------------------
     # Serving ops (the BigWord2VecMatrix query surface)

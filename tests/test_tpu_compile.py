@@ -108,9 +108,10 @@ def _fits(compiled, chips: int = 1) -> dict:
     return mem
 
 
-def _compile_packed_scan(eng):
+def _compile_packed_scan(eng, words=CORPUS_WORDS, sentences=CORPUS_SENTENCES):
     """The packed corpus scan at chip_smoke.py's training geometry (26,215
-    pairs a step, 5 negatives), compiled for the engine's described mesh."""
+    pairs a step, 5 negatives) over a resident corpus of ``words`` tokens,
+    compiled for the engine's described mesh."""
     import jax.numpy as jnp
 
     from glint_word2vec_tpu.corpus.batching import (
@@ -126,11 +127,11 @@ def _compile_packed_scan(eng):
     )
     vocab = eng.vocab_size
     table = sds((eng.padded_vocab, D), jnp.float32, "model", None)
-    offs = sds((CORPUS_SENTENCES + 1,), jnp.int32)
+    offs = sds((sentences + 1,), jnp.int32)
     i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
     return fn.lower(
         table, table, sds((vocab,), jnp.float32), sds((vocab,), jnp.int32),
-        sds((CORPUS_WORDS,), jnp.int32), offs, offs, i32, i32,
+        sds((words,), jnp.int32), offs, offs, i32, i32,
         sds((2,), jnp.uint32), u32, u32, f32, f32, f32,
     ).compile()
 
@@ -152,6 +153,10 @@ def test_packed_corpus_scan_compiles(engines, chips, shared_negatives):
         # Rows really are spread: each device is handed 1/chips of them.
         assert mem["args"] < 2 * V * D * 4 / chips + 64 * 10**6, mem
         assert "all-reduce" in compiled.as_text()
+    else:
+        # One shard exchanges nothing: the chip's compiler drops the
+        # psums over a model axis of one, glint.exchange and all.
+        assert "all-reduce" not in compiled.as_text()
 
 
 def test_packed_corpus_scan_at_the_benchmark_size(engines):
@@ -164,6 +169,26 @@ def test_packed_corpus_scan_at_the_benchmark_size(engines):
     compiled = _compile_packed_scan(engines(1, vocab=2_000_000))
     mem = _fits(compiled)
     assert mem["temp"] <= 7.13e9, mem
+
+
+def test_packed_corpus_scan_at_the_four_chip_cell_size(engines):
+    # The sharded cell's step (benchmark/configs/w2v-300-10m-x4.json): 10M
+    # x 300 f32 is 24 GB of tables, 6 GB a chip over the host's four, and
+    # its corpus (every word once, 5M Zipf draws in 40-word sentences,
+    # 80,000 planted ones) is resident and replicated. The tables are
+    # donated, the rows cross chips through an all-reduce, and the whole
+    # must fit one chip's 16 GB: what is left over is all the room a later
+    # PR has for the step's temporaries.
+    vocab, words = 10_000_000, 15_639_956
+    sentences = -(-(words - 8 * 80_000) // 40) + 80_000
+    eng = engines(4, vocab=vocab)
+    compiled = _compile_packed_scan(eng, words, sentences)
+    mem = _fits(compiled, 4)
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        2 * eng.rows_per_shard * D * 4
+    ), mem
+    assert mem["args"] < 2 * vocab * D * 4 / 4 + 256 * 10**6, mem
+    assert "all-reduce" in compiled.as_text()
 
 
 def test_subsample_compact_compiles(engines):

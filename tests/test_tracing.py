@@ -917,8 +917,8 @@ def test_packed_scan_ops_carry_the_phase_scopes(shared_negatives, layout):
     text, _ = _lowered_packed_scan(shared_negatives, layout)
     scopes = set(re.findall(r"glint\.\w+(?:/syn[01])?", text))
     assert scopes == {
-        "glint.batch", "glint.sample", "glint.gather", "glint.grads",
-        "glint.scatter/syn0", "glint.scatter/syn1",
+        "glint.batch", "glint.sample", "glint.gather", "glint.exchange",
+        "glint.grads", "glint.scatter/syn0", "glint.scatter/syn1",
     }
 
 
