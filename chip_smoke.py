@@ -566,7 +566,8 @@ def run_four_chips(size: dict, backend: str, tol: float) -> dict:
     per_dev = {str(s.device): int(s.data.nbytes)
                for s in model.engine.syn0.addressable_shards}
     say(f"sharded: syn0 bytes per device {per_dev}")
-    whole = size["vocab"] * size["dim"] * 4
+    # a table as it rests: rows of whole lanes (engine.TABLE_LANES)
+    whole = size["vocab"] * model.engine.padded_dim * 4
     check("sharded.rows_spread",
           len(per_dev) == 4 and max(per_dev.values()) <= whole // 4 + 4096,
           f"largest shard {max(per_dev.values())} of {whole} bytes")

@@ -75,6 +75,24 @@ from glint_word2vec_tpu.parallel.mesh import (
 )
 
 
+#: A ``rows`` table keeps each row in whole lanes of the device's
+#: (8, 128) tile: ``dim`` columns rest in ``pad_to_multiple(dim,
+#: TABLE_LANES)``, the rest zero for good (a zero column adds nothing to
+#: a dot product and its gradient is zero). The TPU's default layout for
+#: ``f32[N, 300]`` is column-major (300 pads to 304 sublanes, not to 384
+#: lanes), and every program that gathers or scatters ROWS then copied
+#: the whole table at its edge: four copies a dispatch of the packed
+#: scan, one a ``pull``. For ``f32[N, 384]`` the default IS row-major, so
+#: the tables rest as every program wants them and nothing is pinned.
+#: (Pinning ``jax.experimental.layout.Format`` on the 300-column shape
+#: gives the same bytes, but jaxlib 0.9.0 labels the outputs of an
+#: executable it loaded from the persistent compile cache with the
+#: DEFAULT layout, whatever it was compiled with, so every warm process
+#: lowers the next program for a layout the buffer does not have:
+#: PERF.md, PR 28.)
+TABLE_LANES = 128
+
+
 def _host_or_device(a, dtype=None):
     """Normalize a batch input WITHOUT moving it across the host/device
     boundary: device-resident ``jax.Array`` inputs are kept on device
@@ -453,7 +471,11 @@ class EmbeddingEngine:
             ``dotprod`` servers return). Per-chip HBM traffic for the
             sparse row accesses divides by the model-axis size.
 
-        Guidance: per-chip table memory is identical (V*d/n either way).
+        Guidance: per-chip table memory is V*d/n either way, except that
+        "rows" keeps each row in whole 128-column lanes (``TABLE_LANES``:
+        d = 300 rests in 384 columns, so that the device's default layout
+        keeps rows contiguous and no program copies a table to reach a
+        few rows); "dims" pads the columns to the shard count only.
         For TRAINING at num_model > 1, "dims" is the better default —
         its model-axis collectives are ~d/(1+overlap) times smaller and
         its sparse HBM traffic scales down with the axis. "rows" wins
@@ -591,8 +613,8 @@ class EmbeddingEngine:
         if layout == "rows":
             self.padded_vocab = pad_to_multiple(self.num_rows, self.num_model)
             self.rows_per_shard = self.padded_vocab // self.num_model
-            self.padded_dim = self.dim
-            self.cols_per_shard = self.dim
+            self.padded_dim = pad_to_multiple(self.dim, TABLE_LANES)
+            self.cols_per_shard = self.padded_dim
         else:  # dims
             self.padded_vocab = self.num_rows  # no row padding needed
             self.rows_per_shard = self.num_rows
@@ -1255,7 +1277,7 @@ class EmbeddingEngine:
                 )  # (L, padded_dim)
                 return full[:, :dim_real]
             start = lax.axis_index(MODEL_AXIS) * Vs
-            return _pull_rows(table_l, idx, start, Vs, pm)
+            return _pull_rows(table_l, idx, start, Vs, pm)[:, :dim_real]
 
         self._pull = shared_query_program("pull", lambda: jax.jit(
             self._shard_map(local_pull, in_specs=(tspec, rep), out_specs=rep)
@@ -1274,7 +1296,7 @@ class EmbeddingEngine:
                 return full[:, :dim_real]
             start = lax.axis_index(MODEL_AXIS) * Vs
             rows = _pull_rows(table_l, idx.reshape(-1), start, Vs, pm)
-            rows = rows.reshape(S, L, -1) * m[..., None]
+            rows = rows[:, :dim_real].reshape(S, L, -1) * m[..., None]
             return rows.sum(axis=1) / jnp.maximum(
                 m.sum(axis=1)[:, None], 1.0
             )
@@ -2037,8 +2059,8 @@ class EmbeddingEngine:
         step (the psums under ``glint.exchange``), from shapes alone: the
         float32 rows it pulls in the ``rows`` layout (a centre, a context
         and the negatives, or the shared pool once, for each of its
-        pairs), the logit partials in ``dims``; 0 where the model axis
-        has one shard."""
+        pairs; rows as they rest, ``padded_dim`` wide), the logit partials
+        in ``dims``; 0 where the model axis has one shard."""
         if self.num_model == 1:
             return 0
         pairs = pair_batch // self.num_data
@@ -2047,7 +2069,7 @@ class EmbeddingEngine:
                 1 + (self.shared_negatives or self.num_negatives))
         rows = (2 * pairs + self.shared_negatives if self.shared_negatives
                 else pairs * (2 + self.num_negatives))
-        return 4 * rows * self.dim
+        return 4 * rows * self.padded_dim
 
     # ------------------------------------------------------------------
     # Serving ops (the BigWord2VecMatrix query surface)
@@ -2237,11 +2259,13 @@ class EmbeddingEngine:
         e.g. composed subword vectors, without a host round-trip). The
         start index is a traced argument, so chunked writers compile once
         per chunk shape."""
-        fn = self._row_writer()
-        pad = self.padded_dim - self.dim
-        if pad:
-            rows = jnp.pad(rows, ((0, 0), (0, pad)))
-        self.syn0 = fn(self.syn0, rows, jnp.int32(start_row))
+        # The block is ``dim`` wide and lands at column 0: the padding
+        # columns beside it are zero and stay so, with no padded copy of
+        # the block made first (a whole table's worth when a table is
+        # installed in one call).
+        self.syn0 = self._row_writer()(
+            self.syn0, rows, jnp.int32(start_row)
+        )
         self._tick_tables("write_rows")
         self._ann_touch_rows(range(start_row, start_row + rows.shape[0]))
 
@@ -2452,8 +2476,8 @@ class EmbeddingEngine:
         return self._norms_cache
 
     def _pad_query(self, v: np.ndarray) -> jnp.ndarray:
-        """Pad a (d,) or (Q, d) query to padded_dim for the dims layout
-        (zero columns contribute zero to every partial dot product)."""
+        """Pad a (d,) or (Q, d) query to the tables' ``padded_dim`` (zero
+        columns contribute zero to every dot product)."""
         pad = self.padded_dim - self.dim
         if pad:
             widths = [(0, 0)] * (v.ndim - 1) + [(0, pad)]
@@ -2476,6 +2500,8 @@ class EmbeddingEngine:
         if not 0 < k <= self.padded_vocab:
             raise ValueError(f"k must be in [1, {self.padded_vocab}]")
         v = np.asarray(vec, dtype=np.float32)
+        if v.shape != (self.dim,):
+            raise ValueError(f"vec must have shape ({self.dim},)")
         nrm = float(np.linalg.norm(v))
         if nrm > 0:
             v = v / nrm

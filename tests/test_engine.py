@@ -50,11 +50,16 @@ def test_padding_geometry():
     assert eng.padded_vocab == 52  # 50 -> multiple of 4
     assert eng.rows_per_shard == 13
     assert eng.cols == D
+    # rows rest in whole 128-column lanes, the padding zero
+    assert eng.padded_dim == 128 and eng.syn0.shape == (52, 128)
+    assert not np.asarray(eng.syn0)[:, D:].any()
+    with pytest.raises(ValueError, match="shape"):
+        eng.top_k_cosine(np.ones(eng.padded_dim, np.float32), 3)
 
 
 def test_pull_matches_host_tables():
     eng = _mk_engine(1, 8)
-    syn0 = np.asarray(eng.syn0)[:V]
+    syn0 = np.asarray(eng.syn0)[:V, :D]
     idx = np.array([0, 7, 49, 3, 3], np.int32)
     rows = np.asarray(eng.pull(idx))
     np.testing.assert_allclose(rows, syn0[idx], rtol=1e-6)
@@ -62,7 +67,7 @@ def test_pull_matches_host_tables():
 
 def test_norms_and_multiply_match_host():
     eng = _mk_engine(2, 4)
-    syn0 = np.asarray(eng.syn0, dtype=np.float32)
+    syn0 = np.asarray(eng.syn0, dtype=np.float32)[:, :D]
     nrm = np.asarray(eng.norms())
     np.testing.assert_allclose(nrm, np.linalg.norm(syn0, axis=1), rtol=1e-5)
     v = np.random.default_rng(0).normal(size=D).astype(np.float32)
@@ -72,7 +77,7 @@ def test_norms_and_multiply_match_host():
 
 def test_pull_average_masked_mean_and_empty_row():
     eng = _mk_engine(1, 8)
-    syn0 = np.asarray(eng.syn0)
+    syn0 = np.asarray(eng.syn0)[:, :D]
     idx = np.array([[1, 2, 0], [5, 0, 0], [0, 0, 0]], np.int32)
     m = np.array([[1, 1, 0], [1, 0, 0], [0, 0, 0]], np.float32)
     out = np.asarray(eng.pull_average(idx, m))
@@ -84,7 +89,7 @@ def test_pull_average_masked_mean_and_empty_row():
 
 def test_top_k_cosine_matches_host():
     eng = _mk_engine(2, 4)
-    syn0 = np.asarray(eng.syn0, dtype=np.float32)[:V]
+    syn0 = np.asarray(eng.syn0, dtype=np.float32)[:V, :D]
     q = syn0[17].copy()
     sims, idx = eng.top_k_cosine(q, 5)
     nrm = np.linalg.norm(syn0, axis=1)

@@ -525,7 +525,7 @@ def test_bf16_generation_round_trip(tmp_path):
     # Query path stays fp32: norms cache and top-k scores.
     norms = server_eng.norms()
     assert np.asarray(norms).dtype == np.float32
-    upcast = np.asarray(server_eng.syn0, np.float32)[:Vv]
+    upcast = np.asarray(server_eng.syn0, np.float32)[:Vv, :d]
     safe = np.linalg.norm(upcast, axis=1)
     for qi in (0, 3, 17):
         q = upcast[qi] / np.linalg.norm(upcast[qi])

@@ -27,7 +27,10 @@ from glint_word2vec_tpu.corpus.batching import (  # noqa: E402
     context_width,
     packed_pair_batch,
 )
-from glint_word2vec_tpu.parallel.engine import EmbeddingEngine  # noqa: E402
+from glint_word2vec_tpu.parallel.engine import (  # noqa: E402
+    TABLE_LANES,
+    EmbeddingEngine,
+)
 from glint_word2vec_tpu.parallel.mesh import make_mesh  # noqa: E402
 
 V, D, NEG, WINDOW, BATCH, K = 512, 32, 5, 5, 64, 3
@@ -134,8 +137,10 @@ def test_packed_scan_on_a_1x4_mesh_is_the_sharded_reference():
            "run": {"batch_size": BATCH}}
     batches = capture_batches(eng, cfg, seed, K, total_words)
     rows = touched(batches)
-    prog0 = np.asarray(eng.syn0, np.float32)[rows]
-    prog1 = np.asarray(eng.syn1, np.float32)[rows]
+    # as benchmark/kinds/train_sharded.py reads them: the D real columns
+    # of rows that rest in whole lanes
+    prog0 = np.asarray(eng.syn0, np.float32)[rows][:, :D]
+    prog1 = np.asarray(eng.syn1, np.float32)[rows][:, :D]
     assert {s.data.shape[0] for s in eng.syn0.addressable_shards} == {V // 4}
     gaps = reference_sharded.replay_gaps(
         seed, V, D, rows, batches, prog0, prog1,
@@ -183,7 +188,12 @@ def all_reduces(shape, layout="rows"):
     return found
 
 
-@pytest.mark.parametrize("layout,moved", [("rows", f",{D}]"), ("dims", "f32[")])
+# rows cross the model axis as they rest, in whole lanes
+D_REST = -(-D // TABLE_LANES) * TABLE_LANES
+
+
+@pytest.mark.parametrize(
+    "layout,moved", [("rows", f",{D_REST}]"), ("dims", "f32[")])
 def test_the_exchange_has_its_own_scope(layout, moved):
     found = all_reduces((1, 4), layout)
     across = [f for f in found if f[2] == "{{0,1,2,3}}"]
@@ -219,13 +229,13 @@ def test_exchange_bytes_is_what_the_shapes_say(shape, layout):
         assert counted == 4 * PAIRS * (1 + NEG)
     else:
         assert counted == bytes_sharded.exchange_bytes(
-            BATCH, WINDOW, NEG, D, n_model)
+            BATCH, WINDOW, NEG, D_REST, n_model)
     if shape == (1, 4) and layout == "rows":
         # ... and is what the compiled step hands its row all-reduces
         rows = sum(int(n) for f in all_reduces(shape, layout)
                    if "glint.exchange" in f[1]
-                   for n in re.findall(r"f32\[(\d+),%d\]" % D, f[0]))
-        assert 4 * rows * D == counted
+                   for n in re.findall(r"f32\[(\d+),%d\]" % D_REST, f[0]))
+        assert 4 * rows * D_REST == counted
 
 
 def test_fit_reports_the_exchange(tmp_path):
@@ -245,5 +255,6 @@ def test_fit_reports_the_exchange(tmp_path):
         seen[shards] = model.training_metrics["exchange_bytes_per_step"]
         model.stop()
     assert seen[1] == 0
-    assert seen[4] == bytes_sharded.exchange_bytes(BATCH, WINDOW, NEG, D, 4)
+    assert seen[4] == bytes_sharded.exchange_bytes(
+        BATCH, WINDOW, NEG, D_REST, 4)
     assert bytes_sharded.all_reduce_wire_bytes(seen[4], 4) == 1.5 * seen[4]
