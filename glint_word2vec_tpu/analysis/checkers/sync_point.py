@@ -48,19 +48,17 @@ SYNC_SEAMS: Dict[str, str] = {
     "glint_word2vec_tpu/models/word2vec.py::"
     "Word2Vec._fit_corpus_resident._harvest_packed":
         "the one-group-deferred scalar harvest seam (PR 5): syncs "
-        "group g while group g+1 runs; since ISSUE 11 these are the "
-        "fused Pallas megakernel's result scalars (losses/pair "
-        "counts/position advances) whenever the engine runs "
-        "pallas-fused — the kernel's ONLY host-visible outputs",
+        "group g while group g+1 runs: the packed scan's result "
+        "scalars (losses, pair counts, position advances, rows "
+        "written)",
     "glint_word2vec_tpu/models/word2vec.py::"
     "Word2Vec._fit_with_batcher._harvest_host":
         "host-batcher twin of the deferred harvest: one-group-lagged "
         "loss/word records",
     "glint_word2vec_tpu/streaming/trainer.py::StreamTrainer._harvest":
         "streaming mini-epoch harvest seam (ISSUE 10): syncs one "
-        "dispatched group's result scalars (the fused megakernel's "
-        "scalars under ISSUE 11 pallas-fused engines); the buffer is "
-        "already uploaded, so nothing starves behind the sync",
+        "dispatched group's result scalars; the buffer is already "
+        "uploaded, so nothing starves behind the sync",
     # Checkpoint harvest: device->host shard copies on the save path
     # run on the caller thread by design (PR 5's async protocol).
     "glint_word2vec_tpu/parallel/engine.py::"

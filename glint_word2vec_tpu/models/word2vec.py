@@ -310,8 +310,7 @@ class Word2Vec:
         """Device-corpus dispatch shape: "dense" (the default — valid
         (center, context) pairs prefix-sum-compacted into dense
         fixed-shape pair batches on device before the update, so ~every
-        dispatched FLOP is a useful pair, and the shape the fused
-        Pallas megakernel accelerates) or "grid" (the legacy reference
+        dispatched FLOP is a useful pair) or "grid" (the legacy reference
         (batch, context) window grids — ~43% live lanes at window 5 —
         kept for A/B comparison and old mid-epoch grid checkpoints).
         See README "Dense pair packing"."""
@@ -1222,8 +1221,7 @@ class Word2Vec:
         if steptime:
             model.training_metrics["steptime"] = steptime
         model.training_metrics["batch_packing"] = p.batch_packing
-        model.training_metrics["step_body"] = engine.step_body(packed)
-        model.training_metrics["pallas_mode"] = engine.pallas_mode
+        model.training_metrics["step_body"] = engine.step_body
         if exchanger is not None:
             model.training_metrics["exchange_mode"] = p.exchange
             model.training_metrics["exchange_wire"] = p.exchange_wire
@@ -1628,8 +1626,7 @@ class Word2Vec:
         model = self._make_model(vocab, engine)
         model.training_metrics = {
             **metrics.summary(), "pipeline": "host",
-            "step_body": engine.step_body(False),
-            "pallas_mode": engine.pallas_mode,
+            "step_body": engine.step_body,
         }
         steptime = obs_run.steptime_totals()
         if steptime:

@@ -376,49 +376,6 @@ def train_step_pairs(
     )
 
 
-def train_step_pairs_pallas(
-    syn0: jax.Array,  # (V, d)
-    syn1: jax.Array,  # (V, d)
-    prob: jax.Array,  # (V,) alias acceptance probs
-    alias: jax.Array,  # (V,) alias targets
-    centers: jax.Array,  # (P,) int32
-    contexts: jax.Array,  # (P,) int32
-    pair_mask: jax.Array,  # (P,) float32
-    key: jax.Array,
-    alpha: jax.Array,  # () float32
-    num_negatives: int,
-    *,
-    interpret: bool = False,
-    block_rows: int = 8,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused-Pallas twin of :func:`train_step_pairs` (ISSUE 11): the
-    identical update — same per-GLOBAL-pair-row negative draws, same
-    coefficients, same duplicate-row sum semantics — executed by the
-    ops/pallas_sgns megakernel instead of the XLA-composed gather ->
-    dot -> rank-1 -> scatter chain, so every touched row crosses the
-    HBM<->VMEM boundary once per phase and all arithmetic runs in fp32
-    VMEM accumulators over fp32 OR bf16 table storage. The 3-way parity
-    gate (tests/test_pallas_sgns.py) pins this function against the
-    composed step and a host-NumPy oracle."""
-    from glint_word2vec_tpu.ops.pallas_sgns import fused_pair_step
-
-    P = centers.shape[0]
-    negs = sample_negatives_per_row(
-        key, prob, alias, jnp.arange(P, dtype=jnp.int32),
-        (1, num_negatives),
-    )  # (P, 1, n) — the exact draw train_step_pairs makes (C=1)
-    nmask = negative_mask(
-        negs, contexts[:, None], pair_mask[:, None]
-    )  # (P, 1, n)
-    syn0, syn1, loss_sum = fused_pair_step(
-        syn0, syn1, centers, contexts, pair_mask,
-        negs[:, 0, :], nmask[:, 0, :], alpha.astype(jnp.float32),
-        interpret=interpret, block_rows=block_rows,
-    )
-    loss = loss_sum / jnp.maximum(pair_mask.sum(), 1.0)
-    return syn0, syn1, loss
-
-
 def sgns_loss(
     syn0: jax.Array,
     syn1: jax.Array,

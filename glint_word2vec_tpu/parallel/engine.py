@@ -118,7 +118,7 @@ def _score(table_l, q):
     )
 
 
-def _pull_rows(table_l, idx, start, rows_per_shard, pallas=False):
+def _pull_rows(table_l, idx, start, rows_per_shard):
     """Gather global rows from a shard-local table: contribute owned rows,
     zeros elsewhere, then psum over the model axis. The TPU analogue of the
     servers each answering a pull with their slice (SURVEY.md §2.2 pull).
@@ -127,23 +127,12 @@ def _pull_rows(table_l, idx, start, rows_per_shard, pallas=False):
     files an op under its outermost ``glint.`` scope): the own-row gather
     and its mask under ``glint.gather``, the psum, which is the only part
     that crosses chips, under its sibling ``glint.exchange``.
-
-    ``pallas``: False = XLA gather (default), True = the Pallas row
-    pipeline (ops/pallas_rows.py) in interpret mode, the only mode it has
-    (see PALLAS_TPU_REFUSAL).
     """
     with jax.named_scope("glint.gather"):
         loc = idx - start
         own = (loc >= 0) & (loc < rows_per_shard)
         clipped = jnp.clip(loc, 0, rows_per_shard - 1)
-        if pallas:
-            from glint_word2vec_tpu.ops.pallas_rows import gather_rows
-
-            rows = gather_rows(
-                table_l, clipped, interpret=True
-            ).astype(jnp.float32)
-        else:
-            rows = table_l[clipped].astype(jnp.float32)
+        rows = table_l[clipped].astype(jnp.float32)
         rows = jnp.where(own[:, None], rows, 0.0)
     return _exchange_sum(rows)
 
@@ -217,18 +206,24 @@ def _run_totals(key, coefs, src, hidx, n_rows):
     return u, tot, live_end.sum(dtype=jnp.int32)
 
 
-def _scatter_add_rows(table_l, key, coefs, src, hidx):
-    """``table_l[key[k]] += coefs[k] * src[hidx[k]]`` for every slot whose
-    key is a row of ``table_l``; any other key is dropped. Every table
-    dtype and layout takes this one path: the slots are sorted, each
-    row's updates are summed once in float32 (:func:`_run_totals`), and
-    each distinct row is written once, its total rounded once to the
-    table's dtype. The scatter is told what is then true of its rows (no
-    two alike, strays dropped) but not that they are sorted: that flag
-    selects XLA's other TPU emitter, which passes over the whole table
-    (9.4 ms at 2M x 300) before it adds a slot. Returns ``(table_l, rows
-    written)``."""
-    u, tot, n_u = _run_totals(key, coefs, src, hidx, table_l.shape[0])
+def _scatter_rows(table_l, idx, coefs, src, hidx, start):
+    """Apply global rank-1 updates to the owned slice of a sharded table
+    (the servers' half of ``adjust``, SURVEY.md §2.2): slot k adds
+    ``coefs[k] * src[hidx[k]]`` to global row ``idx[k]``, where ``start``
+    is the global id of local row 0. Updates of rows another shard owns
+    are dropped, not walked. Every table dtype and layout takes this one
+    path: the slots are sorted, each row's updates are summed once in
+    float32 (:func:`_run_totals`), and each distinct row is written once,
+    its total rounded once to the table's dtype. The scatter is told what
+    is then true of its rows (no two alike, strays dropped) but not that
+    they are sorted: that flag selects XLA's other TPU emitter, which
+    passes over the whole table (9.4 ms at 2M x 300) before it adds a
+    slot. Returns ``(table_l, rows written)``."""
+    Vs = table_l.shape[0]
+    loc = idx - start
+    own = (loc >= 0) & (loc < Vs)
+    key = jnp.where(own, loc, Vs)
+    u, tot, n_u = _run_totals(key, coefs, src, hidx, Vs)
     chunk = _writer_chunk(key.shape[0])
 
     def write(k, t):
@@ -239,51 +234,6 @@ def _scatter_add_rows(table_l, key, coefs, src, hidx):
 
     return lax.fori_loop(0, -(-n_u // chunk), write, table_l), n_u
 
-
-def _scatter_rows(table_l, idx, coefs, src, hidx, start, pallas=False):
-    """Apply global rank-1 updates to the owned slice of a sharded table
-    (the servers' half of ``adjust``, SURVEY.md §2.2): slot k adds
-    ``coefs[k] * src[hidx[k]]`` to global row ``idx[k]``. Updates of rows
-    another shard owns are dropped, not walked. Returns ``(table_l, rows
-    written)``.
-
-    ``pallas``: False = the XLA path; ``"rows"`` / ``"rank1"`` = the
-    Pallas kernels of ops/pallas_rows.py in interpret mode, the only mode
-    they have (see PALLAS_TPU_REFUSAL): the row pipeline, or the fused
-    rank-1 scatter (payload formed in VMEM) where ``src`` fits its budget
-    and the table is float32, else the row pipeline."""
-    Vs = table_l.shape[0]
-    loc = idx - start
-    own = (loc >= 0) & (loc < Vs)
-    key = jnp.where(own, loc, Vs)
-    if not pallas:
-        return _scatter_add_rows(table_l, key, coefs, src, hidx)
-    from glint_word2vec_tpu.ops import pallas_rows
-
-    # Both kernels accumulate a run in TABLE dtype, so the row pipeline
-    # is handed float32 run totals, one per distinct row (what bf16
-    # storage needs to round a row's batch total once; the sentinels
-    # land as zero rows on the last row), and the fused one is kept to
-    # float32 tables.
-    u, tot, n_u = _run_totals(key, coefs, src, hidx, Vs)
-    if (
-        pallas == "rank1"
-        and src.shape[0] * src.shape[1] * 4 <= _RANK1_FUSE_VMEM_BYTES
-        and table_l.dtype == jnp.float32
-    ):
-        return pallas_rows.scatter_add_rank1(
-            table_l, jnp.minimum(key, Vs - 1), jnp.where(own, coefs, 0.0),
-            src, hidx, interpret=True,
-        ), n_u
-    return pallas_rows.scatter_add_rows(
-        table_l, jnp.minimum(u, Vs - 1),
-        jnp.where((u < Vs)[:, None], tot, 0.0), interpret=True,
-    ), n_u
-
-
-#: VMEM budget for pinning h_g whole in the fused rank-1 scatter kernel
-#: (ops/pallas_rows.scatter_add_rank1): ~16 MB/core minus block buffers.
-_RANK1_FUSE_VMEM_BYTES = 10_000_000
 
 #: Process-wide memo of the jitted corpus-scan programs, keyed by every
 #: engine attribute their closures capture (:meth:`EmbeddingEngine
@@ -316,13 +266,13 @@ def _scan_memo_put(key, fn):
 #: norms, multiply, and the per-k top-k / batch-top-k factories),
 #: keyed on :meth:`EmbeddingEngine._query_memo_key` — the mesh
 #: geometry plus the query-relevant engine attributes ONLY. Unlike the
-#: scan memo, training-only attributes (negatives, compute dtype,
-#: fused-kernel mode) are deliberately EXCLUDED from the key: two
-#: models trained differently but serving the same (V, d) shape share
-#: every compiled query program, because tables and norms are traced
-#: ARGUMENTS to all of them (ISSUE 20 — loading model #2..N of a
-#: same-shape catalog triggers zero new XLA compiles). Entries hold
-#: only jit closures over specs and scalars, never table buffers.
+#: scan memo, training-only attributes (negatives, compute dtype) are
+#: deliberately EXCLUDED from the key: two models trained differently
+#: but serving the same (V, d) shape share every compiled query program,
+#: because tables and norms are traced ARGUMENTS to all of them (ISSUE
+#: 20 — loading model #2..N of a same-shape catalog triggers zero new
+#: XLA compiles). Entries hold only jit closures over specs and scalars,
+#: never table buffers.
 _QUERY_MEMO: "dict" = {}
 _QUERY_MEMO_MAX = 64
 
@@ -347,22 +297,6 @@ def _query_memo_put(key, fn):
         _QUERY_MEMO.pop(next(iter(_QUERY_MEMO)))
     _QUERY_MEMO[key] = fn
     return fn
-
-#: Why ``use_pallas`` is an error on a tpu backend. The verdicts are the
-#: chip compiler's own (v5e, libtpu 0.0.34, every kernel at d=300, f32 and
-#: bf16; tests/test_tpu_compile.py keeps them as strict xfails).
-PALLAS_TPU_REFUSAL = (
-    "use_pallas / GLINT_W2V_PALLAS=1 cannot run on a tpu backend: the TPU "
-    "compiler refuses every kernel of ops/pallas_rows.py and "
-    "ops/pallas_sgns.py. gather_rows, scatter_add_rank1, scatter_add_rows, "
-    "scatter_add_rows_f32, scatter_add_rank1_hbm: 'Slice shape along "
-    "dimension 0 must be aligned to tiling (8), but is 1' (a (1, d) row "
-    "of the tiled HBM table is not a legal DMA slice); pair_forward, "
-    "fused_pair_step: 'Cannot store scalars to VMEM'; pair_forward_shared, "
-    "fused_pair_step_shared: 'Unimplemented primitive in Pallas TPU "
-    "lowering for KernelType.TC: scatter'. They run in interpret mode "
-    "off-TPU only; leave the flag off (the default XLA step) on the chip."
-)
 
 #: Floor of the top-k k-bucket family. Requested k is rounded up to
 #: ``max(next_pow2(k), TOPK_MIN_K_BUCKET)`` (capped at padded_vocab) and
@@ -450,7 +384,6 @@ class EmbeddingEngine:
         dtype: str = "float32",
         extra_rows: int = 0,
         shared_negatives: int = 0,
-        use_pallas: Optional[bool] = None,
         compute_dtype: Optional[str] = None,
         layout: str = "rows",
     ):
@@ -490,8 +423,7 @@ class EmbeddingEngine:
             unigram_power=unigram_power,
             unigram_table_size=unigram_table_size, seed=seed, dtype=dtype,
             extra_rows=extra_rows, shared_negatives=shared_negatives,
-            use_pallas=use_pallas, compute_dtype=compute_dtype,
-            layout=layout,
+            compute_dtype=compute_dtype, layout=layout,
         )
         if counts.shape != (vocab_size,):
             raise ValueError("counts must have shape (vocab_size,)")
@@ -532,8 +464,7 @@ class EmbeddingEngine:
         self, mesh, vocab_size: int, dim: int, *, num_negatives: int,
         unigram_power: float, unigram_table_size: Optional[int], seed: int,
         dtype: str, extra_rows: int, shared_negatives: int,
-        use_pallas: Optional[bool], compute_dtype: Optional[str],
-        layout: str,
+        compute_dtype: Optional[str], layout: str,
     ) -> None:
         """The host-only half of construction: validate, and derive every
         attribute the jitted closures capture (geometry, dtypes, step
@@ -571,45 +502,9 @@ class EmbeddingEngine:
         self._compute_dtype = (
             jnp.bfloat16 if compute_dtype == "bfloat16" else jnp.float32
         )
-        # Pallas row kernels for the sparse table traffic: opt-in per
-        # engine or via GLINT_W2V_PALLAS=1. Interpret mode only: the
-        # chip's compiler refuses every kernel (see PALLAS_TPU_REFUSAL),
-        # so on a tpu backend the flag is an error, not a Mosaic trace.
-        if use_pallas is None:
-            use_pallas = os.environ.get("GLINT_W2V_PALLAS", "0") == "1"
-        if use_pallas and jax.default_backend() == "tpu":
-            raise NotImplementedError(PALLAS_TPU_REFUSAL)
-        self._pallas_interpret = bool(use_pallas)
         self.num_data = mesh.shape[DATA_AXIS]
         self.num_model = mesh.shape[MODEL_AXIS]
         self.layout = layout
-        # Fused Pallas pair-step megakernel (ISSUE 11, ops/pallas_sgns):
-        # rides the same pallas flag and replaces the composed pair-form
-        # step body wherever every table row is shard-local — the rows
-        # layout with an unsharded model axis (data parallelism is fine:
-        # coefficients/h are all_gathered exactly like the composed
-        # path). Model-sharded meshes keep the composed step (the fused
-        # forward would need a mid-kernel logit psum). Escape hatch:
-        # GLINT_W2V_PALLAS_FUSED=0 keeps the row kernels but not the
-        # fused step.
-        fused = (
-            self._pallas_interpret
-            and layout == "rows"
-            and self.num_model == 1
-            and os.environ.get("GLINT_W2V_PALLAS_FUSED", "1") == "1"
-        )
-        if fused and self.shared_negatives:
-            from glint_word2vec_tpu.ops.pallas_sgns import (
-                shared_pool_vmem_ok,
-            )
-
-            # The shared-pool forward pins the pool (storage + fp32) in
-            # VMEM; an oversized pool takes the composed step instead —
-            # which one ran is on record in :meth:`step_body`.
-            fused = shared_pool_vmem_ok(
-                self.shared_negatives, self.dim, self._dtype
-            )
-        self._pallas_fused = bool(fused)
         if layout == "rows":
             self.padded_vocab = pad_to_multiple(self.num_rows, self.num_model)
             self.rows_per_shard = self.padded_vocab // self.num_model
@@ -622,29 +517,11 @@ class EmbeddingEngine:
             self.cols_per_shard = self.padded_dim // self.num_model
 
     @property
-    def pallas_mode(self) -> str:
-        """``"off"`` (XLA gathers and scatters) or ``"interpret"`` — what
-        the Pallas flag resolved to. There is no compiled mode until a
-        kernel gets past the chip's compiler (PALLAS_TPU_REFUSAL)."""
-        return "interpret" if self._pallas_interpret else "off"
-
-    def step_body(self, pair_form: bool) -> str:
-        """Which step body a dispatch of this engine traces, recorded in
-        ``training_metrics`` so no fit hides a fallback:
-        ``<layout>/<per_pair|shared_pool>/<xla|pallas_rows|pallas_fused>``.
-        ``pair_form`` says whether the dispatches are dense pair batches
-        (the packed scan) — the only shape the fused kernels take.
-        ``pallas_rows`` is the composed step over the Pallas row kernels,
-        which is also what a requested fused shared-pool step becomes
-        when its pool does not fit VMEM."""
-        if self._pallas_fused and pair_form:
-            kernels = "pallas_fused"
-        elif self._pallas_interpret:
-            kernels = "pallas_rows"
-        else:
-            kernels = "xla"
+    def step_body(self) -> str:
+        """Which step body this engine traces, recorded in
+        ``training_metrics``: ``<layout>/<per_pair|shared_pool>``."""
         estimator = "shared_pool" if self.shared_negatives else "per_pair"
-        return f"{self.layout}/{estimator}/{kernels}"
+        return f"{self.layout}/{estimator}"
 
     def _table_sharding(self):
         return (
@@ -666,110 +543,12 @@ class EmbeddingEngine:
     def _build_jitted_fns(self) -> None:
         mesh = self.mesh
         Vs = self.rows_per_shard
-        pm = self._pallas_interpret
         n = self.num_negatives
-        if self._pallas_fused:
-            from glint_word2vec_tpu.ops import pallas_sgns
-        else:
-            pallas_sgns = None  # composed path never references it
         tspec = (
             P(MODEL_AXIS, None) if self.layout == "rows"
             else P(None, MODEL_AXIS)
         )
         rep = P()
-
-        def fused_pair_body(syn0_l, syn1_l, prob, alias, centers,
-                            contexts, mask, key, alpha):
-            # Fused Pallas pair step (ISSUE 11): every table row is
-            # shard-local (rows layout, num_model == 1), so the whole
-            # update runs as ops/pallas_sgns kernels — gathers, dot,
-            # sigmoid, and coefficient math in one VMEM-resident forward
-            # pass, then id-sorted run-summing scatters with fp32
-            # accumulation over the (fp32 or bf16) storage. Only the
-            # data axis remains: the exchange ships the SAME compact
-            # payload as the composed path (h, scalar coefficients,
-            # int32 ids — the gPlus/gMinus wire format) plus the (P, d)
-            # d_center rows the forward pass already materialized.
-            Bl = centers.shape[0]
-            drank = lax.axis_index(DATA_AXIS)
-            a32 = alpha.astype(jnp.float32)
-            cen_g = lax.all_gather(centers, DATA_AXIS, tiled=True)
-            if self.shared_negatives:
-                # ONE pool per step, identical on every rank (shared
-                # key); the pool scoring and d_pool update run as dense
-                # level-3 BLAS blocks inside the forward kernel.
-                pool = sample_negatives(
-                    key, prob, alias, (self.shared_negatives,)
-                )
-                fw = pallas_sgns.pair_forward_shared(
-                    syn0_l, syn1_l, centers, contexts, mask, pool, a32,
-                    n, interpret=True,
-                )
-                cpos_g = lax.all_gather(fw.c_pos, DATA_AXIS, tiled=True)
-                h_g = lax.all_gather(fw.h, DATA_AXIS, tiled=True)
-                dcen_g = lax.all_gather(fw.d_center, DATA_AXIS, tiled=True)
-                ctx_g = lax.all_gather(contexts, DATA_AXIS, tiled=True)
-                # Pool contributions sum across data ranks; after the
-                # psum the dense payload is identical everywhere.
-                dpool_g = lax.psum(fw.d_pool, DATA_AXIS)
-                P = cen_g.shape[0]
-                ids1_g = jnp.concatenate([ctx_g, pool])
-                syn1_l = pallas_sgns.scatter_add_rank1_hbm(
-                    syn1_l, ctx_g, cpos_g, h_g,
-                    jnp.arange(P, dtype=jnp.int32), interpret=True,
-                )
-                syn1_l = pallas_sgns.scatter_add_rows_f32(
-                    syn1_l, pool, dpool_g, interpret=True
-                )
-            else:
-                # Per-pair negatives, keyed by GLOBAL pair row — the
-                # identical draw stream as the composed pair step.
-                rows_g = drank * Bl + jnp.arange(Bl, dtype=jnp.int32)
-                negs = sample_negatives_per_row(
-                    key, prob, alias, rows_g, (1, n)
-                )  # (Bl, 1, n)
-                nmask = sgns.negative_mask(
-                    negs, contexts[:, None], mask[:, None]
-                )
-                fw = pallas_sgns.pair_forward(
-                    syn0_l, syn1_l, centers, contexts, mask,
-                    negs[:, 0, :], nmask[:, 0, :], a32, interpret=True,
-                )
-                cpos_g = lax.all_gather(fw.c_pos, DATA_AXIS, tiled=True)
-                cneg_g = lax.all_gather(fw.c_neg, DATA_AXIS, tiled=True)
-                h_g = lax.all_gather(fw.h, DATA_AXIS, tiled=True)
-                dcen_g = lax.all_gather(fw.d_center, DATA_AXIS, tiled=True)
-                ctx_g = lax.all_gather(contexts, DATA_AXIS, tiled=True)
-                negs_g = lax.all_gather(
-                    negs[:, 0, :], DATA_AXIS, tiled=True
-                )
-                P = cen_g.shape[0]
-                rows_p = jnp.arange(P, dtype=jnp.int32)
-                ids1_g = jnp.concatenate([ctx_g, negs_g.reshape(-1)])
-                syn1_l = pallas_sgns.scatter_add_rank1_hbm(
-                    syn1_l,
-                    ids1_g,
-                    jnp.concatenate([cpos_g, cneg_g.reshape(-1)]),
-                    h_g,
-                    jnp.concatenate([rows_p, jnp.repeat(rows_p, n)]),
-                    interpret=True,
-                )
-            syn0_l = pallas_sgns.scatter_add_rows_f32(
-                syn0_l, cen_g, dcen_g, interpret=True
-            )
-            # Same global masked-mean as the composed body: the kernel
-            # returns the SUM form directly.
-            denom = mask.sum()
-            loss = lax.psum(fw.loss_sum, DATA_AXIS) / jnp.maximum(
-                lax.psum(denom, DATA_AXIS), 1.0
-            )
-            # The kernels sum their runs in VMEM; the rows they write are
-            # counted here as the composed body's scatters count theirs.
-            written = jnp.stack([
-                _run_ends(lax.sort(ids), Vs)[1].sum(dtype=jnp.int32)
-                for ids in (cen_g, ids1_g)
-            ])
-            return syn0_l, syn1_l, loss, written
 
         def step_body_rows(syn0_l, syn1_l, prob, alias, centers, cmask,
                            contexts, mask, key, alpha):
@@ -780,15 +559,6 @@ class EmbeddingEngine:
             # this is exactly the plain word vector).
             Bl, S = centers.shape
             C = contexts.shape[1]
-            if self._pallas_fused and S == 1 and C == 1:
-                # Dense pair form (the packed corpus scan / pair-step
-                # callers): the fused Pallas megakernel path. S/C are
-                # static python ints, so grid-shaped and subword-grouped
-                # traces keep the composed body below.
-                return fused_pair_body(
-                    syn0_l, syn1_l, prob, alias, centers[:, 0],
-                    contexts[:, 0], mask[:, 0], key, alpha,
-                )
             start = lax.axis_index(MODEL_AXIS) * Vs
             drank = lax.axis_index(DATA_AXIS)
 
@@ -799,8 +569,8 @@ class EmbeddingEngine:
             # (benchmark/program_trace.py). A fusion is filed under its
             # root's scope, an op under its OUTERMOST one: _pull_rows
             # opens its own two and is called outside any other.
-            h_rows = _pull_rows(syn0_l, centers.reshape(-1), start, Vs, pm)
-            u_pos = _pull_rows(syn1_l, contexts.reshape(-1), start, Vs, pm)
+            h_rows = _pull_rows(syn0_l, centers.reshape(-1), start, Vs)
+            u_pos = _pull_rows(syn1_l, contexts.reshape(-1), start, Vs)
             with jax.named_scope("glint.gather"):
                 h_rows = h_rows.reshape(Bl, S, -1)
                 cnt = jnp.maximum(
@@ -828,7 +598,7 @@ class EmbeddingEngine:
                     pool = sample_negatives(
                         key, prob, alias, (self.shared_negatives,)
                     )
-                u_pool = _pull_rows(syn1_l, pool, start, Vs, pm)
+                u_pool = _pull_rows(syn1_l, pool, start, Vs)
                 with jax.named_scope("glint.sample"):
                     collide = sgns.pool_collision_mask(pool, contexts, mask)
                 with jax.named_scope("glint.grads"):
@@ -861,7 +631,7 @@ class EmbeddingEngine:
                     negs = sample_negatives_per_row(
                         key, prob, alias, rows_g, (C, n)
                     )
-                u_neg = _pull_rows(syn1_l, negs.reshape(-1), start, Vs, pm)
+                u_neg = _pull_rows(syn1_l, negs.reshape(-1), start, Vs)
                 with jax.named_scope("glint.gather"):
                     u_neg = u_neg.reshape(Bl, C, n, -1)
                 with jax.named_scope("glint.sample"):
@@ -896,14 +666,10 @@ class EmbeddingEngine:
                 with jax.named_scope("syn0"):
                     syn0_l, w0 = _scatter_rows(
                         syn0_l, ids0_g, cmask_g.reshape(-1), dcen_g,
-                        jnp.repeat(jnp.arange(dcen_g.shape[0]), S),
-                        start, pm and "rows",
+                        jnp.repeat(jnp.arange(dcen_g.shape[0]), S), start,
                     )
                 with jax.named_scope("syn1"):
-                    syn1_l, w1 = _scatter_rows(
-                        syn1_l, *scat1, start,
-                        pm and ("rows" if self.shared_negatives else "rank1"),
-                    )
+                    syn1_l, w1 = _scatter_rows(syn1_l, *scat1, start)
                     written = lax.psum(jnp.stack([w0, w1]), MODEL_AXIS)
 
             # Masked-mean loss over the global batch.
@@ -1030,10 +796,7 @@ class EmbeddingEngine:
                         jnp.repeat(jnp.arange(dcen_g.shape[0]), S), 0,
                     )
                 with jax.named_scope("syn1"):
-                    syn1_l, w1 = _scatter_rows(
-                        syn1_l, *scat1, 0,
-                        pm and not self.shared_negatives and "rank1",
-                    )
+                    syn1_l, w1 = _scatter_rows(syn1_l, *scat1, 0)
                     written = jnp.stack([w0, w1])
 
             with jax.named_scope("glint.grads"):
@@ -1277,7 +1040,7 @@ class EmbeddingEngine:
                 )  # (L, padded_dim)
                 return full[:, :dim_real]
             start = lax.axis_index(MODEL_AXIS) * Vs
-            return _pull_rows(table_l, idx, start, Vs, pm)[:, :dim_real]
+            return _pull_rows(table_l, idx, start, Vs)[:, :dim_real]
 
         self._pull = shared_query_program("pull", lambda: jax.jit(
             self._shard_map(local_pull, in_specs=(tspec, rep), out_specs=rep)
@@ -1295,7 +1058,7 @@ class EmbeddingEngine:
                 full = lax.all_gather(mean_l, MODEL_AXIS, tiled=True, axis=1)
                 return full[:, :dim_real]
             start = lax.axis_index(MODEL_AXIS) * Vs
-            rows = _pull_rows(table_l, idx.reshape(-1), start, Vs, pm)
+            rows = _pull_rows(table_l, idx.reshape(-1), start, Vs)
             rows = rows[:, :dim_real].reshape(S, L, -1) * m[..., None]
             return rows.sum(axis=1) / jnp.maximum(
                 m.sum(axis=1)[:, None], 1.0
@@ -1893,7 +1656,6 @@ class EmbeddingEngine:
             tuple(self.mesh.shape.items()),
             self.layout,
             str(self._dtype), str(self._compute_dtype),
-            self._pallas_interpret, self._pallas_fused,
             self.num_negatives, self.shared_negatives,
             self.rows_per_shard, self.cols_per_shard,
             self.padded_vocab, self.padded_dim,
@@ -1903,8 +1665,8 @@ class EmbeddingEngine:
     def _query_memo_key(self, op):
         """Memo key for :data:`_QUERY_MEMO`: the mesh geometry plus
         ONLY the attributes the query closures capture — layout,
-        storage dtype, shard geometry, pallas mode. Training attributes
-        (negatives, compute dtype, fused mode) are excluded on purpose:
+        storage dtype, shard geometry. Training attributes (negatives,
+        compute dtype) are excluded on purpose:
         they never reach a query program, so models that differ only in
         how they were trained still share the whole warm family."""
         return (
@@ -1914,7 +1676,6 @@ class EmbeddingEngine:
             tuple(self.mesh.shape.items()),
             self.layout,
             str(self._dtype),
-            self._pallas_interpret,
             self.rows_per_shard, self.cols_per_shard,
             self.padded_vocab, self.padded_dim, self.dim,
         )

@@ -106,7 +106,7 @@ from glint_word2vec_tpu.utils import faults, next_pow2
 #: Wire dtype of exact (fp32-wire / dense / flush) delta payloads:
 #: accumulation dtype, not storage dtype — deltas of bf16 tables still
 #: travel and sum in fp32 so the reconstruction rounds each row total
-#: once (same contract as ``engine._scatter_add_rows``).
+#: once (same contract as ``engine._scatter_rows``).
 _WIRE_DTYPE = np.float32
 
 #: Supported sparse payload encodings (``--exchange-wire``).

@@ -502,9 +502,7 @@ class StreamTrainer:
         model.training_metrics = {
             **metrics.summary(),
             "pipeline": "stream",
-            # The stream drains through the packed pair scan.
-            "step_body": engine.step_body(True),
-            "pallas_mode": engine.pallas_mode,
+            "step_body": engine.step_body,
             "rounds": self.rounds,
             "words_trained": self.words_trained,
             "vocab_size": sv.size,

@@ -109,11 +109,10 @@ class Word2VecParams:
     #: ISSUE 11) prefix-sum-compacts the valid (center, context) pairs
     #: into dense fixed-shape pair batches before the update
     #: (ops/device_batching.pack_window_pairs), spending ~every
-    #: dispatched FLOP on a real pair — and is the only shape the fused
-    #: Pallas megakernel (ops/pallas_sgns) accelerates. "grid" restores
-    #: the legacy (batch, context) window grids — the reference's shape,
-    #: ~43% live lanes at window 5 — for A/B comparison or to resume an
-    #: old grid-written mid-epoch checkpoint. Same valid-pair multiset
+    #: dispatched FLOP on a real pair. "grid" restores the legacy
+    #: (batch, context) window grids — the reference's shape, ~43% live
+    #: lanes at window 5 — for A/B comparison or to resume an old
+    #: grid-written mid-epoch checkpoint. Same valid-pair multiset
     #: per epoch either way (window draws reproduce the grid mapping);
     #: negative/loss RNG streams differ like host-vs-device already do.
     #: Ignored (with a log line) when training routes to the host

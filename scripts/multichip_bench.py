@@ -1,5 +1,5 @@
 """Weak-scaling harness for pod-scale training (ISSUE 15) — the real
-curves that retire the MULTICHIP_r0*.json dry-run smokes.
+curves that retired the MULTICHIP_r0*.json dry-run smokes (deleted in PR 29).
 
 What it measures, into ``MULTICHIP_BENCH.json`` (repo root):
 

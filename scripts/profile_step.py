@@ -4,8 +4,7 @@ Measures, in PRIORITY order (the decisive numbers come first, and partial
 results are flushed to --out after every section):
 
   1. full engine train steps in the bench's three mode configs
-     (per_pair f32, per_pair bf16 tables+compute, shared bf16) plus the
-     per_pair Pallas fused-scatter variant
+     (per_pair f32, per_pair bf16 tables+compute, shared bf16)
   2. isolated sparse row traffic (gather; scatter with materialized vs
      XLA-fused rank-1 payloads)
   3. the shared-mode matmuls f32 vs bf16, per-pair einsums, sampling
@@ -102,8 +101,6 @@ def main():
                                  compute_dtype="bfloat16")),
         ("shared_bf16ct", dict(shared_negatives=S, dtype="bfloat16",
                                compute_dtype="bfloat16")),
-        ("per_pair_f32_pallas", dict(shared_negatives=0, dtype="float32",
-                                     use_pallas=True)),
     ]
     for tag, kw in step_cfgs:
         note(f"full_step_{tag}...")

@@ -5,6 +5,7 @@ pseudo-cluster integration test (SURVEY.md §4); here every Glint-op
 equivalent is checked for exactness and for mesh-shape invariance.
 """
 
+import itertools
 import os
 
 import jax
@@ -16,6 +17,8 @@ from glint_word2vec_tpu.corpus import build_unigram_alias
 from glint_word2vec_tpu.ops import sgns
 from glint_word2vec_tpu.parallel.engine import EmbeddingEngine
 from glint_word2vec_tpu.parallel.mesh import make_mesh
+from test_sgns import _numpy_oracle
+from test_shared_negatives import _numpy_shared_grads
 
 V, D = 50, 16  # deliberately not divisible by 8: exercises padding
 
@@ -470,51 +473,99 @@ def test_device_resident_inputs_no_host_bounce():
     )
 
 
-def test_use_pallas_on_tpu_backend_is_a_clear_error(monkeypatch):
-    """The chip's compiler refuses every Pallas kernel
-    (tests/test_tpu_compile.py), so asking for them on a tpu backend fails
-    at construction with the verdicts, not inside a Mosaic trace — by
-    argument or by GLINT_W2V_PALLAS=1. Off-TPU the flag still means
-    interpret mode."""
-    counts = np.arange(V, 0, -1).astype(np.int64)
-    mesh = make_mesh(1, 1)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(NotImplementedError, match="cannot run on a tpu"):
-        EmbeddingEngine(mesh, V, D, counts, use_pallas=True)
-    monkeypatch.setenv("GLINT_W2V_PALLAS", "1")
-    with pytest.raises(NotImplementedError, match="Cannot store scalars"):
-        EmbeddingEngine(mesh, V, D, counts)
-    monkeypatch.delenv("GLINT_W2V_PALLAS")
-    # The default path selects none of them, on any backend.
-    eng = EmbeddingEngine(mesh, V, D, counts)
-    assert eng.pallas_mode == "off"
-    assert eng.step_body(pair_form=True) == "rows/per_pair/xla"
+@pytest.mark.parametrize(
+    "kw,want",
+    [
+        ({}, "rows/per_pair"),
+        ({"shared_negatives": 8, "layout": "dims"}, "dims/shared_pool"),
+    ],
+)
+def test_step_body_names_what_runs(kw, want):
+    eng = EmbeddingEngine(make_mesh(1, 1), V, D, np.ones(V, np.int64), **kw)
+    assert eng.step_body == want
 
 
 @pytest.mark.parametrize(
-    "kw,pair_form,want",
-    [
-        ({}, True, "rows/per_pair/xla"),
-        ({"shared_negatives": 8, "layout": "dims"}, False,
-         "dims/shared_pool/xla"),
-        ({"use_pallas": True}, True, "rows/per_pair/pallas_fused"),
-        # Grid-shaped dispatches never take the fused kernels ...
-        ({"use_pallas": True}, False, "rows/per_pair/pallas_rows"),
-        # ... nor does a pool too large for VMEM: the once-silent
-        # fallback at the headline shape (S=4096, d=300) is now named.
-        ({"use_pallas": True, "shared_negatives": 4096, "dim": 300},
-         True, "rows/shared_pool/pallas_rows"),
-        ({"use_pallas": True, "shared_negatives": 8}, True,
-         "rows/shared_pool/pallas_fused"),
-    ],
+    "layout,negatives,dtype,form",
+    list(itertools.product(
+        ["rows", "dims"], ["per_pair", "shared_pool"],
+        ["float32", "bfloat16"], ["grid", "pair"],
+    )),
 )
-def test_step_body_names_what_runs(kw, pair_form, want):
-    kw = dict(kw)
-    dim = kw.pop("dim", D)
+def test_engine_step_matches_numpy_oracle(layout, negatives, dtype, form):
+    """One step of every body the engine can trace (layout x negatives x
+    storage dtype x grid or pair form), on a 2 x 4 mesh, against a numpy
+    oracle that is handed the step's own draws: the net under ROADMAP
+    D1's matrix. float32 tables within reduction order; bfloat16 ones
+    within the README's mixed-precision bound (a row's float32 batch
+    total rounded once, then one bfloat16 add)."""
+    from glint_word2vec_tpu.ops.sampling import (
+        sample_negatives,
+        sample_negatives_per_row,
+    )
+
+    n, S, B, alpha = 4, 16, 16, 0.05
+    C = 5 if form == "grid" else 1
+    counts = np.arange(V, 0, -1).astype(np.int64) * 10
     eng = EmbeddingEngine(
-        make_mesh(1, 1), V, dim, np.ones(V, np.int64), **kw
+        make_mesh(2, 4), V, D, counts, num_negatives=n, seed=3, dtype=dtype,
+        layout=layout, shared_negatives=S if negatives == "shared_pool" else 0,
     )
-    assert eng.step_body(pair_form) == want
-    assert eng.pallas_mode == (
-        "interpret" if kw.get("use_pallas") else "off"
+    assert eng.step_body == f"{layout}/{negatives}"
+    rng = np.random.default_rng(12)
+    eng.set_tables(
+        rng.normal(0, 0.3, (V, D)).astype(np.float32),
+        rng.normal(0, 0.3, (V, D)).astype(np.float32),
     )
+    # what the tables hold once stored (bfloat16 engines round them)
+    s0 = np.asarray(eng.syn0, np.float32)[:V, :D]
+    s1 = np.asarray(eng.syn1, np.float32)[:V, :D]
+    centers = rng.integers(0, V, B).astype(np.int32)
+    centers[:3] = centers[3]  # one center four times
+    contexts = rng.integers(0, V, (B, C)).astype(np.int32)
+    mask = (rng.random((B, C)) < 0.8).astype(np.float32)
+    contexts = np.where(mask > 0, contexts, 0)
+    key = jax.random.PRNGKey(5)
+
+    loss = eng.train_step(centers, contexts, mask, key, alpha)
+
+    if negatives == "per_pair":
+        negs = sample_negatives_per_row(
+            key, eng._prob, eng._alias, jnp.arange(B, dtype=jnp.int32), (C, n)
+        )
+        nmask = np.asarray(sgns.negative_mask(
+            negs, jnp.asarray(contexts), jnp.asarray(mask)
+        ))
+        exp0, exp1 = _numpy_oracle(
+            s0, s1, centers, contexts, mask, np.asarray(negs), nmask, alpha
+        )
+    else:
+        pool = np.asarray(sample_negatives(key, eng._prob, eng._alias, (S,)))
+        collide = (
+            (pool[None, None, :] == contexts[:, :, None])
+            & (mask[:, :, None] > 0)
+        ).any(axis=1).astype(np.float32)
+        h, u_pos = s0[centers], s1[contexts]
+        c_pos, _, d_center, d_pool, exp_loss = _numpy_shared_grads(
+            h, u_pos, s1[pool], mask, collide, alpha, n
+        )
+        exp0, exp1 = s0.copy(), s1.copy()
+        np.add.at(exp0, centers, d_center)
+        np.add.at(exp1, contexts.reshape(-1),
+                  (c_pos[:, :, None] * h[:, None, :]).reshape(-1, D))
+        np.add.at(exp1, pool, d_pool)
+        assert float(loss) == pytest.approx(exp_loss, rel=1e-4)
+    assert np.isfinite(float(loss))
+    for got, exp, start in ((eng.syn0, exp0, s0), (eng.syn1, exp1, s1)):
+        got = np.asarray(got, np.float32)
+        # the padding stays zero: rows past V, columns past D
+        assert not got[V:].any() and not got[:, D:].any()
+        got = got[:V, :D]
+        assert np.abs(exp - start).max() > 1e-3  # an update worth the name
+        if dtype == "float32":
+            np.testing.assert_allclose(got, exp, rtol=2e-5, atol=2e-6)
+        else:
+            bound = 2.0 ** -8 * (np.abs(exp - start) + np.abs(exp)) + 1e-6
+            assert (np.abs(got - exp) <= bound).all(), (
+                (np.abs(got - exp) - bound).max()
+            )

@@ -21,6 +21,26 @@ def _sigmoid(x):
     return 1.0 / (1.0 + np.exp(-x))
 
 
+def _numpy_shared_grads(h, u_pos, u_pool, mask, collide, alpha, n):
+    """The shared-pool estimator in plain numpy: ``(c_pos, c_pool,
+    d_center, d_pool, loss)``, every pool term weighted ``m_i * n / S``
+    and dropped where the pool word is one of the row's contexts."""
+    S = u_pool.shape[0]
+    f_pos = np.einsum("bd,bcd->bc", h, u_pos)
+    f_pool = h @ u_pool.T
+    m_i = mask.sum(axis=1)
+    weight = (m_i * (n / S))[:, None] * (1.0 - collide)
+    c_pos = alpha * (1.0 - _sigmoid(f_pos)) * mask
+    c_pool = -alpha * _sigmoid(f_pool) * weight
+    d_center = np.einsum("bc,bcd->bd", c_pos, u_pos) + c_pool @ u_pool
+    d_pool = c_pool.T @ h
+    loss = (
+        (-np.log(_sigmoid(f_pos)) * mask).sum()
+        + (-np.log(_sigmoid(-f_pool)) * weight).sum()
+    ) / max(mask.sum(), 1.0)
+    return c_pos, c_pool, d_center, d_pool, loss
+
+
 def test_shared_grads_match_numpy_reference():
     rng = np.random.default_rng(0)
     B, C, S, d, n = 4, 3, 6, 8, 5
@@ -36,19 +56,14 @@ def test_shared_grads_match_numpy_reference():
         jnp.asarray(mask), jnp.asarray(collide), jnp.float32(alpha), n,
     )
 
-    f_pos = np.einsum("bd,bcd->bc", h, u_pos)
-    f_pool = h @ u_pool.T
-    m_i = mask.sum(axis=1)
-    weight = (m_i * (n / S))[:, None] * (1.0 - collide)
-    c_pos = alpha * (1.0 - _sigmoid(f_pos)) * mask
-    c_pool = -alpha * _sigmoid(f_pool) * weight
-    d_center = np.einsum("bc,bcd->bd", c_pos, u_pos) + c_pool @ u_pool
-    d_pool = c_pool.T @ h
-
+    c_pos, c_pool, d_center, d_pool, loss = _numpy_shared_grads(
+        h, u_pos, u_pool, mask, collide, alpha, n
+    )
     np.testing.assert_allclose(np.asarray(g.c_pos), c_pos, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(g.c_pool), c_pool, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(g.d_center), d_center, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(g.d_pool), d_pool, rtol=1e-4, atol=1e-5)
+    assert float(g.loss) == pytest.approx(loss, rel=1e-5)
 
 
 def test_pool_collision_mask():

@@ -16,10 +16,6 @@ chip run and is never reported as one; ``python chip_smoke.py`` is the run.
   device's default layout), so what compiles here is what the engine runs;
   at the benchmark's sizes that default must be row-major, in and out, and
   no program may copy a whole table (PR 28).
-* Every public Pallas entry point is a strict xfail carrying the compiler's
-  own words: today all are refused (so ``use_pallas`` raises on a tpu
-  backend, engine.PALLAS_TPU_REFUSAL). The day one is repaired its mark
-  must come off.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU's library, and every xdist worker imports
@@ -92,7 +88,7 @@ def engines(topo):
                 mesh, vocab, D, num_negatives=NEG, unigram_power=0.75,
                 unigram_table_size=None, seed=1, dtype="float32",
                 extra_rows=0, shared_negatives=shared_negatives,
-                use_pallas=False, compute_dtype=None, layout="rows",
+                compute_dtype=None, layout="rows",
             )
             eng._build_jitted_fns()
             built[key] = eng
@@ -382,110 +378,3 @@ def test_query_program_at_the_benchmark_size_copies_no_table(
     assert _rests(compiled.input_formats[0][0], eng)
     assert not _whole_table_copies(compiled, eng)
     assert mem["temp"] < temp_ceiling, mem
-
-
-# ----------------------------------------------------------------------
-# Pallas entry points: the compiler's verdicts, kept loud
-# ----------------------------------------------------------------------
-
-# Few pairs: the verdicts depend on d and the table, not on the batch.
-P_PAIRS, POOL = 256, 1024
-# (exception class name, the compiler's words: any one of them, the reason)
-_ROW_DMA = (
-    "MosaicError",
-    ("Slice shape along dimension 0 must be aligned to tiling (8), but is 1",
-     # the bf16 scatters
-     "cannot statically prove that index in dimension 0 is a multiple of 8"),
-    "the per-row make_async_copy of a (1, d) slice of the tiled HBM table "
-    "is not a legal DMA",
-)
-_SCALAR_STORE = ("ValueError", ("Cannot store scalars to VMEM",), "")
-_NO_SCATTER = (
-    "NotImplementedError",
-    ("Unimplemented primitive in Pallas TPU lowering for KernelType.TC: "
-     "scatter",),
-    "",
-)
-_PALLAS_VERDICTS = {
-    "pallas_rows.gather_rows": _ROW_DMA,
-    "pallas_rows.scatter_add_rank1": _ROW_DMA,
-    "pallas_rows.scatter_add_rows": _ROW_DMA,
-    "pallas_sgns.pair_forward": _SCALAR_STORE,
-    "pallas_sgns.pair_forward_shared": _NO_SCATTER,
-    "pallas_sgns.scatter_add_rows_f32": _ROW_DMA,
-    "pallas_sgns.scatter_add_rank1_hbm": _ROW_DMA,
-    # The two fused steps only compose the above; each dies in its
-    # forward kernel.
-    "pallas_sgns.fused_pair_step": _SCALAR_STORE,
-    "pallas_sgns.fused_pair_step_shared": _NO_SCATTER,
-}
-
-
-def _pallas_call(name: str, table_dtype, sds):
-    """(fn, abstract args) for one public Pallas entry point at d=300."""
-    import jax.numpy as jnp
-
-    from glint_word2vec_tpu.ops import pallas_rows, pallas_sgns
-
-    mod, fn_name = name.split(".")
-    fn = getattr({"pallas_rows": pallas_rows,
-                  "pallas_sgns": pallas_sgns}[mod], fn_name)
-    t = sds((V, D), table_dtype)
-    n_upd = P_PAIRS * (1 + NEG)
-    pairs_i, pairs_f = sds((P_PAIRS,), jnp.int32), sds((P_PAIRS,), jnp.float32)
-    upd_i, upd_f = sds((n_upd,), jnp.int32), sds((n_upd,), jnp.float32)
-    h = sds((P_PAIRS, D), jnp.float32)
-    negs = sds((P_PAIRS, NEG), jnp.int32)
-    nmask = sds((P_PAIRS, NEG), jnp.float32)
-    alpha = sds((), jnp.float32)
-    pool = sds((POOL,), jnp.int32)
-    per_pair = (t, t, pairs_i, pairs_i, pairs_f, negs, nmask, alpha)
-    shared = (t, t, pairs_i, pairs_i, pairs_f, pool, alpha)
-    args = {
-        "gather_rows": (t, pairs_i),
-        "scatter_add_rank1": (t, upd_i, upd_f, h, upd_i),
-        "scatter_add_rows": (t, pairs_i, sds((P_PAIRS, D), table_dtype)),
-        "pair_forward": per_pair,
-        "pair_forward_shared": shared,
-        "scatter_add_rows_f32": (t, pairs_i, h),
-        "scatter_add_rank1_hbm": (t, upd_i, upd_f, h, upd_i),
-        "fused_pair_step": per_pair,
-        "fused_pair_step_shared": shared,
-    }[fn_name]
-    if fn_name.endswith("_shared"):
-        return (lambda *a: fn(*a, NEG)), args
-    return fn, args
-
-
-class CompilerRefused(Exception):
-    """The chip's compiler refused the kernel in the words on record. Any
-    other exception (a wrongly built argument list, a new refusal) is not
-    this one, so it fails the strict xfail instead of satisfying it."""
-
-
-@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize(
-    "name",
-    [
-        pytest.param(name, marks=pytest.mark.xfail(
-            strict=True, raises=CompilerRefused,
-            reason=f"{kind}: {' / '.join(words)}"
-            + (f" — {why}" if why else ""),
-        ))
-        for name, (kind, words, why) in _PALLAS_VERDICTS.items()
-    ],
-)
-def test_pallas_entry_point_compiles(engines, name, table_dtype):
-    import jax
-    import jax.numpy as jnp
-
-    fn, args = _pallas_call(
-        name, jnp.dtype(table_dtype), _shapes(engines(1))
-    )
-    kind, words, _ = _PALLAS_VERDICTS[name]
-    try:
-        jax.jit(fn).lower(*args).compile()
-    except Exception as e:
-        if type(e).__name__ == kind and any(w in str(e) for w in words):
-            raise CompilerRefused(f"{kind}: {e}".splitlines()[0]) from e
-        raise
