@@ -205,7 +205,11 @@ def check_training(tm: dict, tag: str = "train") -> None:
           tm["pipeline"] == "device_corpus"
           and tm.get("batch_packing") == "dense",
           f"pipeline={tm['pipeline']} packing={tm.get('batch_packing')}")
-    check(f"{tag}.step_body", tm.get("step_body") == "rows/per_pair",
+    # The body names the writer its scatters end in: the slab writer where
+    # the program is lowered for a TPU (ops/slab_writer.py), XLA's elsewhere.
+    writer = "slab" if ARGS.platform == "tpu" else "xla"
+    check(f"{tag}.step_body",
+          tm.get("step_body") == f"rows/per_pair/{writer}",
           str(tm.get("step_body")))
     check(f"{tag}.loss_finite",
           first is not None and last is not None

@@ -62,6 +62,7 @@ from glint_word2vec_tpu.utils import (
     atomic_write_npy,
     next_pow2,
 )
+from glint_word2vec_tpu.ops import slab_writer
 from glint_word2vec_tpu.ops.sampling import (
     sample_negatives,
     sample_negatives_per_row,
@@ -206,6 +207,25 @@ def _run_totals(key, coefs, src, hidx, n_rows):
     return u, tot, live_end.sum(dtype=jnp.int32)
 
 
+def _write_rows(table_l, u, tot, n_u):
+    """XLA's writer of :func:`_scatter_rows`: the distinct rows ``u[:n_u]``
+    handed to the TPU scatter a chunk at a time, the loop stopping after
+    the last live chunk. The scatter is told what is true of its rows (no
+    two alike, strays dropped) but not that they are sorted: that flag
+    selects XLA's other TPU emitter, which passes over the whole table
+    (9.4 ms at 2M x 300) before it adds a slot. Returns ``(table_l, 0)``:
+    it moves no slab."""
+    chunk = _writer_chunk(u.shape[0])
+
+    def write(k, t):
+        return t.at[lax.dynamic_slice_in_dim(u, k * chunk, chunk)].add(
+            lax.dynamic_slice_in_dim(tot, k * chunk, chunk).astype(t.dtype),
+            unique_indices=True, mode="drop",
+        )
+
+    return lax.fori_loop(0, -(-n_u // chunk), write, table_l), jnp.int32(0)
+
+
 def _scatter_rows(table_l, idx, coefs, src, hidx, start):
     """Apply global rank-1 updates to the owned slice of a sharded table
     (the servers' half of ``adjust``, SURVEY.md §2.2): slot k adds
@@ -214,25 +234,29 @@ def _scatter_rows(table_l, idx, coefs, src, hidx, start):
     are dropped, not walked. Every table dtype and layout takes this one
     path: the slots are sorted, each row's updates are summed once in
     float32 (:func:`_run_totals`), and each distinct row is written once,
-    its total rounded once to the table's dtype. The scatter is told what
-    is then true of its rows (no two alike, strays dropped) but not that
-    they are sorted: that flag selects XLA's other TPU emitter, which
-    passes over the whole table (9.4 ms at 2M x 300) before it adds a
-    slot. Returns ``(table_l, rows written)``."""
+    its total rounded once to the table's dtype.
+
+    Two writers share those totals and nothing else, and which one runs
+    follows from what is observed, never from an option: where the
+    program is lowered for a TPU and the table's rows can be addressed by
+    whole tile rows (``slab_writer.fits``: every ``rows`` table whose
+    shard is a multiple of 16 rows), ``ops/slab_writer.py``'s kernel moves
+    each touched slab once; everywhere else (CPU, GPU, a ``dims`` shard of
+    75 columns) :func:`_write_rows` hands XLA's scatter the rows. The two
+    give the same table bit for bit. Returns ``(table_l, rows written,
+    slabs moved)``, the last 0 from XLA's writer."""
     Vs = table_l.shape[0]
     loc = idx - start
     own = (loc >= 0) & (loc < Vs)
     key = jnp.where(own, loc, Vs)
     u, tot, n_u = _run_totals(key, coefs, src, hidx, Vs)
-    chunk = _writer_chunk(key.shape[0])
-
-    def write(k, t):
-        return t.at[lax.dynamic_slice_in_dim(u, k * chunk, chunk)].add(
-            lax.dynamic_slice_in_dim(tot, k * chunk, chunk).astype(t.dtype),
-            unique_indices=True, mode="drop",
+    if slab_writer.fits(table_l.shape, table_l.dtype):
+        table_l, moved = lax.platform_dependent(
+            table_l, u, tot, n_u, tpu=slab_writer.write, default=_write_rows
         )
-
-    return lax.fori_loop(0, -(-n_u // chunk), write, table_l), n_u
+    else:
+        table_l, moved = _write_rows(table_l, u, tot, n_u)
+    return table_l, n_u, moved
 
 
 #: Process-wide memo of the jitted corpus-scan programs, keyed by every
@@ -518,10 +542,19 @@ class EmbeddingEngine:
 
     @property
     def step_body(self) -> str:
-        """Which step body this engine traces, recorded in
-        ``training_metrics``: ``<layout>/<per_pair|shared_pool>``."""
+        """Which step body this engine traces and which writer its
+        scatters end in (:func:`_scatter_rows`), recorded in
+        ``training_metrics``: ``<layout>/<per_pair|shared_pool>/
+        <slab|xla>``. The writer is named by the rule :func:`_scatter_rows`
+        applies when the program is lowered for this mesh's devices."""
         estimator = "shared_pool" if self.shared_negatives else "per_pair"
-        return f"{self.layout}/{estimator}"
+        slab = (
+            self.mesh.devices.flat[0].platform == "tpu"
+            and slab_writer.fits(
+                (self.rows_per_shard, self.cols_per_shard), self._dtype
+            )
+        )
+        return f"{self.layout}/{estimator}/{'slab' if slab else 'xla'}"
 
     def _table_sharding(self):
         return (
@@ -664,13 +697,15 @@ class EmbeddingEngine:
             # the scatter's sorted order (_run_totals), never exchanged.
             with jax.named_scope("glint.scatter"):
                 with jax.named_scope("syn0"):
-                    syn0_l, w0 = _scatter_rows(
+                    syn0_l, w0, m0 = _scatter_rows(
                         syn0_l, ids0_g, cmask_g.reshape(-1), dcen_g,
                         jnp.repeat(jnp.arange(dcen_g.shape[0]), S), start,
                     )
                 with jax.named_scope("syn1"):
-                    syn1_l, w1 = _scatter_rows(syn1_l, *scat1, start)
-                    written = lax.psum(jnp.stack([w0, w1]), MODEL_AXIS)
+                    syn1_l, w1, m1 = _scatter_rows(syn1_l, *scat1, start)
+                    written = lax.psum(
+                        jnp.stack([w0, w1, m0, m1]), MODEL_AXIS
+                    )
 
             # Masked-mean loss over the global batch.
             with jax.named_scope("glint.grads"):
@@ -791,13 +826,13 @@ class EmbeddingEngine:
             # every shard writes the same rows of its own columns.
             with jax.named_scope("glint.scatter"):
                 with jax.named_scope("syn0"):
-                    syn0_l, w0 = _scatter_rows(
+                    syn0_l, w0, m0 = _scatter_rows(
                         syn0_l, ids0_g, cmask_g.reshape(-1), dcen_g,
                         jnp.repeat(jnp.arange(dcen_g.shape[0]), S), 0,
                     )
                 with jax.named_scope("syn1"):
-                    syn1_l, w1 = _scatter_rows(syn1_l, *scat1, 0)
-                    written = jnp.stack([w0, w1])
+                    syn1_l, w1, m1 = _scatter_rows(syn1_l, *scat1, 0)
+                    written = jnp.stack([w0, w1, m0, m1])
 
             with jax.named_scope("glint.grads"):
                 denom = mask.sum()
@@ -1752,10 +1787,12 @@ class EmbeddingEngine:
         grid path's (like host-vs-device RNG divergence, documented).
 
         Returns ``(losses (K,), pair_counts (K,), pos_ends (K,),
-        alphas (K,), rows_written (K, 2))`` — per-step loss, live pairs
+        alphas (K,), rows_written (K, 4))`` — per-step loss, live pairs
         packed, consumed position after the step, the device-computed
         alpha, and the distinct rows the step's scatters wrote into
-        (syn0, syn1), of the :meth:`packed_scatter_slots` they were handed.
+        (syn0, syn1), of the :meth:`packed_scatter_slots` they were handed,
+        then the slabs the slab writer moved for them (syn0, syn1; 0 where
+        XLA's writer ran: :func:`_scatter_rows`).
         The caller reads ``pos_ends[-1]`` to schedule the next dispatch
         (one scalar readback per K steps).
         """

@@ -47,7 +47,7 @@ def test_rehearsal_last_line_is_exactly_the_contract():
     assert doc["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
     # The observations are on EARLIER lines, never in the last one.
     out = proc.stdout
-    for needle in ("loss first=", "step_body=rows/per_pair\n",
+    for needle in ("loss first=", "step_body=rows/per_pair/xla\n",
                    "compile cache:", "native host library:",
                    "phase seconds:", "check serve.zero_post_warmup_compiles"):
         assert needle in out, needle

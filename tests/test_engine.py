@@ -476,8 +476,8 @@ def test_device_resident_inputs_no_host_bounce():
 @pytest.mark.parametrize(
     "kw,want",
     [
-        ({}, "rows/per_pair"),
-        ({"shared_negatives": 8, "layout": "dims"}, "dims/shared_pool"),
+        ({}, "rows/per_pair/xla"),
+        ({"shared_negatives": 8, "layout": "dims"}, "dims/shared_pool/xla"),
     ],
 )
 def test_step_body_names_what_runs(kw, want):
@@ -511,7 +511,7 @@ def test_engine_step_matches_numpy_oracle(layout, negatives, dtype, form):
         make_mesh(2, 4), V, D, counts, num_negatives=n, seed=3, dtype=dtype,
         layout=layout, shared_negatives=S if negatives == "shared_pool" else 0,
     )
-    assert eng.step_body == f"{layout}/{negatives}"
+    assert eng.step_body == f"{layout}/{negatives}/xla"  # a CPU mesh
     rng = np.random.default_rng(12)
     eng.set_tables(
         rng.normal(0, 0.3, (V, D)).astype(np.float32),

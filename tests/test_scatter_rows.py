@@ -84,7 +84,7 @@ def test_scatter_rows_against_float64(profile, dtype):
     table = jnp.asarray(rng.normal(0, 0.5, (V, D)), dtype)
     before = np.asarray(table, np.float64)
 
-    out, written = engine._scatter_rows(
+    out, written, _ = engine._scatter_rows(
         table, jnp.asarray(ids), jnp.asarray(coefs), jnp.asarray(src),
         jnp.asarray(hidx), START,
     )
@@ -145,7 +145,7 @@ def test_scatter_rows_sums_exactly(dtype):
     ids = (START + ids).astype(np.int32)
     table = jnp.asarray(before, dtype)
 
-    out, written = engine._scatter_rows(
+    out, written, _ = engine._scatter_rows(
         table, jnp.asarray(ids), jnp.asarray(coefs), jnp.asarray(src),
         jnp.asarray(hidx), START,
     )

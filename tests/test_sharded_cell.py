@@ -203,8 +203,9 @@ def test_the_exchange_has_its_own_scope(layout, moved):
         assert "/glint.exchange/" in op_name, (shape, op_name)
     for shape, op_name, _ in across:
         assert "glint.gather" not in op_name, (shape, op_name)
-        # what else crosses the model axis is the scatters' two counts
-        assert "glint.exchange" in op_name or shape.startswith("s32[2]")
+        # what else crosses the model axis is the scatters' counts: rows
+        # written and slabs moved, of each table
+        assert "glint.exchange" in op_name or shape.startswith("s32[4]")
 
 
 def test_one_shard_exchanges_nothing():

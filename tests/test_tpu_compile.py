@@ -291,6 +291,60 @@ def test_packed_corpus_scan_keeps_the_tables_as_they_rest(packed_scans, name):
     )
 
 
+@pytest.mark.parametrize(
+    "name", ["2m-1chip", "3m-1chip", "10m-4chips", "1chip-shared_pool"]
+)
+def test_packed_corpus_scan_writes_rows_by_slabs(packed_scans, name):
+    # Lowered for a TPU, a resting table's scatter ends in the slab writer
+    # (ops/slab_writer.py): a Mosaic kernel for each table, filed under
+    # glint.scatter, and no XLA scatter whose operand is a table (96 ns a
+    # row: PERF.md, PR 26). The step's temporaries stay what they were
+    # (the shared pool's dense update held 2.22 GB before this writer).
+    import re
+
+    eng, compiled = packed_scans(name)
+    text = compiled.as_text().splitlines()
+    kernels = [line for line in text if "tpu_custom_call" in line]
+    assert kernels and all("glint.scatter/syn" in k for k in kernels), kernels
+    for table in ("syn0", "syn1"):
+        assert any(f"glint.scatter/{table}" in k for k in kernels), table
+    shard = rf"f32\[{eng.rows_per_shard},{eng.padded_dim}\]"
+    assert not [
+        line.strip()[:200] for line in text
+        if re.search(rf"= {shard}\S* scatter\(", line)
+    ]
+    ceiling = 2.3e9 if name == "1chip-shared_pool" else 1.2e9
+    assert _fits(compiled, SCANS[name][0])["temp"] < ceiling
+
+
+def test_slab_writer_compiles_for_bfloat16(topo):
+    # The kernel alone over a 2M x 384 bfloat16 table, (16, 384) slabs, in
+    # place. A single-sublane load at a traced offset is refused there
+    # ("cannot statically prove that index in dimension 1 is a multiple of
+    # 8"); the kernel loads the whole slab and selects the sublane.
+    import jax
+    import jax.numpy as jnp
+
+    from jax.sharding import SingleDeviceSharding
+
+    from glint_word2vec_tpu.ops import slab_writer
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n = 159_744  # syn1's update slots of the benchmark's step, padded
+    compiled = jax.jit(slab_writer.write, donate_argnums=0).lower(
+        sds((2_000_000, D_REST), jnp.bfloat16), sds((n,), jnp.int32),
+        sds((n, D_REST), jnp.float32), sds((), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 2_000_000 * D_REST * 2
+    assert m.temp_size_in_bytes < 10**6, m
+
+
 def test_subsample_compact_compiles(engines):
     import jax
     import jax.numpy as jnp
