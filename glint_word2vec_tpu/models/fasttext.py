@@ -3,7 +3,9 @@
 Extends the word-level SGNS framework with character-n-gram bucket rows
 (BASELINE.json stretch config): the engine's table grows by ``bucket``
 extra rows (corpus/subword.py), a center word trains as the mean of its
-subword group's rows (``EmbeddingEngine.train_step_grouped``), and word
+subword group's rows (formed inside the corpus-resident scans from a
+device-resident group table, ``EmbeddingEngine.upload_center_groups``; by
+``train_steps_grouped`` under the host batcher), and word
 vectors — including OOV words, which the word-level reference cannot
 represent at all — compose on device via ``pull_average``.
 """
@@ -69,11 +71,11 @@ class FastTextWord2Vec(Word2Vec):
 
     # Family hooks -----------------------------------------------------
 
-    def _device_corpus_eligible(self, corpus_words: int = 0) -> bool:
-        # Subword centers need the host-side group expansion
-        # (_train_batches below); the device corpus batcher assembles
-        # word-level centers only.
-        return False
+    def _center_groups(self) -> np.ndarray:
+        # The corpus-resident fit forms every centre's group on the
+        # device from this table; the host-side expansion below is the
+        # host batcher's alone.
+        return np.where(self._sub_mask > 0, self._sub_ids, -1).astype(np.int32)
 
     def _make_engine(self, mesh, vocab: Vocabulary):
         from glint_word2vec_tpu.parallel.engine import EmbeddingEngine

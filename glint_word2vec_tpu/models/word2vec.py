@@ -595,8 +595,9 @@ class Word2Vec:
         )
 
     def _device_corpus_eligible(self, corpus_words: int = 0) -> bool:
-        """Whether the device-resident corpus path applies: word-level
-        centers (subword grouping overrides this to False), the corpus
+        """Whether the device-resident corpus path applies (to every
+        model family: a subword fit adds its group table,
+        :meth:`_center_groups`): the corpus
         fits the HBM budget reserved for it (GLINT_DEVICE_CORPUS_MAX_BYTES
         overrides the 2 GiB default; tables need the rest), and no env
         escape hatch. Frequency subsampling no longer disqualifies —
@@ -679,6 +680,7 @@ class Word2Vec:
         try:
             with obs_run.span("upload_corpus", words=int(ids.shape[0])):
                 engine.upload_corpus(ids, offsets)
+                engine.upload_center_groups(self._center_groups())
             if subsampling:
                 engine.set_keep_probs(
                     vocab.device_keep_probabilities(p.subsample_ratio)
@@ -717,8 +719,9 @@ class Word2Vec:
                 int(stop_after_groups) if stop_after_groups else None
             )
             packed_groups = packed_pairs = packed_slots = 0
-            # distinct rows written (syn0, syn1), slabs moved (syn0, syn1)
-            rows_written = np.zeros(4, np.int64)
+            # distinct rows written (syn0, syn1), slabs moved (syn0, syn1);
+            # a subword fit also: live group ids gathered, centres formed
+            rows_written = np.zeros(6, np.int64)
             early_stop = False
 
             state_path = (
@@ -903,7 +906,8 @@ class Word2Vec:
                     step += spc - n_real  # tail no-ops consumed keys
                 packed_pairs += int(pairs_h[:n_real].sum())
                 packed_slots += n_real * pair_batch
-                rows_written[:] += np.asarray(written)[:n_real].sum(axis=0)
+                written_h = np.asarray(written)[:n_real].sum(axis=0)
+                rows_written[:written_h.size] += written_h
                 packed_groups += 1
                 return int(pos_ends_h[-1])
 
@@ -1237,15 +1241,22 @@ class Word2Vec:
                 packed_pairs=packed_pairs,
                 packed_mask_density=round(packed_pairs / packed_slots, 4),
                 exchange_bytes_per_step=engine.packed_exchange_bytes(
-                    pair_batch),
+                    pair_batch, p.window),
             )
-            if rows_written.any():
+            if rows_written[5]:
+                # Rows a subword centre is the mean of: live group ids the
+                # packed steps gathered over the centres they formed.
+                model.training_metrics.update(
+                    subword_rows_per_center=round(
+                        rows_written[4] / rows_written[5], 4),
+                )
+            if rows_written[:4].any():
                 # Rows the scatters wrote over the update slots they were
                 # handed (the step sums a row's duplicates before it
                 # writes): both tables, then each.
                 steps = packed_slots // pair_batch
-                slots = engine.packed_scatter_slots(pair_batch)
-                rows, slabs = rows_written[:2], rows_written[2:]
+                slots = engine.packed_scatter_slots(pair_batch, p.window)
+                rows, slabs = rows_written[:2], rows_written[2:4]
                 model.training_metrics.update(
                     scatter_distinct_share=round(
                         sum(rows) / (steps * sum(slots)), 4),
@@ -1648,6 +1659,14 @@ class Word2Vec:
         return model
 
     # Hooks specialized by subword/other model families (models/fasttext.py).
+
+    def _center_groups(self) -> Optional[np.ndarray]:
+        """Family hook: the ``(vocab, G)`` table of the rows each word's
+        centre vector is the mean of, -1 padded, for the corpus-resident
+        fit to put on the device beside the corpus
+        (``EmbeddingEngine.upload_center_groups``); None at word level,
+        where a centre is its own row. Called after :meth:`_make_engine`."""
+        return None
 
     def _make_engine(self, mesh, vocab: Vocabulary):
         from glint_word2vec_tpu.parallel.engine import EmbeddingEngine

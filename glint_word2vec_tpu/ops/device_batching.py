@@ -310,6 +310,42 @@ def pack_window_pairs(
     return pcenters, pcontexts, pmask, n_cons, n_pairs
 
 
+def center_runs(pcenters: jax.Array, pmask: jax.Array, n_runs: int):
+    """Group a packed pair list by centre, for a step whose centre is
+    formed from several rows (the subword family): consecutive pair slots
+    with the same centre id are one RUN, and the centre side of the step
+    (gather of the group's rows, their mean, the gradient's fan-out) is
+    formed once a run instead of once a pair.
+
+    :func:`pack_window_pairs` emits pairs position-major, so a run is one
+    consumed centre position, or several adjacent positions that hold the
+    same word (their centre vector is the same, and their gradients add:
+    the sums are those of the per-pair form). A position with no valid
+    pair has no run. ``n_runs`` bounds the runs of one list statically:
+    at most one per examined position and one for the padding, so
+    ``span + 1`` (or the list's length, if shorter).
+
+    Returns ``(run_center (n_runs,), pair_run (P,), run_live (n_runs,))``:
+    the centre id of each run, each pair slot's run, and which runs hold a
+    live pair (padding slots, centre 0 and mask 0, form a dead last run;
+    unused run slots are dead too). A function of the pair list alone.
+    """
+    change = (pcenters[1:] != pcenters[:-1]).astype(jnp.int32)
+    pair_run = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(change)])
+    run_center = (
+        jnp.zeros(n_runs, jnp.int32)
+        .at[pair_run]
+        .set(pcenters, mode="drop")
+    )
+    # Live pairs are a prefix of the list (pmask is 1 below n_pairs).
+    n_live = pmask.sum().astype(jnp.int32)
+    last = pair_run[jnp.maximum(n_live - 1, 0)]
+    run_live = jnp.arange(n_runs, dtype=jnp.int32) < jnp.where(
+        n_live > 0, last + 1, 0
+    )
+    return run_center, pair_run, run_live
+
+
 def device_words_done(
     offsets: jax.Array,  # (S+1,) int32 ORIGINAL sentence offsets
     offsets_c: jax.Array,  # (S+1,) int32 active (possibly compacted) offsets

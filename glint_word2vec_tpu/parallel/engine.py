@@ -43,6 +43,7 @@ O(local rows) draws.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import time
@@ -119,17 +120,22 @@ def _score(table_l, q):
     )
 
 
-def _pull_rows(table_l, idx, start, rows_per_shard):
+def _pull_rows(table_l, idx, start, rows_per_shard, table=None):
     """Gather global rows from a shard-local table: contribute owned rows,
     zeros elsewhere, then psum over the model axis. The TPU analogue of the
     servers each answering a pull with their slice (SURVEY.md §2.2 pull).
+    An id no shard owns (the -1 that pads a subword group) pulls zeros.
 
     Opens its own scopes, so callers keep it OUT of theirs (a device trace
     files an op under its outermost ``glint.`` scope): the own-row gather
-    and its mask under ``glint.gather``, the psum, which is the only part
-    that crosses chips, under its sibling ``glint.exchange``.
+    and its mask under ``glint.gather`` (the step bodies name the
+    ``table``, ``syn0`` or ``syn1``, as an inner scope, as their scatters
+    do), the psum, which is the only part that crosses chips, under its
+    sibling ``glint.exchange``.
     """
-    with jax.named_scope("glint.gather"):
+    with jax.named_scope("glint.gather"), (
+        jax.named_scope(table) if table else contextlib.nullcontext()
+    ):
         loc = idx - start
         own = (loc >= 0) & (loc < rows_per_shard)
         clipped = jnp.clip(loc, 0, rows_per_shard - 1)
@@ -584,14 +590,20 @@ class EmbeddingEngine:
         rep = P()
 
         def step_body_rows(syn0_l, syn1_l, prob, alias, centers, cmask,
-                           contexts, mask, key, alpha):
-            # Data-sharded inputs: centers/cmask (Bl, S), contexts/mask
+                           contexts, mask, key, alpha, pair_run=None):
+            # Data-sharded inputs: centers/cmask (Rl, S), contexts/mask
             # (Bl, C). S = subword-group width; word-level training is the
             # S=1 specialization. The center representation is the masked
             # mean of its group's syn0 rows (fastText composition; for S=1
-            # this is exactly the plain word vector).
-            Bl, S = centers.shape
-            C = contexts.shape[1]
+            # this is exactly the plain word vector). ``pair_run`` (Bl,),
+            # rising, says which group each batch row's centre is (the
+            # packed subword scan forms a group once a run of pairs,
+            # ops/device_batching.center_runs); None: row i has group i.
+            Rl, S = centers.shape
+            Bl, C = contexts.shape
+            # What a grouped centre adds to the step has a scope of its
+            # own; at S = 1 the mean is the row and stays the gather's.
+            compose = "glint.compose" if S > 1 else "glint.gather"
             start = lax.axis_index(MODEL_AXIS) * Vs
             drank = lax.axis_index(DATA_AXIS)
 
@@ -602,14 +614,21 @@ class EmbeddingEngine:
             # (benchmark/program_trace.py). A fusion is filed under its
             # root's scope, an op under its OUTERMOST one: _pull_rows
             # opens its own two and is called outside any other.
-            h_rows = _pull_rows(syn0_l, centers.reshape(-1), start, Vs)
-            u_pos = _pull_rows(syn1_l, contexts.reshape(-1), start, Vs)
-            with jax.named_scope("glint.gather"):
-                h_rows = h_rows.reshape(Bl, S, -1)
+            h_rows = _pull_rows(
+                syn0_l, centers.reshape(-1), start, Vs, "syn0"
+            )
+            u_pos = _pull_rows(
+                syn1_l, contexts.reshape(-1), start, Vs, "syn1"
+            )
+            with jax.named_scope(compose):
+                h_rows = h_rows.reshape(Rl, S, -1)
                 cnt = jnp.maximum(
                     cmask.sum(axis=1, keepdims=True), 1.0
-                )  # (Bl,1)
+                )  # (Rl,1)
                 h = (h_rows * cmask[..., None]).sum(axis=1) / cnt
+                if pair_run is not None:
+                    h = h[pair_run]  # (Bl, d)
+            with jax.named_scope("glint.gather"):
                 u_pos = u_pos.reshape(Bl, C, -1)
 
                 # The data-axis exchange ships ONLY h (B, d), scalar
@@ -631,7 +650,7 @@ class EmbeddingEngine:
                     pool = sample_negatives(
                         key, prob, alias, (self.shared_negatives,)
                     )
-                u_pool = _pull_rows(syn1_l, pool, start, Vs)
+                u_pool = _pull_rows(syn1_l, pool, start, Vs, "syn1")
                 with jax.named_scope("glint.sample"):
                     collide = sgns.pool_collision_mask(pool, contexts, mask)
                 with jax.named_scope("glint.grads"):
@@ -664,7 +683,9 @@ class EmbeddingEngine:
                     negs = sample_negatives_per_row(
                         key, prob, alias, rows_g, (C, n)
                     )
-                u_neg = _pull_rows(syn1_l, negs.reshape(-1), start, Vs)
+                u_neg = _pull_rows(
+                    syn1_l, negs.reshape(-1), start, Vs, "syn1"
+                )
                 with jax.named_scope("glint.gather"):
                     u_neg = u_neg.reshape(Bl, C, n, -1)
                 with jax.named_scope("glint.sample"):
@@ -683,11 +704,18 @@ class EmbeddingEngine:
                     scat1 = _pair_payload(ctx_g, negs_g, cpos_g, cneg_g, h_g)
 
             # The center gradient is distributed over the group's rows
-            # (d mean / d row = 1/count): ship the (Bl, d) gradient + the
-            # (Bl, S) group mask, expand to rows at the consumer.
+            # (d mean / d row = 1/count): ship the (Rl, d) gradient + the
+            # (Rl, S) group mask, expand to rows at the consumer. A group
+            # that several batch rows share first sums their gradients.
+            d_center = g.d_center
+            if pair_run is not None:
+                with jax.named_scope(compose):
+                    d_center = jnp.zeros(
+                        (Rl, d_center.shape[1]), jnp.float32
+                    ).at[pair_run].add(d_center, indices_are_sorted=True)
             with jax.named_scope("glint.grads"):
                 dcen_g = lax.all_gather(
-                    g.d_center / cnt, DATA_AXIS, tiled=True
+                    d_center / cnt, DATA_AXIS, tiled=True
                 )
                 cmask_g = lax.all_gather(cmask, DATA_AXIS, tiled=True)
                 ids0_g = lax.all_gather(
@@ -717,7 +745,7 @@ class EmbeddingEngine:
             return syn0_l, syn1_l, loss, written
 
         def step_body_dims(syn0_l, syn1_l, prob, alias, centers, cmask,
-                           contexts, mask, key, alpha):
+                           contexts, mask, key, alpha, pair_run=None):
             # Column-sharded step (CIKM'16 partitioning, SURVEY.md §2.2):
             # tables are (V, dl) local column slices with EVERY row
             # resident, so gathers and scatter-adds are shard-local. The
@@ -726,17 +754,25 @@ class EmbeddingEngine:
             # servers return from ``dotprod``. The data-axis exchange is
             # the same scalars+h contract as the rows layout, with h now
             # a (B, dl) column slice (1/n the bytes per chip).
-            Bl, S = centers.shape
-            C = contexts.shape[1]
+            # Groups, ``pair_run`` and the compose scope as in
+            # step_body_rows; a padding id (-1) reads a row the mask drops.
+            Rl, S = centers.shape
+            Bl, C = contexts.shape
             drank = lax.axis_index(DATA_AXIS)
             cd = self._compute_dtype
+            compose = "glint.compose" if S > 1 else "glint.gather"
 
-            with jax.named_scope("glint.gather"):
+            with jax.named_scope("glint.gather"), jax.named_scope("syn0"):
                 h_rows = syn0_l[centers.reshape(-1)].astype(jnp.float32)
-                h_rows = h_rows.reshape(Bl, S, -1)
+            with jax.named_scope(compose):
+                h_rows = h_rows.reshape(Rl, S, -1)
                 cnt = jnp.maximum(cmask.sum(axis=1, keepdims=True), 1.0)
-                h = (h_rows * cmask[..., None]).sum(axis=1) / cnt  # (Bl, dl)
-                u_pos = syn1_l[contexts.reshape(-1)].astype(jnp.float32)
+                h = (h_rows * cmask[..., None]).sum(axis=1) / cnt  # (Rl, dl)
+                if pair_run is not None:
+                    h = h[pair_run]  # (Bl, dl)
+            with jax.named_scope("glint.gather"):
+                with jax.named_scope("syn1"):
+                    u_pos = syn1_l[contexts.reshape(-1)].astype(jnp.float32)
                 u_pos = u_pos.reshape(Bl, C, -1)
 
                 h_g = lax.all_gather(h, DATA_AXIS, tiled=True)  # (B, dl)
@@ -746,7 +782,7 @@ class EmbeddingEngine:
                     pool = sample_negatives(
                         key, prob, alias, (self.shared_negatives,)
                     )
-                with jax.named_scope("glint.gather"):
+                with jax.named_scope("glint.gather"), jax.named_scope("syn1"):
                     u_pool = syn1_l[pool].astype(jnp.float32)  # (S, dl)
                 with jax.named_scope("glint.sample"):
                     collide = sgns.pool_collision_mask(pool, contexts, mask)
@@ -784,7 +820,8 @@ class EmbeddingEngine:
                         key, prob, alias, rows_g, (C, n)
                     )
                 with jax.named_scope("glint.gather"):
-                    u_neg = syn1_l[negs.reshape(-1)].astype(jnp.float32)
+                    with jax.named_scope("syn1"):
+                        u_neg = syn1_l[negs.reshape(-1)].astype(jnp.float32)
                     u_neg = u_neg.reshape(Bl, C, n, -1)
                 with jax.named_scope("glint.sample"):
                     nmask = sgns.negative_mask(negs, contexts, mask)
@@ -814,6 +851,11 @@ class EmbeddingEngine:
                     scat1 = _pair_payload(ctx_g, negs_g, cpos_g, cneg_g, h_g)
                 loss_local = co.loss
 
+            if pair_run is not None:
+                with jax.named_scope(compose):
+                    d_center_l = jnp.zeros(
+                        (Rl, d_center_l.shape[1]), jnp.float32
+                    ).at[pair_run].add(d_center_l, indices_are_sorted=True)
             with jax.named_scope("glint.grads"):
                 dcen_g = lax.all_gather(
                     d_center_l / cnt, DATA_AXIS, tiled=True
@@ -901,7 +943,7 @@ class EmbeddingEngine:
         self._corpus_scan_cache: dict = {}
         self._ones_mask_cache: dict = {}
 
-        def make_corpus_scan(B: int, W: int):
+        def make_corpus_scan(B: int, W: int, G: int = 0):
             # Corpus-resident scan: batches are assembled ON DEVICE from
             # the uploaded flat corpus (ops/device_batching) — the only
             # per-dispatch host->device traffic is scalars. Step i of the
@@ -919,9 +961,12 @@ class EmbeddingEngine:
 
             Bl = B // num_data
 
+            # ``G`` > 0 (the subword family): one more replicated
+            # argument, the (vocab, G) group table, and each row's centre
+            # is its word's group (a row past the corpus end has none).
             def local_corpus_scan(syn0_l, syn1_l, prob, alias, ids, soffs,
                                   n_valid, pstart, base_key, step0,
-                                  alphas_k):
+                                  alphas_k, groups=None):
                 drank = lax.axis_index(DATA_AXIS)
                 rows_l = (drank * Bl + jnp.arange(Bl)).astype(jnp.int32)
 
@@ -936,9 +981,18 @@ class EmbeddingEngine:
                         ids, soffs, positions, rows_l, key, W,
                         n_valid=n_valid,
                     )
-                    cmask = jnp.ones((Bl, 1), jnp.float32)
+                    if G:
+                        with jax.named_scope("glint.compose"):
+                            grp = jnp.where(
+                                (positions < n_valid)[:, None],
+                                groups[centers], -1,
+                            )
+                            cmask = (grp >= 0).astype(jnp.float32)
+                    else:
+                        cmask = jnp.ones((Bl, 1), jnp.float32)
+                        grp = centers[:, None]
                     s0, s1, loss, _ = step_body(
-                        s0, s1, prob, alias, centers[:, None], cmask,
+                        s0, s1, prob, alias, grp, cmask,
                         contexts, mask, key, alpha,
                     )
                     return (s0, s1), loss
@@ -954,8 +1008,7 @@ class EmbeddingEngine:
             return jax.jit(
                 self._shard_map(
                     local_corpus_scan,
-                    in_specs=(tspec, tspec, rep, rep, rep, rep,
-                              rep, rep, rep, rep, rep),
+                    in_specs=(tspec, tspec) + (rep,) * (10 if G else 9),
                     out_specs=(tspec, tspec, rep),
                 ),
                 donate_argnums=(0, 1),
@@ -965,7 +1018,7 @@ class EmbeddingEngine:
         self._packed_scan_cache: dict = {}
 
         def make_packed_corpus_scan(P: int, W: int, B_grid: int, S: int,
-                                    K: int):
+                                    K: int, G: int = 0):
             # PACKED corpus-resident scan (ISSUE 4): instead of a (B, C)
             # context grid that is ~57% masked lanes, each step assembles
             # windows over an oversized candidate span of center
@@ -990,17 +1043,29 @@ class EmbeddingEngine:
             # same valid-pair multiset as the grid path at the same
             # (B_grid, key schedule) — the parity gate that keeps "grid"
             # the default until it holds.
+            #
+            # ``G`` > 0 is the subword family (models/fasttext.py): the
+            # scan takes one more replicated argument, the (vocab, G)
+            # group table (:meth:`upload_center_groups`), draws its batch
+            # exactly as above, and forms each centre from its group's
+            # rows, once a run of pairs with the same centre
+            # (ops/device_batching.center_runs), never once a pair. The
+            # centre side is a function of the drawn centre ids and the
+            # table alone. ``written`` then carries two more counts: live
+            # group ids and the centres they formed.
             from glint_word2vec_tpu.ops.device_batching import (
+                center_runs,
                 device_words_done,
                 pack_window_pairs,
             )
 
             Pl = P // num_data
+            R = min(Pl, S + 1)  # runs of one rank's pair list
 
             def local_packed_scan(syn0_l, syn1_l, prob, alias, ids, soffs,
                                   orig_offs, n_valid, pstart, base_key,
                                   step0, grid_step0, step_size,
-                                  inv_total_words, words_base):
+                                  inv_total_words, words_base, groups=None):
                 drank = lax.axis_index(DATA_AXIS)
 
                 def body(carry, i):
@@ -1024,11 +1089,30 @@ class EmbeddingEngine:
                         c_l = lax.dynamic_slice_in_dim(pc, drank * Pl, Pl)
                         x_l = lax.dynamic_slice_in_dim(px, drank * Pl, Pl)
                         m_l = lax.dynamic_slice_in_dim(pm, drank * Pl, Pl)
-                        cmask = jnp.ones((Pl, 1), jnp.float32)
+                        if not G:
+                            cmask = jnp.ones((Pl, 1), jnp.float32)
+                    if G:
+                        with jax.named_scope("glint.compose"):
+                            run_c, pair_run, live = center_runs(c_l, m_l, R)
+                            grp = jnp.where(
+                                live[:, None], groups[run_c], -1
+                            )
+                            cmask = (grp >= 0).astype(jnp.float32)
+                            formed = lax.psum(
+                                jnp.stack([
+                                    (grp >= 0).sum(dtype=jnp.int32),
+                                    live.sum(dtype=jnp.int32),
+                                ]), DATA_AXIS,
+                            )
+                    else:
+                        grp, pair_run = c_l[:, None], None
                     s0, s1, loss, written = step_body(
-                        s0, s1, prob, alias, c_l[:, None], cmask,
+                        s0, s1, prob, alias, grp, cmask,
                         x_l[:, None], m_l[:, None], key, alpha,
+                        pair_run=pair_run,
                     )
+                    if G:
+                        written = jnp.concatenate([written, formed])
                     return (s0, s1, pos_end), (
                         loss, n_pairs, pos_end, alpha, written
                     )
@@ -1043,7 +1127,7 @@ class EmbeddingEngine:
             return jax.jit(
                 self._shard_map(
                     local_packed_scan,
-                    in_specs=(tspec, tspec) + (rep,) * 13,
+                    in_specs=(tspec, tspec) + (rep,) * (14 if G else 13),
                     out_specs=(tspec, tspec, rep, rep, rep, rep, rep),
                 ),
                 donate_argnums=(0, 1),
@@ -1551,6 +1635,36 @@ class EmbeddingEngine:
         self._corpus_compacted = None
         self._n_kept = None
 
+    def upload_center_groups(self, groups: Optional[np.ndarray]) -> None:
+        """Put the subword family's group table on the device, replicated,
+        once per fit: ``groups`` is ``(vocab_size, G)`` int32, row w the
+        table rows whose mean is word w's centre vector (its own row, then
+        its n-gram bucket rows), padded with -1, an id no shard owns.
+        While a table is held the corpus scans form every centre from its
+        group inside the jitted scan (the packed scan once a run of pairs:
+        ``make_packed_corpus_scan``); ``None`` drops it, and the scans are
+        the word-level programs again."""
+        if groups is None:
+            self._center_groups = None
+            return
+        # graftlint: ignore[sync-point] the family's host-built table
+        g = np.asarray(groups, dtype=np.int32)
+        if g.ndim != 2 or g.shape[0] != self.vocab_size or g.shape[1] < 2:
+            raise ValueError(
+                f"groups must have shape ({self.vocab_size}, G >= 2), got "
+                f"{g.shape}"
+            )
+        if g.max(initial=-1) >= self.num_rows or g.min(initial=0) < -1:
+            raise ValueError("group ids must be table rows, or -1 (padding)")
+        self._center_groups = jax.device_put(
+            g, NamedSharding(self.mesh, P())
+        )
+
+    @property
+    def _group_width(self) -> int:
+        g = getattr(self, "_center_groups", None)
+        return 0 if g is None else g.shape[1]
+
     @property
     def corpus_positions(self) -> int:
         """Total center positions of the uploaded corpus (= its words)."""
@@ -1734,13 +1848,14 @@ class EmbeddingEngine:
             raise ValueError(
                 f"batch size {B} not divisible by data axis {self.num_data}"
             )
-        fn = self._corpus_scan_cache.get((B, W))
+        G = self._group_width
+        fn = self._corpus_scan_cache.get((B, W, G))
         if fn is None:
-            mk = self._scan_memo_key("grid", B, W)
+            mk = self._scan_memo_key("grid", B, W, G)
             fn = _SCAN_MEMO.get(mk)
             if fn is None:
-                fn = _scan_memo_put(mk, self._make_corpus_scan(B, W))
-            self._corpus_scan_cache[(B, W)] = fn
+                fn = _scan_memo_put(mk, self._make_corpus_scan(B, W, G))
+            self._corpus_scan_cache[(B, W, G)] = fn
         if getattr(self, "_corpus_compacted", None) is not None:
             ids, soffs = self._corpus_compacted
             n_valid = self._n_kept
@@ -1751,6 +1866,7 @@ class EmbeddingEngine:
             self.syn0, self.syn1, self._prob, self._alias, ids, soffs,
             jnp.int32(n_valid), jnp.int32(start_position), base_key,
             jnp.uint32(step0), jnp.asarray(alphas, dtype=jnp.float32),
+            *((self._center_groups,) if G else ()),
         )
         self._tick_tables("train_steps_corpus")
         return losses
@@ -1792,7 +1908,10 @@ class EmbeddingEngine:
         alpha, and the distinct rows the step's scatters wrote into
         (syn0, syn1), of the :meth:`packed_scatter_slots` they were handed,
         then the slabs the slab writer moved for them (syn0, syn1; 0 where
-        XLA's writer ran: :func:`_scatter_rows`).
+        XLA's writer ran: :func:`_scatter_rows`). While a group table is
+        held (:meth:`upload_center_groups`) ``rows_written`` is ``(K, 6)``:
+        then the live group ids the step gathered and the centres they
+        formed.
         The caller reads ``pos_ends[-1]`` to schedule the next dispatch
         (one scalar readback per K steps).
         """
@@ -1818,15 +1937,16 @@ class EmbeddingEngine:
             # the epoch tail.
             span = -(-3 * P // C)
         S, K = int(span), int(n_steps)
-        fn = self._packed_scan_cache.get((P, W, B, S, K))
+        G = self._group_width
+        fn = self._packed_scan_cache.get((P, W, B, S, K, G))
         if fn is None:
-            mk = self._scan_memo_key("packed", P, W, B, S, K)
+            mk = self._scan_memo_key("packed", P, W, B, S, K, G)
             fn = _SCAN_MEMO.get(mk)
             if fn is None:
                 fn = _scan_memo_put(
-                    mk, self._make_packed_corpus_scan(P, W, B, S, K)
+                    mk, self._make_packed_corpus_scan(P, W, B, S, K, G)
                 )
-            self._packed_scan_cache[(P, W, B, S, K)] = fn
+            self._packed_scan_cache[(P, W, B, S, K, G)] = fn
         if getattr(self, "_corpus_compacted", None) is not None:
             ids, soffs = self._corpus_compacted
             n_valid = self._n_kept
@@ -1840,19 +1960,37 @@ class EmbeddingEngine:
             jnp.uint32(grid_step0), jnp.float32(step_size),
             jnp.float32(1.0 / float(total_words)),
             jnp.float32(words_base),
+            *((self._center_groups,) if G else ()),
         )
         self._tick_tables("train_steps_corpus_packed")
         return tuple(per_step)
 
-    def packed_scatter_slots(self, pair_batch: int) -> Tuple[int, int]:
-        """Update slots one packed step hands the (syn0, syn1) scatters:
-        a centre a pair, and a context plus its negatives (or the shared
-        pool once) a pair."""
-        if self.shared_negatives:
-            return pair_batch, pair_batch + self.shared_negatives
-        return pair_batch, pair_batch * (1 + self.num_negatives)
+    def _packed_center_slots(self, pair_batch: int, window) -> int:
+        """``syn0`` rows one packed step pulls and update slots it hands the
+        ``syn0`` scatter, over all data ranks: a centre a pair, or, while a
+        group table is held, the whole group (padding included) of every
+        run slot of the default span (``make_packed_corpus_scan``)."""
+        G = self._group_width
+        if not G:
+            return pair_batch
+        from glint_word2vec_tpu.corpus.batching import context_width
 
-    def packed_exchange_bytes(self, pair_batch: int) -> int:
+        span = -(-3 * pair_batch // context_width(window))
+        return self.num_data * G * min(pair_batch // self.num_data, span + 1)
+
+    def packed_scatter_slots(self, pair_batch: int,
+                             window: Optional[int] = None) -> Tuple[int, int]:
+        """Update slots one packed step hands the (syn0, syn1) scatters:
+        a centre a pair (:meth:`_packed_center_slots`: ``window`` matters
+        to a subword engine alone), and a context plus its negatives (or
+        the shared pool once) a pair."""
+        centers = self._packed_center_slots(pair_batch, window)
+        if self.shared_negatives:
+            return centers, pair_batch + self.shared_negatives
+        return centers, pair_batch * (1 + self.num_negatives)
+
+    def packed_exchange_bytes(self, pair_batch: int,
+                              window: Optional[int] = None) -> int:
         """Bytes one device hands the model-axis collectives of one packed
         step (the psums under ``glint.exchange``), from shapes alone: the
         float32 rows it pulls in the ``rows`` layout (a centre, a context
@@ -1865,8 +2003,10 @@ class EmbeddingEngine:
         if self.layout == "dims":
             return 4 * pairs * (
                 1 + (self.shared_negatives or self.num_negatives))
-        rows = (2 * pairs + self.shared_negatives if self.shared_negatives
-                else pairs * (2 + self.num_negatives))
+        centers = self._packed_center_slots(pair_batch, window)
+        rows = centers // self.num_data + (
+            pairs + self.shared_negatives if self.shared_negatives
+            else pairs * (1 + self.num_negatives))
         return 4 * rows * self.padded_dim
 
     # ------------------------------------------------------------------

@@ -50,6 +50,42 @@ def test_build_subword_table_shapes():
     assert ids[0, 0] == 0 and ids[1, 0] == 1
 
 
+@pytest.mark.parametrize("min_n,max_n,width,bucket", [
+    (3, 6, 32, 2_000_000), (1, 3, 8, 97), (5, 5, 4, 1000), (2, 7, 64, 50)])
+def test_build_subword_table_is_the_scalar_group(min_n, max_n, width, bucket):
+    # The table is built over all the words' bytes at once; row by row it
+    # is subword_group, for words of one to fifteen characters, of one to
+    # four bytes a character, and for the empty word.
+    rng = np.random.default_rng(0)
+    letters = list("abcdefghijklmnopqrstuvwxyzäöüßéñ日本語🙂_0")
+    words = ["".join(rng.choice(letters, size=rng.integers(1, 16)))
+             for _ in range(400)] + ["a", "", "ab", "日", "🙂" * 12]
+    ids, mask = build_subword_table(
+        words, len(words), bucket, min_n, max_n, width)
+    for w_id, w in enumerate(words):
+        g = subword_group(w, w_id, len(words), bucket, min_n, max_n, width)
+        assert ids[w_id, :len(g)].tolist() == g, w
+        assert mask[w_id].tolist() == [1.0] * len(g) + [0.0] * (width - len(g))
+        assert not ids[w_id, len(g):].any()
+
+
+def test_fasttext_fit_file_takes_the_corpus_resident_path(tiny_corpus, tmp_path):
+    path = tmp_path / "corpus.txt"
+    path.write_text("\n".join(" ".join(s) for s in tiny_corpus) + "\n")
+    m = FastTextWord2Vec(
+        mesh=make_mesh(1, 2), vector_size=32, min_count=5, batch_size=256,
+        num_iterations=2, seed=1, bucket=5000, min_n=3, max_n=5,
+        steps_per_call=4,
+    ).fit_file(str(path))
+    tm = m.training_metrics
+    assert tm["pipeline"] == "device_corpus"
+    assert tm["final_loss"] < tm["first_loss"]
+    # live rows a centre is the mean of: its own and some n-grams'
+    assert 2 < tm["subword_rows_per_center"] <= 32
+    assert np.isfinite(m.transform("austriaa")).all()  # OOV still composes
+    m.stop()
+
+
 @pytest.fixture(scope="module")
 def ft_model(tiny_corpus):
     ft = FastTextWord2Vec(

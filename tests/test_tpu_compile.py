@@ -47,6 +47,14 @@ SCANS = {
     "10m-4chips": (4, 0, 10_000_000, _X4_WORDS,
                    -(-(_X4_WORDS - 8 * 80_000) // 40) + 80_000),
 }
+# The subword scans: the packed scan with a (vocab, 32) group table on the
+# device, over benchmark/configs/ft-300-1m-2mb.json's corpus (each word
+# once, 4M Zipf draws in 40-word sentences, 80,000 planted ones of 8
+# words) and fastText's 2,000,000 bucket rows behind the vocabulary's.
+BUCKET, MAX_SUBWORDS = 2_000_000, 32
+SUBWORD_SCANS = {  # name: (vocabulary, corpus words)
+    "ft-1m-2mb": (1_000_000, 1_000_000 + 4_000_000 + 8 * 80_000),
+}
 
 
 @pytest.fixture(scope="module")
@@ -76,8 +84,9 @@ def engines(topo):
 
     built = {}
 
-    def get(chips: int, shared_negatives: int = 0, vocab: int = V):
-        key = (chips, shared_negatives, vocab)
+    def get(chips: int, shared_negatives: int = 0, vocab: int = V,
+            extra_rows: int = 0):
+        key = (chips, shared_negatives, vocab, extra_rows)
         if key not in built:
             mesh = Mesh(
                 np.asarray(topo.devices[:chips]).reshape(1, chips),
@@ -87,7 +96,7 @@ def engines(topo):
             eng._configure(
                 mesh, vocab, D, num_negatives=NEG, unigram_power=0.75,
                 unigram_table_size=None, seed=1, dtype="float32",
-                extra_rows=0, shared_negatives=shared_negatives,
+                extra_rows=extra_rows, shared_negatives=shared_negatives,
                 compute_dtype=None, layout="rows",
             )
             eng._build_jitted_fns()
@@ -164,10 +173,13 @@ def _fits(compiled, chips: int = 1) -> dict:
     return mem
 
 
-def _compile_packed_scan(eng, words=CORPUS_WORDS, sentences=CORPUS_SENTENCES):
+def _compile_packed_scan(eng, words=CORPUS_WORDS, sentences=CORPUS_SENTENCES,
+                         group_width=0):
     """The packed corpus scan at chip_smoke.py's training geometry (26,215
     pairs a step, 5 negatives) over a resident corpus of ``words`` tokens,
-    compiled for the engine's described mesh."""
+    compiled for the engine's described mesh; with ``group_width`` the
+    subword family's, a (vocab, group_width) group table its last
+    argument."""
     import jax.numpy as jnp
 
     from glint_word2vec_tpu.corpus.batching import (
@@ -179,16 +191,17 @@ def _compile_packed_scan(eng, words=CORPUS_WORDS, sentences=CORPUS_SENTENCES):
     P_ = packed_pair_batch(BATCH, WINDOW, 1)
     span = -(-3 * P_ // context_width(WINDOW))
     fn = eng._make_packed_corpus_scan(
-        P_, WINDOW, BATCH, span, STEPS_PER_CALL
+        P_, WINDOW, BATCH, span, STEPS_PER_CALL, group_width
     )
     vocab = eng.vocab_size
+    groups = (sds((vocab, group_width), jnp.int32),) if group_width else ()
     table = _table(eng)
     offs = sds((sentences + 1,), jnp.int32)
     i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
     return fn.lower(
         table, table, sds((vocab,), jnp.float32), sds((vocab,), jnp.int32),
         sds((words,), jnp.int32), offs, offs, i32, i32,
-        sds((2,), jnp.uint32), u32, u32, f32, f32, f32,
+        sds((2,), jnp.uint32), u32, u32, f32, f32, f32, *groups,
     ).compile()
 
 
@@ -315,6 +328,48 @@ def test_packed_corpus_scan_writes_rows_by_slabs(packed_scans, name):
     ]
     ceiling = 2.3e9 if name == "1chip-shared_pool" else 1.2e9
     assert _fits(compiled, SCANS[name][0])["temp"] < ceiling
+
+
+def _compile_subword_scan(engines, name):
+    vocab, words = SUBWORD_SCANS[name]
+    eng = engines(1, 0, vocab, BUCKET)
+    sentences = -(-(words - 8 * 80_000) // 40) + 80_000
+    return eng, _compile_packed_scan(eng, words, sentences, MAX_SUBWORDS)
+
+
+def test_subword_packed_scan_at_the_cell_size(engines):
+    # The subword cell's step (benchmark/configs/ft-300-1m-2mb.json): two
+    # tables of 1M word rows + 2M bucket rows, 9.22 GB at rest, donated;
+    # the (1M, 32) group table, 128 MB, replicated beside the corpus. A
+    # centre's group is gathered and scattered once a run of pairs: the
+    # step's temporaries grow by a few buffers of (7,866 runs x 32) rows
+    # of 384 columns, 387 MB each. ISSUE 31 reckoned 11.5-12.5 GB in all.
+    eng, compiled = _compile_subword_scan(engines, "ft-1m-2mb")
+    mem = _fits(compiled)
+    assert eng.padded_vocab == 3_000_000
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        2 * eng.rows_per_shard * D_REST * 4
+    ), mem
+    assert mem["total"] < 12.5e9 and mem["temp"] < 3.0e9, mem
+    assert not _whole_table_copies(compiled, eng)
+    text = compiled.as_text()
+    assert "all-reduce" not in text
+    # both tables' scatters end in the slab writer, the group's rows too
+    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for table in ("syn0", "syn1"):
+        assert any(f"glint.scatter/{table}" in k for k in kernels), table
+    assert "glint.compose" in text and "glint.gather/syn0" in text
+    # fastText's cc.en.300 shape, 2M words + 2M buckets, which ISSUE 31
+    # reckoned too large for one chip: compiled once by hand it FITS, at
+    # 13,793,810,432 B with the same 1,205,386,752 B of temporaries
+    # (compile check, PR 31; a second compile costs this suite a minute).
+    # That is this program and what a million more words add to its
+    # arguments: rows of both tables, of the group table, of the sampler's
+    # two tables, and the corpus's words. PERF.md section 7 says what the
+    # cut to 1M words rests on since.
+    more = 1_000_000 * (2 * D_REST * 4 + MAX_SUBWORDS * 4 + 8 + 4)
+    assert abs(mem["total"] + more - 13_793_810_432) < 64e6, mem
+    assert mem["total"] + more < HBM_BYTES
 
 
 def test_slab_writer_compiles_for_bfloat16(topo):
