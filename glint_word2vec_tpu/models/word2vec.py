@@ -602,11 +602,12 @@ class Word2Vec:
         overrides the 2 GiB default; tables need the rest), and no env
         escape hatch. Frequency subsampling no longer disqualifies —
         the per-epoch compaction pass runs on device
-        (ops/device_batching.subsample_compact) — but it triples the HBM
-        charge: the flat corpus plus the compacted buffer plus the
-        transient prefix sums hold ~12 bytes/word replicated per device,
-        vs ~4 bytes/word without subsampling. Single-process only — the
-        caller checks process count."""
+        (ops/device_batching.subsample_compact) — but it doubles the HBM
+        charge: the flat corpus, the compacted buffer, its per-position
+        record (ops/device_batching.position_sentences) and the transient
+        prefix sums hold ~16 bytes/word replicated per device, vs ~8
+        bytes/word without subsampling (the corpus and its record).
+        Single-process only — the caller checks process count."""
         raw_budget = os.environ.get("GLINT_DEVICE_CORPUS_MAX_BYTES")
         try:
             budget = int(raw_budget) if raw_budget is not None else 2 << 30
@@ -616,7 +617,7 @@ class Word2Vec:
                 "using the 2 GiB default", raw_budget,
             )
             budget = 2 << 30
-        bytes_per_word = 12 if self.params.subsample_ratio > 0 else 4
+        bytes_per_word = 16 if self.params.subsample_ratio > 0 else 8
         return (
             bytes_per_word * corpus_words <= budget
             # upload_corpus indexes the flat corpus with int32; an

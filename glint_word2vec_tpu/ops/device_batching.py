@@ -15,7 +15,8 @@ corpus/batching.py (mllib:381-390): for center position ``i`` draw
 min(i+b, len)) \\ {i}`` — the half-open upper bound inherited from
 Scala's ``until``, hence lanes spanning offsets ``[-(W-1), W-2]`` and
 ``context_width(W) = 2W-3``. Sentence bounds come from ``offsets`` via
-``searchsorted``. Without subsampling the center-position stream is the
+``searchsorted``, or, where the caller holds the view's per-position
+record (:func:`position_sentences`), from a slice of it. Without subsampling the center-position stream is the
 corpus in order — exactly the host batcher's packing — so the device
 path is batch-for-batch identical to the Python path modulo the window
 shrink RNG stream (device threefry vs host PCG64; the host native/C++
@@ -52,6 +53,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from glint_word2vec_tpu.corpus.batching import window_offsets
 
@@ -184,6 +186,39 @@ def subsample_compact(
     return ids_c, offsets_c, n_kept
 
 
+def position_sentences(offsets: jax.Array, n: int) -> jax.Array:
+    """The sentence of every position of a corpus view, ``(n,) int32``:
+    entry ``p`` is ``searchsorted(offsets, p, side="right") - 1``, the
+    index the window functions' search finds, laid down once for the view
+    (a one at every sentence offset, summed along the positions; repeated
+    offsets, sentences subsampling emptied, add up by themselves, and an
+    offset at ``n`` falls off the end). Two positions lie in the same
+    sentence iff their entries are equal, so a step that holds this record
+    reads its span's sentence bounds as a slice
+    (:func:`pack_window_pairs`) where it would search ``offsets`` once a
+    position. The dead tail of a compacted view reads the sentence count,
+    which no live position has."""
+    marks = jnp.zeros(n, jnp.int32).at[offsets].add(
+        1, mode="drop", indices_are_sorted=True
+    )
+    return jnp.cumsum(marks) - 1
+
+
+def _span_slice(arr: jax.Array, first, length: int, fill) -> jax.Array:
+    """``arr[first : first + length]`` for a traced ``first``, with
+    ``fill`` wherever that leaves the array (a span that starts before 0,
+    runs past the end, or is longer than the array): two slices and no
+    gather, the same ops whatever ``first`` is."""
+    n = arr.shape[0]
+    m = min(length, n)
+    at = jnp.clip(first, 0, n - m)
+    pad = jnp.full(length, fill, arr.dtype)
+    padded = jnp.concatenate([pad, lax.dynamic_slice(arr, (at,), (m,)), pad])
+    # dynamic_slice holds the start inside [0, length + m]: a span wholly
+    # outside the array reads one of the pads.
+    return lax.dynamic_slice(padded, (length + (first - at),), (length,))
+
+
 def grid_window_shrink(
     base_key: jax.Array,
     positions: jax.Array,  # (S,) int32 center positions, >= 0
@@ -229,6 +264,7 @@ def pack_window_pairs(
     pair_batch: int,  # P: dense pair slots per step
     grid_batch: int,  # B of the grid scan whose draws are reproduced
     n_valid,  # traced int32 corpus-end bound
+    sent_of=None,  # (N,) int32 position_sentences(offsets, N), if held
 ):
     """Assemble one DENSE (center, context) pair batch on device.
 
@@ -253,35 +289,61 @@ def pack_window_pairs(
     position yields at most C pairs), so the scan always makes progress;
     positions at or past ``n_valid`` contribute zero pairs but are still
     consumed (the epoch tail drains in ``span``-sized strides).
+
+    ``sent_of`` is the view's per-position record
+    (:func:`position_sentences` of the same ``offsets``). With it the
+    span's words and their sentences are SLICES at ``pos`` (the positions
+    of a step are consecutive), a context lane is the slice shifted by the
+    lane's offset, and a context is its centre's iff their sentences are
+    equal: no search, no gather, nothing that depends on what the corpus
+    holds. Without it every position's sentence is searched for in
+    ``offsets`` and its words gathered. The five outputs are bit-equal.
     """
     N = ids.shape[0]
     W = int(window)
     S = int(span)
     P = int(pair_batch)
-    offs = jnp.asarray(window_offsets(W), dtype=jnp.int32)  # (C,) static
+    lanes = window_offsets(W).tolist()  # C static offsets
+    offs = jnp.asarray(lanes, dtype=jnp.int32)
     C = offs.shape[0]
     if P < C:
         raise ValueError(f"pair_batch ({P}) must be >= context lanes ({C})")
 
     positions = pos + jnp.arange(S, dtype=jnp.int32)
     in_corpus = (positions >= 0) & (positions < n_valid)
-    p = jnp.clip(positions, 0, max(N - 1, 0))
-    sent = jnp.searchsorted(offsets, p, side="right") - 1
-    start = offsets[sent]
-    end = offsets[sent + 1]
+    if sent_of is None:
+        p = jnp.clip(positions, 0, max(N - 1, 0))
+        sent = jnp.searchsorted(offsets, p, side="right") - 1
+        start = offsets[sent]
+        end = offsets[sent + 1]
+        cpos = p[:, None] + offs[None, :]
+        in_sentence = (cpos >= start[:, None]) & (cpos < end[:, None])
+        center_ids = ids[p]
+        lane_ids = ids[jnp.clip(cpos, 0, max(N - 1, 0))]
+    else:
+        # The span and the reach of its lanes, [pos + lo, pos + S + hi).
+        lo, hi = min(lanes + [0]), max(lanes + [0])
+        ids_x = _span_slice(ids, pos + lo, S + hi - lo, 0)
+        sent_x = _span_slice(sent_of, pos + lo, S + hi - lo, -1)
+        own = sent_x[-lo:S - lo]
+        shifted = [o - lo for o in lanes]
+        # A sentence index is >= 0 wherever offsets[0] == 0; before a
+        # first offset the search finds no sentence either.
+        in_sentence = (own[:, None] >= 0) & (
+            jnp.stack([sent_x[a:a + S] for a in shifted], axis=1)
+            == own[:, None]
+        )
+        center_ids = ids_x[-lo:S - lo]
+        lane_ids = jnp.stack([ids_x[a:a + S] for a in shifted], axis=1)
     b = grid_window_shrink(base_key, positions, grid_batch, grid_step0, W)
-    cpos = p[:, None] + offs[None, :]
     valid = (
         (offs[None, :] >= -b[:, None])
         & (offs[None, :] <= b[:, None] - 1)
-        & (cpos >= start[:, None])
-        & (cpos < end[:, None])
+        & in_sentence
         & in_corpus[:, None]
     )  # (S, C)
-    centers = jnp.where(in_corpus, ids[p], 0).astype(jnp.int32)
-    contexts = jnp.where(
-        valid, ids[jnp.clip(cpos, 0, max(N - 1, 0))], 0
-    ).astype(jnp.int32)
+    centers = jnp.where(in_corpus, center_ids, 0).astype(jnp.int32)
+    contexts = jnp.where(valid, lane_ids, 0).astype(jnp.int32)
 
     # Whole-position consumption: take the longest span prefix whose
     # cumulative pair count fits in P (cum is non-decreasing, so the
@@ -296,18 +358,26 @@ def pack_window_pairs(
     n_pairs = incl[-1]  # == cum[n_cons - 1] <= P by construction
     dest = incl - take
     scatter_idx = jnp.where(take > 0, dest, P)  # dropped lanes out of range
-    pcenters = (
-        jnp.zeros(P, jnp.int32)
-        .at[scatter_idx]
-        .set(jnp.repeat(centers, C), mode="drop")
-    )
     pcontexts = (
         jnp.zeros(P, jnp.int32)
         .at[scatter_idx]
         .set(contexts.reshape(-1), mode="drop")
     )
-    pmask = (jnp.arange(P, dtype=jnp.int32) < n_pairs).astype(jnp.float32)
-    return pcenters, pcontexts, pmask, n_cons, n_pairs
+    live = jnp.arange(P, dtype=jnp.int32) < n_pairs
+    # A position's pairs are consecutive slots that hold one centre, so the
+    # centres are a step function of the slot: lay each consumed position's
+    # step (its centre less the one before) at its first slot, S adds where
+    # a scatter of every lane's centre would be S x C, and sum along the
+    # slots. A position with no pair shares the next one's first slot and
+    # the steps add up; int32 sums wrap and telescope exactly.
+    step = centers - jnp.concatenate([jnp.zeros(1, jnp.int32), centers[:-1]])
+    first = jnp.where(consumed, cum - v, P)
+    pcenters = jnp.where(
+        live,
+        jnp.cumsum(jnp.zeros(P, jnp.int32).at[first].add(step, mode="drop")),
+        0,
+    )
+    return pcenters, pcontexts, live.astype(jnp.float32), n_cons, n_pairs
 
 
 def center_runs(pcenters: jax.Array, pmask: jax.Array, n_runs: int):

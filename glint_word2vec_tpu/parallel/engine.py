@@ -65,8 +65,9 @@ from glint_word2vec_tpu.utils import (
 )
 from glint_word2vec_tpu.ops import slab_writer
 from glint_word2vec_tpu.ops.sampling import (
-    sample_negatives,
-    sample_negatives_per_row,
+    pack_alias_table,
+    sample_negatives_packed,
+    sample_negatives_per_row_packed,
 )
 from glint_word2vec_tpu.parallel.mesh import (
     DATA_AXIS,
@@ -93,6 +94,16 @@ from glint_word2vec_tpu.parallel.mesh import (
 #: lowers the next program for a layout the buffer does not have:
 #: PERF.md, PR 28.)
 TABLE_LANES = 128
+
+
+def _free(*buffers) -> None:
+    """Give device buffers back now, not at collection; what is no device
+    array (None, a host copy) or is already gone is passed over."""
+    for a in buffers:
+        try:
+            a.delete()
+        except Exception:
+            pass
 
 
 def _host_or_device(a, dtype=None):
@@ -462,12 +473,9 @@ class EmbeddingEngine:
         # identical for every mesh shape (padding never enters sampling),
         # and padded rows can never be drawn as negatives.
         self._counts = np.asarray(counts, dtype=np.int64).copy()
-        table = build_unigram_alias(
+        self._put_alias_table(build_unigram_alias(
             self._counts, power=unigram_power, table_size=unigram_table_size
-        )
-        repl = NamedSharding(mesh, P())
-        self._prob = jax.device_put(table.prob, repl)
-        self._alias = jax.device_put(table.alias, repl)
+        ))
 
         # Initialize tables directly sharded on-device (no host round-trip):
         # syn0 ~ U[-0.5/d, 0.5/d), syn1 = 0 (word2vec standard, ops/sgns.py).
@@ -489,6 +497,19 @@ class EmbeddingEngine:
             jax.random.PRNGKey(seed)
         )
         self._build_jitted_fns()
+
+    def _put_alias_table(self, table) -> None:
+        """Put the noise distribution's alias table on the device,
+        replicated, in both its forms: the plain pair ``_prob (V,)
+        float32`` / ``_alias (V,) int32``, and ``_alias_packed``, an
+        entry's pair kept in one row (ops/sampling.pack_alias_table), which
+        is what the step programs draw from: one look-up a draw."""
+        repl = NamedSharding(self.mesh, P())
+        self._prob = jax.device_put(table.prob, repl)
+        self._alias = jax.device_put(table.alias, repl)
+        self._alias_packed = jax.device_put(
+            pack_alias_table(table.prob, table.alias), repl
+        )
 
     def _configure(
         self, mesh, vocab_size: int, dim: int, *, num_negatives: int,
@@ -583,13 +604,17 @@ class EmbeddingEngine:
         mesh = self.mesh
         Vs = self.rows_per_shard
         n = self.num_negatives
+        # ``noise`` in every step program below is the packed alias table
+        # (_put_alias_table); its rows do not say where their padding
+        # starts, so the programs close over the vocabulary's size.
+        V = self.vocab_size
         tspec = (
             P(MODEL_AXIS, None) if self.layout == "rows"
             else P(None, MODEL_AXIS)
         )
         rep = P()
 
-        def step_body_rows(syn0_l, syn1_l, prob, alias, centers, cmask,
+        def step_body_rows(syn0_l, syn1_l, noise, centers, cmask,
                            contexts, mask, key, alpha, pair_run=None):
             # Data-sharded inputs: centers/cmask (Rl, S), contexts/mask
             # (Bl, C). S = subword-group width; word-level training is the
@@ -647,8 +672,8 @@ class EmbeddingEngine:
                 # and updated by dense MXU matmuls instead of B*C*n sparse
                 # row accesses (ops.sgns.shared_sgns_grads).
                 with jax.named_scope("glint.sample"):
-                    pool = sample_negatives(
-                        key, prob, alias, (self.shared_negatives,)
+                    pool = sample_negatives_packed(
+                        key, noise, V, (self.shared_negatives,)
                     )
                 u_pool = _pull_rows(syn1_l, pool, start, Vs, "syn1")
                 with jax.named_scope("glint.sample"):
@@ -680,8 +705,8 @@ class EmbeddingEngine:
                 # own Bl rows (ops.sampling.sample_negatives_per_row).
                 with jax.named_scope("glint.sample"):
                     rows_g = drank * Bl + jnp.arange(Bl, dtype=jnp.int32)
-                    negs = sample_negatives_per_row(
-                        key, prob, alias, rows_g, (C, n)
+                    negs = sample_negatives_per_row_packed(
+                        key, noise, V, rows_g, (C, n)
                     )
                 u_neg = _pull_rows(
                     syn1_l, negs.reshape(-1), start, Vs, "syn1"
@@ -744,7 +769,7 @@ class EmbeddingEngine:
                 )
             return syn0_l, syn1_l, loss, written
 
-        def step_body_dims(syn0_l, syn1_l, prob, alias, centers, cmask,
+        def step_body_dims(syn0_l, syn1_l, noise, centers, cmask,
                            contexts, mask, key, alpha, pair_run=None):
             # Column-sharded step (CIKM'16 partitioning, SURVEY.md §2.2):
             # tables are (V, dl) local column slices with EVERY row
@@ -779,8 +804,8 @@ class EmbeddingEngine:
 
             if self.shared_negatives:
                 with jax.named_scope("glint.sample"):
-                    pool = sample_negatives(
-                        key, prob, alias, (self.shared_negatives,)
+                    pool = sample_negatives_packed(
+                        key, noise, V, (self.shared_negatives,)
                     )
                 with jax.named_scope("glint.gather"), jax.named_scope("syn1"):
                     u_pool = syn1_l[pool].astype(jnp.float32)  # (S, dl)
@@ -816,8 +841,8 @@ class EmbeddingEngine:
             else:
                 with jax.named_scope("glint.sample"):
                     rows_g = drank * Bl + jnp.arange(Bl, dtype=jnp.int32)
-                    negs = sample_negatives_per_row(
-                        key, prob, alias, rows_g, (C, n)
+                    negs = sample_negatives_per_row_packed(
+                        key, noise, V, rows_g, (C, n)
                     )
                 with jax.named_scope("glint.gather"):
                     with jax.named_scope("syn1"):
@@ -891,7 +916,7 @@ class EmbeddingEngine:
         self._train_step = jax.jit(
             self._shard_map(
                 lambda *a: step_body(*a)[:3],
-                in_specs=(tspec, tspec, rep, rep, P(DATA_AXIS, None),
+                in_specs=(tspec, tspec, rep, P(DATA_AXIS, None),
                           P(DATA_AXIS, None), P(DATA_AXIS, None),
                           P(DATA_AXIS, None), rep, rep),
                 out_specs=(tspec, tspec, rep),
@@ -899,7 +924,7 @@ class EmbeddingEngine:
             donate_argnums=(0, 1),
         )
 
-        def local_train_scan(syn0_l, syn1_l, prob, alias, centers_k, cmask_k,
+        def local_train_scan(syn0_l, syn1_l, noise, centers_k, cmask_k,
                              contexts_k, mask_k, base_key, step0, alphas_k):
             # K stacked minibatches executed by one on-device lax.scan —
             # one dispatch + one host->device transfer per K steps instead
@@ -912,7 +937,7 @@ class EmbeddingEngine:
                 centers, cmask, contexts, mask, i, alpha = xs
                 key = jax.random.fold_in(base_key, step0 + i)
                 s0, s1, loss, _ = step_body(
-                    s0, s1, prob, alias, centers, cmask, contexts, mask,
+                    s0, s1, noise, centers, cmask, contexts, mask,
                     key, alpha,
                 )
                 return (s0, s1), loss
@@ -930,7 +955,7 @@ class EmbeddingEngine:
         self._train_scan = jax.jit(
             self._shard_map(
                 local_train_scan,
-                in_specs=(tspec, tspec, rep, rep,
+                in_specs=(tspec, tspec, rep,
                           P(None, DATA_AXIS, None), P(None, DATA_AXIS, None),
                           P(None, DATA_AXIS, None), P(None, DATA_AXIS, None),
                           rep, rep, rep),
@@ -964,7 +989,7 @@ class EmbeddingEngine:
             # ``G`` > 0 (the subword family): one more replicated
             # argument, the (vocab, G) group table, and each row's centre
             # is its word's group (a row past the corpus end has none).
-            def local_corpus_scan(syn0_l, syn1_l, prob, alias, ids, soffs,
+            def local_corpus_scan(syn0_l, syn1_l, noise, ids, soffs,
                                   n_valid, pstart, base_key, step0,
                                   alphas_k, groups=None):
                 drank = lax.axis_index(DATA_AXIS)
@@ -992,7 +1017,7 @@ class EmbeddingEngine:
                         cmask = jnp.ones((Bl, 1), jnp.float32)
                         grp = centers[:, None]
                     s0, s1, loss, _ = step_body(
-                        s0, s1, prob, alias, grp, cmask,
+                        s0, s1, noise, grp, cmask,
                         contexts, mask, key, alpha,
                     )
                     return (s0, s1), loss
@@ -1008,7 +1033,7 @@ class EmbeddingEngine:
             return jax.jit(
                 self._shard_map(
                     local_corpus_scan,
-                    in_specs=(tspec, tspec) + (rep,) * (10 if G else 9),
+                    in_specs=(tspec, tspec) + (rep,) * (9 if G else 8),
                     out_specs=(tspec, tspec, rep),
                 ),
                 donate_argnums=(0, 1),
@@ -1062,7 +1087,7 @@ class EmbeddingEngine:
             Pl = P // num_data
             R = min(Pl, S + 1)  # runs of one rank's pair list
 
-            def local_packed_scan(syn0_l, syn1_l, prob, alias, ids, soffs,
+            def local_packed_scan(syn0_l, syn1_l, noise, ids, sent_of, soffs,
                                   orig_offs, n_valid, pstart, base_key,
                                   step0, grid_step0, step_size,
                                   inv_total_words, words_base, groups=None):
@@ -1076,6 +1101,7 @@ class EmbeddingEngine:
                             ids, soffs, pos, base_key, grid_step0,
                             window=W, span=S, pair_batch=P,
                             grid_batch=B_grid, n_valid=n_valid,
+                            sent_of=sent_of,
                         )
                         pos_end = pos + n_cons
                         done = device_words_done(
@@ -1107,7 +1133,7 @@ class EmbeddingEngine:
                     else:
                         grp, pair_run = c_l[:, None], None
                     s0, s1, loss, written = step_body(
-                        s0, s1, prob, alias, grp, cmask,
+                        s0, s1, noise, grp, cmask,
                         x_l[:, None], m_l[:, None], key, alpha,
                         pair_run=pair_run,
                     )
@@ -1400,7 +1426,7 @@ class EmbeddingEngine:
         self._ckpt_forced_sync = 0
         # Pre-dispatched next-epoch subsample-compact pass (ISSUE 5
         # prefetch overlap): (epoch_key host copy, ids_c, offsets_c,
-        # n_kept) awaiting adoption by compact_corpus.
+        # n_kept, sent_c) awaiting adoption by compact_corpus.
         self._compact_prefetch = None
         # Touched-row replica-exchange telemetry (ISSUE 15,
         # parallel/exchange.py): per-engine counters surfaced on the
@@ -1518,7 +1544,7 @@ class EmbeddingEngine:
                 f"batch size {B} not divisible by data axis {self.num_data}"
             )
         self.syn0, self.syn1, loss = self._train_step(
-            self.syn0, self.syn1, self._prob, self._alias,
+            self.syn0, self.syn1, self._alias_packed,
             cg, gm, cx, mk, key, jnp.float32(alpha),
         )
         self._tick_tables("train_step")
@@ -1586,7 +1612,7 @@ class EmbeddingEngine:
                 f"batch size {B} not divisible by data axis {self.num_data}"
             )
         self.syn0, self.syn1, losses = self._train_scan(
-            self.syn0, self.syn1, self._prob, self._alias,
+            self.syn0, self.syn1, self._alias_packed,
             cg, gm, cx, mk,
             base_key, jnp.uint32(step0),
             jnp.asarray(alphas, dtype=jnp.float32),
@@ -1605,8 +1631,9 @@ class EmbeddingEngine:
         :meth:`train_steps_corpus` dispatches assemble minibatches
         entirely on device (ops/device_batching) — per-dispatch
         host->device traffic drops to scalars. ~4 bytes/word of HBM
-        replicated per device (~12 with the subsampled path's compacted
-        buffers, see :meth:`compact_corpus`).
+        replicated per device, ~8 once a packed dispatch has laid down
+        the view's per-position record (~16 with the subsampled path's
+        compacted buffers, see :meth:`compact_corpus`).
 
         ``n_valid`` bounds the live center positions to a PREFIX of the
         buffer: positions at or past it never train (they become
@@ -1627,13 +1654,25 @@ class EmbeddingEngine:
             raise ValueError(
                 f"n_valid ({n_valid}) must be in [0, len(ids)={n}]"
             )
+        # Replicated on the mesh and committed there, like the alias
+        # table: what the compaction pass makes of them then lies on every
+        # device too, and a dispatch hands the scans views they already
+        # hold. (Left on one device, uncommitted, each dispatch of a mesh
+        # program copied the whole view to every other device again.)
+        repl = NamedSharding(self.mesh, P())
         self._corpus = (
-            jnp.asarray(ids, dtype=jnp.int32),
-            jnp.asarray(offsets, dtype=jnp.int32),
+            jax.device_put(np.asarray(ids, dtype=np.int32), repl),
+            jax.device_put(np.asarray(offsets, dtype=np.int32), repl),
         )
         self._corpus_n_valid = int(n_valid)
         self._corpus_compacted = None
         self._n_kept = None
+        # The views' per-position records (_position_sentences): the
+        # uploaded view's is laid down by the first packed dispatch over
+        # it (a fit that compacts every epoch never reads it), a compacted
+        # view's with the compaction pass.
+        self._corpus_sent = None
+        self._compacted_sent = None
 
     def upload_center_groups(self, groups: Optional[np.ndarray]) -> None:
         """Put the subword family's group table on the device, replicated,
@@ -1682,7 +1721,7 @@ class EmbeddingEngine:
                 f"keep_prob must have shape ({self.vocab_size},), "
                 f"got {kp.shape}"
             )
-        self._keep_prob = jnp.asarray(kp)
+        self._keep_prob = jax.device_put(kp, NamedSharding(self.mesh, P()))
 
     def compact_corpus(self, epoch_key) -> int:
         """Run one epoch's on-device subsample-and-compact pass
@@ -1709,15 +1748,9 @@ class EmbeddingEngine:
                 "view is unsupported (subsample host-side when filling "
                 "the buffer)"
             )
-        old = self._corpus_compacted
-        self._corpus_compacted = None
+        _free(*(self._corpus_compacted or ()), self._compacted_sent)
+        self._corpus_compacted = self._compacted_sent = None
         self._compacted_offsets_host = None
-        if old is not None:
-            for a in old:
-                try:
-                    a.delete()
-                except Exception:
-                    pass
         pre, self._compact_prefetch = self._compact_prefetch, None
         if pre is not None and np.array_equal(
             pre[0], np.asarray(epoch_key)
@@ -1726,24 +1759,37 @@ class EmbeddingEngine:
             # previous epoch's tail group was still executing: same jitted
             # function, same key — bitwise-identical buffers, already (or
             # still becoming) computed on device.
-            ids_c, offsets_c, n_kept = pre[1], pre[2], pre[3]
+            ids_c, offsets_c, n_kept, sent_c = pre[1:]
         else:
-            if pre is not None:
-                # Prefetched for a different key (e.g. an out-of-order
-                # resume): discard, recompute fresh.
-                for a in pre[1:3]:
-                    try:
-                        a.delete()
-                    except Exception:
-                        pass
-            ids_c, offsets_c, n_kept = self._compact_dispatch(epoch_key)
+            # Prefetched for a different key (e.g. an out-of-order
+            # resume): discard, recompute fresh.
+            _free(*(pre or ()))
+            ids_c, offsets_c, n_kept, sent_c = self._compact_dispatch(
+                epoch_key
+            )
         self._corpus_compacted = (ids_c, offsets_c)
+        self._compacted_sent = sent_c
         self._n_kept = int(n_kept)
         return self._n_kept
 
+    def _position_sentences(self, offsets, n: int):
+        """Dispatch a view's per-position record
+        (ops/device_batching.position_sentences): the sentence of each of
+        its ``n`` positions, made on every device of the mesh, where the
+        view's offsets lie."""
+        if not hasattr(self, "_sentences_fn"):
+            from glint_word2vec_tpu.ops.device_batching import (
+                position_sentences,
+            )
+
+            self._sentences_fn = jax.jit(position_sentences, static_argnums=1)
+        return self._sentences_fn(offsets, n)
+
     def _compact_dispatch(self, epoch_key):
         """Dispatch (without blocking) one subsample-compact pass over
-        the uploaded flat corpus; returns the lazy device triple."""
+        the uploaded flat corpus and the compacted view's per-position
+        record; returns the lazy device ``(ids_c, offsets_c, n_kept,
+        sent_c)``."""
         if not hasattr(self, "_compact_fn"):
             from glint_word2vec_tpu.ops.device_batching import (
                 subsample_compact,
@@ -1751,7 +1797,12 @@ class EmbeddingEngine:
 
             self._compact_fn = jax.jit(subsample_compact)
         ids, offsets = self._corpus
-        return self._compact_fn(ids, offsets, self._keep_prob, epoch_key)
+        ids_c, offsets_c, n_kept = self._compact_fn(
+            ids, offsets, self._keep_prob, epoch_key
+        )
+        return ids_c, offsets_c, n_kept, self._position_sentences(
+            offsets_c, ids.shape[0]
+        )
 
     def prefetch_compact_corpus(self, epoch_key) -> None:
         """Dispatch the NEXT epoch's subsample-compact pass into fresh
@@ -1769,16 +1820,10 @@ class EmbeddingEngine:
             raise ValueError(
                 "no keep probabilities installed (call set_keep_probs first)"
             )
-        old, self._compact_prefetch = self._compact_prefetch, None
-        if old is not None:
-            for a in old[1:3]:
-                try:
-                    a.delete()
-                except Exception:
-                    pass
-        key_h = np.asarray(epoch_key)
-        ids_c, offsets_c, n_kept = self._compact_dispatch(epoch_key)
-        self._compact_prefetch = (key_h, ids_c, offsets_c, n_kept)
+        _free(*(self._compact_prefetch or ()))
+        self._compact_prefetch = (
+            np.asarray(epoch_key), *self._compact_dispatch(epoch_key)
+        )
 
     def compacted_offsets(self) -> np.ndarray:
         """Host copy of the active epoch's compacted sentence offsets —
@@ -1807,7 +1852,7 @@ class EmbeddingEngine:
             str(self._dtype), str(self._compute_dtype),
             self.num_negatives, self.shared_negatives,
             self.rows_per_shard, self.cols_per_shard,
-            self.padded_vocab, self.padded_dim,
+            self.padded_vocab, self.padded_dim, self.vocab_size,
             *shape_key,
         )
 
@@ -1863,7 +1908,7 @@ class EmbeddingEngine:
             ids, soffs = self._corpus
             n_valid = getattr(self, "_corpus_n_valid", ids.shape[0])
         self.syn0, self.syn1, losses = fn(
-            self.syn0, self.syn1, self._prob, self._alias, ids, soffs,
+            self.syn0, self.syn1, self._alias_packed, ids, soffs,
             jnp.int32(n_valid), jnp.int32(start_position), base_key,
             jnp.uint32(step0), jnp.asarray(alphas, dtype=jnp.float32),
             *((self._center_groups,) if G else ()),
@@ -1949,12 +1994,17 @@ class EmbeddingEngine:
             self._packed_scan_cache[(P, W, B, S, K, G)] = fn
         if getattr(self, "_corpus_compacted", None) is not None:
             ids, soffs = self._corpus_compacted
-            n_valid = self._n_kept
+            sent_of, n_valid = self._compacted_sent, self._n_kept
         else:
             ids, soffs = self._corpus
+            if self._corpus_sent is None:
+                self._corpus_sent = self._position_sentences(
+                    soffs, ids.shape[0]
+                )
+            sent_of = self._corpus_sent
             n_valid = getattr(self, "_corpus_n_valid", ids.shape[0])
         self.syn0, self.syn1, *per_step = fn(
-            self.syn0, self.syn1, self._prob, self._alias, ids, soffs,
+            self.syn0, self.syn1, self._alias_packed, ids, sent_of, soffs,
             self._corpus[1], jnp.int32(n_valid),
             jnp.int32(start_position), base_key, jnp.uint32(step0),
             jnp.uint32(grid_step0), jnp.float32(step_size),
@@ -2394,9 +2444,7 @@ class EmbeddingEngine:
             c, power=self.unigram_power, table_size=self.unigram_table_size
         )
         self._counts = c.copy()
-        repl = NamedSharding(self.mesh, P())
-        self._prob = jax.device_put(jnp.asarray(table.prob), repl)
-        self._alias = jax.device_put(jnp.asarray(table.alias), repl)
+        self._put_alias_table(table)
         obs_events.emit(
             # graftlint: ignore[sync-point] c is the host counts array
             "noise_counts_updated", train_words=int(c.sum()),
@@ -3741,24 +3789,21 @@ class EmbeddingEngine:
         Drains any in-flight async save first (its snapshot copies are
         separate buffers, but a half-written checkpoint helps nobody)."""
         self.wait_pending_saves(reraise=False)
-        corpus = getattr(self, "_corpus", None) or ()
-        compacted = getattr(self, "_corpus_compacted", None) or ()
-        keep_prob = getattr(self, "_keep_prob", None)
-        extras = (keep_prob,) if keep_prob is not None else ()
-        pre = getattr(self, "_compact_prefetch", None)
-        prefetched = pre[1:3] if pre is not None else ()
-        self._compact_prefetch = None
-        for a in (
+        _free(
             self.syn0, self.syn1, self._prob, self._alias,
-            *corpus, *compacted, *extras, *prefetched,
-        ):
-            try:
-                a.delete()
-            except Exception:
-                pass
+            self._alias_packed, getattr(self, "_keep_prob", None),
+            *(getattr(self, "_corpus", None) or ()),
+            *(getattr(self, "_corpus_compacted", None) or ()),
+            getattr(self, "_corpus_sent", None),
+            getattr(self, "_compacted_sent", None),
+            *(getattr(self, "_compact_prefetch", None) or ()),
+        )
+        self._compact_prefetch = None
         self.syn0 = self.syn1 = self._prob = self._alias = None
+        self._alias_packed = None
         self._corpus = None
         self._corpus_compacted = None
+        self._corpus_sent = self._compacted_sent = None
         self._keep_prob = None
         self._ann = None
         self._tick_tables("destroy")

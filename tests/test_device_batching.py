@@ -487,31 +487,31 @@ def test_device_corpus_routing_respects_hbm_budget(monkeypatch):
 
     m = Word2Vec(subsample_ratio=0.0)
     assert m._device_corpus_eligible(1000)
-    assert not m._device_corpus_eligible((2 << 30) // 4 + 1)
-    monkeypatch.setenv("GLINT_DEVICE_CORPUS_MAX_BYTES", "4000")
+    assert not m._device_corpus_eligible((2 << 30) // 8 + 1)
+    monkeypatch.setenv("GLINT_DEVICE_CORPUS_MAX_BYTES", "8000")
     assert m._device_corpus_eligible(1000)
     assert not m._device_corpus_eligible(1001)
 
 
 def test_device_corpus_budget_charges_subsampled_path(monkeypatch):
     """With subsampling the path holds the flat corpus + the compacted
-    buffer + the transient prefix sums (~12 bytes/word, not 4): the
-    budget check must charge accordingly, including under the env
-    override."""
+    buffer + its per-position record + the transient prefix sums (~16
+    bytes/word, not 8): the budget check must charge accordingly,
+    including under the env override."""
     from glint_word2vec_tpu.models.word2vec import Word2Vec
 
     sub = Word2Vec(subsample_ratio=1e-3)
     flat = Word2Vec(subsample_ratio=0.0)
-    edge = (2 << 30) // 12  # largest subsampled-eligible corpus
+    edge = (2 << 30) // 16  # largest subsampled-eligible corpus
     assert sub._device_corpus_eligible(edge)
     assert not sub._device_corpus_eligible(edge + 1)
-    # The same corpus stays eligible without subsampling (4 bytes/word).
+    # The same corpus stays eligible without subsampling (8 bytes/word).
     assert flat._device_corpus_eligible(edge + 1)
-    monkeypatch.setenv("GLINT_DEVICE_CORPUS_MAX_BYTES", "1200")
+    monkeypatch.setenv("GLINT_DEVICE_CORPUS_MAX_BYTES", "1600")
     assert sub._device_corpus_eligible(100)
     assert not sub._device_corpus_eligible(101)
-    assert flat._device_corpus_eligible(300)
-    assert not flat._device_corpus_eligible(301)
+    assert flat._device_corpus_eligible(200)
+    assert not flat._device_corpus_eligible(201)
 
 
 def test_device_corpus_budget_malformed_env_warns(monkeypatch, caplog):

@@ -167,7 +167,7 @@ def test_dims_model_axis_traffic_is_scalar_logits():
     )
     centers, contexts, mask = _batch(B=B, C=C)
     lowered = eng._train_step.lower(
-        eng.syn0, eng.syn1, eng._prob, eng._alias,
+        eng.syn0, eng.syn1, eng._alias_packed,
         jnp.asarray(centers[:, None]),
         jnp.ones((B, 1), jnp.float32),
         jnp.asarray(contexts), jnp.asarray(mask),
@@ -209,7 +209,7 @@ def test_dims_data_axis_exchange_ships_scalars_not_payloads():
     )
     centers, contexts, mask = _batch(B=B, C=C)
     lowered = eng._train_step.lower(
-        eng.syn0, eng.syn1, eng._prob, eng._alias,
+        eng.syn0, eng.syn1, eng._alias_packed,
         jnp.asarray(centers[:, None]), jnp.ones((B, 1), jnp.float32),
         jnp.asarray(contexts), jnp.asarray(mask),
         jax.random.PRNGKey(0), jnp.float32(0.05),

@@ -385,7 +385,7 @@ def test_data_axis_exchange_ships_scalars_not_payloads():
     cg = jnp.asarray(centers[:, None])
     gm = jnp.ones((B, 1), jnp.float32)
     lowered = eng._train_step.lower(
-        eng.syn0, eng.syn1, eng._prob, eng._alias,
+        eng.syn0, eng.syn1, eng._alias_packed,
         cg, gm, jnp.asarray(contexts), jnp.asarray(mask),
         jax.random.PRNGKey(0), jnp.float32(0.05),
     )

@@ -175,9 +175,9 @@ def all_reduces(shape, layout="rows"):
     offs = sds((61,), jnp.int32)
     i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
     text = fn.lower(
-        table, table, sds((V,), jnp.float32), sds((V,), jnp.int32),
-        sds((900,), jnp.int32), offs, offs, i32, i32, sds((2,), jnp.uint32),
-        u32, u32, f32, f32, f32).compile().as_text()
+        table, table, sds((-(-V // 64), 128), jnp.int32),
+        sds((900,), jnp.int32), sds((900,), jnp.int32), offs, offs, i32, i32,
+        sds((2,), jnp.uint32), u32, u32, f32, f32, f32).compile().as_text()
     found = []
     for line in text.splitlines():
         m = re.search(r"= (.*?) all-reduce(?:-start)?\(", line)

@@ -289,13 +289,16 @@ def test_the_grid_scan_forms_its_centres_from_the_group_table():
     assert np.abs(prog0[V:] - init0[V:]).max() > 0
 
 
-# sha256[:16] of the lowered word-level scans' StableHLO text on commit
-# 7dbd80a (the parent of ISSUE 31), by mesh and layout: (packed, grid).
+# sha256[:16] of the lowered word-level scans' StableHLO text, by mesh and
+# layout: (packed, grid). Taken on the tree of ISSUE 33, which meant to
+# change the word-level programs (the batch read from the view's
+# per-position record, the negatives from the packed alias table); between
+# ISSUE 31's parent (7dbd80a) and that tree they had not moved.
 WORD_LEVEL_PROGRAMS = {
-    ((1, 1), "rows"): ("511fee5ba7137dc1", "94a88fa0013be618"),
-    ((1, 2), "rows"): ("436a6c9f5a5a9908", "459bc90236d4ca52"),
-    ((2, 2), "rows"): ("6254485208eb2e61", "bd9be488603d227f"),
-    ((1, 2), "dims"): ("ec645d661fb1c111", "37e6ed10667f9621"),
+    ((1, 1), "rows"): ("c25535e8d88b2523", "06febc13e39fd956"),
+    ((1, 2), "rows"): ("adb59586a26fae1c", "92266292fedc1225"),
+    ((2, 2), "rows"): ("32ecc4bfc99c0ddd", "31970820a451766a"),
+    ((1, 2), "dims"): ("20f87872e52f75cf", "82a09710cd4cecac"),
 }
 
 
@@ -310,16 +313,16 @@ def lowered(eng, groups_width=0):
     table = sds(eng.syn0.shape, jnp.float32, *eng.syn0.sharding.spec)
     offs = sds((61,), jnp.int32)
     i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
-    head = (table, table, sds((V,), jnp.float32), sds((V,), jnp.int32),
-            sds((900,), jnp.int32), offs)
+    words = sds((900,), jnp.int32)
+    head = (table, table, sds((-(-V // 64), 128), jnp.int32), words)
     extra = (sds((V, groups_width), jnp.int32),) if groups_width else ()
     packed = eng._make_packed_corpus_scan(
         pairs, WINDOW, BATCH, span, K, groups_width).lower(
-            *head, offs, i32, i32, sds((2,), jnp.uint32), u32, u32, f32, f32,
-            f32, *extra)
+            *head, words, offs, offs, i32, i32, sds((2,), jnp.uint32), u32,
+            u32, f32, f32, f32, *extra)
     grid = eng._make_corpus_scan(BATCH, WINDOW, groups_width).lower(
-        *head, i32, i32, sds((2,), jnp.uint32), u32, sds((K,), jnp.float32),
-        *extra)
+        *head, offs, i32, i32, sds((2,), jnp.uint32), u32,
+        sds((K,), jnp.float32), *extra)
     return packed, grid
 
 

@@ -199,8 +199,9 @@ def _compile_packed_scan(eng, words=CORPUS_WORDS, sentences=CORPUS_SENTENCES,
     offs = sds((sentences + 1,), jnp.int32)
     i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
     return fn.lower(
-        table, table, sds((vocab,), jnp.float32), sds((vocab,), jnp.int32),
-        sds((words,), jnp.int32), offs, offs, i32, i32,
+        table, table, sds((-(-vocab // 64), 128), jnp.int32),
+        sds((words,), jnp.int32), sds((words,), jnp.int32), offs, offs,
+        i32, i32,
         sds((2,), jnp.uint32), u32, u32, f32, f32, f32, *groups,
     ).compile()
 
@@ -285,6 +286,36 @@ def test_packed_corpus_scan_at_three_million_rows_fits_one_chip(packed_scans):
     mem = _fits(compiled)
     assert mem["temp"] < 1.5e9, mem
     assert not _whole_table_copies(compiled, eng)
+
+
+@pytest.mark.parametrize("name", ["2m-1chip", "10m-4chips"])
+def test_packed_corpus_scan_draws_its_batch_without_a_branch(packed_scans,
+                                                            name):
+    # ISSUE 33: under glint.batch the chip's program chooses nothing at run
+    # time and searches nothing a position: no conditional, no loop that
+    # carries a span-wide operand (the one loop left is words_done's binary
+    # search over a scalar), no gather a candidate position or a context
+    # lane (the span's words and sentences are slices of the view and of
+    # its per-position record).
+    import re
+
+    from glint_word2vec_tpu.corpus.batching import (
+        context_width,
+        packed_pair_batch,
+    )
+
+    _, compiled = packed_scans(name)
+    span = -(-3 * packed_pair_batch(BATCH, WINDOW, 1)
+             // context_width(WINDOW))
+    lanes = span * context_width(WINDOW)
+    batch = [line for line in compiled.as_text().splitlines()
+             if "glint.batch" in line]
+    assert len(batch) > 20  # the scope reached the compiled program
+    wide = re.compile(rf"\[(?:{span}|{lanes})[,\]]")
+    for line in batch:
+        assert " conditional(" not in line, line[:300]
+        if " while(" in line or " gather(" in line:
+            assert not wide.search(line.split("metadata=")[0]), line[:300]
 
 
 @pytest.mark.parametrize("name", ["2m-1chip", "10m-4chips"])

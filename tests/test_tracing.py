@@ -899,8 +899,9 @@ def _lowered_packed_scan(shared_negatives, layout):
         i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32,
                                               jnp.float32))
         text = eng._make_packed_corpus_scan(32, 2, 16, 24, 2).lower(
-            table, table, sds(eng._prob.shape, eng._prob.dtype),
-            sds(eng._alias.shape, eng._alias.dtype), sds((100,), jnp.int32),
+            table, table,
+            sds(eng._alias_packed.shape, eng._alias_packed.dtype),
+            sds((100,), jnp.int32), sds((100,), jnp.int32),
             offs, offs, i32, i32, sds((2,), jnp.uint32), u32, u32, f32, f32,
             f32,
         ).as_text(debug_info=True)
