@@ -53,6 +53,12 @@ def _add_train(sub):
                         "embedding dims (CIKM column sharding)")
     p.add_argument("--steps-per-call", type=int, default=16,
                    help="minibatches per device dispatch (on-device scan)")
+    p.add_argument("--architecture", choices=["skipgram", "cbow"],
+                   default="skipgram",
+                   help="the model: skip-gram (default) or CBOW with "
+                        "negative sampling (word2vec's -cbow 1: the mean "
+                        "of a position's context rows predicts its word; "
+                        "corpus-resident path only, saved with the model)")
     p.add_argument("--shared-negatives", type=int, default=0,
                    help="shared noise-pool size per step "
                         "(0 = per-pair reference semantics)")
@@ -1425,6 +1431,7 @@ def _run(args) -> int:
             compute_dtype=args.compute_dtype,
             layout=args.layout,
             steps_per_call=args.steps_per_call,
+            architecture=args.architecture,
             shared_negatives=args.shared_negatives,
             batch_packing=args.packing,
             exchange=args.exchange,

@@ -20,6 +20,11 @@ Parameter mapping (camelCase as in ml_glintword2vec.py:101-170):
       host raises.
   inputCol / outputCol -> stored for API compatibility; this layer takes
       tokenized sentences directly instead of DataFrames.
+  (no reference parameter) <- Word2VecParams.architecture: the reference
+      is skip-gram only (its servers' dotprod/adjust are the skip-gram
+      pair update), so this surface has no such parameter and always fits
+      a skip-gram; CBOW is reached through Word2Vec(architecture="cbow")
+      or `cli train --architecture cbow`.
 
 Documented behavioral divergences (see README "Faithfulness"):
   * subsampleRatio defaults to 0.0 here. The reference declares 1e-6 but

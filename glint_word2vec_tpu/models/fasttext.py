@@ -43,6 +43,11 @@ class FastTextParams(Word2VecParams):
         _require(0 < self.min_n <= self.max_n, "need 0 < min_n <= max_n")
         _require(self.bucket > 0, "bucket must be > 0")
         _require(self.max_subwords >= 2, "max_subwords must be >= 2")
+        _require(
+            self.architecture == "skipgram",
+            "the subword family trains skip-gram only: a CBOW bag over "
+            "subword groups is not supported",
+        )
 
 
 class FastTextWord2Vec(Word2Vec):

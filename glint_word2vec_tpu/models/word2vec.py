@@ -306,6 +306,10 @@ class Word2Vec:
         semantics; see Word2VecParams.shared_negatives)."""
         return self._set(shared_negatives=v)
 
+    def set_architecture(self, v: str) -> "Word2Vec":
+        """"skipgram" (default) or "cbow" (see Word2VecParams)."""
+        return self._set(architecture=v)
+
     def set_batch_packing(self, v: str) -> "Word2Vec":
         """Device-corpus dispatch shape: "dense" (the default — valid
         (center, context) pairs prefix-sum-compacted into dense
@@ -448,6 +452,7 @@ class Word2Vec:
                 vocab, ids, offsets, checkpoint_dir,
                 checkpoint_every_epochs, stop_after_epochs,
             )
+        self._skipgram_for_host_batcher()
         if pc > 1:
             from glint_word2vec_tpu.parallel import distributed as dist
 
@@ -541,6 +546,7 @@ class Word2Vec:
                 vocab, ids, offsets, checkpoint_dir,
                 checkpoint_every_epochs, stop_after_epochs,
             )
+        self._skipgram_for_host_batcher()
         if pc > 1:
             from glint_word2vec_tpu.parallel import distributed as dist
 
@@ -593,6 +599,20 @@ class Word2Vec:
             vocab, ids, offsets, checkpoint_dir,
             checkpoint_every_epochs, stop_after_epochs,
         )
+
+    def _skipgram_for_host_batcher(self) -> None:
+        """A fit that the corpus-resident path does not take goes to the
+        host batcher, which builds skip-gram batches: a CBOW fit is
+        refused there, not trained as something else or somewhere
+        slower."""
+        if self.params.architecture != "skipgram":
+            raise ValueError(
+                f"architecture={self.params.architecture!r} trains on the "
+                "corpus-resident path only, which this fit does not take "
+                "(one process; the corpus within GLINT_DEVICE_CORPUS_MAX_"
+                "BYTES, 2 GiB by default; GLINT_HOST_BATCHER unset): "
+                "there is no host-batcher CBOW"
+            )
 
     def _device_corpus_eligible(self, corpus_words: int = 0) -> bool:
         """Whether the device-resident corpus path applies (to every
@@ -701,7 +721,10 @@ class Word2Vec:
             # while spending ~zero dispatched lanes on masked padding
             # (each step is ~density x the grid step's FLOPs).
             packed = p.batch_packing == "dense"
-            pair_batch = packed_pair_batch(
+            cbow = p.architecture == "cbow"
+            # A CBOW step trains B positions, each with its bag: its
+            # batch rows are positions and its advance is the static B.
+            pair_batch = B if cbow else packed_pair_batch(
                 B, p.window, mesh.shape["data"]
             )
             resume_position = 0
@@ -721,7 +744,8 @@ class Word2Vec:
             )
             packed_groups = packed_pairs = packed_slots = 0
             # distinct rows written (syn0, syn1), slabs moved (syn0, syn1);
-            # a subword fit also: live group ids gathered, centres formed
+            # a subword fit also: live group ids gathered, centres formed;
+            # a CBOW fit: live bag slots, positions trained
             rows_written = np.zeros(6, np.int64)
             early_stop = False
 
@@ -1238,13 +1262,25 @@ class Word2Vec:
             # Packed fill = live pairs / dispatched pair slots — the
             # effective mask density of the packed dispatches (the grid
             # path runs ~0.43 at window 5; the CI smoke job gates >= 0.9).
+            # A CBOW step's slots are positions: positions trained over
+            # position slots (packed_pairs still counts the live bag
+            # slots, the rows the bags gathered).
             model.training_metrics.update(
                 packed_pairs=packed_pairs,
-                packed_mask_density=round(packed_pairs / packed_slots, 4),
+                packed_mask_density=round(
+                    (rows_written[5] if cbow else packed_pairs)
+                    / packed_slots, 4),
                 exchange_bytes_per_step=engine.packed_exchange_bytes(
                     pair_batch, p.window),
             )
-            if rows_written[5]:
+            if cbow and rows_written[5]:
+                # Rows a bag is the mean of: live bag slots over the
+                # positions that trained.
+                model.training_metrics.update(
+                    cbow_rows_per_bag=round(
+                        rows_written[4] / rows_written[5], 4),
+                )
+            elif rows_written[5]:
                 # Rows a subword centre is the mean of: live group ids the
                 # packed steps gathered over the centres they formed.
                 model.training_metrics.update(
@@ -1686,6 +1722,7 @@ class Word2Vec:
             shared_negatives=p.shared_negatives,
             compute_dtype=p.compute_dtype,
             layout=p.layout,
+            architecture=p.architecture,
         )
 
     def _train_batches(self, engine, group: BatchGroup, base_key, step0,

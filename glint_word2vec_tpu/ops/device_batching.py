@@ -46,6 +46,12 @@ span, the valid (center, context) pairs are prefix-sum scatter-compacted
 into a fixed-shape dense pair list, and the step runs the rank-1 SGNS
 update over pairs — effective mask density ~0.43 -> >=0.95 on the corpus
 path at the same dispatched step cost.
+
+CBOW (``Word2VecParams.architecture``) takes the same view a POSITION at
+a time: :func:`bag_window_batch` forms, for B consecutive positions, each
+position's bag of context words (word2vec's own window, ``2 * window``
+lanes) from the same slices and the same shrink draws; nothing is
+compacted, and a step advances by the static B.
 """
 
 from __future__ import annotations
@@ -219,6 +225,29 @@ def _span_slice(arr: jax.Array, first, length: int, fill) -> jax.Array:
     return lax.dynamic_slice(padded, (length + (first - at),), (length,))
 
 
+def _span_lanes(ids, sent_of, pos, length: int, lanes: list):
+    """The ``length`` positions from ``pos`` and their context lanes, read
+    from the view and its per-position record as slices: ``(words
+    (length,), lane words (length, len(lanes)), in_sentence)``, lane k of
+    position t the word at ``t + lanes[k]`` and in its sentence iff the two
+    records are equal. Nothing is searched or gathered."""
+    # The span and the reach of its lanes, [pos + lo, pos + length + hi).
+    lo, hi = min(lanes + [0]), max(lanes + [0])
+    ids_x = _span_slice(ids, pos + lo, length + hi - lo, 0)
+    sent_x = _span_slice(sent_of, pos + lo, length + hi - lo, -1)
+    own = sent_x[-lo:length - lo]
+    shifted = [o - lo for o in lanes]
+    # A sentence index is >= 0 wherever offsets[0] == 0; before a first
+    # offset the search finds no sentence either.
+    in_sentence = (own[:, None] >= 0) & (
+        jnp.stack([sent_x[a:a + length] for a in shifted], axis=1)
+        == own[:, None]
+    )
+    words = ids_x[-lo:length - lo]
+    lane_words = jnp.stack([ids_x[a:a + length] for a in shifted], axis=1)
+    return words, lane_words, in_sentence
+
+
 def grid_window_shrink(
     base_key: jax.Array,
     positions: jax.Array,  # (S,) int32 center positions, >= 0
@@ -321,20 +350,9 @@ def pack_window_pairs(
         center_ids = ids[p]
         lane_ids = ids[jnp.clip(cpos, 0, max(N - 1, 0))]
     else:
-        # The span and the reach of its lanes, [pos + lo, pos + S + hi).
-        lo, hi = min(lanes + [0]), max(lanes + [0])
-        ids_x = _span_slice(ids, pos + lo, S + hi - lo, 0)
-        sent_x = _span_slice(sent_of, pos + lo, S + hi - lo, -1)
-        own = sent_x[-lo:S - lo]
-        shifted = [o - lo for o in lanes]
-        # A sentence index is >= 0 wherever offsets[0] == 0; before a
-        # first offset the search finds no sentence either.
-        in_sentence = (own[:, None] >= 0) & (
-            jnp.stack([sent_x[a:a + S] for a in shifted], axis=1)
-            == own[:, None]
+        center_ids, lane_ids, in_sentence = _span_lanes(
+            ids, sent_of, pos, S, lanes
         )
-        center_ids = ids_x[-lo:S - lo]
-        lane_ids = jnp.stack([ids_x[a:a + S] for a in shifted], axis=1)
     b = grid_window_shrink(base_key, positions, grid_batch, grid_step0, W)
     valid = (
         (offs[None, :] >= -b[:, None])
@@ -378,6 +396,70 @@ def pack_window_pairs(
         0,
     )
     return pcenters, pcontexts, live.astype(jnp.float32), n_cons, n_pairs
+
+
+def bag_lanes(window: int) -> list:
+    """The context offsets of a CBOW bag, ``2 * window`` of them: every
+    position within ``window`` of the centre, either side, the centre left
+    out (``word2vec.c``'s ``a != window``). Not the skip-gram lanes
+    (``corpus.batching.window_offsets``, the reference's half-open
+    ``[-b, b)``): the bag is word2vec's own window."""
+    return [o for o in range(-window, window + 1) if o]
+
+
+def bag_window_batch(
+    ids: jax.Array,  # (N,) int32 flat corpus (active view)
+    sent_of: jax.Array,  # (N,) int32 position_sentences of the view
+    pos,  # traced int32 scalar: the batch's first centre position
+    base_key: jax.Array,
+    grid_step0,  # traced uint32 (see grid_window_shrink)
+    *,
+    window: int,
+    batch: int,  # B: consecutive centre positions of the batch
+    grid_batch: int,  # B of the grid scan whose draws are reproduced
+    n_valid,  # traced int32 corpus-end bound
+):
+    """Assemble the CBOW bags of ``batch`` consecutive positions on device.
+
+    Position ``t`` of ``[pos, pos + batch)`` draws its shrink ``b`` in
+    ``[0, window)`` and its bag is every position within ``window - b`` of
+    ``t`` in ``t``'s sentence, ``t`` left out: ``word2vec.c``'s loop
+    ``for (a = b; a < window * 2 + 1 - b; a++) if (a != window)``. The draw
+    is :func:`grid_window_shrink`'s for that position, the one the
+    skip-gram scans make under the same key schedule, so a bag is a
+    function of the view, the key and the position alone, on every mesh.
+
+    As :func:`pack_window_pairs` with the view's record: the span's words
+    and sentences are two SLICES at ``pos`` (:func:`_span_slice`), a lane
+    is the slice shifted by its offset, and a lane is in the bag iff its
+    sentence equals the centre's and its offset is within reach. No
+    search, no gather, no compaction: the batch is position-major and the
+    advance a step is the static ``batch``.
+
+    Returns ``(centres (B,), bags (B, 2 * window), mask (B, 2 * window),
+    live (B,))``: each position's word, its bag's words with -1 (a row no
+    table has) wherever ``mask`` is 0, and which positions train: those
+    inside the corpus whose bag is not empty (``word2vec.c`` skips a
+    position with ``cw == 0``).
+    """
+    W, B = window, batch
+    lanes = bag_lanes(W)
+    offs = jnp.asarray(lanes, dtype=jnp.int32)
+    positions = pos + jnp.arange(B, dtype=jnp.int32)
+    in_corpus = (positions >= 0) & (positions < n_valid)
+    words, lane_ids, in_sentence = _span_lanes(ids, sent_of, pos, B, lanes)
+    b = grid_window_shrink(base_key, positions, grid_batch, grid_step0, W)
+    valid = (
+        (jnp.abs(offs)[None, :] <= (W - b)[:, None])
+        & in_sentence
+        & in_corpus[:, None]
+        # a bounded view's sentence may run past its live prefix
+        & (positions[:, None] + offs[None, :] < n_valid)
+    )  # (B, 2W)
+    centres = jnp.where(in_corpus, words, 0).astype(jnp.int32)
+    bags = jnp.where(valid, lane_ids, -1).astype(jnp.int32)
+    live = valid.any(axis=1)
+    return centres, bags, valid.astype(jnp.float32), live.astype(jnp.float32)
 
 
 def center_runs(pcenters: jax.Array, pmask: jax.Array, n_runs: int):

@@ -427,8 +427,13 @@ class EmbeddingEngine:
         shared_negatives: int = 0,
         compute_dtype: Optional[str] = None,
         layout: str = "rows",
+        architecture: str = "skipgram",
     ):
-        """``extra_rows`` appends non-vocabulary rows to both tables (e.g.
+        """``architecture`` is the model's (``Word2VecParams.architecture``):
+        a ``"cbow"`` engine trains through :meth:`train_steps_corpus_packed`
+        alone, whose scan then forms bags (``make_packed_corpus_scan``).
+
+        ``extra_rows`` appends non-vocabulary rows to both tables (e.g.
         fastText char-ngram buckets, models/fasttext.py): they are trained
         through subword center groups but are never negative-sampled (the
         noise table spans the vocab only) and never surface from the query
@@ -465,6 +470,7 @@ class EmbeddingEngine:
             unigram_table_size=unigram_table_size, seed=seed, dtype=dtype,
             extra_rows=extra_rows, shared_negatives=shared_negatives,
             compute_dtype=compute_dtype, layout=layout,
+            architecture=architecture,
         )
         if counts.shape != (vocab_size,):
             raise ValueError("counts must have shape (vocab_size,)")
@@ -516,6 +522,7 @@ class EmbeddingEngine:
         unigram_power: float, unigram_table_size: Optional[int], seed: int,
         dtype: str, extra_rows: int, shared_negatives: int,
         compute_dtype: Optional[str], layout: str,
+        architecture: str = "skipgram",
     ) -> None:
         """The host-only half of construction: validate, and derive every
         attribute the jitted closures capture (geometry, dtypes, step
@@ -530,6 +537,14 @@ class EmbeddingEngine:
             raise ValueError("extra_rows must be >= 0")
         if shared_negatives < 0:
             raise ValueError("shared_negatives must be >= 0")
+        if architecture not in ("skipgram", "cbow"):
+            raise ValueError("architecture must be 'skipgram' or 'cbow'")
+        if architecture == "cbow" and shared_negatives:
+            raise ValueError(
+                "architecture='cbow' draws its negatives a position: "
+                "shared_negatives must be 0"
+            )
+        self.architecture = architecture
         self.mesh = mesh
         self.vocab_size = int(vocab_size)
         self._seed = int(seed)  # graftlint: ignore[sync-point] host config scalar
@@ -615,7 +630,8 @@ class EmbeddingEngine:
         rep = P()
 
         def step_body_rows(syn0_l, syn1_l, noise, centers, cmask,
-                           contexts, mask, key, alpha, pair_run=None):
+                           contexts, mask, key, alpha, pair_run=None,
+                           mean_gradient=True):
             # Data-sharded inputs: centers/cmask (Rl, S), contexts/mask
             # (Bl, C). S = subword-group width; word-level training is the
             # S=1 specialization. The center representation is the masked
@@ -624,6 +640,11 @@ class EmbeddingEngine:
             # rising, says which group each batch row's centre is (the
             # packed subword scan forms a group once a run of pairs,
             # ops/device_batching.center_runs); None: row i has group i.
+            # CBOW is the same step with the roles of the index sets
+            # swapped: the group is a position's bag of context words, the
+            # one "context" (C = 1) the position's own word, and
+            # ``mean_gradient`` False: every row of the bag takes the
+            # whole gradient, as word2vec.c adds the undivided neu1e.
             Rl, S = centers.shape
             Bl, C = contexts.shape
             # What a grouped centre adds to the step has a scope of its
@@ -729,9 +750,10 @@ class EmbeddingEngine:
                     scat1 = _pair_payload(ctx_g, negs_g, cpos_g, cneg_g, h_g)
 
             # The center gradient is distributed over the group's rows
-            # (d mean / d row = 1/count): ship the (Rl, d) gradient + the
-            # (Rl, S) group mask, expand to rows at the consumer. A group
-            # that several batch rows share first sums their gradients.
+            # (d mean / d row = 1/count; undivided for CBOW): ship the
+            # (Rl, d) gradient + the (Rl, S) group mask, expand to rows at
+            # the consumer. A group that several batch rows share first
+            # sums their gradients.
             d_center = g.d_center
             if pair_run is not None:
                 with jax.named_scope(compose):
@@ -740,7 +762,8 @@ class EmbeddingEngine:
                     ).at[pair_run].add(d_center, indices_are_sorted=True)
             with jax.named_scope("glint.grads"):
                 dcen_g = lax.all_gather(
-                    d_center / cnt, DATA_AXIS, tiled=True
+                    d_center / cnt if mean_gradient else d_center,
+                    DATA_AXIS, tiled=True,
                 )
                 cmask_g = lax.all_gather(cmask, DATA_AXIS, tiled=True)
                 ids0_g = lax.all_gather(
@@ -770,7 +793,8 @@ class EmbeddingEngine:
             return syn0_l, syn1_l, loss, written
 
         def step_body_dims(syn0_l, syn1_l, noise, centers, cmask,
-                           contexts, mask, key, alpha, pair_run=None):
+                           contexts, mask, key, alpha, pair_run=None,
+                           mean_gradient=True):
             # Column-sharded step (CIKM'16 partitioning, SURVEY.md §2.2):
             # tables are (V, dl) local column slices with EVERY row
             # resident, so gathers and scatter-adds are shard-local. The
@@ -779,8 +803,9 @@ class EmbeddingEngine:
             # servers return from ``dotprod``. The data-axis exchange is
             # the same scalars+h contract as the rows layout, with h now
             # a (B, dl) column slice (1/n the bytes per chip).
-            # Groups, ``pair_run`` and the compose scope as in
-            # step_body_rows; a padding id (-1) reads a row the mask drops.
+            # Groups, ``pair_run``, ``mean_gradient`` and the compose scope
+            # as in step_body_rows; a padding id (-1) reads a row the mask
+            # drops.
             Rl, S = centers.shape
             Bl, C = contexts.shape
             drank = lax.axis_index(DATA_AXIS)
@@ -883,7 +908,8 @@ class EmbeddingEngine:
                     ).at[pair_run].add(d_center_l, indices_are_sorted=True)
             with jax.named_scope("glint.grads"):
                 dcen_g = lax.all_gather(
-                    d_center_l / cnt, DATA_AXIS, tiled=True
+                    d_center_l / cnt if mean_gradient else d_center_l,
+                    DATA_AXIS, tiled=True,
                 )
                 cmask_g = lax.all_gather(cmask, DATA_AXIS, tiled=True)
                 ids0_g = lax.all_gather(
@@ -1078,7 +1104,11 @@ class EmbeddingEngine:
             # centre side is a function of the drawn centre ids and the
             # table alone. ``written`` then carries two more counts: live
             # group ids and the centres they formed.
+            #
+            # A CBOW engine (``architecture``) gets the position-major
+            # scan below instead: ``P`` is then the positions of a step.
             from glint_word2vec_tpu.ops.device_batching import (
+                bag_window_batch,
                 center_runs,
                 device_words_done,
                 pack_window_pairs,
@@ -1086,6 +1116,74 @@ class EmbeddingEngine:
 
             Pl = P // num_data
             R = min(Pl, S + 1)  # runs of one rank's pair list
+
+            def alpha_at(pos_end, orig_offs, soffs, n_valid, step_size,
+                         inv_total_words, words_base):
+                # The LR after the positions before ``pos_end``: the
+                # host's pre-subsampling words_done rule, on the device.
+                done = device_words_done(orig_offs, soffs, pos_end, n_valid)
+                wd = words_base + done.astype(jnp.float32)
+                return jnp.maximum(
+                    step_size * (1.0 - wd * inv_total_words),
+                    step_size * 1e-4,
+                )
+
+            def local_bag_packed_scan(syn0_l, syn1_l, noise, ids, sent_of,
+                                      soffs, orig_offs, n_valid, pstart,
+                                      base_key, step0, grid_step0,
+                                      step_size, inv_total_words,
+                                      words_base):
+                # CBOW: step i trains the P consecutive positions from
+                # ``pos``, each rank its own Pl of them. A position's bag
+                # (ops/device_batching.bag_window_batch) is the step
+                # body's GROUP, its own word the one context, and the
+                # negatives are drawn a position, keyed by its global row:
+                # the roles of the two tables' index sets are swapped and
+                # nothing else is. Nothing is compacted, the advance is
+                # the static P, and the same key schedule, shrink draws
+                # and alpha rule hold as in the pair scan below. The
+                # per-step outputs are that scan's: ``n_pairs`` reads the
+                # live bag slots, and ``written`` carries them again with
+                # the positions trained, as the subword scan appends its
+                # two counts.
+                drank = lax.axis_index(DATA_AXIS)
+
+                def body(carry, i):
+                    s0, s1, pos = carry
+                    with jax.named_scope("glint.batch"):
+                        key = jax.random.fold_in(base_key, step0 + i)
+                        c_l, bag, cmask, live = bag_window_batch(
+                            ids, sent_of, pos + drank * Pl, base_key,
+                            grid_step0, window=W, batch=Pl,
+                            grid_batch=B_grid, n_valid=n_valid,
+                        )
+                        pos_end = pos + P
+                        alpha = alpha_at(
+                            pos_end, orig_offs, soffs, n_valid, step_size,
+                            inv_total_words, words_base,
+                        )
+                        formed = lax.psum(
+                            jnp.stack([
+                                cmask.sum(dtype=jnp.int32),
+                                live.sum(dtype=jnp.int32),
+                            ]), DATA_AXIS,
+                        )
+                    s0, s1, loss, written = step_body(
+                        s0, s1, noise, bag, cmask,
+                        c_l[:, None], live[:, None], key, alpha,
+                        mean_gradient=False,
+                    )
+                    return (s0, s1, pos_end), (
+                        loss, formed[0], pos_end, alpha,
+                        jnp.concatenate([written, formed]),
+                    )
+
+                (syn0_l, syn1_l, _), ys = lax.scan(
+                    body,
+                    (syn0_l, syn1_l, pstart),
+                    jnp.arange(K, dtype=jnp.uint32),
+                )
+                return (syn0_l, syn1_l) + ys
 
             def local_packed_scan(syn0_l, syn1_l, noise, ids, sent_of, soffs,
                                   orig_offs, n_valid, pstart, base_key,
@@ -1104,13 +1202,9 @@ class EmbeddingEngine:
                             sent_of=sent_of,
                         )
                         pos_end = pos + n_cons
-                        done = device_words_done(
-                            orig_offs, soffs, pos_end, n_valid
-                        )
-                        wd = words_base + done.astype(jnp.float32)
-                        alpha = jnp.maximum(
-                            step_size * (1.0 - wd * inv_total_words),
-                            step_size * 1e-4,
+                        alpha = alpha_at(
+                            pos_end, orig_offs, soffs, n_valid, step_size,
+                            inv_total_words, words_base,
                         )
                         c_l = lax.dynamic_slice_in_dim(pc, drank * Pl, Pl)
                         x_l = lax.dynamic_slice_in_dim(px, drank * Pl, Pl)
@@ -1152,7 +1246,8 @@ class EmbeddingEngine:
 
             return jax.jit(
                 self._shard_map(
-                    local_packed_scan,
+                    local_bag_packed_scan if self.architecture == "cbow"
+                    else local_packed_scan,
                     in_specs=(tspec, tspec) + (rep,) * (14 if G else 13),
                     out_specs=(tspec, tspec, rep, rep, rep, rep, rep),
                 ),
@@ -1483,6 +1578,16 @@ class EmbeddingEngine:
     # Training
     # ------------------------------------------------------------------
 
+    def _skipgram_only(self, entry: str) -> None:
+        """The host-batch entries and the grid corpus scan train skip-gram
+        pairs; a CBOW engine trains through the packed corpus scan alone."""
+        if self.architecture != "skipgram":
+            raise ValueError(
+                f"{entry} trains skip-gram batches; an engine with "
+                f"architecture={self.architecture!r} trains through "
+                "train_steps_corpus_packed (the corpus-resident path) only"
+            )
+
     def train_step(self, centers, contexts, mask, key, alpha) -> float:
         """One synchronous SGNS minibatch update; returns the batch loss.
 
@@ -1531,6 +1636,7 @@ class EmbeddingEngine:
         of its group's syn0 rows (fastText subword composition; the center
         gradient splits 1/count over the group's rows). Word-level training
         is the width-1 special case used by :meth:`train_step`."""
+        self._skipgram_only("train_step_grouped")
         cg, gm, cx, mk = self._device_batch(
             _host_or_device(center_groups),
             _host_or_device(group_mask, jnp.float32),
@@ -1599,6 +1705,7 @@ class EmbeddingEngine:
         multi-host each process passes its own data-axis slice of every
         step's batch (B here = local rows); the global batch is assembled
         across processes before dispatch."""
+        self._skipgram_only("train_steps_grouped")
         cg, gm, cx, mk = self._device_batch(
             _host_or_device(center_groups_k),
             _host_or_device(group_mask_k, jnp.float32),
@@ -1848,7 +1955,7 @@ class EmbeddingEngine:
             tuple(d.id for d in self.mesh.devices.flat),
             self.mesh.axis_names,
             tuple(self.mesh.shape.items()),
-            self.layout,
+            self.layout, self.architecture,
             str(self._dtype), str(self._compute_dtype),
             self.num_negatives, self.shared_negatives,
             self.rows_per_shard, self.cols_per_shard,
@@ -1886,6 +1993,7 @@ class EmbeddingEngine:
         positions past the corpus end become zero-mask rows (the epoch
         tail). Returns the (K,) per-step losses. Key schedule matches
         :meth:`train_steps` exactly."""
+        self._skipgram_only("train_steps_corpus")
         if getattr(self, "_corpus", None) is None:
             raise ValueError("no corpus uploaded (call upload_corpus first)")
         B, W = int(batch_size), int(window)
@@ -1959,13 +2067,27 @@ class EmbeddingEngine:
         formed.
         The caller reads ``pos_ends[-1]`` to schedule the next dispatch
         (one scalar readback per K steps).
+
+        A CBOW engine trains POSITIONS, not pairs: ``pair_batch`` is then
+        the consecutive centre positions of a step (each with its bag of
+        up to ``2 * window`` context words, one draw of negatives a
+        position), the advance a step is that many, and ``span`` does not
+        apply. ``pair_counts`` reads the live bag slots of a step and
+        ``rows_written`` is ``(K, 6)``: then the live bag slots again and
+        the positions that trained (inside the corpus, bag not empty).
         """
         if getattr(self, "_corpus", None) is None:
             raise ValueError("no corpus uploaded (call upload_corpus first)")
         from glint_word2vec_tpu.corpus.batching import context_width
 
         P, W, B = int(pair_batch), int(window), int(grid_batch)
-        C = context_width(W)
+        cbow = self.architecture == "cbow"
+        C = 1 if cbow else context_width(W)
+        if cbow and self._group_width:
+            raise ValueError(
+                "a CBOW bag over subword groups is not supported: drop the "
+                "group table (upload_center_groups(None))"
+            )
         if P % self.num_data:
             raise ValueError(
                 f"pair batch {P} not divisible by data axis {self.num_data}"
@@ -1974,7 +2096,9 @@ class EmbeddingEngine:
             raise ValueError(
                 f"pair_batch ({P}) must be >= context lanes ({C})"
             )
-        if span is None:
+        if cbow:
+            span = 0
+        elif span is None:
             # Enough candidates that the cumulative valid-pair count
             # almost always reaches P (expected live lanes per position
             # is ~0.43*C at W=5, ~0.5*C at W=2): 3*P/C positions carry
@@ -2019,8 +2143,11 @@ class EmbeddingEngine:
         """``syn0`` rows one packed step pulls and update slots it hands the
         ``syn0`` scatter, over all data ranks: a centre a pair, or, while a
         group table is held, the whole group (padding included) of every
-        run slot of the default span (``make_packed_corpus_scan``)."""
+        run slot of the default span (``make_packed_corpus_scan``); on a
+        CBOW engine the ``2 * window`` lanes of every position's bag."""
         G = self._group_width
+        if self.architecture == "cbow":
+            return pair_batch * 2 * window
         if not G:
             return pair_batch
         from glint_word2vec_tpu.corpus.batching import context_width
@@ -2032,8 +2159,9 @@ class EmbeddingEngine:
                              window: Optional[int] = None) -> Tuple[int, int]:
         """Update slots one packed step hands the (syn0, syn1) scatters:
         a centre a pair (:meth:`_packed_center_slots`: ``window`` matters
-        to a subword engine alone), and a context plus its negatives (or
-        the shared pool once) a pair."""
+        to a subword or CBOW engine alone), and a context plus its
+        negatives (or the shared pool once) a pair; on a CBOW engine a
+        bag, and its own word plus the negatives, a position."""
         centers = self._packed_center_slots(pair_batch, window)
         if self.shared_negatives:
             return centers, pair_batch + self.shared_negatives
@@ -3244,6 +3372,7 @@ class EmbeddingEngine:
                 "bfloat16" if self._dtype == jnp.bfloat16 else "float32"
             ),
             "shared_negatives": self.shared_negatives,
+            "architecture": self.architecture,
         }
 
     def _write_snapshot(self, path: str, files, meta: dict,
@@ -3589,6 +3718,7 @@ class EmbeddingEngine:
             shared_negatives=overrides.get(
                 "shared_negatives", meta.get("shared_negatives", 0)
             ),
+            architecture=meta.get("architecture", "skipgram"),
         )
         eng.load_tables(path)
         return eng

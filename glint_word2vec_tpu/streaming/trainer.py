@@ -98,6 +98,12 @@ class StreamTrainer:
             raise ValueError("buffer_words must be >= 256")
         if extra_rows < 0:
             raise ValueError("extra_rows must be >= 0")
+        if w2v.params.architecture != "skipgram":
+            raise ValueError(
+                "the streaming trainer trains skip-gram only "
+                f"(architecture={w2v.params.architecture!r}): fit a CBOW "
+                "model with fit / fit_file"
+            )
         self.w2v = w2v
         self.publish_dir = publish_dir
         self.bootstrap_words = bootstrap_words

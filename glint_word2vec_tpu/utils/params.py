@@ -75,6 +75,15 @@ class Word2VecParams:
         expected gradient (ops.sgns.shared_sgns_grads) — the TPU-shaped
         estimator: dense MXU matmuls instead of batch*contexts*n sparse
         row accesses. 1024-8192 are typical pool sizes.
+      architecture: "skipgram" (default: each word predicts each of its
+        context words, the reference's model) or "cbow" (continuous bag of
+        words with negative sampling, the word2vec tool's own default,
+        ``-cbow 1``: the mean of a position's context rows predicts the
+        position's word, and every context row takes the whole gradient).
+        A property of the model, saved with it. CBOW trains on the
+        corpus-resident packed path only (ops/device_batching
+        .bag_window_batch), draws its negatives a position, and is
+        refused with a shared pool, grid packing or replica exchange.
     """
 
     vector_size: int = 100
@@ -160,6 +169,9 @@ class Word2VecParams:
     #: touched-row unions that size every exchange buffer
     #: (arXiv:1909.03359).
     exchange_shard: str = "roundrobin"
+    #: Which model is trained: "skipgram" or "cbow" (see the class
+    #: docstring). A saved model without the key is a skip-gram.
+    architecture: str = "skipgram"
 
     def __post_init__(self) -> None:
         self.validate()
@@ -215,6 +227,22 @@ class Word2VecParams:
             self.exchange_shard in ("roundrobin", "locality"),
             "exchange_shard must be roundrobin|locality",
         )
+        _require(
+            self.architecture in ("skipgram", "cbow"),
+            "architecture must be skipgram|cbow",
+        )
+        if self.architecture == "cbow":
+            _require(
+                self.shared_negatives == 0,
+                "architecture='cbow' draws its negatives a position: "
+                "shared_negatives must be 0",
+            )
+            _require(
+                self.batch_packing == "dense" and self.exchange == "none",
+                "architecture='cbow' trains on the corpus-resident packed "
+                "path only: batch_packing must be 'dense' and exchange "
+                "'none'",
+            )
 
     def replace(self, **kwargs) -> "Word2VecParams":
         return dataclasses.replace(self, **kwargs)

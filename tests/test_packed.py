@@ -535,13 +535,17 @@ def test_mid_epoch_state_refuses_cross_mode_resume(tmp_path, monkeypatch):
     assert m.training_metrics["pipeline"] == "device_corpus"
 
 
-def test_packed_subsampled_checkpoint_resume(tmp_path, monkeypatch):
+@pytest.mark.parametrize("architecture", ["skipgram", "cbow"])
+def test_packed_subsampled_checkpoint_resume(tmp_path, monkeypatch,
+                                             architecture):
     # Mid-epoch resume with subsampling: the epoch recompacts from
     # (seed, epoch) alone, so the restored position indexes the identical
-    # compacted stream.
+    # compacted stream. A CBOW fit (bags of positions, a static advance)
+    # keeps the same counters in the same state file.
     ck = str(tmp_path / "ck")
     os.makedirs(ck, exist_ok=True)
-    kw = dict(batch_packing="dense", subsample_ratio=0.01)
+    kw = dict(batch_packing="dense", subsample_ratio=0.01,
+              architecture=architecture)
     monkeypatch.setenv("GLINT_PACKED_STOP_AFTER_GROUPS", "2")
     _w2v(**kw).fit(CORPUS, checkpoint_dir=ck)
     monkeypatch.delenv("GLINT_PACKED_STOP_AFTER_GROUPS")

@@ -334,6 +334,26 @@ def test_a_word_level_fit_lowers_to_the_program_it_lowered_to(shape, layout):
     assert got == WORD_LEVEL_PROGRAMS[(shape, layout)]
 
 
+# The same for the SUBWORD scans (a (V, G) group table on the device), taken
+# on the parent of ISSUE 34 (069d339), which gave the step bodies a flag for
+# CBOW's undivided gradient and the scan factory a second scan: a skip-gram
+# fit, word level and subword, must lower to the program it lowered to.
+SUBWORD_PROGRAMS = {
+    ((1, 1), "rows"): ("b24a0a5aa17641ab", "b4a25e1e65d61771"),
+    ((1, 2), "rows"): ("6daad024d3f22cc7", "959ac1e1767884d6"),
+    ((2, 2), "rows"): ("ffd3b8ca935a8686", "f276e47581b1ff89"),
+    ((1, 2), "dims"): ("79541a8534a46c2a", "44cec185549ed410"),
+}
+
+
+@pytest.mark.parametrize("shape,layout", sorted(SUBWORD_PROGRAMS))
+def test_a_subword_fit_lowers_to_the_program_it_lowered_to(shape, layout):
+    eng = engine(shape, random_groups(), layout=layout)
+    got = tuple(hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
+                for low in lowered(eng, G))
+    assert got == SUBWORD_PROGRAMS[(shape, layout)]
+
+
 def test_the_subword_scan_keeps_its_name_and_scopes():
     eng = engine((1, 1), random_groups())
     packed, _ = lowered(eng, G)

@@ -85,8 +85,8 @@ def engines(topo):
     built = {}
 
     def get(chips: int, shared_negatives: int = 0, vocab: int = V,
-            extra_rows: int = 0):
-        key = (chips, shared_negatives, vocab, extra_rows)
+            extra_rows: int = 0, architecture: str = "skipgram"):
+        key = (chips, shared_negatives, vocab, extra_rows, architecture)
         if key not in built:
             mesh = Mesh(
                 np.asarray(topo.devices[:chips]).reshape(1, chips),
@@ -98,6 +98,7 @@ def engines(topo):
                 unigram_table_size=None, seed=1, dtype="float32",
                 extra_rows=extra_rows, shared_negatives=shared_negatives,
                 compute_dtype=None, layout="rows",
+                architecture=architecture,
             )
             eng._build_jitted_fns()
             built[key] = eng
@@ -179,7 +180,8 @@ def _compile_packed_scan(eng, words=CORPUS_WORDS, sentences=CORPUS_SENTENCES,
     pairs a step, 5 negatives) over a resident corpus of ``words`` tokens,
     compiled for the engine's described mesh; with ``group_width`` the
     subword family's, a (vocab, group_width) group table its last
-    argument."""
+    argument. A CBOW engine's scan trains 8,192 positions a step, each
+    with its bag, and has no span."""
     import jax.numpy as jnp
 
     from glint_word2vec_tpu.corpus.batching import (
@@ -190,6 +192,8 @@ def _compile_packed_scan(eng, words=CORPUS_WORDS, sentences=CORPUS_SENTENCES,
     sds = _shapes(eng)
     P_ = packed_pair_batch(BATCH, WINDOW, 1)
     span = -(-3 * P_ // context_width(WINDOW))
+    if eng.architecture == "cbow":
+        P_, span = BATCH, 0
     fn = eng._make_packed_corpus_scan(
         P_, WINDOW, BATCH, span, STEPS_PER_CALL, group_width
     )
@@ -401,6 +405,34 @@ def test_subword_packed_scan_at_the_cell_size(engines):
     more = 1_000_000 * (2 * D_REST * 4 + MAX_SUBWORDS * 4 + 8 + 4)
     assert abs(mem["total"] + more - 13_793_810_432) < 64e6, mem
     assert mem["total"] + more < HBM_BYTES
+
+
+def test_cbow_packed_scan_at_the_cell_size(engines):
+    # The CBOW cell's step (benchmark/configs/w2v-cbow-300-3m.json): two
+    # tables of 3M rows, 9.22 GB at rest, donated; 8,192 positions a step,
+    # a bag of up to 10 syn0 rows and 6 syn1 rows a position (81,920 +
+    # 49,152 row slots where the subword step has 360k + 157k), over the
+    # cell's corpus of 7,639,956 tokens. ISSUE 34 reckoned 10.2-10.8 GB
+    # at a fit's peak.
+    words = 3_000_000 - 44 + 4_000_000 + 8 * 80_000
+    sentences = -(-(words - 8 * 80_000) // 40) + 80_000
+    eng = engines(1, 0, 3_000_000, architecture="cbow")
+    compiled = _compile_packed_scan(eng, words, sentences)
+    mem = _fits(compiled)
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        2 * eng.rows_per_shard * D_REST * 4
+    ), mem
+    assert mem["total"] < 10.8e9 and mem["temp"] < 1.5e9, mem
+    assert not _whole_table_copies(compiled, eng)
+    text = compiled.as_text()
+    assert "all-reduce" not in text and "packed_scan" in text
+    # both tables' scatters end in the slab writer, the bags' rows too
+    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for table in ("syn0", "syn1"):
+        assert any(f"glint.scatter/{table}" in k for k in kernels), table
+    for scope in ("glint.batch", "glint.sample", "glint.compose",
+                  "glint.gather/syn0", "glint.gather/syn1", "glint.grads"):
+        assert scope in text, scope
 
 
 def test_slab_writer_compiles_for_bfloat16(topo):
