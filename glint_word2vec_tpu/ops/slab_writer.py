@@ -1,4 +1,5 @@
-"""The SGNS step's row writer for a TPU: move each touched slab once.
+"""The SGNS step's row writer for a TPU: move each touched slab once, and
+total each row's run while the slab is held.
 
 A table that rests in whole lanes (``engine.TABLE_LANES``) is tiled
 ``(8, 128)`` on the device (``(16, 128)`` for bfloat16), and the rows that
@@ -7,15 +8,23 @@ row is not a legal DMA slice of such a table ("Slice shape along dimension
 0 must be aligned to tiling (8), but is 1": PERF.md, PR 29); a slab is. So
 where XLA's TPU scatter costs about 96 ns for every row it is handed
 (PERF.md, PR 26), this writer copies every DISTINCT slab among the rows
-HBM -> VMEM, adds the slab's totals into their sublanes and copies it
-back, with ``AHEAD`` reads in flight and the write-backs waited lazily.
+HBM -> VMEM, adds what the batch adds to its rows and copies it back, with
+``AHEAD`` reads in flight and the write-backs waited lazily.
 
-:func:`write` takes what ``engine._run_totals`` returns (the distinct rows
-sorted, so the rows of one slab are neighbours; their f32 totals; how many
-are live) and gives the same table ``engine._scatter_rows``' XLA writer
-gives, bit for bit: the total is rounded once to the table's dtype and
-added to its row once. It exists only for a TPU (a Mosaic kernel) and only
-for a table :func:`fits` admits; the engine chooses between the two at
+:func:`write` takes the update slots themselves as ``engine._sort_slots``
+orders them: the target rows sorted (so the slots of one row, and the rows
+of one slab, are neighbours, a run in the order it stood in the batch;
+slots no row of the table takes sorted to the end), each with its
+coefficient and its source row's index. It forms a chunk's payload
+``coefs * src[hidx]`` in float32, and the kernel adds every slot of a slab
+into a zeroed float32 accumulator at its row's sublane, rounds that once
+to the table's dtype and adds it to the slab: the arithmetic of
+``engine._run_totals`` + ``engine._write_rows`` (XLA's writer), so the
+table is the same bit for bit, with no buffer of slots x columns, no
+scatter and no second sort (PERF.md, PR 35). A slab whose slots a chunk's
+edge cuts is not moved by the earlier call: its accumulator is carried
+into the next. The kernel exists only for a TPU (Mosaic) and only for a
+table :func:`fits` admits; the engine chooses between the two writers at
 lowering time and no option selects either. Tests run the kernel on the
 CPU through ``write(..., interpret=True)``.
 """
@@ -28,13 +37,13 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: Rows of totals one call of the kernel takes into VMEM (3 MB of f32 at
-#: 384 columns, which the call's pipeline holds twice; a wider table takes
-#: fewer rows, ``TOT_BYTES`` at most) and whose ids it takes into SMEM. A
-#: slab that straddles two chunks is moved twice, by two calls that run in
-#: order.
-CHUNK = 2048
-TOT_BYTES = 3 << 20
+#: Slots one call of the kernel takes: their payload into VMEM (6 MB of f32
+#: at 384 columns; a wider table takes fewer slots, ``PAYLOAD_BYTES`` at
+#: most), their rows into SMEM. Stand-alone on the chip 4,096 slots a call
+#: took 1.5% (word level) to 17% (CBOW's bags) less than 2,048, and 8,192
+#: little less again for twice the VMEM (PERF.md, PR 35).
+CHUNK = 4096
+PAYLOAD_BYTES = 6 << 20
 #: Slab buffers in VMEM, and how many reads are started ahead of the slab
 #: being added to. A buffer's write-back is waited for only when the
 #: buffer is read into again, ``SLOTS - AHEAD`` slabs later.
@@ -57,19 +66,21 @@ def fits(shape, dtype) -> bool:
     )
 
 
-def _kernel(meta_ref, u_ref, nxt_ref, tot_ref, table_in, table, buf, sem_in,
-            sem_out, *, sub, slots, ahead):
-    """One chunk: ``meta_ref`` = (chunk index, live rows, slabs) of it,
-    ``u_ref`` its rows' ids, ``nxt_ref[i]`` the chunk-local index of the
-    first row of the next slab after row ``i``, ``tot_ref`` its totals.
-    ``table`` is ``table_in``, in place, in HBM."""
+def _kernel(meta_ref, row_ref, nxt_ref, pay_ref, carry_ref, table_in, table,
+            carry_out, buf, sem_in, sem_out, *, sub, slots, ahead):
+    """One chunk of sorted slots. ``meta_ref`` = (live slots, slabs to
+    move, whether the first slab's accumulator starts from ``carry_ref``,
+    whether the slots after the last moved slab go to ``carry_out``);
+    ``row_ref`` the slots' rows, ``nxt_ref[i]`` the chunk-local index of
+    the first slot of the next slab after slot ``i``, ``pay_ref`` the
+    slots' payload. ``table`` is ``table_in``, in place, in HBM."""
     del table_in
-    n_rows, n_slabs = meta_ref[1], meta_ref[2]
+    n_live, n_slabs = meta_ref[0], meta_ref[1]
     shift = sub.bit_length() - 1
     sublane = lax.broadcasted_iota(jnp.int32, buf.shape[1:], 0)
 
     def slab_of(p):
-        row0 = pl.multiple_of((u_ref[p] >> shift) << shift, sub)
+        row0 = pl.multiple_of((row_ref[p] >> shift) << shift, sub)
         return table.at[pl.ds(row0, sub)]
 
     # A wait reads the semaphore and the copy's size, not its addresses.
@@ -82,18 +93,21 @@ def _kernel(meta_ref, u_ref, nxt_ref, tot_ref, table_in, table, buf, sem_in,
         pltpu.make_async_copy(buf.at[s], any_slab, sem_out.at[s]).wait()
 
     def fetch(j, pf):
-        """Start reading the slab whose first row is ``pf`` into slab
-        ``j``'s buffer; returns the next slab's first row."""
+        """Start reading the slab whose first slot is ``pf`` into slab
+        ``j``'s buffer; returns the next slab's first slot."""
         s = j & (slots - 1)
         pltpu.make_async_copy(slab_of(pf), buf.at[s], sem_in.at[s]).start()
         return nxt_ref[pf]
 
-    def add_row(i, acc):
-        row = jnp.broadcast_to(tot_ref[pl.ds(i, 1), :], acc.shape)
-        # The total is rounded to the table's dtype, then added: what
-        # ``t.at[u].add(tot.astype(t.dtype))`` does.
-        row = row.astype(buf.dtype).astype(jnp.float32)
-        return jnp.where(sublane == (u_ref[i] & (sub - 1)), acc + row, acc)
+    def add_slot(i, acc):
+        row = jnp.broadcast_to(pay_ref[pl.ds(i, 1), :], acc.shape)
+        return jnp.where(sublane == (row_ref[i] & (sub - 1)), acc + row, acc)
+
+    def carried(first):
+        """The accumulator a slab starts from: what the last call carried
+        for the chunk's first slab if that call cut it, else zero."""
+        rows = jnp.where(first & (meta_ref[2] != 0), sub, 0)
+        return jnp.where(sublane < rows, carry_ref[...], 0.0)
 
     def step(j, carry, *, prefetch, reuse):
         p, pf = carry
@@ -102,13 +116,17 @@ def _kernel(meta_ref, u_ref, nxt_ref, tot_ref, table_in, table, buf, sem_in,
                 wait_write((j + ahead) & (slots - 1))
             pf = fetch(j + ahead, pf)
         s = j & (slots - 1)
+        q = jnp.minimum(nxt_ref[p], n_live)
+        # Most slabs carry one slot or two (the benchmark's steps: 1.2 to
+        # 1.8 slots a slab), so the first is added outside the loop: 7 ns
+        # a slab.
+        acc = add_slot(p, carried(j == 0))
+        acc = lax.fori_loop(p + 1, q, add_slot, acc)
         wait_read(s)
-        q = jnp.minimum(nxt_ref[p], n_rows)
-        # Six slabs in seven carry one row (the benchmark's step: 1.35 rows
-        # a slab), so the first is added outside the loop: 7 ns a slab.
-        acc = add_row(p, buf[s].astype(jnp.float32))
-        acc = lax.fori_loop(p + 1, q, add_row, acc)
-        buf[s] = acc.astype(buf.dtype)
+        # The run's total is rounded to the table's dtype, then added:
+        # what ``t.at[u].add(tot.astype(t.dtype))`` does.
+        acc = acc.astype(buf.dtype).astype(jnp.float32)
+        buf[s] = (buf[s].astype(jnp.float32) + acc).astype(buf.dtype)
         pltpu.make_async_copy(buf.at[s], slab_of(p), sem_out.at[s]).start()
         return q, pf
 
@@ -120,7 +138,13 @@ def _kernel(meta_ref, u_ref, nxt_ref, tot_ref, table_in, table, buf, sem_in,
     fresh = jnp.minimum(slots - ahead, fetching)
     carry = steps(0, fresh, (0, pf), prefetch=True, reuse=False)
     carry = steps(fresh, fetching, carry, prefetch=True, reuse=True)
-    steps(fetching, n_slabs, carry, prefetch=False, reuse=False)
+    p, _ = steps(fetching, n_slabs, carry, prefetch=False, reuse=False)
+
+    # The slots of a slab that goes on in the next chunk: summed, not moved.
+    cut = meta_ref[3] != 0
+    carry_out[...] = lax.fori_loop(
+        p, jnp.where(cut, n_live, p), add_slot, carried(n_slabs == 0)
+    )
 
     def drain(j, c):
         wait_write(j & (slots - 1))
@@ -129,35 +153,59 @@ def _kernel(meta_ref, u_ref, nxt_ref, tot_ref, table_in, table, buf, sem_in,
     lax.fori_loop(jnp.maximum(n_slabs - slots, 0), n_slabs, drain, 0)
 
 
-def write(table, u, tot, n_u, *, chunk=CHUNK, slots=SLOTS, ahead=AHEAD,
-          interpret=False):
-    """Add ``tot[i]`` to row ``u[i]`` of ``table`` for ``i < n_u``: ``u``
-    sorted and distinct, ``tot`` float32, as ``engine._run_totals`` makes
-    them. Returns ``(table, slabs moved)``."""
+def _shifted(x, by, fill):
+    """``x[:, by:]`` with ``fill`` behind it: slot ``i`` of a chunk reads
+    slot ``i + by``."""
+    return jnp.pad(x[:, by:], ((0, 0), (0, by)), constant_values=fill)
+
+
+def write(table, rows, coefs, src, hidx, *, chunk=CHUNK, slots=SLOTS,
+          ahead=AHEAD, interpret=False):
+    """Add ``coefs[k] * src[hidx[k]]`` to row ``rows[k]`` of ``table`` for
+    every slot ``k`` whose row the table has: ``rows`` sorted, as
+    ``engine._sort_slots`` gives them with their ``coefs`` and ``hidx``
+    (anything from ``table.shape[0]`` up marks a slot to skip, and sorts
+    last). Returns ``(table, slabs moved)``: each touched slab once."""
     assert fits(table.shape, table.dtype)
     assert slots & (slots - 1) == 0 and 0 < ahead < slots
     sub = slab_rows(table.dtype)
-    n = u.shape[0]
-    while chunk > 8 and chunk * tot.shape[1] * 4 > TOT_BYTES:
+    d = table.shape[1]
+    n = rows.shape[0]
+    while chunk > 8 and chunk * d * 4 > PAYLOAD_BYTES:
         chunk //= 2
     chunk = min(chunk, -(-n // 8) * 8)
-    n_pad = -(-n // chunk) * chunk
-    u = jnp.pad(u, (0, n_pad - n), constant_values=jnp.iinfo(jnp.int32).max)
-    tot = jnp.pad(tot, ((0, n_pad - n), (0, 0)))
-    # Where a slab starts (every chunk starts one) and, from a start, the
-    # chunk-local index of the next one: a slab's rows are neighbours in the
-    # sorted ``u``, at most ``sub`` of them. The kernel's two cursors hop
-    # along it.
-    index = jnp.arange(n_pad, dtype=jnp.int32)
-    pos = index % chunk
-    slab = u >> (sub.bit_length() - 1)
-    start = (pos == 0) | (slab != jnp.roll(slab, 1))
-    nxt = pos + 1
-    for t in range(1, sub):
-        nxt += (jnp.roll(slab, -t) == slab) & (pos + t < chunk)
-    slabs = (start & (index < n_u)).reshape(-1, chunk).sum(
-        1, dtype=jnp.int32
-    )
+    n_chunks = -(-n // chunk)
+    pad = (0, n_chunks * chunk - n)
+    rows = jnp.pad(rows, pad, constant_values=jnp.iinfo(jnp.int32).max)
+    coefs = jnp.pad(coefs.astype(jnp.float32), pad)
+    hidx = jnp.pad(hidx, pad)
+    # Bookkeeping on the sorted rows alone. A slab's slots are neighbours,
+    # any number of them; from a slot, the chunk-local index of the next
+    # slab's first slot is a reverse running minimum over the starts. The
+    # kernel's two cursors hop along it.
+    live = (rows < table.shape[0]).reshape(n_chunks, chunk)
+    slab = rows >> (sub.bit_length() - 1)
+    new = jnp.concatenate(
+        [jnp.ones(1, bool), slab[1:] != slab[:-1]]
+    ).reshape(n_chunks, chunk)
+    pos = jnp.arange(chunk, dtype=jnp.int32)
+    start = new | (pos == 0)
+    nxt = _shifted(jnp.where(start, pos, chunk), 1, chunk)
+    # A doubling scan: ``lax.cummin`` lowers to reduce-windows that carry
+    # no scope of the step's and take 0.12 ms.
+    by = 1
+    while by < chunk:
+        nxt = jnp.minimum(nxt, _shifted(nxt, by, chunk))
+        by *= 2
+    n_live = live.sum(dtype=jnp.int32)
+    # A chunk whose last slab goes on at the head of the next one leaves
+    # it to that one, with what it summed of it.
+    cut = jnp.pad(live[1:, 0] & ~new[1:, 0], (0, 1))
+    meta = jnp.stack([
+        live.sum(1, dtype=jnp.int32),
+        (start & live).sum(1, dtype=jnp.int32) - cut,
+        jnp.roll(cut, 1), cut,
+    ], axis=1)
 
     call = pl.pallas_call(
         functools.partial(_kernel, sub=sub, slots=slots, ahead=ahead),
@@ -165,32 +213,41 @@ def write(table, u, tot, n_u, *, chunk=CHUNK, slots=SLOTS, ahead=AHEAD,
             num_scalar_prefetch=3,
             grid=(1,),
             in_specs=[
-                pl.BlockSpec(
-                    (chunk, tot.shape[1]), lambda g, meta, *_: (meta[0], 0)
-                ),
+                pl.BlockSpec((chunk, d), lambda g, *_: (0, 0)),
+                pl.BlockSpec((sub, d), lambda g, *_: (0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            out_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((sub, d), lambda g, *_: (0, 0)),
+            ],
             scratch_shapes=[
-                pltpu.VMEM((slots, sub, table.shape[1]), table.dtype),
+                pltpu.VMEM((slots, sub, d), table.dtype),
                 pltpu.SemaphoreType.DMA((slots,)),
                 pltpu.SemaphoreType.DMA((slots,)),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-        input_output_aliases={4: 0},
+        out_shape=[
+            jax.ShapeDtypeStruct(table.shape, table.dtype),
+            jax.ShapeDtypeStruct((sub, d), jnp.float32),
+        ],
+        input_output_aliases={5: 0, 4: 1},
         interpret=pltpu.InterpretParams() if interpret else False,
     )
 
-    def one(k, t):
-        meta = jnp.stack([k, jnp.clip(n_u - k * chunk, 0, chunk), slabs[k]])
-        return call(
-            meta,
-            lax.dynamic_slice_in_dim(u, k * chunk, chunk),
-            lax.dynamic_slice_in_dim(nxt, k * chunk, chunk),
-            tot, t,
-        )
+    def one(k, state):
+        t, carry = state
+        at = k * chunk
+        c = lax.dynamic_slice_in_dim(coefs, at, chunk)
+        h = lax.dynamic_slice_in_dim(hidx, at, chunk)
+        payload = c[:, None] * src[h].astype(jnp.float32)
+        return tuple(call(
+            meta[k], lax.dynamic_slice_in_dim(rows, at, chunk), nxt[k],
+            payload, carry, t,
+        ))
 
-    table = lax.fori_loop(0, -(-n_u // chunk), one, table)
-    return table, slabs.sum(dtype=jnp.int32)
-
+    table, _ = lax.fori_loop(
+        0, -(-n_live // chunk), one,
+        (table, jnp.zeros((sub, d), jnp.float32)),
+    )
+    return table, (new & live).sum(dtype=jnp.int32)

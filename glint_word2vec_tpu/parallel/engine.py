@@ -187,29 +187,39 @@ def _run_ends(sid, n_rows):
     )
 
 
-def _run_totals(key, coefs, src, hidx, n_rows):
-    """Sort the N update slots by target row and total each run of equal
-    rows once, in float32: slot k adds ``coefs[k] * src[hidx[k]]`` to row
-    ``key[k]``; a key of ``n_rows`` marks a slot whose row another shard
-    owns. Returns ``(u, tot, n_u)``: the ``n_u`` distinct owned rows in
-    rising order with their totals in ``tot[:n_u]``, then sentinels (all
-    different, all past ``n_rows``) whose ``tot`` rows no writer reads.
-    Both are padded to a whole number of writer chunks.
-
-    The payload is formed in sorted order (coefficient and source index
-    ride through the sort, the source ROW is gathered after it), never in
-    batch order. A run is totalled by a sorted scatter-add into
-    consecutive rows of a fresh buffer, a pass over that buffer and 17 ns
-    a slot: it adds a run in the order it stood in the batch, so its error
-    is that of the plain sum, where a difference of prefix sums carries
-    the whole prefix's (PERF.md, PR 26: 1.2 against 3.8e8 units of
-    eps * sum|x|)."""
-    n = key.shape[0]
-    chunk = _writer_chunk(n)
-    n_pad = -(-n // chunk) * chunk
-    sid, coefs, hidx = lax.sort(
+def _sort_slots(key, coefs, hidx):
+    """The N update slots ordered by target row, each with its coefficient
+    and its source row's index: what both writers of :func:`_scatter_rows`
+    start from. The sort is stable, so a run of equal rows keeps the order
+    it stood in in the batch; a key past the table (another shard's row, a
+    group's or a bag's padding) sorts to the end."""
+    return lax.sort(
         (key.astype(jnp.int32), coefs.astype(jnp.float32), hidx), num_keys=1
     )
+
+
+def _run_totals(sid, coefs, src, hidx, n_rows):
+    """Total each run of equal rows once, in float32, for XLA's writer:
+    slot k of the sorted slots (:func:`_sort_slots`) adds ``coefs[k] *
+    src[hidx[k]]`` to row ``sid[k]``; a row of ``n_rows`` marks a slot
+    whose row another shard owns. Returns ``(u, tot, n_u)``: the ``n_u``
+    distinct owned rows in rising order with their totals in
+    ``tot[:n_u]``, then sentinels (all different, all past ``n_rows``)
+    whose ``tot`` rows no writer reads. Both are padded to a whole number
+    of writer chunks.
+
+    The payload is formed in sorted order (coefficient and source index
+    rode through the sort, the source ROW is gathered after it), never in
+    batch order. A run is totalled by a sorted scatter-add into
+    consecutive rows of a fresh buffer, a pass over that buffer and 21 ns
+    a slot on a TPU (where the slab writer totals the runs itself, in
+    VMEM: PERF.md, PR 35): it adds a run in the order it stood in the
+    batch, so its error is that of the plain sum, where a difference of
+    prefix sums carries the whole prefix's (PERF.md, PR 26: 1.2 against
+    3.8e8 units of eps * sum|x|)."""
+    n = sid.shape[0]
+    chunk = _writer_chunk(n)
+    n_pad = -(-n // chunk) * chunk
     rows = coefs[:, None] * src[hidx].astype(jnp.float32)
     is_start, live_end = _run_ends(sid, n_rows)
     slot = jnp.cumsum(is_start.astype(jnp.int32)) - 1
@@ -224,14 +234,15 @@ def _run_totals(key, coefs, src, hidx, n_rows):
     return u, tot, live_end.sum(dtype=jnp.int32)
 
 
-def _write_rows(table_l, u, tot, n_u):
-    """XLA's writer of :func:`_scatter_rows`: the distinct rows ``u[:n_u]``
-    handed to the TPU scatter a chunk at a time, the loop stopping after
-    the last live chunk. The scatter is told what is true of its rows (no
-    two alike, strays dropped) but not that they are sorted: that flag
-    selects XLA's other TPU emitter, which passes over the whole table
-    (9.4 ms at 2M x 300) before it adds a slot. Returns ``(table_l, 0)``:
-    it moves no slab."""
+def _write_rows(table_l, sid, coefs, src, hidx):
+    """XLA's writer of :func:`_scatter_rows`: the runs totalled
+    (:func:`_run_totals`), then the distinct rows handed to the scatter a
+    chunk at a time, the loop stopping after the last live chunk. The
+    scatter is told what is true of its rows (no two alike, strays
+    dropped) but not that they are sorted: that flag selects XLA's other
+    TPU emitter, which passes over the whole table (9.4 ms at 2M x 300)
+    before it adds a slot. Returns ``(table_l, 0)``: it moves no slab."""
+    u, tot, n_u = _run_totals(sid, coefs, src, hidx, table_l.shape[0])
     chunk = _writer_chunk(u.shape[0])
 
     def write(k, t):
@@ -249,30 +260,34 @@ def _scatter_rows(table_l, idx, coefs, src, hidx, start):
     ``coefs[k] * src[hidx[k]]`` to global row ``idx[k]``, where ``start``
     is the global id of local row 0. Updates of rows another shard owns
     are dropped, not walked. Every table dtype and layout takes this one
-    path: the slots are sorted, each row's updates are summed once in
-    float32 (:func:`_run_totals`), and each distinct row is written once,
-    its total rounded once to the table's dtype.
+    path: the slots are sorted by row (:func:`_sort_slots`), each row's
+    run is summed once in float32 in the order it stood in the batch, and
+    each distinct row is written once, its total rounded once to the
+    table's dtype.
 
-    Two writers share those totals and nothing else, and which one runs
-    follows from what is observed, never from an option: where the
-    program is lowered for a TPU and the table's rows can be addressed by
-    whole tile rows (``slab_writer.fits``: every ``rows`` table whose
-    shard is a multiple of 16 rows), ``ops/slab_writer.py``'s kernel moves
-    each touched slab once; everywhere else (CPU, GPU, a ``dims`` shard of
-    75 columns) :func:`_write_rows` hands XLA's scatter the rows. The two
-    give the same table bit for bit. Returns ``(table_l, rows written,
-    slabs moved)``, the last 0 from XLA's writer."""
+    Two writers share the sorted slots and the count of their runs, and
+    which one runs follows from what is observed, never from an option:
+    where the program is lowered for a TPU and the table's rows can be
+    addressed by whole tile rows (``slab_writer.fits``: every ``rows``
+    table whose shard is a multiple of 16 rows), ``ops/slab_writer.py``'s
+    kernel moves each touched slab once and totals the runs of its rows
+    while it holds it; everywhere else (CPU, GPU, a ``dims`` shard of 75
+    columns) :func:`_write_rows` totals them into a buffer and hands XLA's
+    scatter the distinct rows. The two give the same table bit for bit.
+    Returns ``(table_l, rows written, slabs moved)``, the last 0 from
+    XLA's writer."""
     Vs = table_l.shape[0]
     loc = idx - start
     own = (loc >= 0) & (loc < Vs)
-    key = jnp.where(own, loc, Vs)
-    u, tot, n_u = _run_totals(key, coefs, src, hidx, Vs)
+    sid, coefs, hidx = _sort_slots(jnp.where(own, loc, Vs), coefs, hidx)
+    n_u = _run_ends(sid, Vs)[1].sum(dtype=jnp.int32)
     if slab_writer.fits(table_l.shape, table_l.dtype):
         table_l, moved = lax.platform_dependent(
-            table_l, u, tot, n_u, tpu=slab_writer.write, default=_write_rows
+            table_l, sid, coefs, src, hidx,
+            tpu=slab_writer.write, default=_write_rows,
         )
     else:
-        table_l, moved = _write_rows(table_l, u, tot, n_u)
+        table_l, moved = _write_rows(table_l, sid, coefs, src, hidx)
     return table_l, n_u, moved
 
 
@@ -770,7 +785,8 @@ class EmbeddingEngine:
                     centers.reshape(-1), DATA_AXIS, tiled=True
                 )
             # The outer products coef x row are formed at the consumer, in
-            # the scatter's sorted order (_run_totals), never exchanged.
+            # the scatter's sorted order (_scatter_rows' writers), never
+            # exchanged.
             with jax.named_scope("glint.scatter"):
                 with jax.named_scope("syn0"):
                     syn0_l, w0, m0 = _scatter_rows(

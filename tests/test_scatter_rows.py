@@ -4,7 +4,11 @@ that break a scatter: all distinct, all equal, a Zipf batch with one run
 of 600, ids another shard owns, sizes the writer's chunk does not divide,
 and distinct rows enough for the writer's second trip (the benchmark's
 step makes 29), its last chunk partly live. Then exact sums, and the one
-row gather (``engine._pull_rows``) across shard edges."""
+row gather (``engine._pull_rows``) across shard edges. The float64 cases
+run twice: through XLA's writer, which the CPU's programs take, and through
+the slab writer's kernel in interpret mode, which a TPU's take."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +16,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from glint_word2vec_tpu.ops import slab_writer
 from glint_word2vec_tpu.parallel import engine
 from glint_word2vec_tpu.parallel.mesh import MODEL_AXIS, make_mesh
 
@@ -36,6 +41,15 @@ def _ids(profile, rng):
         return START + rng.integers(0, V, size=engine._SCATTER_CHUNK + 1)
     if profile == "one":
         return np.asarray([START + V - 1])
+    if profile == "run_across_slab_chunk_edge":
+        # the kernel's chunks cut at SLOTS: a run of 600 that, once sorted,
+        # stands across the first chunk's last slot, every other row of the
+        # shard once around it
+        lo = slab_writer.CHUNK - 248
+        ids = np.concatenate([
+            np.arange(lo), np.full(600, lo), np.arange(lo + 1, V),
+        ])
+        return START + rng.permutation(ids)
     # The writer's trips: it walks the DISTINCT owned rows a chunk at a
     # time, so only these reach its second trip.
     chunk = engine._SCATTER_CHUNK
@@ -72,19 +86,27 @@ def _ids(profile, rng):
     "distinct", "equal", "zipf_run_600", "other_shards", "chunk_plus_one",
     "one", "distinct_one_chunk", "distinct_chunk_plus_one",
     "distinct_two_trips", "all_rows", "run_across_chunk_edge",
-    "row0_and_last_row_runs",
+    "row0_and_last_row_runs", "run_across_slab_chunk_edge",
 ])
-def test_scatter_rows_against_float64(profile, dtype):
+@pytest.mark.parametrize("writer", ["xla", "slab"])
+def test_scatter_rows_against_float64(profile, dtype, writer, monkeypatch):
     rng = np.random.default_rng(len(profile))
     ids = _ids(profile, rng).astype(np.int32)
     n = ids.size
+    # The kernel wants rows of whole lanes; on the CPU it stands where a
+    # TPU's lowering puts it only if it is handed over as the default.
+    D = {"xla": 24, "slab": 128}[writer]
+    if writer == "slab":
+        monkeypatch.setattr(engine, "_write_rows", functools.partial(
+            slab_writer.write, interpret=True
+        ))
     src = rng.normal(0, 1, (max(n // 3, 1), D)).astype(np.float32)
     hidx = rng.integers(0, src.shape[0], n).astype(np.int32)
     coefs = rng.normal(0, 0.05, n).astype(np.float32)
     table = jnp.asarray(rng.normal(0, 0.5, (V, D)), dtype)
     before = np.asarray(table, np.float64)
 
-    out, written, _ = engine._scatter_rows(
+    out, written, moved = jax.jit(engine._scatter_rows)(
         table, jnp.asarray(ids), jnp.asarray(coefs), jnp.asarray(src),
         jnp.asarray(hidx), START,
     )
@@ -97,6 +119,9 @@ def test_scatter_rows_against_float64(profile, dtype):
     mass = np.zeros((V, D))  # what a run's in-order sum may lose an ulp of
     np.add.at(mass, loc, np.abs(upd))
     assert int(written) == np.unique(loc).size
+    sub = slab_writer.slab_rows(dtype)
+    assert int(moved) == (np.unique(loc // sub).size if writer == "slab"
+                          else 0)
     assert out.dtype == table.dtype
     got = np.asarray(out, np.float64)
     touched = np.zeros(V, bool)

@@ -1,9 +1,10 @@
 """The slab writer (``ops/slab_writer.py``) against the XLA writer of
-``engine._scatter_rows`` on the same ``(u, tot, n_u)``: bit-equal, float32
-and bfloat16. The kernel runs on the CPU in Pallas' TPU interpret mode,
-through the module's own ``write(..., interpret=True)``; that it compiles
-for the chip is ``tests/test_tpu_compile.py``'s, and what it costs is a
-chip run's (PERF.md, PR 30). Then: which writer ``_scatter_rows`` picks."""
+``engine._scatter_rows`` on the same sorted slots: bit-equal, float32 and
+bfloat16, whatever the runs, the slots a slab and where a chunk's edge
+falls. The kernel runs on the CPU in Pallas' TPU interpret mode, through
+the module's own ``write(..., interpret=True)``; that it compiles for the
+chip is ``tests/test_tpu_compile.py``'s, and what it costs is a chip run's
+(PERF.md, PRs 30 and 35). Then: which writer ``_scatter_rows`` picks."""
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,8 @@ CH = 64  # the chunk most cases pass: interpret mode takes 5 ms a slab
 
 
 def _rows(case, sub, rng):
-    """Distinct local rows a case writes, and the chunk it passes."""
+    """Distinct local rows a case writes, each named twice in the batch,
+    and the chunk it passes."""
     if case == "dense_head":  # every row of the first 40 slabs
         return np.arange(40 * sub), CH
     if case == "singletons":  # one row a slab, every sublane in turn
@@ -28,8 +30,9 @@ def _rows(case, sub, rng):
             case[4:]]
         return np.sort(rng.permutation(V)[:n]), CH
     if case == "slab_across_chunks":
-        # sorted rows 60..67 of u are one f32 slab (rows 8 x 70 + 0..7):
-        # chunk 0 ends inside it, so two calls move it
+        # sorted rows 60..67 are one f32 slab (rows 8 x 70 + 0..7), their
+        # slots 120..135: a chunk's edge falls inside the slab, between
+        # two of its rows' runs
         lone = np.arange(60) * sub
         return np.concatenate([lone, 70 * sub + np.arange(sub), [V - 1]]), CH
     if case == "last_slab":
@@ -41,42 +44,85 @@ def _rows(case, sub, rng):
     return np.unique(rng.choice(V, size=700, p=p / p.sum())), slab_writer.CHUNK
 
 
-CASES = [
+ROW_CASES = [
     "dense_head", "singletons", "n_u=0", "n_u=1", "n_u=CH-1", "n_u=CH",
     "n_u=CH+1", "slab_across_chunks", "last_slab",
     "fewer_slabs_than_buffers", "default_chunk",
 ]
 
 
-def _totals(rows, rng):
-    """``(u, tot, n_u)`` as ``_run_totals`` makes them, each row named
-    twice in the batch, the ``tot`` rows no writer may read poisoned."""
-    ids = rng.permutation(np.concatenate([rows, rows, [V, V + 5]]))
+def _runs(case, sub):
+    """The live slots' rows of a case that names its runs outright."""
+    lone = np.arange(3, 40) * sub + 5  # 37 slabs of one slot each
+    if case == "run_of_600":  # over nine chunks' edges, rows on both sides
+        return np.concatenate([lone, np.full(600, 50 * sub + 2), [V - 1]])
+    if case == "slab_over_sub_slots":  # 3 * sub + 1 slots, two of its rows
+        return np.concatenate([
+            lone, np.full(2 * sub + 1, 60 * sub), np.full(sub, 60 * sub + 3),
+        ])
+    if case == "runs_in_one_slab":  # every row of two slabs, a run each
+        rows = np.concatenate([60 * sub + np.arange(sub),
+                               61 * sub + np.arange(sub)])
+        return np.concatenate([lone, np.repeat(rows, 1 + rows % 5)])
+    if case == "sentinels_only":
+        return np.zeros(0, np.int64)
+    if case in ("live=CH", "live=CH+1", "live=2CH"):
+        n = {"CH": CH, "CH+1": CH + 1, "2CH": 2 * CH}[case[5:]]
+        return np.sort(np.random.default_rng(n).integers(0, V, n))
+    if case == "run_cut_by_chunk_edge":
+        # 37 lone slots, then a run of 40 on slots 37..76: across slot 64
+        return np.concatenate([lone, np.full(40, 50 * sub + 1), [V - 2]])
+    if case == "run_ends_at_chunk_edge":
+        # the run stands on slots 37..63 and the next chunk starts another
+        # row of the SAME slab: the slab is cut, no run is
+        return np.concatenate([
+            lone, np.full(27, 50 * sub + 1), np.full(5, 50 * sub + 2),
+        ])
+    assert case == "cut_slab_is_the_last"
+    # the last live slab goes over a chunk's edge and the sentinels follow
+    return np.concatenate([lone, np.full(30, V - 1)])
+
+
+RUN_CASES = [
+    "run_of_600", "slab_over_sub_slots", "runs_in_one_slab",
+    "sentinels_only", "live=CH", "live=CH+1", "live=2CH",
+    "run_cut_by_chunk_edge", "run_ends_at_chunk_edge",
+    "cut_slab_is_the_last",
+]
+
+
+def _slots(live, rng):
+    """``(sid, coefs, src, hidx)`` as ``_scatter_rows`` hands them to its
+    writers: the batch's slots (``live`` in a random order among a few
+    slots of other shards' rows) sorted by row."""
+    ids = rng.permutation(np.concatenate([live, [V, V, V]]))
     src = rng.normal(0, 1, (32, D)).astype(np.float32)
     hidx = rng.integers(0, 32, ids.size).astype(np.int32)
     coefs = rng.normal(0, 0.05, ids.size).astype(np.float32)
-    key = np.where(ids < V, ids, V).astype(np.int32)
-    u, tot, n_u = engine._run_totals(
-        jnp.asarray(key), jnp.asarray(coefs), jnp.asarray(src),
-        jnp.asarray(hidx), V,
+    coefs[ids >= V] = np.nan  # what no writer may add to a row
+    sid, coefs, hidx = engine._sort_slots(
+        jnp.asarray(ids.astype(np.int32)), jnp.asarray(coefs),
+        jnp.asarray(hidx),
     )
-    assert int(n_u) == rows.size
-    dead = jnp.arange(tot.shape[0]) >= n_u
-    return u, jnp.where(dead[:, None], jnp.nan, tot), n_u
+    return sid, coefs, jnp.asarray(src), hidx
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("case", ROW_CASES + RUN_CASES)
 def test_slab_writer_is_bit_equal_to_xla_writer(case, dtype):
     rng = np.random.default_rng(len(case))
     sub = slab_writer.slab_rows(dtype)
-    rows, chunk = _rows(case, sub, rng)
+    if case in ROW_CASES:
+        rows, chunk = _rows(case, sub, rng)
+        live = np.concatenate([rows, rows])
+    else:
+        live, chunk = _runs(case, sub), CH
     table = jnp.asarray(rng.normal(0, 0.5, (V, D)), dtype)
-    u, tot, n_u = _totals(rows, rng)
+    slots = _slots(live, rng)
 
-    want, none = engine._write_rows(table, u, tot, n_u)
+    want, none = engine._write_rows(table, *slots)
     got, moved = slab_writer.write(
-        table, u, tot, n_u, chunk=chunk, interpret=True
+        table, *slots, chunk=chunk, interpret=True
     )
 
     assert got.dtype == table.dtype and int(none) == 0
@@ -84,18 +130,24 @@ def test_slab_writer_is_bit_equal_to_xla_writer(case, dtype):
     np.testing.assert_array_equal(
         np.asarray(got).view(bits), np.asarray(want).view(bits)
     )
-    if rows.size:  # something was written, and nothing poisoned it
+    if live.size:  # something was written, and no dead slot poisoned it
         assert (np.asarray(got) != np.asarray(table)).any()
         assert np.isfinite(np.asarray(got, np.float32)).all()
-    # every distinct slab once, and once more where a chunk's edge cuts it
-    step = min(chunk, -(-u.shape[0] // 8) * 8)
-    slabs = np.sort(rows) // sub
-    a_chunk = [np.unique(slabs[k:k + step]).size
-               for k in range(0, rows.size, step)]
-    assert int(moved) == sum(a_chunk)
-    cut = sum(a_chunk) - np.unique(slabs).size
-    if case == "slab_across_chunks" and dtype == "float32":
-        assert cut == 1
+    else:
+        np.testing.assert_array_equal(
+            np.asarray(got).view(bits), np.asarray(table).view(bits)
+        )
+    # Every distinct slab once: one a chunk's edge cuts is carried, not
+    # moved twice.
+    slabs = np.sort(live) // sub
+    assert int(moved) == np.unique(slabs).size
+    cut = [k for k in range(chunk, live.size, chunk)
+           if slabs[k] == slabs[k - 1]]
+    if case in ("slab_across_chunks", "run_of_600", "run_cut_by_chunk_edge",
+                "run_ends_at_chunk_edge", "cut_slab_is_the_last"):
+        assert cut, case  # the case is what its name says
+    if case == "run_of_600":
+        assert len(cut) >= 9
     if case == "dense_head":
         assert int(moved) * sub == rows.size
 
@@ -108,11 +160,10 @@ def test_slab_writer_whatever_the_buffers(slots, ahead):
     table = jnp.asarray(rng.normal(0, 0.5, (V, D)), jnp.float32)
     for n in sorted({1, ahead, slots - ahead, slots, slots + 1, 3 * slots}):
         rows = np.sort(rng.permutation(V // 8)[:n]) * 8 + rng.integers(0, 8, n)
-        u, tot, n_u = _totals(rows, rng)
-        want, _ = engine._write_rows(table, u, tot, n_u)
+        args = _slots(np.concatenate([rows, rows]), rng)
+        want, _ = engine._write_rows(table, *args)
         got, moved = slab_writer.write(
-            table, u, tot, n_u, chunk=CH, slots=slots, ahead=ahead,
-            interpret=True,
+            table, *args, chunk=CH, slots=slots, ahead=ahead, interpret=True,
         )
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         assert int(moved) == n
@@ -147,11 +198,10 @@ def test_scatter_rows_picks_the_xla_writer(shape, dtype, why):
     out, n_u, moved = jax.jit(engine._scatter_rows)(*args)
     assert int(moved) == 0 and int(n_u) == np.unique(
         ids[ids < shape[0]]).size
-    u, tot, n_live = engine._run_totals(
-        jnp.where(args[1] < shape[0], args[1], shape[0]), *args[2:5],
-        shape[0],
+    sid, coefs, hidx = engine._sort_slots(
+        jnp.where(args[1] < shape[0], args[1], shape[0]), args[2], args[4]
     )
-    want, _ = engine._write_rows(args[0], u, tot, n_live)
+    want, _ = engine._write_rows(args[0], sid, coefs, args[3], hidx)
     np.testing.assert_array_equal(
         np.asarray(out, np.float32), np.asarray(want, np.float32)
     )

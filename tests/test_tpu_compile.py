@@ -339,30 +339,69 @@ def test_packed_corpus_scan_keeps_the_tables_as_they_rest(packed_scans, name):
     )
 
 
+def _scatter_holds_no_slot_buffer(compiled, eng, source_rows=0) -> list:
+    """The lines of the compiled program under ``glint.scatter``, after
+    asserting what PR 35 took out of them: no XLA scatter at all (the run
+    totals were one, the writer before PR 30 another) and no float32
+    buffer of rows but the table and one chunk's payload (the totals of
+    every slot were ``f32[159744,384]`` at word level, 245 MB).
+    ``source_rows``: the rows of a payload SOURCE the step forms under the
+    scope (the shared pool's ``h ++ d_pool``), which is no slot buffer."""
+    import re
+
+    from glint_word2vec_tpu.ops import slab_writer
+
+    lines = [line for line in compiled.as_text().splitlines()
+             if "glint.scatter" in line]
+    assert len(lines) > 50  # the scope reached the compiled program
+    rows = re.compile(rf"f32\[(\d+),{eng.padded_dim}\]")
+    # the table, a chunk's payload, a slab's carried accumulator
+    known = (eng.rows_per_shard, slab_writer.CHUNK,
+             slab_writer.slab_rows("float32"), source_rows)
+    for line in lines:
+        result = line.split("metadata=")[0].split("(")[0]
+        assert " scatter(" not in line, line[:300]
+        assert not [n for n in rows.findall(result)
+                    if int(n) not in known], line[:300]
+    return lines
+
+
+# What each program held in temporaries at PR 35's parent (compile check,
+# PR 35). The totals buffer is gone from them, yet they are the parent's
+# less a megabyte or two: the peak lies in the grads' buffers of
+# (131,075, 384), not in the scatter's.
+PARENT_TEMP = {
+    "2m-1chip": 1_140_963_840, "3m-1chip": 1_140_963_840,
+    "10m-4chips": 1_150_474_752, "1chip-shared_pool": 2_222_158_848,
+}
+
+
 @pytest.mark.parametrize(
     "name", ["2m-1chip", "3m-1chip", "10m-4chips", "1chip-shared_pool"]
 )
 def test_packed_corpus_scan_writes_rows_by_slabs(packed_scans, name):
     # Lowered for a TPU, a resting table's scatter ends in the slab writer
     # (ops/slab_writer.py): a Mosaic kernel for each table, filed under
-    # glint.scatter, and no XLA scatter whose operand is a table (96 ns a
-    # row: PERF.md, PR 26). The step's temporaries stay what they were
-    # (the shared pool's dense update held 2.22 GB before this writer).
-    import re
+    # glint.scatter, which totals the runs of the sorted slots itself, so
+    # that no XLA scatter is left there, on a table (96 ns a row: PERF.md,
+    # PR 26) or into a buffer of totals (21 ns a slot: PR 35).
+    from glint_word2vec_tpu.corpus.batching import packed_pair_batch
 
     eng, compiled = packed_scans(name)
-    text = compiled.as_text().splitlines()
-    kernels = [line for line in text if "tpu_custom_call" in line]
+    pool = SCANS[name][1]
+    lines = _scatter_holds_no_slot_buffer(
+        compiled, eng, pool and packed_pair_batch(BATCH, WINDOW, 1) + pool
+    )
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line]
     assert kernels and all("glint.scatter/syn" in k for k in kernels), kernels
     for table in ("syn0", "syn1"):
         assert any(f"glint.scatter/{table}" in k for k in kernels), table
-    shard = rf"f32\[{eng.rows_per_shard},{eng.padded_dim}\]"
-    assert not [
-        line.strip()[:200] for line in text
-        if re.search(rf"= {shard}\S* scatter\(", line)
-    ]
-    ceiling = 2.3e9 if name == "1chip-shared_pool" else 1.2e9
-    assert _fits(compiled, SCANS[name][0])["temp"] < ceiling
+        # one sort a table: the second, which brought the distinct rows to
+        # the front, went with the totals
+        assert len([line for line in lines if " sort(" in line
+                    and f"glint.scatter/{table}" in line]) == 1, table
+    assert _fits(compiled, SCANS[name][0])["temp"] < PARENT_TEMP[name]
 
 
 def _compile_subword_scan(engines, name):
@@ -393,6 +432,7 @@ def test_subword_packed_scan_at_the_cell_size(engines):
     kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
     for table in ("syn0", "syn1"):
         assert any(f"glint.scatter/{table}" in k for k in kernels), table
+    _scatter_holds_no_slot_buffer(compiled, eng)  # f32[360448,384] was one
     assert "glint.compose" in text and "glint.gather/syn0" in text
     # fastText's cc.en.300 shape, 2M words + 2M buckets, which ISSUE 31
     # reckoned too large for one chip: compiled once by hand it FITS, at
@@ -430,6 +470,7 @@ def test_cbow_packed_scan_at_the_cell_size(engines):
     kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
     for table in ("syn0", "syn1"):
         assert any(f"glint.scatter/{table}" in k for k in kernels), table
+    _scatter_holds_no_slot_buffer(compiled, eng)  # f32[81920,384] was one
     for scope in ("glint.batch", "glint.sample", "glint.compose",
                   "glint.gather/syn0", "glint.gather/syn1", "glint.grads"):
         assert scope in text, scope
@@ -452,10 +493,11 @@ def test_slab_writer_compiles_for_bfloat16(topo):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    n = 159_744  # syn1's update slots of the benchmark's step, padded
+    n = 157_290  # syn1's update slots of the benchmark's step
     compiled = jax.jit(slab_writer.write, donate_argnums=0).lower(
         sds((2_000_000, D_REST), jnp.bfloat16), sds((n,), jnp.int32),
-        sds((n, D_REST), jnp.float32), sds((), jnp.int32),
+        sds((n,), jnp.float32), sds((26_215, D_REST), jnp.float32),
+        sds((n,), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     m = compiled.memory_analysis()
