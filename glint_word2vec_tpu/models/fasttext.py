@@ -278,24 +278,21 @@ class FastTextModel(Word2VecModel):
         if getattr(self, "_qeng", None) is None:
             from glint_word2vec_tpu.parallel.engine import EmbeddingEngine
 
-            with obs_events.span(
-                "compose_query_engine", vocab=self.vocab.size
-            ):
-                qeng = EmbeddingEngine(
-                    self.engine.mesh,
-                    self.vocab.size,
-                    self.vector_size,
-                    self.vocab.counts,
-                    num_negatives=self.engine.num_negatives,
-                    seed=0,
+            qeng = EmbeddingEngine(
+                self.engine.mesh,
+                self.vocab.size,
+                self.vector_size,
+                self.vocab.counts,
+                num_negatives=self.engine.num_negatives,
+                seed=0,
+            )
+            B = self.COMPOSE_BLOCK
+            for s in range(0, self.vocab.size, B):
+                e = min(s + B, self.vocab.size)
+                block = self._compose_device(
+                    self._sub_ids[s:e], self._sub_mask[s:e]
                 )
-                B = self.COMPOSE_BLOCK
-                for s in range(0, self.vocab.size, B):
-                    e = min(s + B, self.vocab.size)
-                    block = self._compose_device(
-                        self._sub_ids[s:e], self._sub_mask[s:e]
-                    )
-                    qeng.write_rows(s, block)
+                qeng.write_rows(s, block)
             self._qeng = qeng
         return self._qeng
 

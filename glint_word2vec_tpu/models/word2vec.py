@@ -896,33 +896,47 @@ class Word2Vec:
                 nonlocal packed_pairs, packed_slots, packed_groups
                 (losses, pair_counts, pos_ends, alphas_d, written,
                  start_h) = pend
+                # The three children part the host BLOCKED on the device
+                # from the read-back and from the host's own accounting
+                # (benchmark/fit_trace.py files the device's idle under
+                # the wait and under the rest). They are no ledger phases:
+                # readback_harvest charges the whole.
                 with metrics.timing("step"), obs_run.span(
                     "readback_harvest", packed=True
                 ) as hspan:
-                    pos_ends_h = np.asarray(pos_ends)
-                    pairs_h = np.asarray(pair_counts)
-                    alphas_h = np.asarray(alphas_d)
+                    with obs_run.span("harvest_wait"):
+                        jax.block_until_ready(pend[:5])
+                    with obs_run.span("harvest_convert"):
+                        pos_ends_h = np.asarray(pos_ends)
+                        pairs_h = np.asarray(pair_counts)
+                        alphas_h = np.asarray(alphas_d)
+                        written_h = np.asarray(written)
                     starts = np.concatenate(([start_h], pos_ends_h[:-1]))
                     # Live steps form a prefix: positions only ever
                     # advance, so the first start past the corpus end
                     # makes all later steps no-ops.
                     n_real = int((starts < n_pos).sum())
                     hspan.update(n=n_real)
-                    for i in range(n_real):
-                        step += 1
-                        end_pos = int(min(pos_ends_h[i], n_pos))
-                        if subsampling:
-                            done = corpus_words_done_compacted(
-                                offsets, offsets_c, end_pos, n_pos
+                    with obs_run.span("harvest_account", n=n_real):
+                        for i in range(n_real):
+                            step += 1
+                            end_pos = int(min(pos_ends_h[i], n_pos))
+                            if subsampling:
+                                done = corpus_words_done_compacted(
+                                    offsets, offsets_c, end_pos, n_pos
+                                )
+                            else:
+                                done = corpus_words_done(offsets, end_pos)
+                            epoch_wd = epoch * twc + done
+                            # losses[i] stays a device value (record_step
+                            # reads it at log points only)
+                            metrics.record_step(
+                                int(epoch_wd), loss=losses[i],
+                                alpha=float(alphas_h[i]),
                             )
-                        else:
-                            done = corpus_words_done(offsets, end_pos)
-                        epoch_wd = epoch * twc + done
-                        metrics.record_step(
-                            int(epoch_wd), loss=losses[i],
-                            alpha=float(alphas_h[i]),
+                        obs_run.observe_losses(
+                            step - n_real, losses, n_real
                         )
-                    obs_run.observe_losses(step - n_real, losses, n_real)
                 if n_real:
                     obs_run.update(
                         step=step, words_done=int(epoch_wd),
@@ -931,7 +945,7 @@ class Word2Vec:
                     step += spc - n_real  # tail no-ops consumed keys
                 packed_pairs += int(pairs_h[:n_real].sum())
                 packed_slots += n_real * pair_batch
-                written_h = np.asarray(written)[:n_real].sum(axis=0)
+                written_h = written_h[:n_real].sum(axis=0)
                 rows_written[:written_h.size] += written_h
                 packed_groups += 1
                 return int(pos_ends_h[-1])
@@ -993,7 +1007,8 @@ class Word2Vec:
                     while pos < n_pos:
                         faults.fire("worker.step")
                         with metrics.timing("step"), obs_run.span(
-                            "device_steps", step0=dstep, n=spc, packed=True
+                            "device_steps", step0=dstep, n=spc, packed=True,
+                            epoch=epoch,
                         ):
                             (
                                 losses, pair_counts, pos_ends, alphas_d,

@@ -2544,9 +2544,6 @@ class EmbeddingEngine:
                 self._ann, self.syn0, range(start, start + n)
             )
             self._ann.table_version = self.table_version
-        obs_events.emit(
-            "extra_rows_freed", freed=n, assigned=self.extra_rows_assigned,
-        )
         return n
 
     def _ann_touch_rows(self, rows) -> None:
@@ -2589,10 +2586,6 @@ class EmbeddingEngine:
         )
         self._counts = c.copy()
         self._put_alias_table(table)
-        obs_events.emit(
-            # graftlint: ignore[sync-point] c is the host counts array
-            "noise_counts_updated", train_words=int(c.sum()),
-        )
 
     def norms(self) -> jax.Array:
         """Per-row Euclidean norms of syn0, computed shard-local (Glint
@@ -2887,29 +2880,26 @@ class EmbeddingEngine:
              for p in (*nprobes, self._ann_conf["nprobe"])}
         )
         d = self.dim
-        with obs_events.span("engine_warmup_ann") as warm:
-            for p in nps:
-                for q in sorted(
-                    {min(self._q_bucket(q), ANN_MAX_Q)
-                     for q in q_buckets}
+        for p in nps:
+            for q in sorted(
+                {min(self._q_bucket(q), ANN_MAX_Q)
+                 for q in q_buckets}
+            ):
+                for k in sorted(
+                    {self._k_bucket(k) for k in k_buckets}
                 ):
-                    for k in sorted(
-                        {self._k_bucket(k) for k in k_buckets}
-                    ):
-                        self.ann_top_k_batch(
-                            np.zeros((q, d), np.float32), k, p
-                        )
-            # The promotion path's fixed-chunk assignment program.
-            _ann._score_fn(
-                _ann.INCREMENTAL_BLOCK, idx.clusters, idx.dim
-            )(
-                self.syn0, self.norms(),
-                jnp.zeros(_ann.INCREMENTAL_BLOCK, jnp.int32),
-                idx.centroids,
-            )
-            n = self.query_compiles - before
-            warm.update(shapes_compiled=n)
-        return n
+                    self.ann_top_k_batch(
+                        np.zeros((q, d), np.float32), k, p
+                    )
+        # The promotion path's fixed-chunk assignment program.
+        _ann._score_fn(
+            _ann.INCREMENTAL_BLOCK, idx.clusters, idx.dim
+        )(
+            self.syn0, self.norms(),
+            jnp.zeros(_ann.INCREMENTAL_BLOCK, jnp.int32),
+            idx.centroids,
+        )
+        return self.query_compiles - before
 
     def ann_recall_at_k(
         self, k: int = 10, sample: int = 64, nprobe: Optional[int] = None,
@@ -3011,26 +3001,23 @@ class EmbeddingEngine:
         requests, so a warmed bucket can never re-compile. Returns the
         number of shapes this call compiled (0 = already warm)."""
         before = self.query_compiles
-        with obs_events.span("engine_warmup") as warm:
-            d = self.dim
-            ks = sorted({self._k_bucket(int(k)) for k in k_buckets})
+        d = self.dim
+        ks = sorted({self._k_bucket(int(k)) for k in k_buckets})
+        for k in ks:
+            self.top_k_cosine(np.zeros(d, np.float32), k)
+        for q in sorted({next_pow2(int(q)) for q in q_buckets}):
+            self.pull(np.zeros(q, np.int32))
+        for q in sorted({self._q_bucket(int(q)) for q in q_buckets}):
+            zq = np.zeros((q, d), np.float32)
             for k in ks:
-                self.top_k_cosine(np.zeros(d, np.float32), k)
-            for q in sorted({next_pow2(int(q)) for q in q_buckets}):
-                self.pull(np.zeros(q, np.int32))
-            for q in sorted({self._q_bucket(int(q)) for q in q_buckets}):
-                zq = np.zeros((q, d), np.float32)
-                for k in ks:
-                    self.top_k_cosine_batch(zq, k)
-            for s in sorted({next_pow2(int(s)) for s in sentence_rows}):
-                for L in sorted({next_pow2(int(L)) for L in sentence_lens}):
-                    self.pull_average(
-                        np.zeros((s, L), np.int32),
-                        np.zeros((s, L), np.float32),
-                    )
-            n = self.query_compiles - before
-            warm.update(shapes_compiled=n)
-        return n
+                self.top_k_cosine_batch(zq, k)
+        for s in sorted({next_pow2(int(s)) for s in sentence_rows}):
+            for L in sorted({next_pow2(int(L)) for L in sentence_lens}):
+                self.pull_average(
+                    np.zeros((s, L), np.int32),
+                    np.zeros((s, L), np.float32),
+                )
+        return self.query_compiles - before
 
     # ------------------------------------------------------------------
     # Persistence / lifecycle

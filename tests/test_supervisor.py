@@ -13,12 +13,13 @@ from glint_word2vec_tpu.parallel.supervisor import Supervisor
 # Stub worker: writes generation-stamped heartbeats (with the progress
 # fields the gang aggregator sums) plus a per-rank event-log JSONL (the
 # flight recorder's collection source), then follows the behavior its
-# env/generation selects. argv: <status_file> <behavior> [<rank>]
+# env/generation selects. argv: <status_file> <behavior> [<rank> [<n>]]
 _STUB = r"""
 import json, os, sys, time
 
 status_file, behavior = sys.argv[1], sys.argv[2]
 rank = int(sys.argv[3]) if len(sys.argv) > 3 else 0
+n = int(sys.argv[4]) if len(sys.argv) > 4 else 1
 gen = int(os.environ.get("GLINT_SUPERVISOR_GEN", "-1"))
 
 events_file = os.path.join(
@@ -54,6 +55,13 @@ if behavior == "ok":
 if behavior == "crash-env":
     # Crashes only when the first-launch-only env var is present.
     if os.environ.get("GLINT_TEST_CRASH") == "1":
+        # not before every peer has a heartbeat for the flight recorder
+        # to collect: a loaded host starts them tens of ms apart
+        peers = [os.path.join(os.path.dirname(status_file),
+                              "status-%d.json" % r) for r in range(n)]
+        deadline = time.time() + 30
+        while time.time() < deadline and not all(map(os.path.exists, peers)):
+            time.sleep(0.01)
         sys.exit(3)
     time.sleep(0.1)
     beat("done")
@@ -99,6 +107,7 @@ def _sup(tmp_path, behavior, workers=1, **kw):
     def build_argv(rank, n, port, status_file, generation):
         return [
             sys.executable, str(stub), status_file, behavior, str(rank),
+            str(n),
         ]
 
     defaults = dict(
