@@ -79,37 +79,67 @@ class SgnsGrads(NamedTuple):
     loss: jax.Array  # () masked-mean SGNS loss (monitoring only)
 
 
+def _rounded(x: jax.Array, compute_dtype) -> jax.Array:
+    return x.astype(compute_dtype).astype(jnp.float32)
+
+
+def row_dots(h: jax.Array, u, compute_dtype=jnp.float32) -> jax.Array:
+    """``f[b, k] = sum_d h[b, d] * u[k][b, d]``, ``(B, K)`` float32: the
+    logits of K blocks of ``(B, d)`` rows (a sequence, or a ``(K, B, d)``
+    array) against one centre a batch row. Gathered rows keep the batch
+    axis beside d; a small axis (contexts, negatives, a bag's slots) is
+    only ever a MAJOR one, made on the ids before the gather and never on
+    the rows after it: a float32 ``(..., k, d)`` is tiled ``(8, 128)`` on
+    a TPU, pays for 8 where k is 5, and a reshape to or from it copies
+    every row (PERF.md, PR 38). ``compute_dtype=bfloat16`` rounds both
+    operands; products and sums stay float32, as on the MXU."""
+    hc = _rounded(h, compute_dtype)
+    return jnp.stack(
+        [(hc * _rounded(block, compute_dtype)).sum(axis=-1) for block in u],
+        axis=-1,
+    )
+
+
+def row_sums(c: jax.Array, u, compute_dtype=jnp.float32) -> jax.Array:
+    """``sum_k c[b, k] * u[k][b, :]``, ``(B, d)`` float32: the blocks of
+    :func:`row_dots`, each row weighted, summed over the major axis.
+    Columnwise-independent, so a dim-sharded shard passes its local column
+    slices and gets its local slice: no communication."""
+    cc = _rounded(c, compute_dtype)
+    terms = [
+        cc[:, k, None] * _rounded(block, compute_dtype)
+        for k, block in enumerate(u)
+    ]
+    return sum(terms[1:], terms[0])
+
+
 def sgns_grads(
     h: jax.Array,  # (B, d) float32 — syn0 rows of the centers
-    u_pos: jax.Array,  # (B, C, d) float32 — syn1 rows of the contexts
-    u_neg: jax.Array,  # (B, C, n, d) float32 — syn1 rows of the negatives
+    u_pos,  # C blocks of (B, d) float32 — syn1 rows of the contexts
+    u_neg,  # C * n blocks of (B, d) float32 — syn1 rows of the negatives
     mask: jax.Array,  # (B, C) float32 — 1.0 where the context slot is real
     neg_mask: jax.Array,  # (B, C, n) float32 — negatives kept (see train step)
     alpha: jax.Array,  # () float32 learning rate
     compute_dtype=jnp.float32,
 ) -> SgnsGrads:
-    """Forward + backward of the SGNS objective for pre-gathered rows.
+    """Forward + backward of the SGNS objective for pre-gathered rows, the
+    blocks as :func:`row_dots` takes them (``u_neg[c * n + k]``: every
+    pair ``(b, c)``'s k-th negative); every scalar array is batch-major,
+    as the sampler and the scatter meet it.
 
     Objective per real (center, context) pair (README.md:10-15 model):
         L = -log sigma(u_ctx . h) - sum_n log sigma(-u_neg . h)
     SGD coefficients (matching the reference's label-vs-sigmoid form at
     mllib:422-424): c_pos = alpha*(1 - sigma(f_pos)), c_neg = -alpha*sigma(f_neg).
 
-    ``compute_dtype=bfloat16`` feeds the d-contraction einsums bf16
-    operands with f32 accumulation (the MXU-native regime); coefficient
-    math, masking, and the loss stay f32. Word2vec SGD tolerates the
-    ~3-decimal-digit operand rounding (embeddings are trained with far
-    noisier estimators); the exactness-tested default stays f32.
+    ``compute_dtype=bfloat16`` feeds the d-contractions bf16 operands with
+    f32 accumulation (the MXU-native regime); coefficient math, masking,
+    and the loss stay f32. Word2vec SGD tolerates the ~3-decimal-digit
+    operand rounding (embeddings are trained with far noisier estimators);
+    the exactness-tested default stays f32.
     """
-    hc = h.astype(compute_dtype)
-    upc = u_pos.astype(compute_dtype)
-    unc = u_neg.astype(compute_dtype)
-    f_pos = jnp.einsum(
-        "bd,bcd->bc", hc, upc, preferred_element_type=jnp.float32
-    )  # (B, C)
-    f_neg = jnp.einsum(
-        "bd,bcnd->bcn", hc, unc, preferred_element_type=jnp.float32
-    )  # (B, C, n)
+    f_pos = row_dots(h, u_pos, compute_dtype)
+    f_neg = row_dots(h, u_neg, compute_dtype).reshape(neg_mask.shape)
     co = sgns_coefs(f_pos, f_neg, mask, neg_mask, alpha)
     d_center = sgns_d_center(co.c_pos, co.c_neg, u_pos, u_neg, compute_dtype)
     return SgnsGrads(
@@ -120,20 +150,24 @@ def sgns_grads(
 def sgns_d_center(
     c_pos: jax.Array,  # (B, C)
     c_neg: jax.Array,  # (B, C, n)
-    u_pos: jax.Array,  # (B, C, dl) — full d or a column slice of it
-    u_neg: jax.Array,  # (B, C, n, dl)
+    u_pos,  # C blocks of (B, dl) — full d or a column slice of it
+    u_neg,  # C * n blocks of (B, dl)
     compute_dtype=jnp.float32,
 ) -> jax.Array:
-    """d L/d h with the learning rate folded in (pure SGD step direction).
-    Columnwise-independent, so a dim-sharded shard passes its local column
-    slices and gets its local d_center slice — no communication."""
-    return jnp.einsum(
-        "bc,bcd->bd", c_pos.astype(compute_dtype),
-        u_pos.astype(compute_dtype), preferred_element_type=jnp.float32,
-    ) + jnp.einsum(
-        "bcn,bcnd->bd", c_neg.astype(compute_dtype),
-        u_neg.astype(compute_dtype), preferred_element_type=jnp.float32,
+    """d L/d h with the learning rate folded in (pure SGD step direction)."""
+    return row_sums(c_pos, u_pos, compute_dtype) + row_sums(
+        c_neg.reshape(c_neg.shape[0], -1), u_neg, compute_dtype
     )
+
+
+def gather_blocks(table: jax.Array, ids: jax.Array):
+    """Float32 rows of ``table`` for batch-major ``ids`` ``(B, ...)``, as
+    the K blocks of ``(B, d)`` :func:`row_dots` takes, block k the rows of
+    every batch row's k-th id: the ids are transposed, not the rows."""
+    return [
+        table[col].astype(jnp.float32)
+        for col in ids.reshape(ids.shape[0], -1).T
+    ]
 
 
 def init_tables(
@@ -170,7 +204,7 @@ class SharedSgnsGrads(NamedTuple):
 
 def shared_sgns_grads(
     h: jax.Array,  # (B, d) float32 — syn0 rows of the centers
-    u_pos: jax.Array,  # (B, C, d) float32 — syn1 rows of the contexts
+    u_pos,  # C blocks of (B, d) float32 — syn1 rows of the contexts
     u_pool: jax.Array,  # (S, d) float32 — syn1 rows of the shared pool
     mask: jax.Array,  # (B, C) float32
     collide: jax.Array,  # (B, S) float32 — 1.0 where pool word hits one of
@@ -205,10 +239,7 @@ def shared_sgns_grads(
     """
     hc = h.astype(compute_dtype)
     upool_c = u_pool.astype(compute_dtype)
-    f_pos = jnp.einsum(
-        "bd,bcd->bc", hc, u_pos.astype(compute_dtype),
-        preferred_element_type=jnp.float32,
-    )  # (B, C)
+    f_pos = row_dots(h, u_pos, compute_dtype)  # (B, C)
     f_pool = jnp.dot(
         hc, upool_c.T, preferred_element_type=jnp.float32
     )  # (B, S)
@@ -259,7 +290,7 @@ def shared_sgns_updates(
     c_pos: jax.Array,  # (B, C)
     c_pool: jax.Array,  # (B, S)
     h: jax.Array,  # (B, dl) — full d or a column slice
-    u_pos: jax.Array,  # (B, C, dl)
+    u_pos,  # C blocks of (B, dl)
     u_pool: jax.Array,  # (S, dl)
     compute_dtype=jnp.float32,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -267,10 +298,9 @@ def shared_sgns_updates(
     dim-sharded shard passes local column slices (see :func:`sgns_d_center`)."""
     cpool_c = c_pool.astype(compute_dtype)
     upool_c = u_pool.astype(compute_dtype)
-    d_center = jnp.einsum(
-        "bc,bcd->bd", c_pos.astype(compute_dtype),
-        u_pos.astype(compute_dtype), preferred_element_type=jnp.float32,
-    ) + jnp.dot(cpool_c, upool_c, preferred_element_type=jnp.float32)
+    d_center = row_sums(c_pos, u_pos, compute_dtype) + jnp.dot(
+        cpool_c, upool_c, preferred_element_type=jnp.float32
+    )
     d_pool = jnp.dot(
         cpool_c.T, h.astype(compute_dtype), preferred_element_type=jnp.float32
     )  # (S, dl)
@@ -327,8 +357,8 @@ def train_step(
     )
     compute = jnp.float32
     h = syn0[centers].astype(compute)
-    u_pos = syn1[contexts].astype(compute)
-    u_neg = syn1[negs].astype(compute)
+    u_pos = gather_blocks(syn1, contexts)
+    u_neg = gather_blocks(syn1, negs)
     nmask = negative_mask(negs, contexts, mask)
 
     g = sgns_grads(h, u_pos, u_neg, mask, nmask, alpha.astype(compute))
@@ -393,8 +423,8 @@ def sgns_loss(
         key, prob, alias, jnp.arange(B, dtype=jnp.int32), (C, num_negatives)
     )
     h = syn0[centers].astype(jnp.float32)
-    u_pos = syn1[contexts].astype(jnp.float32)
-    u_neg = syn1[negs].astype(jnp.float32)
+    u_pos = gather_blocks(syn1, contexts)
+    u_neg = gather_blocks(syn1, negs)
     nmask = negative_mask(negs, contexts, mask)
     g = sgns_grads(h, u_pos, u_neg, mask, nmask, jnp.float32(1.0))
     return g.loss

@@ -155,6 +155,24 @@ def _pull_rows(table_l, idx, start, rows_per_shard, table=None):
     return _exchange_sum(rows)
 
 
+#: A float32 array is tiled (8, 128) on a TPU: its second-minor axis is laid
+#: down in blocks of 8. An axis of 5 (the negatives) or 10 (a CBOW bag)
+#: beside d therefore pays for 8 or 16, and a reshape that puts it there
+#: is a copy of every row (PERF.md, PR 38).
+_SUBLANES = 8
+
+
+def _pull_blocks(table_l, ids, start, rows_per_shard, table=None):
+    """:func:`_pull_rows` for batch-major ``ids`` ``(B, ...)`` with a few
+    ids a batch row (a pair's contexts or negatives, a bag's slots): the
+    K blocks of ``(B, d)`` rows ``ops/sgns.row_dots`` takes, block k the
+    rows of the k-th id of every batch row. The ids are transposed, never
+    the rows: the batch axis stays beside d."""
+    with jax.named_scope("glint.gather"):
+        cols = list(ids.reshape(ids.shape[0], -1).T)
+    return [_pull_rows(table_l, c, start, rows_per_shard, table) for c in cols]
+
+
 def _exchange_sum(x):
     """The step's model-axis exchange: one psum, under ``glint.exchange``
     (``EmbeddingEngine.packed_exchange_bytes`` counts what it is handed)."""
@@ -675,23 +693,34 @@ class EmbeddingEngine:
             # (benchmark/program_trace.py). A fusion is filed under its
             # root's scope, an op under its OUTERMOST one: _pull_rows
             # opens its own two and is called outside any other.
-            h_rows = _pull_rows(
-                syn0_l, centers.reshape(-1), start, Vs, "syn0"
-            )
-            u_pos = _pull_rows(
-                syn1_l, contexts.reshape(-1), start, Vs, "syn1"
-            )
+            #
+            # Gathered rows keep the batch axis beside d (_pull_blocks). A
+            # group of whole tiles (the subword family's 32) is free
+            # group-major and is summed over its second-minor axis; any
+            # other (one row, a CBOW bag of 10) lies slot-major and is
+            # summed over the major one.
+            slot_major = S % _SUBLANES != 0
+            if slot_major:
+                h_rows = _pull_blocks(syn0_l, centers, start, Vs, "syn0")
+            else:
+                h_rows = _pull_rows(
+                    syn0_l, centers.reshape(-1), start, Vs, "syn0"
+                )
+            u_pos = _pull_blocks(syn1_l, contexts, start, Vs, "syn1")
             with jax.named_scope(compose):
-                h_rows = h_rows.reshape(Rl, S, -1)
                 cnt = jnp.maximum(
                     cmask.sum(axis=1, keepdims=True), 1.0
                 )  # (Rl,1)
-                h = (h_rows * cmask[..., None]).sum(axis=1) / cnt
+                if slot_major:
+                    h = sgns.row_sums(cmask, h_rows)
+                else:
+                    h = (
+                        h_rows.reshape(Rl, S, -1) * cmask[..., None]
+                    ).sum(axis=1)
+                h = h / cnt
                 if pair_run is not None:
                     h = h[pair_run]  # (Bl, d)
             with jax.named_scope("glint.gather"):
-                u_pos = u_pos.reshape(Bl, C, -1)
-
                 # The data-axis exchange ships ONLY h (B, d), scalar
                 # gradient coefficients, and int32 indices — the TPU
                 # restatement of the reference's defining ship-scalars
@@ -744,11 +773,7 @@ class EmbeddingEngine:
                     negs = sample_negatives_per_row_packed(
                         key, noise, V, rows_g, (C, n)
                     )
-                u_neg = _pull_rows(
-                    syn1_l, negs.reshape(-1), start, Vs, "syn1"
-                )
-                with jax.named_scope("glint.gather"):
-                    u_neg = u_neg.reshape(Bl, C, n, -1)
+                u_neg = _pull_blocks(syn1_l, negs, start, Vs, "syn1")
                 with jax.named_scope("glint.sample"):
                     nmask = sgns.negative_mask(negs, contexts, mask)
                 with jax.named_scope("glint.grads"):
@@ -829,17 +854,15 @@ class EmbeddingEngine:
             compose = "glint.compose" if S > 1 else "glint.gather"
 
             with jax.named_scope("glint.gather"), jax.named_scope("syn0"):
-                h_rows = syn0_l[centers.reshape(-1)].astype(jnp.float32)
+                h_rows = sgns.gather_blocks(syn0_l, centers)
             with jax.named_scope(compose):
-                h_rows = h_rows.reshape(Rl, S, -1)
                 cnt = jnp.maximum(cmask.sum(axis=1, keepdims=True), 1.0)
-                h = (h_rows * cmask[..., None]).sum(axis=1) / cnt  # (Rl, dl)
+                h = sgns.row_sums(cmask, h_rows) / cnt  # (Rl, dl)
                 if pair_run is not None:
                     h = h[pair_run]  # (Bl, dl)
             with jax.named_scope("glint.gather"):
                 with jax.named_scope("syn1"):
-                    u_pos = syn1_l[contexts.reshape(-1)].astype(jnp.float32)
-                u_pos = u_pos.reshape(Bl, C, -1)
+                    u_pos = sgns.gather_blocks(syn1_l, contexts)
 
                 h_g = lax.all_gather(h, DATA_AXIS, tiled=True)  # (B, dl)
 
@@ -853,10 +876,7 @@ class EmbeddingEngine:
                 with jax.named_scope("glint.sample"):
                     collide = sgns.pool_collision_mask(pool, contexts, mask)
                 with jax.named_scope("glint.grads"):
-                    f_pos = jnp.einsum(
-                        "bd,bcd->bc", h.astype(cd), u_pos.astype(cd),
-                        preferred_element_type=jnp.float32,
-                    )
+                    f_pos = sgns.row_dots(h, u_pos, cd)
                     f_pool = jnp.dot(
                         h.astype(cd), u_pool.astype(cd).T,
                         preferred_element_type=jnp.float32,
@@ -885,21 +905,13 @@ class EmbeddingEngine:
                     negs = sample_negatives_per_row_packed(
                         key, noise, V, rows_g, (C, n)
                     )
-                with jax.named_scope("glint.gather"):
-                    with jax.named_scope("syn1"):
-                        u_neg = syn1_l[negs.reshape(-1)].astype(jnp.float32)
-                    u_neg = u_neg.reshape(Bl, C, n, -1)
+                with jax.named_scope("glint.gather"), jax.named_scope("syn1"):
+                    u_neg = sgns.gather_blocks(syn1_l, negs)
                 with jax.named_scope("glint.sample"):
                     nmask = sgns.negative_mask(negs, contexts, mask)
                 with jax.named_scope("glint.grads"):
-                    f_pos = jnp.einsum(
-                        "bd,bcd->bc", h.astype(cd), u_pos.astype(cd),
-                        preferred_element_type=jnp.float32,
-                    )
-                    f_neg = jnp.einsum(
-                        "bd,bcnd->bcn", h.astype(cd), u_neg.astype(cd),
-                        preferred_element_type=jnp.float32,
-                    )
+                    f_pos = sgns.row_dots(h, u_pos, cd)
+                    f_neg = sgns.row_dots(h, u_neg, cd).reshape(Bl, C, n)
                 f_pos, f_neg = _exchange_sum(f_pos), _exchange_sum(f_neg)
                 with jax.named_scope("glint.grads"):
                     co = sgns.sgns_coefs(

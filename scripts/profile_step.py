@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from glint_word2vec_tpu.ops import sgns  # noqa: E402
+
 V, d, B, C, n, S = 1_000_000, 300, 8192, 7, 5, 4096
 
 
@@ -202,22 +204,18 @@ def main():
     @jax.jit
     def gen2(key):
         k1, k2 = jax.random.split(key)
+        # blocks of (B, d) rows, the small axes major (ops/sgns.row_dots)
         return (
-            jax.random.normal(k1, (B, C, d), jnp.float32),
-            jax.random.normal(k2, (B, C, n, d), jnp.float32),
+            jax.random.normal(k1, (C, B, d), jnp.float32),
+            jax.random.normal(k2, (C * n, B, d), jnp.float32),
         )
 
     u_pos, u_neg = gen2(jax.random.PRNGKey(1))
 
     def pp_einsums(hh, up, un):
-        f_pos = jnp.einsum("bd,bcd->bc", hh, up)
-        f_neg = jnp.einsum("bd,bcnd->bcn", hh, un)
-        cp = jax.nn.sigmoid(f_pos)
-        cn = jax.nn.sigmoid(f_neg)
-        return (
-            jnp.einsum("bc,bcd->bd", cp, up)
-            + jnp.einsum("bcn,bcnd->bd", cn, un)
-        ).sum()
+        cp = jax.nn.sigmoid(sgns.row_dots(hh, up))
+        cn = jax.nn.sigmoid(sgns.row_dots(hh, un))
+        return (sgns.row_sums(cp, up) + sgns.row_sums(cn, un)).sum()
 
     note("per_pair_einsums...")
     res["per_pair_einsums_us"] = timeit(jax.jit(pp_einsums), h, u_pos, u_neg)

@@ -41,6 +41,12 @@ def _numpy_shared_grads(h, u_pos, u_pool, mask, collide, alpha, n):
     return c_pos, c_pool, d_center, d_pool, loss
 
 
+def _blocks(u):
+    """Batch-major rows ``(B, ..., d)`` as the ``(K, B, d)`` blocks
+    ``ops/sgns.row_dots`` takes (the engine transposes the ids instead)."""
+    return jnp.moveaxis(u.reshape(u.shape[0], -1, u.shape[-1]), 1, 0)
+
+
 def test_shared_grads_match_numpy_reference():
     rng = np.random.default_rng(0)
     B, C, S, d, n = 4, 3, 6, 8, 5
@@ -52,7 +58,7 @@ def test_shared_grads_match_numpy_reference():
     alpha = 0.05
 
     g = sgns.shared_sgns_grads(
-        jnp.asarray(h), jnp.asarray(u_pos), jnp.asarray(u_pool),
+        jnp.asarray(h), _blocks(jnp.asarray(u_pos)), jnp.asarray(u_pool),
         jnp.asarray(mask), jnp.asarray(collide), jnp.float32(alpha), n,
     )
 
@@ -166,6 +172,7 @@ def test_bf16_compute_dtype_close_to_f32():
     collide = jnp.zeros((B, Sp), jnp.float32)
     a = jnp.float32(0.05)
 
+    u_pos = _blocks(u_pos)
     g32 = S.shared_sgns_grads(h, u_pos, u_pool, mask, collide, a, n)
     g16 = S.shared_sgns_grads(
         h, u_pos, u_pool, mask, collide, a, n, compute_dtype=jnp.bfloat16
@@ -178,7 +185,8 @@ def test_bf16_compute_dtype_close_to_f32():
         atol=5e-4,
     )
 
-    u_neg = jnp.asarray(rng.normal(0, 0.5, (B, C, n, d)).astype(np.float32))
+    u_neg = _blocks(
+        jnp.asarray(rng.normal(0, 0.5, (B, C, n, d)).astype(np.float32)))
     nmask = jnp.asarray((rng.random((B, C, n)) < 0.9).astype(np.float32))
     p32 = S.sgns_grads(h, u_pos, u_neg, mask, nmask, a)
     p16 = S.sgns_grads(

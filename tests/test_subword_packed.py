@@ -298,12 +298,17 @@ def test_the_grid_scan_forms_its_centres_from_the_group_table():
 # sort stands before the choice of writer and the run totals inside XLA's
 # (the same ops in another nesting: the tables a fit makes are the parent's
 # bit for bit; the grid scans of `dims` and of the subword family lower as
-# they did).
+# they did). All sixteen taken again on the tree of ISSUE 38, which meant to
+# change every step program: the ids are transposed before the gather, the
+# rows come a block of (batch, d) a gather, and the logits and `d_center`
+# are multiplies and sums over those blocks where they were einsums over
+# `(B, C, n, d)` (`tests/test_row_blocks.py` holds the scatters to what
+# the parent formulation handed them).
 WORD_LEVEL_PROGRAMS = {
-    ((1, 1), "rows"): ("cca2bb49c69043a3", "8c86ea10d93d9cf5"),
-    ((1, 2), "rows"): ("4ceb32cf7a2e55b3", "46a07c3670bc3f45"),
-    ((2, 2), "rows"): ("68db1ff890af0a9e", "5ee579c9bcb40def"),
-    ((1, 2), "dims"): ("e5a7d5465db9d195", "82a09710cd4cecac"),
+    ((1, 1), "rows"): ("06c9e22caed12cf7", "124ae8075d865073"),
+    ((1, 2), "rows"): ("9cfb9ebcff5e7f47", "0550e22a6e53853a"),
+    ((2, 2), "rows"): ("6a4989188a2c551a", "086f184fadd4d1d0"),
+    ((1, 2), "dims"): ("eae80f04913a1961", "16b11e49808ec3ed"),
 }
 
 
@@ -343,12 +348,13 @@ def test_a_word_level_fit_lowers_to_the_program_it_lowered_to(shape, layout):
 # on the parent of ISSUE 34 (069d339), which gave the step bodies a flag for
 # CBOW's undivided gradient and the scan factory a second scan: a skip-gram
 # fit, word level and subword, must lower to the program it lowered to.
-# The packed scans' hashes taken again on ISSUE 35's tree, as above.
+# The packed scans' hashes taken again on ISSUE 35's tree, and all eight on
+# ISSUE 38's, as above.
 SUBWORD_PROGRAMS = {
-    ((1, 1), "rows"): ("124e2b97fab96a74", "b4a25e1e65d61771"),
-    ((1, 2), "rows"): ("eda3a8b61f767fa2", "959ac1e1767884d6"),
-    ((2, 2), "rows"): ("fb226a3bd543d5a3", "f276e47581b1ff89"),
-    ((1, 2), "dims"): ("77ad1071e11cc2e7", "44cec185549ed410"),
+    ((1, 1), "rows"): ("5435cb76a767556f", "5e7e6d3939851660"),
+    ((1, 2), "rows"): ("0198c0fa9efbef21", "2dbc1d5a91874ef4"),
+    ((2, 2), "rows"): ("d0c7f6ba02063a7c", "653f273411458e62"),
+    ((1, 2), "dims"): ("c8c5a48cd3b85a5c", "16aaa556b419b3b6"),
 }
 
 
