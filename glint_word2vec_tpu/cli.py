@@ -58,7 +58,11 @@ def _add_train(sub):
                    help="the model: skip-gram (default) or CBOW with "
                         "negative sampling (word2vec's -cbow 1: the mean "
                         "of a position's context rows predicts its word; "
-                        "corpus-resident path only, saved with the model)")
+                        "with --fasttext, `fasttext cbow`: one mean over "
+                        "the subword rows of the bag's words). CBOW takes "
+                        "the corpus-resident path only and is refused "
+                        "with --shared-negatives, --packing grid or "
+                        "--exchange; saved with the model")
     p.add_argument("--shared-negatives", type=int, default=0,
                    help="shared noise-pool size per step "
                         "(0 = per-pair reference semantics)")

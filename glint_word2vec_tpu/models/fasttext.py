@@ -8,6 +8,13 @@ device-resident group table, ``EmbeddingEngine.upload_center_groups``; by
 ``train_steps_grouped`` under the host batcher), and word
 vectors — including OOV words, which the word-level reference cannot
 represent at all — compose on device via ``pull_average``.
+
+Both of fastText's unsupervised commands train here: ``architecture=
+"skipgram"`` (``fasttext skipgram``) and ``"cbow"`` (``fasttext cbow``: one
+mean over the rows of every word of a position's bag predicts the
+position's word, ``ops/cbow_subword_reference.py``), the latter on the
+corpus-resident packed path only, as at word level. A word's vector is its
+group's mean whichever trained it.
 """
 
 from __future__ import annotations
@@ -31,7 +38,11 @@ from glint_word2vec_tpu.utils.params import Word2VecParams, _require
 
 @dataclass
 class FastTextParams(Word2VecParams):
-    """Word2Vec params + subword geometry (fastText conventions)."""
+    """Word2Vec params + subword geometry (fastText conventions). Both
+    architectures train (see the module docstring); what ``architecture=
+    "cbow"`` refuses at word level (a shared pool, grid packing, replica
+    exchange, the streaming trainer, any fit the corpus-resident path
+    does not take) it refuses here."""
 
     min_n: int = 3
     max_n: int = 6
@@ -43,17 +54,13 @@ class FastTextParams(Word2VecParams):
         _require(0 < self.min_n <= self.max_n, "need 0 < min_n <= max_n")
         _require(self.bucket > 0, "bucket must be > 0")
         _require(self.max_subwords >= 2, "max_subwords must be >= 2")
-        _require(
-            self.architecture == "skipgram",
-            "the subword family trains skip-gram only: a CBOW bag over "
-            "subword groups is not supported",
-        )
 
 
 class FastTextWord2Vec(Word2Vec):
-    """Subword SGNS estimator. Same fluent surface as Word2Vec, plus
-    subword knobs; fit() shares the full word-level training loop
-    (LR anneal, metrics, checkpoint/resume) via the family hooks."""
+    """Subword estimator, skip-gram or CBOW. Same fluent surface as
+    Word2Vec, plus subword knobs; fit() shares the full word-level
+    training loop (LR anneal, metrics, checkpoint/resume) via the family
+    hooks."""
 
     def __init__(self, params: Optional[FastTextParams] = None, mesh=None, **kw):
         super().__init__(params or FastTextParams(), mesh=mesh, **kw)
@@ -103,6 +110,7 @@ class FastTextWord2Vec(Word2Vec):
             shared_negatives=p.shared_negatives,
             compute_dtype=p.compute_dtype,
             layout=p.layout,
+            architecture=p.architecture,
         )
 
     def _train_batches(self, engine, group, base_key, step0, alphas):

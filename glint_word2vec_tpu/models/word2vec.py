@@ -745,8 +745,11 @@ class Word2Vec:
             packed_groups = packed_pairs = packed_slots = 0
             # distinct rows written (syn0, syn1), slabs moved (syn0, syn1);
             # a subword fit also: live group ids gathered, centres formed;
-            # a CBOW fit: live bag slots, positions trained
-            rows_written = np.zeros(6, np.int64)
+            # a CBOW fit: live bag slots, positions trained; a subword
+            # CBOW fit, after those: live group ids gathered, span words
+            # composed, input rows (EmbeddingEngine.train_steps_corpus_
+            # packed)
+            rows_written = np.zeros(9, np.int64)
             early_stop = False
 
             state_path = (
@@ -1289,12 +1292,26 @@ class Word2Vec:
                     pair_batch, p.window),
             )
             if cbow and rows_written[5]:
-                # Rows a bag is the mean of: live bag slots over the
+                # Words a bag is the mean of: live bag slots over the
                 # positions that trained.
                 model.training_metrics.update(
                     cbow_rows_per_bag=round(
                         rows_written[4] / rows_written[5], 4),
                 )
+                if rows_written[7]:
+                    # A subword fit: rows a composed word is the sum of
+                    # (live group ids over the span words composed), the
+                    # rows a bag's mean is over (fastText's input.size(),
+                    # a position trained), and the group rows a step
+                    # gathered, each once for all the bags it is in.
+                    model.training_metrics.update(
+                        subword_rows_per_center=round(
+                            rows_written[6] / rows_written[7], 4),
+                        cbow_input_rows_per_bag=round(
+                            rows_written[8] / rows_written[5], 4),
+                        subword_rows_per_step=round(
+                            rows_written[6] * pair_batch / packed_slots, 4),
+                    )
             elif rows_written[5]:
                 # Rows a subword centre is the mean of: live group ids the
                 # packed steps gathered over the centres they formed.

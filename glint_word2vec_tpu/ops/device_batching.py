@@ -225,26 +225,41 @@ def _span_slice(arr: jax.Array, first, length: int, fill) -> jax.Array:
     return lax.dynamic_slice(padded, (length + (first - at),), (length,))
 
 
+def _span_record(ids, sent_of, pos, length: int, lanes: list):
+    """The words and the sentences of the ``length`` positions from ``pos``
+    and the reach of their lanes, ``[pos + lo, pos + length + hi)``, read
+    from the view and its per-position record as two slices: ``(words,
+    sentences, lo)``, position t of the span at index ``t - lo``."""
+    lo, hi = min(lanes + [0]), max(lanes + [0])
+    ids_x = _span_slice(ids, pos + lo, length + hi - lo, 0)
+    sent_x = _span_slice(sent_of, pos + lo, length + hi - lo, -1)
+    return ids_x, sent_x, lo
+
+
+def _lanes_in_sentence(sent_x, length: int, lo: int, lanes: list):
+    """``(length, len(lanes))``: whether lane k of position t lies in t's
+    sentence, which it does iff the two records are equal."""
+    own = sent_x[-lo:length - lo]
+    # A sentence index is >= 0 wherever offsets[0] == 0; before a first
+    # offset the search finds no sentence either.
+    return (own[:, None] >= 0) & (
+        jnp.stack([sent_x[o - lo:o - lo + length] for o in lanes], axis=1)
+        == own[:, None]
+    )
+
+
 def _span_lanes(ids, sent_of, pos, length: int, lanes: list):
     """The ``length`` positions from ``pos`` and their context lanes, read
     from the view and its per-position record as slices: ``(words
     (length,), lane words (length, len(lanes)), in_sentence)``, lane k of
-    position t the word at ``t + lanes[k]`` and in its sentence iff the two
-    records are equal. Nothing is searched or gathered."""
-    # The span and the reach of its lanes, [pos + lo, pos + length + hi).
-    lo, hi = min(lanes + [0]), max(lanes + [0])
-    ids_x = _span_slice(ids, pos + lo, length + hi - lo, 0)
-    sent_x = _span_slice(sent_of, pos + lo, length + hi - lo, -1)
-    own = sent_x[-lo:length - lo]
-    shifted = [o - lo for o in lanes]
-    # A sentence index is >= 0 wherever offsets[0] == 0; before a first
-    # offset the search finds no sentence either.
-    in_sentence = (own[:, None] >= 0) & (
-        jnp.stack([sent_x[a:a + length] for a in shifted], axis=1)
-        == own[:, None]
-    )
+    position t the word at ``t + lanes[k]``. Nothing is searched or
+    gathered."""
+    ids_x, sent_x, lo = _span_record(ids, sent_of, pos, length, lanes)
+    in_sentence = _lanes_in_sentence(sent_x, length, lo, lanes)
     words = ids_x[-lo:length - lo]
-    lane_words = jnp.stack([ids_x[a:a + length] for a in shifted], axis=1)
+    lane_words = jnp.stack(
+        [ids_x[o - lo:o - lo + length] for o in lanes], axis=1
+    )
     return words, lane_words, in_sentence
 
 
@@ -407,6 +422,21 @@ def bag_lanes(window: int) -> list:
     return [o for o in range(-window, window + 1) if o]
 
 
+def _bag_valid(positions, in_corpus, in_sentence, offs, base_key, grid_batch,
+               grid_step0, W: int, n_valid):
+    """``(B, 2W)``: which lanes are in their position's bag. The position
+    draws its shrink, and a lane is in iff it is within reach, in the
+    position's sentence, and both lie inside the view."""
+    b = grid_window_shrink(base_key, positions, grid_batch, grid_step0, W)
+    return (
+        (jnp.abs(offs)[None, :] <= (W - b)[:, None])
+        & in_sentence
+        & in_corpus[:, None]
+        # a bounded view's sentence may run past its live prefix
+        & (positions[:, None] + offs[None, :] < n_valid)
+    )
+
+
 def bag_window_batch(
     ids: jax.Array,  # (N,) int32 flat corpus (active view)
     sent_of: jax.Array,  # (N,) int32 position_sentences of the view
@@ -448,18 +478,61 @@ def bag_window_batch(
     positions = pos + jnp.arange(B, dtype=jnp.int32)
     in_corpus = (positions >= 0) & (positions < n_valid)
     words, lane_ids, in_sentence = _span_lanes(ids, sent_of, pos, B, lanes)
-    b = grid_window_shrink(base_key, positions, grid_batch, grid_step0, W)
-    valid = (
-        (jnp.abs(offs)[None, :] <= (W - b)[:, None])
-        & in_sentence
-        & in_corpus[:, None]
-        # a bounded view's sentence may run past its live prefix
-        & (positions[:, None] + offs[None, :] < n_valid)
-    )  # (B, 2W)
+    valid = _bag_valid(
+        positions, in_corpus, in_sentence, offs, base_key, grid_batch,
+        grid_step0, W, n_valid,
+    )
     centres = jnp.where(in_corpus, words, 0).astype(jnp.int32)
     bags = jnp.where(valid, lane_ids, -1).astype(jnp.int32)
     live = valid.any(axis=1)
     return centres, bags, valid.astype(jnp.float32), live.astype(jnp.float32)
+
+
+def bag_span_batch(
+    ids: jax.Array,
+    sent_of: jax.Array,
+    pos,
+    base_key: jax.Array,
+    grid_step0,
+    *,
+    window: int,
+    batch: int,
+    grid_batch: int,
+    n_valid,
+):
+    """:func:`bag_window_batch` for a step that forms each word of the
+    batch's SPAN once and lets every bag it is in read it (the subword
+    family's CBOW: a word is a group of rows). The same positions, draws
+    and rule, and so the same bags; what is returned names a bag's words
+    by where they stand, not by what they are.
+
+    Returns ``(centres (B,), span words (B + 2 * window,), mask (B, 2 *
+    window), live (B,))``: the words of the positions ``[pos - window, pos
+    + B + window)``, -1 outside the view; ``mask[t, k]`` is 1 iff lane k of
+    position t is in its bag, and the word it reads is then span word
+    ``t + window + bag_lanes(window)[k]``.
+    """
+    W, B = window, batch
+    lanes = bag_lanes(W)
+    offs = jnp.asarray(lanes, dtype=jnp.int32)
+    positions = pos + jnp.arange(B, dtype=jnp.int32)
+    in_corpus = (positions >= 0) & (positions < n_valid)
+    ids_x, sent_x, lo = _span_record(ids, sent_of, pos, B, lanes)
+    in_sentence = _lanes_in_sentence(sent_x, B, lo, lanes)
+    valid = _bag_valid(
+        positions, in_corpus, in_sentence, offs, base_key, grid_batch,
+        grid_step0, W, n_valid,
+    )
+    centres = jnp.where(in_corpus, ids_x[W:W + B], 0).astype(jnp.int32)
+    span_at = pos - W + jnp.arange(B + 2 * W, dtype=jnp.int32)
+    span_words = jnp.where(
+        (span_at >= 0) & (span_at < n_valid), ids_x, -1
+    ).astype(jnp.int32)
+    live = valid.any(axis=1)
+    return (
+        centres, span_words, valid.astype(jnp.float32),
+        live.astype(jnp.float32),
+    )
 
 
 def center_runs(pcenters: jax.Array, pmask: jax.Array, n_runs: int):

@@ -395,6 +395,30 @@ TOPK_MIN_Q_BUCKET = 8
 ANN_MAX_Q = 16
 
 
+def _bag_sums(lanes, rows):
+    """``(Bl, ...)``: row t the masked sum of a bag's lanes over composed
+    ``rows (Bl + reach, ...)``. ``lanes`` is ``(starts, lmask (Bl, L))``:
+    lane k of batch row t reads ``rows[t + starts[k]]`` where
+    ``lmask[t, k]``. Batch rows are consecutive positions, so a lane is
+    the whole array shifted: L masked adds of slices, no gather."""
+    starts, lmask = lanes
+    Bl = lmask.shape[0]
+    return sum(
+        lmask[:, k, None] * rows[a:a + Bl] for k, a in enumerate(starts)
+    )
+
+
+def _bag_spread(lanes, e, n_rows: int):
+    """The transpose of :func:`_bag_sums`: ``(n_rows, d)``, row p the sum
+    of ``e[t]`` over the batch rows t whose bag holds p."""
+    starts, lmask = lanes
+    Bl = e.shape[0]
+    return sum(
+        jnp.pad(lmask[:, k, None] * e, ((a, n_rows - Bl - a), (0, 0)))
+        for k, a in enumerate(starts)
+    )
+
+
 def _pair_payload(ctx_g, negs_g, cpos_g, cneg_g, h_g):
     """``(ids, coefs, src, hidx)`` of the per-pair syn1 update for
     :func:`_scatter_rows`: slot order [contexts.flat | negs.flat]
@@ -464,7 +488,8 @@ class EmbeddingEngine:
     ):
         """``architecture`` is the model's (``Word2VecParams.architecture``):
         a ``"cbow"`` engine trains through :meth:`train_steps_corpus_packed`
-        alone, whose scan then forms bags (``make_packed_corpus_scan``).
+        alone, whose scan then forms bags (``make_packed_corpus_scan``): of
+        words or, while the engine holds a group table, of their groups.
 
         ``extra_rows`` appends non-vocabulary rows to both tables (e.g.
         fastText char-ngram buckets, models/fasttext.py): they are trained
@@ -664,7 +689,7 @@ class EmbeddingEngine:
 
         def step_body_rows(syn0_l, syn1_l, noise, centers, cmask,
                            contexts, mask, key, alpha, pair_run=None,
-                           mean_gradient=True):
+                           mean_gradient=True, lanes=None):
             # Data-sharded inputs: centers/cmask (Rl, S), contexts/mask
             # (Bl, C). S = subword-group width; word-level training is the
             # S=1 specialization. The center representation is the masked
@@ -678,6 +703,14 @@ class EmbeddingEngine:
             # one "context" (C = 1) the position's own word, and
             # ``mean_gradient`` False: every row of the bag takes the
             # whole gradient, as word2vec.c adds the undivided neu1e.
+            # ``lanes`` (:func:`_bag_sums`) generalises ``pair_run`` from
+            # "row i's hidden is group ``pair_run[i]``" to "row t's hidden
+            # is the masked SUM of the groups its lanes name over the sum
+            # of their counts": fastText's CBOW, one mean over the rows of
+            # every word of the bag, each word's group summed once
+            # (``group``) and read by every bag it is in (``bag``). The
+            # whole gradient goes back the same way (``mean_gradient`` is
+            # False with it).
             Rl, S = centers.shape
             Bl, C = contexts.shape
             # What a grouped centre adds to the step has a scope of its
@@ -708,15 +741,21 @@ class EmbeddingEngine:
                 )
             u_pos = _pull_blocks(syn1_l, contexts, start, Vs, "syn1")
             with jax.named_scope(compose):
-                cnt = jnp.maximum(
-                    cmask.sum(axis=1, keepdims=True), 1.0
-                )  # (Rl,1)
-                if slot_major:
-                    h = sgns.row_sums(cmask, h_rows)
-                else:
-                    h = (
-                        h_rows.reshape(Rl, S, -1) * cmask[..., None]
-                    ).sum(axis=1)
+                with (jax.named_scope("group") if lanes
+                      else contextlib.nullcontext()):
+                    cnt = cmask.sum(axis=1, keepdims=True)  # (Rl,1)
+                    if lanes is None:
+                        cnt = jnp.maximum(cnt, 1.0)
+                    if slot_major:
+                        h = sgns.row_sums(cmask, h_rows)
+                    else:
+                        h = (
+                            h_rows.reshape(Rl, S, -1) * cmask[..., None]
+                        ).sum(axis=1)
+                if lanes is not None:
+                    with jax.named_scope("bag"):
+                        cnt = jnp.maximum(_bag_sums(lanes, cnt), 1.0)
+                        h = _bag_sums(lanes, h)  # (Bl, d)
                 h = h / cnt
                 if pair_run is not None:
                     h = h[pair_run]  # (Bl, d)
@@ -800,6 +839,9 @@ class EmbeddingEngine:
                     d_center = jnp.zeros(
                         (Rl, d_center.shape[1]), jnp.float32
                     ).at[pair_run].add(d_center, indices_are_sorted=True)
+            if lanes is not None:
+                with jax.named_scope(compose), jax.named_scope("bag"):
+                    d_center = _bag_spread(lanes, d_center, Rl)
             with jax.named_scope("glint.grads"):
                 dcen_g = lax.all_gather(
                     d_center / cnt if mean_gradient else d_center,
@@ -835,7 +877,7 @@ class EmbeddingEngine:
 
         def step_body_dims(syn0_l, syn1_l, noise, centers, cmask,
                            contexts, mask, key, alpha, pair_run=None,
-                           mean_gradient=True):
+                           mean_gradient=True, lanes=None):
             # Column-sharded step (CIKM'16 partitioning, SURVEY.md §2.2):
             # tables are (V, dl) local column slices with EVERY row
             # resident, so gathers and scatter-adds are shard-local. The
@@ -844,9 +886,9 @@ class EmbeddingEngine:
             # servers return from ``dotprod``. The data-axis exchange is
             # the same scalars+h contract as the rows layout, with h now
             # a (B, dl) column slice (1/n the bytes per chip).
-            # Groups, ``pair_run``, ``mean_gradient`` and the compose scope
-            # as in step_body_rows; a padding id (-1) reads a row the mask
-            # drops.
+            # Groups, ``pair_run``, ``mean_gradient``, ``lanes`` and the
+            # compose scope as in step_body_rows; a padding id (-1) reads a
+            # row the mask drops.
             Rl, S = centers.shape
             Bl, C = contexts.shape
             drank = lax.axis_index(DATA_AXIS)
@@ -856,8 +898,16 @@ class EmbeddingEngine:
             with jax.named_scope("glint.gather"), jax.named_scope("syn0"):
                 h_rows = sgns.gather_blocks(syn0_l, centers)
             with jax.named_scope(compose):
-                cnt = jnp.maximum(cmask.sum(axis=1, keepdims=True), 1.0)
-                h = sgns.row_sums(cmask, h_rows) / cnt  # (Rl, dl)
+                if lanes is None:
+                    cnt = jnp.maximum(cmask.sum(axis=1, keepdims=True), 1.0)
+                    h = sgns.row_sums(cmask, h_rows) / cnt  # (Rl, dl)
+                else:
+                    with jax.named_scope("group"):
+                        cnt = cmask.sum(axis=1, keepdims=True)
+                        h = sgns.row_sums(cmask, h_rows)
+                    with jax.named_scope("bag"):
+                        cnt = jnp.maximum(_bag_sums(lanes, cnt), 1.0)
+                        h = _bag_sums(lanes, h) / cnt  # (Bl, dl)
                 if pair_run is not None:
                     h = h[pair_run]  # (Bl, dl)
             with jax.named_scope("glint.gather"):
@@ -934,6 +984,9 @@ class EmbeddingEngine:
                     d_center_l = jnp.zeros(
                         (Rl, d_center_l.shape[1]), jnp.float32
                     ).at[pair_run].add(d_center_l, indices_are_sorted=True)
+            if lanes is not None:
+                with jax.named_scope(compose), jax.named_scope("bag"):
+                    d_center_l = _bag_spread(lanes, d_center_l, Rl)
             with jax.named_scope("glint.grads"):
                 dcen_g = lax.all_gather(
                     d_center_l / cnt if mean_gradient else d_center_l,
@@ -1136,6 +1189,8 @@ class EmbeddingEngine:
             # A CBOW engine (``architecture``) gets the position-major
             # scan below instead: ``P`` is then the positions of a step.
             from glint_word2vec_tpu.ops.device_batching import (
+                bag_lanes,
+                bag_span_batch,
                 bag_window_batch,
                 center_runs,
                 device_words_done,
@@ -1160,7 +1215,7 @@ class EmbeddingEngine:
                                       soffs, orig_offs, n_valid, pstart,
                                       base_key, step0, grid_step0,
                                       step_size, inv_total_words,
-                                      words_base):
+                                      words_base, groups=None):
                 # CBOW: step i trains the P consecutive positions from
                 # ``pos``, each rank its own Pl of them. A position's bag
                 # (ops/device_batching.bag_window_batch) is the step
@@ -1174,13 +1229,27 @@ class EmbeddingEngine:
                 # live bag slots, and ``written`` carries them again with
                 # the positions trained, as the subword scan appends its
                 # two counts.
+                #
+                # With a group table (``G`` > 0: fastText's CBOW) a bag's
+                # word is its group of rows and the bag's mean is ONE mean
+                # over all of them. The step body's groups are then the
+                # words of this rank's span, ``Pl + 2 * W`` of them, each
+                # gathered and summed once; a position's bag is ``lanes``
+                # into them (``bag_span_batch``), so a composed word is
+                # shared by the bags it is in, where the skip-gram scan's
+                # ``pair_run`` shares one among the pairs of a run.
+                # ``written`` then appends the live group ids gathered, the
+                # span words composed, and the input rows: the rows a
+                # bag's mean is over, summed over the positions.
                 drank = lax.axis_index(DATA_AXIS)
 
                 def body(carry, i):
                     s0, s1, pos = carry
                     with jax.named_scope("glint.batch"):
                         key = jax.random.fold_in(base_key, step0 + i)
-                        c_l, bag, cmask, live = bag_window_batch(
+                        c_l, bag, cmask, live = (
+                            bag_span_batch if G else bag_window_batch
+                        )(
                             ids, sent_of, pos + drank * Pl, base_key,
                             grid_step0, window=W, batch=Pl,
                             grid_batch=B_grid, n_valid=n_valid,
@@ -1196,10 +1265,34 @@ class EmbeddingEngine:
                                 live.sum(dtype=jnp.int32),
                             ]), DATA_AXIS,
                         )
+                    lanes = None
+                    if G:
+                        # ``bag`` holds the span's words so far, ``cmask``
+                        # the lanes' mask: from here they are the step
+                        # body's, the span words' groups and their mask.
+                        lanes = ([W + o for o in bag_lanes(W)], cmask)
+                        with jax.named_scope("glint.compose"):
+                            with jax.named_scope("group"):
+                                composed = bag >= 0
+                                bag = jnp.where(
+                                    composed[:, None], groups[bag], -1
+                                )
+                                cmask = (bag >= 0).astype(jnp.float32)
+                            with jax.named_scope("bag"):
+                                input_rows = _bag_sums(
+                                    lanes, cmask.sum(axis=1, keepdims=True)
+                                ).sum()
+                            formed = jnp.concatenate([formed, lax.psum(
+                                jnp.stack([
+                                    cmask.sum(dtype=jnp.int32),
+                                    composed.sum(dtype=jnp.int32),
+                                    input_rows.astype(jnp.int32),
+                                ]), DATA_AXIS,
+                            )])
                     s0, s1, loss, written = step_body(
                         s0, s1, noise, bag, cmask,
                         c_l[:, None], live[:, None], key, alpha,
-                        mean_gradient=False,
+                        mean_gradient=False, lanes=lanes,
                     )
                     return (s0, s1, pos_end), (
                         loss, formed[0], pos_end, alpha,
@@ -1816,8 +1909,9 @@ class EmbeddingEngine:
         its n-gram bucket rows), padded with -1, an id no shard owns.
         While a table is held the corpus scans form every centre from its
         group inside the jitted scan (the packed scan once a run of pairs:
-        ``make_packed_corpus_scan``); ``None`` drops it, and the scans are
-        the word-level programs again."""
+        ``make_packed_corpus_scan``), and a CBOW engine's packed scan every
+        word of a bag from its group, once a step; ``None`` drops it, and
+        the scans are the word-level programs again."""
         if groups is None:
             self._center_groups = None
             return
@@ -2103,6 +2197,12 @@ class EmbeddingEngine:
         apply. ``pair_counts`` reads the live bag slots of a step and
         ``rows_written`` is ``(K, 6)``: then the live bag slots again and
         the positions that trained (inside the corpus, bag not empty).
+        While it holds a group table a bag's word is its group
+        (fastText's CBOW) and ``rows_written`` is ``(K, 9)``: then the
+        live group ids the step gathered, the span words they composed
+        (each rank's ``pair_batch / num_data + 2 * window``, less what
+        lies outside the view), and the input rows, the rows a bag's mean
+        is over, summed over the step's positions.
         """
         if getattr(self, "_corpus", None) is None:
             raise ValueError("no corpus uploaded (call upload_corpus first)")
@@ -2111,11 +2211,6 @@ class EmbeddingEngine:
         P, W, B = int(pair_batch), int(window), int(grid_batch)
         cbow = self.architecture == "cbow"
         C = 1 if cbow else context_width(W)
-        if cbow and self._group_width:
-            raise ValueError(
-                "a CBOW bag over subword groups is not supported: drop the "
-                "group table (upload_center_groups(None))"
-            )
         if P % self.num_data:
             raise ValueError(
                 f"pair batch {P} not divisible by data axis {self.num_data}"
@@ -2172,10 +2267,12 @@ class EmbeddingEngine:
         ``syn0`` scatter, over all data ranks: a centre a pair, or, while a
         group table is held, the whole group (padding included) of every
         run slot of the default span (``make_packed_corpus_scan``); on a
-        CBOW engine the ``2 * window`` lanes of every position's bag."""
+        CBOW engine the ``2 * window`` lanes of every position's bag, or
+        the whole group of every word of every rank's span."""
         G = self._group_width
         if self.architecture == "cbow":
-            return pair_batch * 2 * window
+            return G * (pair_batch + self.num_data * 2 * window) if G else (
+                pair_batch * 2 * window)
         if not G:
             return pair_batch
         from glint_word2vec_tpu.corpus.batching import context_width

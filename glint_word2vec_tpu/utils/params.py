@@ -80,10 +80,14 @@ class Word2VecParams:
         words with negative sampling, the word2vec tool's own default,
         ``-cbow 1``: the mean of a position's context rows predicts the
         position's word, and every context row takes the whole gradient).
-        A property of the model, saved with it. CBOW trains on the
-        corpus-resident packed path only (ops/device_batching
-        .bag_window_batch), draws its negatives a position, and is
-        refused with a shared pool, grid packing or replica exchange.
+        A property of the model, saved with it. Both families train
+        both: with ``FastTextParams`` a bag's word is its subword group
+        and the mean is one mean over every row of the bag (``fasttext
+        cbow``). CBOW trains on the corpus-resident packed path only
+        (ops/device_batching.bag_window_batch), draws its negatives a
+        position, and is refused with a shared pool, grid packing,
+        replica exchange, the streaming trainer, or a fit that path does
+        not take (there is no host-batcher CBOW).
     """
 
     vector_size: int = 100

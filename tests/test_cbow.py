@@ -441,8 +441,9 @@ def test_what_cannot_train_cbow_says_so(monkeypatch):
         Word2Vec(architecture="cbow", batch_packing="grid")
     with pytest.raises(ValueError, match="exchange"):
         Word2Vec(architecture="cbow", exchange="sparse")
-    with pytest.raises(ValueError, match="subword"):
-        FastTextWord2Vec(architecture="cbow", bucket=100)
+    # the subword family trains it too (tests/test_cbow_subword.py)
+    assert FastTextWord2Vec(
+        architecture="cbow", bucket=100).params.architecture == "cbow"
     with pytest.raises(ValueError, match="streaming"):
         _w2v().fit_stream(iter(CORPUS))
     # a fit the corpus-resident path does not take is refused, not routed
@@ -454,7 +455,7 @@ def test_what_cannot_train_cbow_says_so(monkeypatch):
         _w2v().fit(iter(CORPUS))
     monkeypatch.delenv("GLINT_HOST_BATCHER")
     # the engine's skip-gram entries refuse a CBOW engine, and a CBOW
-    # engine a group table or a shared pool
+    # engine a shared pool
     eng = engine((1, 1))
     with pytest.raises(ValueError, match="train_steps_corpus_packed"):
         eng.train_steps(np.zeros((1, 8), np.int32),
@@ -467,24 +468,51 @@ def test_what_cannot_train_cbow_says_so(monkeypatch):
     with pytest.raises(ValueError, match="shared_negatives"):
         EmbeddingEngine(make_mesh(1, 1), V, D, np.ones(V, np.int64),
                         shared_negatives=64, architecture="cbow")
-    groups = np.full((V, 2), -1, np.int32)
-    groups[:, 0] = np.arange(V)
-    eng.upload_center_groups(groups)
-    with pytest.raises(ValueError, match="subword"):
-        eng.train_steps_corpus_packed(0, 8, 2, 8, jax.random.PRNGKey(0), 1)
+
+
+def lowered(eng):
+    """The CBOW packed scan an engine builds, lowered."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype,
+            sharding=NamedSharding(eng.mesh, PartitionSpec(*spec)))
+
+    table = sds(eng.syn0.shape, jnp.float32, *eng.syn0.sharding.spec)
+    i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
+    words, offs = sds((900,), jnp.int32), sds((61,), jnp.int32)
+    return eng._make_packed_corpus_scan(BATCH, WINDOW, BATCH, 0, K).lower(
+        table, table, sds((-(-V // 64), 128), jnp.int32), words, words, offs,
+        offs, i32, i32, sds((2,), jnp.uint32), u32, u32, f32, f32, f32)
+
+
+# sha256[:16] of the lowered word-level CBOW scan's StableHLO text, by mesh
+# and layout, as tests/test_subword_packed.py holds the skip-gram scans':
+# taken on the parent of ISSUE 39 (a47d4f9), which gave the step bodies
+# ``lanes`` and this scan a group table for fastText's CBOW. A word-level
+# CBOW fit must lower to the program it lowered to.
+CBOW_PROGRAMS = {
+    ((1, 1), "rows"): "f172fc0bdf762ac0",
+    ((1, 2), "rows"): "4591c695ad80a9fe",
+    ((2, 2), "rows"): "67478dca6ca9aa4d",
+    ((1, 2), "dims"): "af4a2a0271bb4d84",
+}
+
+
+@pytest.mark.parametrize("shape,layout", sorted(CBOW_PROGRAMS))
+def test_a_word_level_cbow_fit_lowers_to_the_program_it_lowered_to(
+        shape, layout):
+    import hashlib
+
+    low = lowered(engine(shape, layout))
+    assert (hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
+            == CBOW_PROGRAMS[(shape, layout)])
 
 
 def test_the_cbow_scan_keeps_the_programs_name_and_scopes():
     eng = engine((1, 1))
-    eng.upload_corpus(*zipf_corpus())
-    fn = eng._make_packed_corpus_scan(BATCH, WINDOW, BATCH, 0, K)
-    sds = jax.ShapeDtypeStruct
-    i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
-    words, offs = sds((900,), jnp.int32), sds((61,), jnp.int32)
-    low = fn.lower(
-        sds(eng.syn0.shape, jnp.float32), sds(eng.syn1.shape, jnp.float32),
-        sds((-(-V // 64), 128), jnp.int32), words, words, offs, offs, i32,
-        i32, sds((2,), jnp.uint32), u32, u32, f32, f32, f32)
+    low = lowered(eng)
     assert "packed_scan" in low.as_text()
     compiled = low.compile().as_text()
     for scope in ("glint.batch", "glint.sample", "glint.gather/syn0",

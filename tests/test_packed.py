@@ -381,15 +381,17 @@ CORPUS = [
 ] * 30
 
 
-def _w2v(**kw):
-    from glint_word2vec_tpu import Word2Vec
-
-    defaults = dict(
+def _w2v_defaults():
+    return dict(
         vector_size=12, batch_size=32, min_count=1, num_iterations=2,
         seed=7, steps_per_call=4, window=3,
     )
-    defaults.update(kw)
-    return Word2Vec(**defaults)
+
+
+def _w2v(**kw):
+    from glint_word2vec_tpu import Word2Vec
+
+    return Word2Vec(**{**_w2v_defaults(), **kw})
 
 
 def test_set_batch_packing_validates():
@@ -535,24 +537,35 @@ def test_mid_epoch_state_refuses_cross_mode_resume(tmp_path, monkeypatch):
     assert m.training_metrics["pipeline"] == "device_corpus"
 
 
-@pytest.mark.parametrize("architecture", ["skipgram", "cbow"])
+@pytest.mark.parametrize("architecture,subword", [
+    ("skipgram", False), ("cbow", False), ("cbow", True)])
 def test_packed_subsampled_checkpoint_resume(tmp_path, monkeypatch,
-                                             architecture):
+                                             architecture, subword):
     # Mid-epoch resume with subsampling: the epoch recompacts from
     # (seed, epoch) alone, so the restored position indexes the identical
     # compacted stream. A CBOW fit (bags of positions, a static advance)
-    # keeps the same counters in the same state file.
+    # keeps the same counters in the same state file, over words and over
+    # subword groups (fastText's CBOW).
     ck = str(tmp_path / "ck")
     os.makedirs(ck, exist_ok=True)
     kw = dict(batch_packing="dense", subsample_ratio=0.01,
               architecture=architecture)
+    make = _w2v
+    if subword:
+        from glint_word2vec_tpu import FastTextWord2Vec
+
+        kw.update(bucket=200, min_n=3, max_n=4, max_subwords=8)
+
+        def make(**k):
+            return FastTextWord2Vec(**{**_w2v_defaults(), **k})
+
     monkeypatch.setenv("GLINT_PACKED_STOP_AFTER_GROUPS", "2")
-    _w2v(**kw).fit(CORPUS, checkpoint_dir=ck)
+    make(**kw).fit(CORPUS, checkpoint_dir=ck)
     monkeypatch.delenv("GLINT_PACKED_STOP_AFTER_GROUPS")
     state = json.load(open(os.path.join(ck, "train_state.json")))
     assert state["position"] > 0
-    m_resumed = _w2v(**kw).fit(CORPUS, checkpoint_dir=ck)
-    m_full = _w2v(**kw).fit(CORPUS)
+    m_resumed = make(**kw).fit(CORPUS, checkpoint_dir=ck)
+    m_full = make(**kw).fit(CORPUS)
     np.testing.assert_array_equal(
         np.asarray(m_resumed.engine.syn0, np.float32),
         np.asarray(m_full.engine.syn0, np.float32),

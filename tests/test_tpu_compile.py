@@ -85,8 +85,10 @@ def engines(topo):
     built = {}
 
     def get(chips: int, shared_negatives: int = 0, vocab: int = V,
-            extra_rows: int = 0, architecture: str = "skipgram"):
-        key = (chips, shared_negatives, vocab, extra_rows, architecture)
+            extra_rows: int = 0, architecture: str = "skipgram",
+            negatives: int = NEG):
+        key = (chips, shared_negatives, vocab, extra_rows, architecture,
+               negatives)
         if key not in built:
             mesh = Mesh(
                 np.asarray(topo.devices[:chips]).reshape(1, chips),
@@ -94,7 +96,7 @@ def engines(topo):
             )
             eng = EmbeddingEngine.__new__(EmbeddingEngine)
             eng._configure(
-                mesh, vocab, D, num_negatives=NEG, unigram_power=0.75,
+                mesh, vocab, D, num_negatives=negatives, unigram_power=0.75,
                 unigram_table_size=None, seed=1, dtype="float32",
                 extra_rows=extra_rows, shared_negatives=shared_negatives,
                 compute_dtype=None, layout="rows",
@@ -495,6 +497,45 @@ def test_cbow_packed_scan_at_the_cell_size(engines):
     for scope in ("glint.batch", "glint.sample", "glint.compose",
                   "glint.gather/syn0", "glint.gather/syn1", "glint.grads"):
         assert scope in text, scope
+
+
+def test_subword_cbow_packed_scan_at_the_cell_size(engines):
+    # fastText's CBOW cell (benchmark/configs/ft-cbow-300-1m-2mb.json): the
+    # subword cell's tables (1M word rows + 2M bucket rows, 9.22 GB at
+    # rest, donated) and text, a (1M, 16) group table of 64 MB, 8,192
+    # positions a step with 10 negatives. Each of a rank's 8,202 span
+    # words is gathered and summed once (131,232 syn0 row slots where the
+    # skip-gram subword step has 251,712 and a bag of whole groups would
+    # have 1,310,720) and 11 syn1 rows a position (90,112 slots). ISSUE 39
+    # reckoned 10.2-10.8 GB at a fit's peak.
+    vocab, words = SUBWORD_SCANS["ft-1m-2mb"]
+    sentences = -(-(words - 8 * 80_000) // 40) + 80_000
+    eng = engines(1, 0, vocab, BUCKET, architecture="cbow", negatives=10)
+    compiled = _compile_packed_scan(eng, words, sentences, 16)
+    mem = _fits(compiled)
+    assert eng.padded_vocab == 3_000_000
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        2 * eng.rows_per_shard * D_REST * 4
+    ), mem
+    # 9,695,186,432 B by part: 9,334,779,904 of arguments (9.216 GB of
+    # tables, the 64 MB group table, the corpus and its record, the
+    # sampler's table), 360,395,776 of temporaries (compile check, PR 39);
+    # a fit peaked at 10,163,197,952 B on the chip (my chip runs, PR 39).
+    assert mem["total"] < 10.2e9 and mem["temp"] < 0.6e9, mem
+    assert not _whole_table_copies(compiled, eng)
+    text = compiled.as_text()
+    assert "all-reduce" not in text and "packed_scan" in text
+    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for table in ("syn0", "syn1"):
+        assert any(f"glint.scatter/{table}" in k for k in kernels), table
+    _scatter_holds_no_slot_buffer(compiled, eng)
+    for scope in ("glint.batch", "glint.sample", "glint.compose/group",
+                  "glint.compose/bag", "glint.gather/syn0",
+                  "glint.gather/syn1", "glint.grads"):
+        assert scope in text, scope
+    # the bags read the composed words as shifted slices: no (positions x
+    # lanes x d) tensor is ever formed
+    assert "f32[8192,10,384]" not in text and "f32[10,8192,384]" not in text
 
 
 def test_slab_writer_compiles_for_bfloat16(topo):
