@@ -1291,6 +1291,9 @@ class Word2Vec:
                 exchange_bytes_per_step=engine.packed_exchange_bytes(
                     pair_batch, p.window),
             )
+            # Live steps, and the update slots one hands the scatters.
+            steps = packed_slots // pair_batch
+            slots = engine.packed_scatter_slots(pair_batch, p.window)
             if cbow and rows_written[5]:
                 # Words a bag is the mean of: live bag slots over the
                 # positions that trained.
@@ -1302,8 +1305,9 @@ class Word2Vec:
                     # A subword fit: rows a composed word is the sum of
                     # (live group ids over the span words composed), the
                     # rows a bag's mean is over (fastText's input.size(),
-                    # a position trained), and the group rows a step
-                    # gathered, each once for all the bags it is in.
+                    # a position trained), the group rows a step gathered,
+                    # each once for all the bags it is in, and how many
+                    # bags read a gathered row.
                     model.training_metrics.update(
                         subword_rows_per_center=round(
                             rows_written[6] / rows_written[7], 4),
@@ -1311,6 +1315,16 @@ class Word2Vec:
                             rows_written[8] / rows_written[5], 4),
                         subword_rows_per_step=round(
                             rows_written[6] * pair_batch / packed_slots, 4),
+                        cbow_span_reuse=round(
+                            rows_written[8] / rows_written[6], 4),
+                    )
+                else:
+                    # Word level: a span word is one row, gathered once a
+                    # step (``syn0``'s slots), and a bag's mean is over
+                    # its live slots.
+                    model.training_metrics.update(
+                        cbow_span_reuse=round(
+                            rows_written[4] / (steps * slots[0]), 4),
                     )
             elif rows_written[5]:
                 # Rows a subword centre is the mean of: live group ids the
@@ -1323,8 +1337,6 @@ class Word2Vec:
                 # Rows the scatters wrote over the update slots they were
                 # handed (the step sums a row's duplicates before it
                 # writes): both tables, then each.
-                steps = packed_slots // pair_batch
-                slots = engine.packed_scatter_slots(pair_batch, p.window)
                 rows, slabs = rows_written[:2], rows_written[2:4]
                 model.training_metrics.update(
                     scatter_distinct_share=round(

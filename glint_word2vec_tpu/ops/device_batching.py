@@ -48,10 +48,13 @@ update over pairs — effective mask density ~0.43 -> >=0.95 on the corpus
 path at the same dispatched step cost.
 
 CBOW (``Word2VecParams.architecture``) takes the same view a POSITION at
-a time: :func:`bag_window_batch` forms, for B consecutive positions, each
-position's bag of context words (word2vec's own window, ``2 * window``
-lanes) from the same slices and the same shrink draws; nothing is
-compacted, and a step advances by the static B.
+a time: for B consecutive positions, each position's bag of context words
+(word2vec's own window, ``2 * window`` lanes) from the same slices and the
+same shrink draws; nothing is compacted, and a step advances by the static
+B. :func:`bag_span_batch` names a bag's words by where they stand in the
+step's span (what the scan trains from: each span word is gathered once),
+:func:`bag_window_batch` by what they are (what a replay or a reference
+reads).
 """
 
 from __future__ import annotations
@@ -501,10 +504,10 @@ def bag_span_batch(
     n_valid,
 ):
     """:func:`bag_window_batch` for a step that forms each word of the
-    batch's SPAN once and lets every bag it is in read it (the subword
-    family's CBOW: a word is a group of rows). The same positions, draws
-    and rule, and so the same bags; what is returned names a bag's words
-    by where they stand, not by what they are.
+    batch's SPAN once and lets every bag it is in read it (the CBOW scan
+    of both families: a word is its row, or its group of rows). The same
+    positions, draws and rule, and so the same bags; what is returned
+    names a bag's words by where they stand, not by what they are.
 
     Returns ``(centres (B,), span words (B + 2 * window,), mask (B, 2 *
     window), live (B,))``: the words of the positions ``[pos - window, pos

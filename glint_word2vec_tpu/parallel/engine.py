@@ -699,23 +699,28 @@ class EmbeddingEngine:
             # packed subword scan forms a group once a run of pairs,
             # ops/device_batching.center_runs); None: row i has group i.
             # CBOW is the same step with the roles of the index sets
-            # swapped: the group is a position's bag of context words, the
-            # one "context" (C = 1) the position's own word, and
+            # swapped: the one "context" (C = 1) is the position's own
+            # word, the hidden vector the mean of its bag, and
             # ``mean_gradient`` False: every row of the bag takes the
             # whole gradient, as word2vec.c adds the undivided neu1e.
             # ``lanes`` (:func:`_bag_sums`) generalises ``pair_run`` from
             # "row i's hidden is group ``pair_run[i]``" to "row t's hidden
             # is the masked SUM of the groups its lanes name over the sum
-            # of their counts": fastText's CBOW, one mean over the rows of
-            # every word of the bag, each word's group summed once
-            # (``group``) and read by every bag it is in (``bag``). The
-            # whole gradient goes back the same way (``mean_gradient`` is
-            # False with it).
+            # of their counts": the groups are the words of the step's
+            # span (a row each, or fastText's group of rows: then one
+            # mean over the rows of every word of the bag), each summed
+            # once (``group``) and read by every bag it is in (``bag``).
+            # The whole gradient goes back the same way. Without
+            # ``lanes`` a CBOW group is a position's whole bag (the form
+            # ``_train_step`` keeps for the tests).
             Rl, S = centers.shape
             Bl, C = contexts.shape
-            # What a grouped centre adds to the step has a scope of its
-            # own; at S = 1 the mean is the row and stays the gather's.
-            compose = "glint.compose" if S > 1 else "glint.gather"
+            # What a grouped centre or a bag adds to the step has a scope
+            # of its own; at S = 1 without lanes the mean is the row and
+            # stays the gather's.
+            compose = (
+                "glint.compose" if S > 1 or lanes else "glint.gather"
+            )
             start = lax.axis_index(MODEL_AXIS) * Vs
             drank = lax.axis_index(DATA_AXIS)
 
@@ -893,7 +898,9 @@ class EmbeddingEngine:
             Bl, C = contexts.shape
             drank = lax.axis_index(DATA_AXIS)
             cd = self._compute_dtype
-            compose = "glint.compose" if S > 1 else "glint.gather"
+            compose = (
+                "glint.compose" if S > 1 or lanes else "glint.gather"
+            )
 
             with jax.named_scope("glint.gather"), jax.named_scope("syn0"):
                 h_rows = sgns.gather_blocks(syn0_l, centers)
@@ -1020,9 +1027,16 @@ class EmbeddingEngine:
             step_body_rows if self.layout == "rows" else step_body_dims
         )
 
+        # A CBOW engine's one-step program is the role-swapped form: the
+        # groups are the positions' bags as ``bag_window_batch`` names
+        # them, each row taking the whole gradient. Its fits train through
+        # the packed scan alone (``_skipgram_only``); tests/test_cbow.py
+        # holds that scan's span form to this one.
         self._train_step = jax.jit(
             self._shard_map(
-                lambda *a: step_body(*a)[:3],
+                lambda *a: step_body(
+                    *a, mean_gradient=self.architecture != "cbow"
+                )[:3],
                 in_specs=(tspec, tspec, rep, P(DATA_AXIS, None),
                           P(DATA_AXIS, None), P(DATA_AXIS, None),
                           P(DATA_AXIS, None), rep, rep),
@@ -1191,7 +1205,6 @@ class EmbeddingEngine:
             from glint_word2vec_tpu.ops.device_batching import (
                 bag_lanes,
                 bag_span_batch,
-                bag_window_batch,
                 center_runs,
                 device_words_done,
                 pack_window_pairs,
@@ -1217,43 +1230,41 @@ class EmbeddingEngine:
                                       step_size, inv_total_words,
                                       words_base, groups=None):
                 # CBOW: step i trains the P consecutive positions from
-                # ``pos``, each rank its own Pl of them. A position's bag
-                # (ops/device_batching.bag_window_batch) is the step
-                # body's GROUP, its own word the one context, and the
-                # negatives are drawn a position, keyed by its global row:
-                # the roles of the two tables' index sets are swapped and
-                # nothing else is. Nothing is compacted, the advance is
-                # the static P, and the same key schedule, shrink draws
-                # and alpha rule hold as in the pair scan below. The
-                # per-step outputs are that scan's: ``n_pairs`` reads the
-                # live bag slots, and ``written`` carries them again with
-                # the positions trained, as the subword scan appends its
-                # two counts.
-                #
-                # With a group table (``G`` > 0: fastText's CBOW) a bag's
-                # word is its group of rows and the bag's mean is ONE mean
-                # over all of them. The step body's groups are then the
-                # words of this rank's span, ``Pl + 2 * W`` of them, each
-                # gathered and summed once; a position's bag is ``lanes``
-                # into them (``bag_span_batch``), so a composed word is
-                # shared by the bags it is in, where the skip-gram scan's
-                # ``pair_run`` shares one among the pairs of a run.
-                # ``written`` then appends the live group ids gathered, the
-                # span words composed, and the input rows: the rows a
-                # bag's mean is over, summed over the positions.
+                # ``pos``, each rank its own Pl of them, and the roles of
+                # the two tables' index sets are swapped: a position's own
+                # word is the step body's one context, its negatives are
+                # drawn a position, keyed by its global row, and its
+                # hidden vector is the mean of its bag. The bags of
+                # consecutive positions draw on the words of ONE span,
+                # ``Pl + 2 * W`` of them (``bag_span_batch``): the step
+                # body's groups are the span's words, each gathered once,
+                # and a bag is ``lanes`` into them (:func:`_bag_sums`), so
+                # a word is shared by the bags it is in and takes its
+                # gradient summed over them as one slot of the scatter. A
+                # word is its group's rows where the engine holds a group
+                # table (``G`` > 0: fastText's CBOW, one mean over all of
+                # a bag's rows) and its own row where it holds none.
+                # Nothing is compacted, the advance is the static P, and
+                # the same key schedule, shrink draws and alpha rule hold
+                # as in the pair scan below. The per-step outputs are
+                # that scan's: ``n_pairs`` reads the live bag slots, and
+                # ``written`` carries them again with the positions
+                # trained; with a group table then the live group ids
+                # gathered, the span words composed, and the input rows:
+                # the rows a bag's mean is over, summed over the positions.
                 drank = lax.axis_index(DATA_AXIS)
+                starts = [W + o for o in bag_lanes(W)]
 
                 def body(carry, i):
                     s0, s1, pos = carry
                     with jax.named_scope("glint.batch"):
                         key = jax.random.fold_in(base_key, step0 + i)
-                        c_l, bag, cmask, live = (
-                            bag_span_batch if G else bag_window_batch
-                        )(
+                        c_l, span, lmask, live = bag_span_batch(
                             ids, sent_of, pos + drank * Pl, base_key,
                             grid_step0, window=W, batch=Pl,
                             grid_batch=B_grid, n_valid=n_valid,
                         )
+                        lanes = (starts, lmask)
                         pos_end = pos + P
                         alpha = alpha_at(
                             pos_end, orig_offs, soffs, n_valid, step_size,
@@ -1261,23 +1272,28 @@ class EmbeddingEngine:
                         )
                         formed = lax.psum(
                             jnp.stack([
-                                cmask.sum(dtype=jnp.int32),
+                                lmask.sum(dtype=jnp.int32),
                                 live.sum(dtype=jnp.int32),
                             ]), DATA_AXIS,
                         )
-                    lanes = None
-                    if G:
-                        # ``bag`` holds the span's words so far, ``cmask``
-                        # the lanes' mask: from here they are the step
-                        # body's, the span words' groups and their mask.
-                        lanes = ([W + o for o in bag_lanes(W)], cmask)
+                    if not G:
+                        with jax.named_scope("glint.batch"):
+                            # A span word no bag reads (a sentence's edge
+                            # under a short shrink) is no row of the step.
+                            read = _bag_spread(
+                                lanes, jnp.ones((Pl, 1), jnp.float32),
+                                Pl + 2 * W,
+                            ) > 0
+                            words = jnp.where(read, span[:, None], -1)
+                            cmask = (words >= 0).astype(jnp.float32)
+                    else:
                         with jax.named_scope("glint.compose"):
                             with jax.named_scope("group"):
-                                composed = bag >= 0
-                                bag = jnp.where(
-                                    composed[:, None], groups[bag], -1
+                                composed = span >= 0
+                                words = jnp.where(
+                                    composed[:, None], groups[span], -1
                                 )
-                                cmask = (bag >= 0).astype(jnp.float32)
+                                cmask = (words >= 0).astype(jnp.float32)
                             with jax.named_scope("bag"):
                                 input_rows = _bag_sums(
                                     lanes, cmask.sum(axis=1, keepdims=True)
@@ -1290,7 +1306,7 @@ class EmbeddingEngine:
                                 ]), DATA_AXIS,
                             )])
                     s0, s1, loss, written = step_body(
-                        s0, s1, noise, bag, cmask,
+                        s0, s1, noise, words, cmask,
                         c_l[:, None], live[:, None], key, alpha,
                         mean_gradient=False, lanes=lanes,
                     )
@@ -2197,12 +2213,14 @@ class EmbeddingEngine:
         apply. ``pair_counts`` reads the live bag slots of a step and
         ``rows_written`` is ``(K, 6)``: then the live bag slots again and
         the positions that trained (inside the corpus, bag not empty).
+        The bags read the words of each rank's span (``pair_batch /
+        num_data + 2 * window`` of them), every one gathered once a step;
+        ``rows_written[:, 0]`` counts the distinct words some bag read.
         While it holds a group table a bag's word is its group
         (fastText's CBOW) and ``rows_written`` is ``(K, 9)``: then the
         live group ids the step gathered, the span words they composed
-        (each rank's ``pair_batch / num_data + 2 * window``, less what
-        lies outside the view), and the input rows, the rows a bag's mean
-        is over, summed over the step's positions.
+        (less what lies outside the view), and the input rows, the rows a
+        bag's mean is over, summed over the step's positions.
         """
         if getattr(self, "_corpus", None) is None:
             raise ValueError("no corpus uploaded (call upload_corpus first)")
@@ -2267,12 +2285,11 @@ class EmbeddingEngine:
         ``syn0`` scatter, over all data ranks: a centre a pair, or, while a
         group table is held, the whole group (padding included) of every
         run slot of the default span (``make_packed_corpus_scan``); on a
-        CBOW engine the ``2 * window`` lanes of every position's bag, or
-        the whole group of every word of every rank's span."""
+        CBOW engine every word of every rank's span (its positions and
+        their reach, ``2 * window``), a row each or its whole group."""
         G = self._group_width
         if self.architecture == "cbow":
-            return G * (pair_batch + self.num_data * 2 * window) if G else (
-                pair_batch * 2 * window)
+            return max(G, 1) * (pair_batch + self.num_data * 2 * window)
         if not G:
             return pair_batch
         from glint_word2vec_tpu.corpus.batching import context_width

@@ -83,8 +83,10 @@ class Word2VecParams:
         A property of the model, saved with it. Both families train
         both: with ``FastTextParams`` a bag's word is its subword group
         and the mean is one mean over every row of the bag (``fasttext
-        cbow``). CBOW trains on the corpus-resident packed path only
-        (ops/device_batching.bag_window_batch), draws its negatives a
+        cbow``). Both form a bag from the rows of the step's span, each
+        word gathered once and read by every bag it is in
+        (ops/device_batching.bag_span_batch). CBOW trains on the
+        corpus-resident packed path only, draws its negatives a
         position, and is refused with a shared pool, grid packing,
         replica exchange, the streaming trainer, or a fit that path does
         not take (there is no host-batcher CBOW).

@@ -9,6 +9,8 @@
   stream's own pairs under the same draws.
 * The engine's CBOW packed scan against the reference, on the batches the
   scan drew, at 1x1, 1x2 and one ``dims`` mesh; 1x1 against 1x2.
+* The scan's span form (each span row gathered once, ISSUE 42) against the
+  role-swapped form it replaced and ``word2vec.c``'s loop, on those meshes.
 * Bags of one context each are the skip-gram step on the swapped pair.
 * The architecture is saved and loaded, an older checkpoint is a skip-gram,
   and what cannot train CBOW says so.
@@ -196,6 +198,11 @@ def test_bags_of_an_epoch_are_word2vecs_window_under_the_skipgram_draws(
             lambda o, bp: o in lanes and -bp <= o <= bp - 1)
 
 
+# The meshes and layouts the CBOW scan is held on.
+MESHES = [((1, 1), "rows"), ((1, 2), "rows"), ((2, 2), "rows"),
+          ((1, 2), "dims")]
+
+
 def engine(shape, layout="rows", architecture="cbow", seed=3):
     counts = np.arange(V, 0, -1).astype(np.int64) * 3
     return EmbeddingEngine(make_mesh(*shape), V, D, counts, num_negatives=NEG,
@@ -239,8 +246,7 @@ def gaps(prog, ref, init):
     return np.abs(prog - ref).max() / change, abs(d_prog - d_ref) / d_ref
 
 
-@pytest.mark.parametrize("shape,layout", [
-    ((1, 1), "rows"), ((1, 2), "rows"), ((2, 2), "rows"), ((1, 2), "dims")])
+@pytest.mark.parametrize("shape,layout", MESHES)
 def test_packed_cbow_scan_is_the_reference(shape, layout):
     eng = engine(shape, layout)
     (init0, init1), out = run_packed(eng, zipf_corpus())
@@ -288,6 +294,61 @@ def test_packed_cbow_scan_is_the_reference(shape, layout):
             jnp.asarray(b["live"]), jnp.asarray(b["negs"]),
             jnp.float32(b["alpha"]))
     assert gaps(prog0, np.asarray(div0), init0)[0] > 10 * GAP
+
+
+@pytest.mark.parametrize("shape,layout", MESHES)
+def test_the_span_form_is_the_role_swapped_form(shape, layout):
+    """ISSUE 42: the scan names a bag's words by where they stand in the
+    step's span, gathers each span row once and sums a row's gradient over
+    its bags before the scatter. Held here to the form it replaced, the
+    step body with each position's bag (``bag_window_batch``) as its group
+    and no ``lanes``, and to ``word2vec.c``'s loop in numpy, on the same
+    draws. Only the order of two float32 sums differs."""
+    span = engine(shape, layout)
+    (init0, init1), out = run_packed(span, zipf_corpus())
+    losses, counts, _, alphas, written = (np.asarray(a) for a in out)
+    batches = captured(span)
+    swapped = engine(shape, layout)
+    for a, b in zip(tables(swapped), (init0, init1)):
+        np.testing.assert_array_equal(a, b)
+    c0, c1 = init0.astype(np.float32), init1.astype(np.float32)
+    key, swapped_losses, c_losses = jax.random.PRNGKey(3), [], []
+    for i, b in enumerate(batches):
+        swapped.syn0, swapped.syn1, loss = swapped._train_step(
+            swapped.syn0, swapped.syn1, swapped._alias_packed,
+            jnp.asarray(b["bags"]),
+            jnp.asarray(b["bags"] >= 0, jnp.float32),
+            jnp.asarray(b["centres"])[:, None],
+            jnp.asarray(b["live"], jnp.float32)[:, None],
+            jax.random.fold_in(key, jnp.uint32(i)), jnp.float32(b["alpha"]))
+        swapped_losses.append(float(loss))
+        c0, c1, loss = _word2vec_c(
+            c0, c1, b["bags"], b["centres"], b["live"], b["negs"],
+            np.float32(b["alpha"]))
+        c_losses.append(loss)
+    np.testing.assert_array_equal(alphas, [b["alpha"] for b in batches])
+    for prog, ref, c, init in zip(tables(span), tables(swapped), (c0, c1),
+                                  (init0, init1)):
+        # within 1e-6, entry by entry of the table's scale and as the
+        # difference's norm over the change's (1.2e-7, one ulp of the
+        # largest entry, and 4.7e-7 here)
+        assert np.abs(prog - ref).max() < 1e-6 * np.abs(ref).max()
+        assert np.sqrt(np.square(prog - ref).sum()
+                       / np.square(ref - init).sum()) < 1e-6
+        gap, dnorm = gaps(prog, c, init)
+        assert gap < GAP and dnorm < DNORM_GAP, (gap, dnorm)
+    # one float32 ulp
+    np.testing.assert_allclose(losses, swapped_losses, rtol=2.0 ** -23)
+    np.testing.assert_allclose(losses, c_losses, rtol=LOSS_GAP)
+    # the counts are the bags': live bag slots, positions trained
+    slots = [int((b["bags"] >= 0).sum()) for b in batches]
+    trained = [int(b["live"].sum()) for b in batches]
+    assert written[:, 4].tolist() == slots == counts.tolist()
+    assert written[:, 5].tolist() == trained
+    # and a span word no bag reads is no slot of the scatter: syn0 wrote
+    # the bags' distinct words, step by step
+    assert written[:, 0].tolist() == [
+        np.unique(b["bags"][b["bags"] >= 0]).size for b in batches]
 
 
 def test_the_benchmarks_enumeration_holds_the_bags_and_the_device_counts():
@@ -391,6 +452,25 @@ def test_cbow_fit_takes_the_corpus_resident_path_and_counts_its_bags():
     assert len(m.find_synonyms("dog", 3)) == 3
 
 
+@pytest.mark.parametrize("shards,partitions", [(1, 1), (2, 1), (1, 2)])
+def test_cbow_span_reuse_is_the_live_bag_slots_over_the_span_rows(
+        shards, partitions):
+    """How many bags read a row the step gathered: live bag slots over the
+    words of every rank's span (its 32 / ranks positions and their reach,
+    2 x 3), a live step. From the counts the scan already returns."""
+    m = _w2v(num_shards=shards, num_partitions=partitions,
+             subsample_ratio=0.01, step_size=0.05).fit(CORPUS)
+    tm = m.training_metrics
+    span = 32 + 2 * 3 * partitions
+    assert m.engine.packed_scatter_slots(32, 3) == (span, 32 * (1 + 5))
+    reuse = tm["cbow_rows_per_bag"] * tm["packed_mask_density"] * 32 / span
+    assert abs(tm["cbow_span_reuse"] - reuse) < 2e-3
+    assert 1.0 < tm["cbow_span_reuse"] < tm["cbow_rows_per_bag"]
+    # syn0's slots are the span's words, not the bags' lanes: most of them
+    # are distinct rows some bag read
+    assert 0.3 < tm["scatter_distinct_share_syn0"] <= 1.0
+
+
 def test_save_and_load_keep_the_architecture(tmp_path, monkeypatch):
     from glint_word2vec_tpu.models import load_model
 
@@ -489,14 +569,14 @@ def lowered(eng):
 
 # sha256[:16] of the lowered word-level CBOW scan's StableHLO text, by mesh
 # and layout, as tests/test_subword_packed.py holds the skip-gram scans':
-# taken on the parent of ISSUE 39 (a47d4f9), which gave the step bodies
-# ``lanes`` and this scan a group table for fastText's CBOW. A word-level
-# CBOW fit must lower to the program it lowered to.
+# taken in ISSUE 42, which gave this scan the span form (CHANGES.md has the
+# role-swapped form's); the skip-gram scans' and fastText's CBOW scan's
+# stayed. A word-level CBOW fit must lower to the program it lowered to.
 CBOW_PROGRAMS = {
-    ((1, 1), "rows"): "f172fc0bdf762ac0",
-    ((1, 2), "rows"): "4591c695ad80a9fe",
-    ((2, 2), "rows"): "67478dca6ca9aa4d",
-    ((1, 2), "dims"): "af4a2a0271bb4d84",
+    ((1, 1), "rows"): "1154f439cd01e8c9",
+    ((1, 2), "rows"): "8e817484952fdc81",
+    ((2, 2), "rows"): "c1abb5503c4cbe85",
+    ((1, 2), "dims"): "819e03e6aaba47e0",
 }
 
 

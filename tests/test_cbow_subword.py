@@ -309,10 +309,10 @@ def test_a_shared_row_is_counted_twice_and_takes_the_sum():
 def test_groups_of_one_row_are_the_word_level_cbow_scan():
     """Every group cut to its word's own row (no n-gram of the range fits
     any word): the same tables, losses and counts as the word-level CBOW
-    scan on the same view, within the replay's float32 limits (each span
-    word's gradient is summed over its bags first and added to the row
-    once; the word-level scatter sums a row's slots in batch order:
-    float32 addition does not associate)."""
+    scan on the same view, within the replay's float32 limits (both sum
+    a span word's gradient over its bags first; the group of two slots,
+    one of them padding, is summed over its second-minor axis where the
+    word's one row is not)."""
     alone = np.full((V, 2), -1, np.int32)
     alone[:, 0] = np.arange(V)
     counts = np.arange(V, 0, -1).astype(np.int64) * 3
@@ -408,6 +408,17 @@ def test_fit_takes_the_corpus_resident_path_and_counts_its_bags():
     assert tm["final_loss"] < tm["first_loss"]
     assert len(m.find_synonyms("dog", 3)) == 3
     assert m.transform("doggo").shape == (12,)  # an OOV word composes
+
+
+def test_cbow_span_reuse_is_the_input_rows_over_the_rows_gathered():
+    """How many bags read a row the step gathered, each once, for its
+    span's words: the rows the bags' means are over, over the live group
+    ids gathered (PERF.md read it by hand: 5.39 in the cell)."""
+    tm = _ft(subsample_ratio=0.01, step_size=0.05).fit(CORPUS).training_metrics
+    reuse = (tm["cbow_input_rows_per_bag"] * tm["packed_mask_density"] * 32
+             / tm["subword_rows_per_step"])
+    assert abs(tm["cbow_span_reuse"] - reuse) < 5e-3
+    assert 1.0 < tm["cbow_span_reuse"] < tm["cbow_rows_per_bag"] + 1.0
 
 
 def test_save_and_load_keep_the_architecture_and_the_geometry(tmp_path):

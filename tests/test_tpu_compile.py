@@ -470,33 +470,70 @@ def test_subword_packed_scan_at_the_cell_size(engines):
     assert mem["total"] + more < HBM_BYTES
 
 
-def test_cbow_packed_scan_at_the_cell_size(engines):
-    # The CBOW cell's step (benchmark/configs/w2v-cbow-300-3m.json): two
-    # tables of 3M rows, 9.22 GB at rest, donated; 8,192 positions a step,
-    # a bag of up to 10 syn0 rows and 6 syn1 rows a position (81,920 +
-    # 49,152 row slots where the subword step has 360k + 157k), over the
-    # cell's corpus of 7,639,956 tokens. ISSUE 34 reckoned 10.2-10.8 GB
-    # at a fit's peak.
+@pytest.fixture(scope="module")
+def cbow_scan(engines):
+    """The CBOW cell's step (benchmark/configs/w2v-cbow-300-3m.json),
+    compiled once for the module: two tables of 3M rows, 9.22 GB at rest,
+    donated; 8,192 positions a step over the cell's corpus of 7,639,956
+    tokens."""
     words = 3_000_000 - 44 + 4_000_000 + 8 * 80_000
     sentences = -(-(words - 8 * 80_000) // 40) + 80_000
     eng = engines(1, 0, 3_000_000, architecture="cbow")
-    compiled = _compile_packed_scan(eng, words, sentences)
+    return eng, _compile_packed_scan(eng, words, sentences)
+
+
+def test_cbow_packed_scan_at_the_cell_size(cbow_scan):
+    # The 8,202 words of the step's span are one row of syn0 each and 6
+    # syn1 rows a position (8,202 + 49,152 row slots where the subword
+    # step has 360k + 157k, and the role-swapped form had 81,920 + 49,152).
+    # ISSUE 34 reckoned 10.2-10.8 GB at a fit's peak; the program is
+    # 9,357,983,744 B, 54,801,920 of them temporaries, where the ten
+    # gathered blocks of the bags' rows kept about 0.4 GB (compile check,
+    # PR 42).
+    eng, compiled = cbow_scan
     mem = _fits(compiled)
     assert compiled.memory_analysis().alias_size_in_bytes >= (
         2 * eng.rows_per_shard * D_REST * 4
     ), mem
-    assert mem["total"] < 10.8e9 and mem["temp"] < 1.5e9, mem
+    assert mem["total"] < 9.6e9 and mem["temp"] < 0.2e9, mem
     assert not _whole_table_copies(compiled, eng)
     text = compiled.as_text()
     assert "all-reduce" not in text and "packed_scan" in text
-    # both tables' scatters end in the slab writer, the bags' rows too
+    # both tables' scatters end in the slab writer, the span's rows too
     kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
     for table in ("syn0", "syn1"):
         assert any(f"glint.scatter/{table}" in k for k in kernels), table
     _scatter_holds_no_slot_buffer(compiled, eng)  # f32[81920,384] was one
-    for scope in ("glint.batch", "glint.sample", "glint.compose",
-                  "glint.gather/syn0", "glint.gather/syn1", "glint.grads"):
+    for scope in ("glint.batch", "glint.sample", "glint.compose/group",
+                  "glint.compose/bag", "glint.gather/syn0",
+                  "glint.gather/syn1", "glint.grads"):
         assert scope in text, scope
+
+
+def test_cbow_packed_scan_reads_each_span_row_once(cbow_scan):
+    # ISSUE 42: a bag's words are named by where they stand in the span.
+    # The span's 8,202 rows are gathered ONCE (the role-swapped form
+    # gathered f32[81920,384], then ten blocks of f32[8192,384], a row 5.5
+    # times), the bags' sums are ten shifted slices that the compiler
+    # takes into the logits' fusion, and the gradient goes back through
+    # ONE fusion of ten padded adds into the span, which the scatter takes
+    # as 8,202 slots.
+    import re
+
+    _, compiled = cbow_scan
+    text = compiled.as_text()
+    gathers = re.findall(
+        r"= (f32\[[\d,]+\])\S* gather\(.*glint\.gather/(syn[01])/", text)
+    assert sorted(gathers) == (
+        [("f32[8192,384]", "syn1")] * (1 + NEG) + [("f32[8202,384]", "syn0")]
+    ), gathers
+    for shape in ("[81920,384]", "[8192,10,384]", "[10,8192,384]",
+                  "[8202,1,384]"):
+        assert shape not in text, shape
+    spread = re.findall(r"%pad_add_fusion[.\d]* = f32\[([\d,]+)\]", text)
+    assert spread == ["8202,384"], spread
+    assert re.search(r"s32\[8202\]\S*, f32\[8202\]\S*, s32\[8202\]\S*\) "
+                     r"sort\(.*glint\.scatter/syn0", text)
 
 
 def test_subword_cbow_packed_scan_at_the_cell_size(engines):
