@@ -290,7 +290,9 @@ def _add_ann_flags(p):
     ann.add_argument("--ann", action="store_true",
                      help="enable the approximate /synonyms path "
                           "(built + recall-gated before the port "
-                          "binds; refreshed on every hot-swap)")
+                          "binds; refreshed on every hot-swap; "
+                          "word-level models only: a subword model "
+                          "stays exact, with a warning)")
     ann.add_argument("--ann-clusters", type=int, default=-1,
                      help="coarse cluster count (-1 auto: "
                           "next_pow2(sqrt(rows)))")
@@ -346,7 +348,10 @@ def _add_query(sub):
         "serve",
         help="serve a saved model over HTTP (the separate-PS-cluster "
              "deployment analogue: trainers/clients come and go, the "
-             "model stays resident)",
+             "model stays resident); a word-level or a subword "
+             "(--fasttext) model, both on the coalesced, cached, "
+             "pre-warmed path: a subword model also answers words "
+             "that are not in its dictionary, from their n-gram rows",
     )
     p.add_argument("--model", default=None,
                    help="saved model directory (optional when "

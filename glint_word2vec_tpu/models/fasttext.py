@@ -19,6 +19,7 @@ group's mean whichever trained it.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple
 
@@ -33,6 +34,7 @@ from glint_word2vec_tpu.models.word2vec import (
     Word2Vec,
     Word2VecModel,
 )
+from glint_word2vec_tpu.utils import next_pow2
 from glint_word2vec_tpu.utils.params import Word2VecParams, _require
 
 
@@ -148,12 +150,20 @@ class FastTextModel(Word2VecModel):
         super().__init__(vocab, engine, params)
         self._sub_ids = sub_ids
         self._sub_mask = sub_mask
+        #: The composed query engine, the training engine's
+        #: ``table_version`` its rows were composed from, and how often
+        #: and how long it was built (``/metrics`` reads the last two).
+        self._qeng = None
+        self._qeng_version = None
+        self.query_engine_builds = 0
+        self.query_engine_build_seconds = 0.0
 
     # -- composition ---------------------------------------------------
 
-    #: Fixed row-block size for composition calls: bounds XLA to at most
-    #: two compiled shapes (full block + final remainder) regardless of
-    #: input sizes.
+    #: Most rows one composition call hands the device: a longer input
+    #: goes in blocks of this many, and a shorter one (or a remainder) in
+    #: its power-of-two bucket, so XLA sees log2(COMPOSE_BLOCK) + 1 shapes
+    #: whatever the input sizes.
     COMPOSE_BLOCK = 4096
 
     def _compose_device(self, groups: np.ndarray, gmask: np.ndarray):
@@ -161,38 +171,96 @@ class FastTextModel(Word2VecModel):
         return self.engine.pull_average(groups, gmask)
 
     def _compose(self, groups: np.ndarray, gmask: np.ndarray) -> np.ndarray:
-        """Compose arbitrarily many rows, block-quantized to COMPOSE_BLOCK
-        (padded with row 0 / zero mask, sliced off after) so repeated calls
-        never trigger per-shape recompiles."""
+        """Compose arbitrarily many rows: whole COMPOSE_BLOCKs, then the
+        remainder padded (row 0 / zero mask, sliced off after) to its
+        power of two. One word costs a ``(1, max_subwords)`` block, a
+        coalesced round its Q bucket's, and no call compiles a shape
+        :meth:`warm_compose` has not."""
         n = groups.shape[0]
         B = self.COMPOSE_BLOCK
         out = np.empty((n, self.vector_size), np.float32)
         for s in range(0, n, B):
-            e = min(s + B, n)
-            g, m = groups[s:e], gmask[s:e]
-            if e - s < B:
-                pad = B - (e - s)
+            g, m = groups[s : s + B], gmask[s : s + B]
+            k = g.shape[0]
+            pad = next_pow2(k) - k
+            if pad:
                 g = np.pad(g, ((0, pad), (0, 0)))
                 m = np.pad(m, ((0, pad), (0, 0)))
-            out[s:e] = np.asarray(self._compose_device(g, m))[: e - s]
+            out[s : s + k] = np.asarray(self._compose_device(g, m))[:k]
         return out
 
-    def _oov_group(self, word: str) -> Tuple[np.ndarray, np.ndarray]:
+    def warm_compose(self, max_rows: Optional[int] = None) -> int:
+        """Compile every block shape :meth:`_compose` can dispatch for
+        inputs of up to ``max_rows`` rows (default: any size). Returns
+        the number of shapes compiled (0 = already warm)."""
+        before = self.engine.query_compiles
+        top = self.COMPOSE_BLOCK
+        if max_rows is not None:
+            top = min(top, next_pow2(max_rows))
+        S = self.params.max_subwords
+        n = 1
+        while n <= top:
+            g = np.zeros((n, S), np.int32)
+            np.asarray(self._compose_device(g, np.zeros(g.shape, np.float32)))
+            n *= 2
+        return self.engine.query_compiles - before
+
+    def _oov_groups(
+        self, words: Sequence[str]
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """``(n, max_subwords)`` ids and mask of words taken as having no
+        dictionary row: their n-grams' bucket rows alone. A word too
+        short for any n-gram gets an all-zero mask row, which the caller
+        refuses (it has no vector)."""
         p: FastTextParams = self.params
-        ids = subword_group(
-            word, None, self.vocab.size, p.bucket, p.min_n, p.max_n,
-            p.max_subwords,
-        )
-        if not ids:
-            raise KeyError(
-                f"word {word!r} is OOV and too short for any "
-                f"[{p.min_n},{p.max_n}]-gram"
+        g = np.zeros((len(words), p.max_subwords), np.int32)
+        m = np.zeros((len(words), p.max_subwords), np.float32)
+        for i, word in enumerate(words):
+            ids = subword_group(
+                word, None, self.vocab.size, p.bucket, p.min_n, p.max_n,
+                p.max_subwords,
             )
-        g = np.zeros((1, p.max_subwords), np.int32)
-        m = np.zeros((1, p.max_subwords), np.float32)
-        g[0, : len(ids)] = ids
-        m[0, : len(ids)] = 1.0
+            g[i, : len(ids)] = ids
+            m[i, : len(ids)] = 1.0
         return g, m
+
+    def _too_short(self, word: str) -> KeyError:
+        p: FastTextParams = self.params
+        return KeyError(
+            f"word {word!r} is OOV and too short for any "
+            f"[{p.min_n},{p.max_n}]-gram"
+        )
+
+    def _oov_group(self, word: str) -> Tuple[np.ndarray, np.ndarray]:
+        g, m = self._oov_groups([word])
+        if not m.any():
+            raise self._too_short(word)
+        return g, m
+
+    def compose_oov(self, words: Sequence[str]):
+        """Vectors of words that have no dictionary row, for a coalesced
+        serving round: host n-gram hashing, then ONE bucketed compose
+        over the words that have a group. Returns ``(vectors, errors,
+        slots, rows)``: ``vectors[i]`` is word i's vector or None,
+        ``errors[i]`` the KeyError of a word too short for any n-gram
+        (it fails alone), and the group slots gathered (padding
+        included) with the live rows among them."""
+        g, m = self._oov_groups(words)
+        ok = m.any(axis=1)
+        vectors = [None] * len(words)
+        errors = [
+            None if ok[i] else self._too_short(w)
+            for i, w in enumerate(words)
+        ]
+        n = int(ok.sum())
+        if n:
+            vecs = self._compose(g[ok], m[ok])
+            for i, v in zip(np.flatnonzero(ok), vecs):
+                vectors[i] = v
+        return (
+            vectors, errors,
+            next_pow2(n) * g.shape[1] if n else 0, int(m.sum()),
+        )
 
     def transform(self, word: str) -> np.ndarray:
         """Word -> composed vector. Unlike the word-level model, OOV words
@@ -218,9 +286,9 @@ class FastTextModel(Word2VecModel):
         """Mean of composed word vectors per sentence (OOV words dropped,
         matching the word-level DataFrame-transform semantics).
 
-        All chunk words are composed in fixed-size device blocks (one or
-        two compiled shapes total), then segment-averaged on host — no
-        per-sentence device calls."""
+        All chunk words are composed in bucketed device blocks
+        (``_compose``), then segment-averaged on host — no per-sentence
+        device calls."""
         sentences = list(sentences)
         out = np.zeros((len(sentences), self.vector_size), np.float32)
         encoded = [self.vocab.encode(s) for s in sentences]
@@ -243,7 +311,7 @@ class FastTextModel(Word2VecModel):
         """Bulk-transform hook on the subword-compose path: the packed
         word-id block is flattened back to its real tokens (row-major, so
         the flat order matches :meth:`transform_sentences`' concatenation)
-        and composed in the usual fixed COMPOSE_BLOCK device blocks, then
+        and composed in the usual bucketed device blocks, then
         segment-averaged on host. Row results are independent of how the
         producer batched the stream — each composed word vector is a
         within-row reduction — so resume/bitwise guarantees carry over."""
@@ -263,17 +331,13 @@ class FastTextModel(Word2VecModel):
         return out
 
     def bulk_warmup(self, rows: int, max_len: int) -> int:
-        """The compose path dispatches only ``(COMPOSE_BLOCK,
-        max_subwords)`` pull-average blocks regardless of the producer's
-        packing (``_compose`` pads every partial block), so ONE shape
-        warms the whole stream — the producer's (rows, len) geometry
-        never reaches the device here."""
-        before = self.engine.query_compiles
-        g = np.zeros(
-            (self.COMPOSE_BLOCK, self.params.max_subwords), np.int32
-        )
-        np.asarray(self._compose_device(g, np.zeros(g.shape, np.float32)))
-        return self.engine.query_compiles - before
+        """The compose path dispatches ``(n, max_subwords)`` pull-average
+        blocks, n a power of two up to COMPOSE_BLOCK, whatever the
+        producer's packing (``_compose`` pads a remainder to its bucket):
+        a block of ``rows`` x ``max_len`` tokens reaches the buckets up
+        to its own size, and those are warmed here. The producer's
+        (rows, len) geometry never reaches the device."""
+        return self.warm_compose(rows * max_len)
 
     # -- similarity over composed vectors ------------------------------
 
@@ -281,27 +345,42 @@ class FastTextModel(Word2VecModel):
         """A second sharded engine whose syn0 holds the composed per-word
         vectors, assembled entirely on device (compose block ->
         ``write_rows``; nothing of O(vocab x dim) ever touches the host).
-        Built lazily, cached; similarity queries then reuse the standard
+        Composed from the training engine's tables as of its
+        ``table_version``: built on the first call (the server makes
+        that call before its port binds) and composed anew, in place,
+        once that version has moved (a training step, ``write_rows``,
+        ``set_tables``). Similarity queries then reuse the standard
         distributed top-k."""
-        if getattr(self, "_qeng", None) is None:
-            from glint_word2vec_tpu.parallel.engine import EmbeddingEngine
+        ver = self.engine.table_version
+        if self._qeng is not None and self._qeng_version == ver:
+            return self._qeng
+        t0 = time.perf_counter()
+        with obs_events.span(
+            "query_engine_build", words=self.vocab.size, version=ver
+        ):
+            if self._qeng is None:
+                from glint_word2vec_tpu.parallel.engine import EmbeddingEngine
 
-            qeng = EmbeddingEngine(
-                self.engine.mesh,
-                self.vocab.size,
-                self.vector_size,
-                self.vocab.counts,
-                num_negatives=self.engine.num_negatives,
-                seed=0,
-            )
+                self._qeng = EmbeddingEngine(
+                    self.engine.mesh,
+                    self.vocab.size,
+                    self.vector_size,
+                    self.vocab.counts,
+                    num_negatives=self.engine.num_negatives,
+                    seed=0,
+                )
             B = self.COMPOSE_BLOCK
             for s in range(0, self.vocab.size, B):
                 e = min(s + B, self.vocab.size)
                 block = self._compose_device(
                     self._sub_ids[s:e], self._sub_mask[s:e]
                 )
-                qeng.write_rows(s, block)
-            self._qeng = qeng
+                self._qeng.write_rows(s, block)
+            # The span and the seconds are the build's, not its enqueue's.
+            self._qeng.syn0.block_until_ready()
+        self._qeng_version = ver
+        self.query_engine_builds += 1
+        self.query_engine_build_seconds += time.perf_counter() - t0
         return self._qeng
 
     def to_local(self) -> LocalWord2VecModel:
@@ -321,7 +400,7 @@ class FastTextModel(Word2VecModel):
                 yield self.vocab.words[int(i)], r
 
     def stop(self) -> None:
-        if getattr(self, "_qeng", None) is not None:
+        if self._qeng is not None:
             self._qeng.destroy()
             self._qeng = None
         super().stop()

@@ -339,6 +339,12 @@ def merge_serving_snapshots(snaps: Iterable[dict]) -> Optional[dict]:
 
     batches: Dict[str, int] = {}
     cache = {"hits": 0, "misses": 0}
+    # Subword family's compose block (ISSUE 43): every key a counter.
+    compose = {
+        "oov_queries_total": 0, "dispatches_total": 0,
+        "group_slots_total": 0, "group_rows_total": 0,
+        "table_builds_total": 0, "table_build_seconds_total": 0.0,
+    }
     over = {
         "shed_admission_total": 0, "shed_degraded_total": 0,
         "deadline_504_total": 0, "degraded_entered_total": 0,
@@ -391,6 +397,9 @@ def merge_serving_snapshots(snaps: Iterable[dict]) -> Optional[dict]:
         c = s.get("synonym_cache") or {}
         cache["hits"] += int(c.get("hits") or 0)
         cache["misses"] += int(c.get("misses") or 0)
+        for k, v in (s.get("compose") or {}).items():
+            if k in compose:
+                compose[k] += v or 0
         o = s.get("overload") or {}
         for k in over:
             v = int(o.get(k) or 0)
@@ -526,6 +535,7 @@ def merge_serving_snapshots(snaps: Iterable[dict]) -> Optional[dict]:
             k: batches[k] for k in sorted(batches, key=int)
         },
         "synonym_cache": cache,
+        "compose": compose,
         "overload": over,
         "compiles": compiles,
         "hot_swap": swap,

@@ -65,6 +65,10 @@ REQUEST_SPANS = {
     "req.dispatch": "warm-bucket device dispatch of one coalesced batch",
     "req.pull": "the dispatch's row pull, device program and read-back "
                 "(child of req.dispatch; args: rows)",
+    "req.compose": "the dispatch's compose of its out-of-dictionary "
+                   "query words (subword family): host n-gram hashing, "
+                   "one bucketed pull_average and its read-back (child "
+                   "of req.dispatch; args: words, oov, slots, rows)",
     "req.query": "engine query path (args carry mode=ann|exact)",
     "req.readback": "device result harvest / host materialization",
     "req.serialize": "response serialization + socket write",

@@ -758,6 +758,27 @@ def serving_to_prometheus(snap: dict) -> str:
     p.head("glint_serving_cache_misses_total", "counter",
            "Synonym result-cache misses.")
     p.sample("glint_serving_cache_misses_total", None, cache.get("misses", 0))
+    # Subword family (ISSUE 43): the rounds' composes of query words
+    # outside the dictionary, and the composed word table's builds.
+    comp = snap.get("compose") or {}
+    for name, key, help_ in [
+        ("glint_serving_compose_oov_queries_total", "oov_queries_total",
+         "Synonym queries for a word outside the dictionary, composed "
+         "from its n-gram rows."),
+        ("glint_serving_compose_dispatches_total", "dispatches_total",
+         "Coalesced rounds' compose dispatches."),
+        ("glint_serving_compose_group_slots_total", "group_slots_total",
+         "Group slots those dispatches gathered, padding included."),
+        ("glint_serving_compose_group_rows_total", "group_rows_total",
+         "Live table rows among those slots."),
+        ("glint_serving_compose_table_builds_total", "table_builds_total",
+         "Builds of the composed word table."),
+        ("glint_serving_compose_table_build_seconds_total",
+         "table_build_seconds_total",
+         "Seconds spent building the composed word table."),
+    ]:
+        p.head(name, "counter", help_)
+        p.sample(name, None, comp.get(key, 0))
     compiles = snap.get("compiles", {})
     p.head("glint_serving_compiles_total", "counter",
            "Query-op shapes jit-compiled since engine construction.")

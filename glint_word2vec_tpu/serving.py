@@ -22,8 +22,12 @@ Endpoints (JSON in/out, stdlib-only server):
   POST /synonyms           {"word": w, "num": k}
   POST /synonyms_vector    {"vector": [...], "num": k}
   POST /analogy            {"positive": [...], "negative": [...], "num": k}
-  POST /vector             {"word": w}            (strict OOV -> 404)
-  POST /transform          {"sentences": [[w, ...], ...]}  (OOV dropped)
+  POST /vector             {"word": w}            (word-level family:
+                           strict, OOV -> 404; subword family: an OOV
+                           word's vector is its n-gram rows' mean, 404
+                           only where it is too short for any n-gram)
+  POST /transform          {"sentences": [[w, ...], ...]}  (OOV dropped,
+                           in both families)
   POST /shutdown           stops the server (the terminateOtherClients
                            analogue: an explicit, remote, cross-client kill)
   POST /reload             hot-swap the served tables to a published
@@ -50,6 +54,15 @@ shape family: coalesced batches pad to power-of-two Q buckets (capped at
 ``MAX_QUERY_ROWS`` exactly like ``transform_words``, and ``ModelServer``
 compiles the whole family BEFORE binding the port — so the first real
 request (and every later one inside the family) never pays a jit compile.
+
+Both model families ride that path. A word-level model's query vector is
+a row of its table. A subword (fastText) model's is composed: a
+dictionary word's from the composed word table (``FastTextModel.
+_query_engine``, built before the port binds and composed anew once the
+training tables' ``table_version`` has moved), a word outside the
+dictionary from its character n-grams' bucket rows, hashed on the host
+and averaged by one bucketed ``pull_average`` inside the coalesced round
+(span ``req.compose``); its compose buckets are warmed with the rest.
 
 Overload protection (ISSUE 7): device-touching requests are bounded by
 an admission high-water mark (shed with 429 + ``Retry-After`` past
@@ -243,15 +256,21 @@ class _SynonymCoalescer:
     powers of two and rounds k up to its bucket, so every chunk reuses a
     pre-warmed compiled program.
 
-    Only the base word-level family batches: a subclass overriding
-    ``find_synonyms``/``find_synonyms_vector``/``transform`` (FastText
-    serves OOV words through subwords) keeps its own semantics via the
-    single-query path.
+    Two families batch. The base word-level family: a query word's
+    vector is its row of the table. The subword family
+    (``FastTextModel``, ``self.composes``): a dictionary word's vector is
+    its row of the COMPOSED table and its neighbours are scored against
+    that table; a word outside the dictionary is composed inside the
+    round from its n-gram rows (``req.compose``) and excludes nothing.
+    Any other subclass overriding ``find_synonyms``/
+    ``find_synonyms_vector``/``transform`` keeps its own semantics via
+    the single-query path.
     """
 
     def __init__(self, model, device_lock, max_batch: int = 64,
                  metrics: Optional[ServingMetrics] = None,
                  cache_size: int = 65536):
+        from glint_word2vec_tpu.models.fasttext import FastTextModel
         from glint_word2vec_tpu.models.word2vec import Word2VecModel
 
         self.model = model
@@ -298,6 +317,14 @@ class _SynonymCoalescer:
         #: the approximate path back — those exact serves are counted
         #: as gate fallbacks, not user-requested ones.
         self.gate_failing = lambda: False
+        #: The subword family, decided once: a round composes the
+        #: vectors of its out-of-dictionary words (``compose_oov``) and
+        #: pulls and scores against ``model._query_engine()``. Its
+        #: ``transform`` is the one override the round reproduces.
+        self.composes = (
+            isinstance(model, FastTextModel)
+            and type(model).transform is FastTextModel.transform
+        )
         self.can_batch = (
             isinstance(model, Word2VecModel)
             and type(model).find_synonyms is Word2VecModel.find_synonyms
@@ -305,7 +332,10 @@ class _SynonymCoalescer:
             # silently served base batched top-k (ADVICE.md round 5).
             and type(model).find_synonyms_vector
             is Word2VecModel.find_synonyms_vector
-            and type(model).transform is Word2VecModel.transform
+            and (
+                type(model).transform is Word2VecModel.transform
+                or self.composes
+            )
         )
 
     def _acquire_device(self, deadline: Optional[float]) -> bool:
@@ -335,7 +365,7 @@ class _SynonymCoalescer:
         tr = trace if trace is not None else obs_events.NULL_TRACE
         if not self.can_batch:
             # Overriding families define their own semantics end to end
-            # (FastText OOV-by-subwords, its own num validation).
+            # (their own vectors, their own num validation).
             with tr.phase("req.queue"):
                 acquired = self._acquire_device(deadline)
             if not acquired:
@@ -356,7 +386,9 @@ class _SynonymCoalescer:
             # always raises on num<=0.
             if word is not None:
                 if word not in self.model.vocab.word_index:
-                    raise KeyError(f"word {word!r} not in vocabulary")
+                    if not self.composes:
+                        raise KeyError(f"word {word!r} not in vocabulary")
+                    self.model._oov_group(word)  # too short: KeyError
                 if num == 0:
                     return []
             raise ValueError("num must be > 0")
@@ -489,11 +521,14 @@ class _SynonymCoalescer:
             try:
                 if r["word"] is not None:
                     i = m.vocab.word_index.get(r["word"])
-                    if i is None:
+                    if i is not None:
+                        r["idx"] = i
+                    elif not self.composes:
                         raise KeyError(
                             f"word {r['word']!r} not in vocabulary"
                         )
-                    r["idx"] = i
+                    # else: the round composes it (``_compose_round``),
+                    # and refuses it there alone if it has no n-gram.
                 else:
                     v = np.asarray(r["vector"], dtype=np.float32)
                     if v.shape != (m.vector_size,):
@@ -533,7 +568,9 @@ class _SynonymCoalescer:
     def _dispatch(self, chunk, mode: str = "exact") -> None:
         """Answer one <= max_batch slice of the drained batch with one
         bucketed pull + one bucketed batch top-k dispatch (exact masked
-        GEMM, or the two-stage coarse+rerank when ``mode == "ann"``)."""
+        GEMM, or the two-stage coarse+rerank when ``mode == "ann"``);
+        the subword family's out-of-dictionary words add one bucketed
+        compose in between."""
         faults.fire("serving.dispatch")
         m = self.model
         # Version BEFORE the reads: if a table mutation lands mid-
@@ -549,15 +586,24 @@ class _SynonymCoalescer:
             "req.dispatch", batch=len(chunk), mode=mode,
             traces=[r["trace"] for r in chunk if r.get("trace")],
         ):
+            # The table a word's row is pulled from is the table its
+            # neighbours are scored against: the training table at word
+            # level, the composed one for the subword family (composed
+            # anew here, first, if the training tables have moved).
+            qeng = m._query_engine()
             word_rows = [r for r in chunk if "idx" in r]
             if word_rows:
                 with obs_events.phase_span("req.pull", rows=len(word_rows)):
                     pulled = _pull_coalesced(
-                        m.engine,
+                        qeng,
                         np.asarray([r["idx"] for r in word_rows], np.int32),
                     )
                 for r, v in zip(word_rows, pulled):
                     r["vec"] = v
+            if self.composes:
+                chunk = self._compose_round(chunk, len(word_rows))
+                if not chunk:
+                    return
             k = max(
                 r["num"] + (1 if r["word"] is not None else 0)
                 for r in chunk
@@ -609,6 +655,31 @@ class _SynonymCoalescer:
                         self._cache[
                             (r["word"], r["num"], mode)
                         ] = r["result"]
+
+    def _compose_round(self, chunk, n_dictionary: int):
+        """The subword family's share of a round: the vectors of the
+        chunk's out-of-dictionary words, hashed on the host and averaged
+        from ``syn0`` by ONE bucketed ``pull_average``. A word too short
+        for any n-gram takes its KeyError (-> 404) alone; returns the
+        requests that go on to the top-k."""
+        oov = [r for r in chunk if r["word"] is not None and "idx" not in r]
+        if not oov:
+            return chunk
+        with obs_events.phase_span(
+            "req.compose", words=n_dictionary, oov=len(oov)
+        ) as span:
+            vectors, errors, slots, rows = self.model.compose_oov(
+                [r["word"] for r in oov]
+            )
+            span.update(slots=slots, rows=rows)
+        for r, v, e in zip(oov, vectors, errors):
+            if e is None:
+                r["vec"] = v
+            else:
+                r["error"] = e
+        if self.metrics is not None:
+            self.metrics.record_compose(len(oov), slots, rows)
+        return [r for r in chunk if r["error"] is None]
 
 
 class SnapshotWatcher:
@@ -869,7 +940,12 @@ class ServedModel:
         fn = getattr(eng, "resident_bytes", None)
         if fn is None or not self.resident:
             return 0
-        return int(fn())
+        # The subword family's composed query engine rests beside the
+        # training tables: the budget counts both.
+        qeng = getattr(self.model, "_qeng", None)
+        return int(fn()) + (
+            int(qeng.resident_bytes()) if qeng is not None else 0
+        )
 
 
 class ModelCatalog:
@@ -1245,11 +1321,22 @@ class ModelServer:
         self.catalog.install(_default_entry, default=True)
         # -- approximate top-k (ISSUE 12) ------------------------------
         #: Whether the two-stage device index serves default /synonyms
-        #: traffic. Only the base word-level family (the batching
-        #: population) qualifies; per-request ``exact=true`` always
-        #: escapes to the exact masked GEMM, and the measured recall
-        #: gate can hold the approximate path back entirely.
-        self.ann = bool(ann) and self._coalescer.can_batch
+        #: traffic. Only the base word-level family qualifies (the index
+        #: is built over the training table's rows, which are not the
+        #: subword family's word vectors: that family stays exact);
+        #: per-request ``exact=true`` always escapes to the exact masked
+        #: GEMM, and the measured recall gate can hold the approximate
+        #: path back entirely.
+        self.ann = (
+            bool(ann) and self._coalescer.can_batch
+            and not self._coalescer.composes
+        )
+        if ann and not self.ann:
+            logger.warning(
+                "ANN index refused for a %s: the approximate path covers "
+                "the word-level family only; serving exact",
+                type(model).__name__,
+            )
         self.ann_recall_gate = float(ann_recall_gate)
         self.ann_recall_sample = max(1, int(ann_recall_sample))
         self._ann_live = False
@@ -1268,9 +1355,7 @@ class ModelServer:
                     time.time() - t0, conf["clusters"], conf["slots"],
                 )
         if warmup:
-            self._warmup(
-                warm_ks, warm_sentence_lens, warm_sentence_rows
-            )
+            self._warmup(self.catalog.default)
         if self.ann:
             # Recall gate AFTER warmup: the check rides the warmed
             # exact + approximate programs, so it proves the index AND
@@ -1781,15 +1866,8 @@ class ModelServer:
             metrics.generation = generation
         self.catalog.install(entry)
         do_warm = self._do_warmup if warmup is None else bool(warmup)
-        if do_warm and coalescer.can_batch:
-            warm_ks, warm_lens, warm_rows = self._warm_params
-            q_buckets = [
-                1 << i for i in range(self.max_batch.bit_length())
-            ]
-            model.engine.warmup(
-                q_buckets, warm_ks,
-                sentence_lens=warm_lens, sentence_rows=warm_rows,
-            )
+        if do_warm:
+            self._warmup(entry)
         metrics.warmup_compiles = self._query_compiles(entry)
         self.catalog.enforce_budget()
         logger.info(
@@ -1829,6 +1907,7 @@ class ModelServer:
         snap = entry.metrics.snapshot(
             self._query_compiles(entry),
             checkpoint=self._checkpoint_stats(entry),
+            composed_table=self._composed_table_stats(entry),
             index_staleness=(
                 self._index_staleness(entry) if is_default else None
             ),
@@ -2130,12 +2209,24 @@ class ModelServer:
         except Exception:
             return {}
 
+    def _composed_table_stats(self, entry: ServedModel) -> dict:
+        """How often, and for how many seconds, the subword family's
+        composed word table was built ({} for a family without one)."""
+        model = entry.model
+        builds = getattr(model, "query_engine_builds", None)
+        if builds is None:
+            return {}
+        return {
+            "builds": int(builds),
+            "seconds": float(model.query_engine_build_seconds),
+        }
+
     def _query_compiles(
         self, entry: Optional[ServedModel] = None
     ) -> int:
         """Total query-op shapes compiled across one model's engines
-        (the training engine plus FastText's lazily-built composed query
-        engine, when it exists). Per-engine first-seen counts: a shape
+        (the training engine plus the subword family's composed query
+        engine, once it exists). Per-engine first-seen counts: a shape
         another model already built still counts here (that is the
         warmed-family contract each model asserts individually);
         process-level build counts live on the catalog snapshot."""
@@ -2150,36 +2241,55 @@ class ModelServer:
             if e is not None
         )
 
-    def _warmup(
-        self, warm_ks, warm_sentence_lens, warm_sentence_rows
-    ) -> None:
-        """Compile the serving shape family before the port binds (only
-        the base word-level family — an overriding family keeps its own
-        dispatch shapes and its own single-query path)."""
-        if not self._coalescer.can_batch:
+    def _warmup(self, entry: ServedModel) -> None:
+        """Compile one model's serving shape family before the port
+        binds (or, for ``add_model``, before the entry takes requests):
+        the SAME bucket family for every entry, so same-(V, d) models
+        share every compiled program. The word-level family warms its
+        training engine (pull and top-k over the Q and k buckets, the
+        sentence grid). The subword family builds its composed word
+        table first, warms the pull and the top-k family over THAT
+        engine, and its compose buckets (``warm_compose``: every block
+        shape a round's out-of-dictionary words, ``/vector``,
+        ``/analogy`` or ``/transform`` can dispatch) over the training
+        engine. Any other overriding family keeps its own dispatch
+        shapes and its single-query path: nothing to warm here."""
+        model = entry.model
+        if not entry.coalescer.can_batch:
             return
+        warm_ks, warm_sentence_lens, warm_sentence_rows = self._warm_params
         q_buckets = [1 << i for i in range(self.max_batch.bit_length())]
         t0 = time.time()
-        n = self.model.engine.warmup(
-            q_buckets,
-            warm_ks,
-            sentence_lens=warm_sentence_lens,
-            sentence_rows=warm_sentence_rows,
+        if entry.coalescer.composes:
+            n = model._query_engine().warmup(q_buckets, warm_ks)
+            n += model.warm_compose()
+        else:
+            n = model.engine.warmup(
+                q_buckets,
+                warm_ks,
+                sentence_lens=warm_sentence_lens,
+                sentence_rows=warm_sentence_rows,
+            )
+        ann = (
+            self.ann and entry is self.catalog.default
+            and model.engine.ann_index is not None
         )
-        if self.ann and self.model.engine.ann_index is not None:
+        if ann:
             # The approximate dispatch family (coarse score + bucketed
             # rerank + the promotion-path assignment program) warms
             # with the exact family, BEFORE the port binds — the
             # zero-post-warmup-compiles contract covers both paths
             # (ISSUE 12 satellite).
-            n += self.model.engine.warmup_ann(
+            n += model.engine.warmup_ann(
                 q_buckets=q_buckets, k_buckets=warm_ks,
             )
         logger.info(
-            "serving warmup: %d shapes compiled in %.1fs "
-            "(Q buckets %s, k buckets %s%s)",
-            n, time.time() - t0, q_buckets, tuple(warm_ks),
-            ", +ann" if self.ann else "",
+            "serving warmup of %r: %d shapes compiled in %.1fs "
+            "(Q buckets %s, k buckets %s%s%s)",
+            entry.model_id, n, time.time() - t0, q_buckets,
+            tuple(warm_ks), ", +ann" if ann else "",
+            ", +composed table and compose buckets"
+            if entry.coalescer.composes else "",
         )
 
     # -- request dispatch ---------------------------------------------

@@ -443,6 +443,12 @@ class ServingMetrics:
         self.ann_queries = 0
         self.ann_probes = 0
         self.exact_fallbacks: Dict[str, int] = {}
+        # Subword family (ISSUE 43): the coalesced rounds' composes of
+        # out-of-dictionary query words.
+        self.oov_queries = 0
+        self.compose_dispatches = 0
+        self.compose_slots = 0
+        self.compose_rows = 0
 
     #: Cap on distinct tracked endpoint paths: the key is the raw
     #: client-supplied request path, and without a bound a port scanner
@@ -576,13 +582,27 @@ class ServingMetrics:
                 self.exact_fallbacks.get(reason, 0) + int(n)
             )
 
+    def record_compose(self, words: int, slots: int, rows: int) -> None:
+        """One coalesced round's compose of ``words`` out-of-dictionary
+        query words: ``slots`` group slots gathered (the bucket's
+        padding included; 0 where no word had a group and nothing was
+        dispatched), ``rows`` of them live table rows."""
+        with self._mu:
+            self.oov_queries += words
+            self.compose_dispatches += 1 if slots else 0
+            self.compose_slots += slots
+            self.compose_rows += rows
+
     def snapshot(self, total_compiles: int = 0,
                  checkpoint: Optional[dict] = None,
-                 index_staleness: Optional[int] = None) -> dict:
+                 index_staleness: Optional[int] = None,
+                 composed_table: Optional[dict] = None) -> dict:
         """``checkpoint`` is the engine's ``checkpoint_stats()`` dict
         (pending_async_saves / last_checkpoint_age_seconds /
         checkpoint_write_seconds); serving a freshly-loaded model reports
-        Nones — the keys exist either way so dashboards never branch."""
+        Nones — the keys exist either way so dashboards never branch.
+        ``composed_table`` is the subword family's ``{"builds",
+        "seconds"}`` of its composed word table (zeros elsewhere)."""
         slo = self.slo
         slo_snap = slo.snapshot() if slo is not None else None
         with self._mu:
@@ -672,6 +692,18 @@ class ServingMetrics:
                     "exact_fallbacks": dict(self.exact_fallbacks),
                     "table_versions_behind": index_staleness,
                 },
+            }
+            out["compose"] = {
+                "oov_queries_total": self.oov_queries,
+                "dispatches_total": self.compose_dispatches,
+                "group_slots_total": self.compose_slots,
+                "group_rows_total": self.compose_rows,
+                "table_builds_total": (composed_table or {}).get(
+                    "builds", 0
+                ),
+                "table_build_seconds_total": round(
+                    (composed_table or {}).get("seconds", 0.0), 3
+                ),
             }
             if slo_snap is not None:
                 out["slo"] = slo_snap

@@ -691,3 +691,21 @@ def test_query_program_at_the_benchmark_size_copies_no_table(
     assert _rests(compiled.input_formats[0][0], eng)
     assert not _whole_table_copies(compiled, eng)
     assert mem["temp"] < temp_ceiling, mem
+
+
+# The served subword cell's compose (benchmark/configs/ft-nn-300-1m-2mb.json:
+# 1M words + 2M bucket rows, groups 16 wide): a coalesced round's
+# out-of-dictionary words are ONE pull-average at their power-of-two bucket
+# (PR 43), one word through /vector the bucket of 1, the composed table's
+# build and the bulk paths the whole block of 4,096. None may copy the
+# 4.6 GB table to reach its rows.
+@pytest.mark.parametrize("rows", [1, 64, 4096])
+def test_compose_bucket_at_the_served_subword_cell_size(engines, rows):
+    eng = engines(1, vocab=1_000_000, extra_rows=BUCKET)
+    compiled = _lower_query(eng, "pull_average", (rows, 16)).compile()
+    mem = _fits(compiled)
+    assert _rests(compiled.input_formats[0][0], eng)
+    assert not _whole_table_copies(compiled, eng)
+    # the gathered slots, twice over (the gather and its masked product)
+    assert mem["temp"] < 2 * rows * 16 * D_REST * 4 + 10**6, mem
+

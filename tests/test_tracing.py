@@ -135,8 +135,8 @@ def test_trace_id_adoption_and_minting():
 def test_request_span_registry_is_closed():
     assert set(obs_events.REQUEST_SPANS) == {
         "req.accept", "req.admission", "req.queue", "req.hop",
-        "req.grace", "req.dispatch", "req.pull", "req.query",
-        "req.readback", "req.serialize",
+        "req.grace", "req.dispatch", "req.pull", "req.compose",
+        "req.query", "req.readback", "req.serialize",
     }
     assert obs_events.TRACE_HEADER.lower() == "x-glint-trace"
 
