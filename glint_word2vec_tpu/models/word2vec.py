@@ -743,6 +743,7 @@ class Word2Vec:
                 int(stop_after_groups) if stop_after_groups else None
             )
             packed_groups = packed_pairs = packed_slots = 0
+            steps_run = 0  # steps the device ran, by what it wrote
             # distinct rows written (syn0, syn1), slabs moved (syn0, syn1);
             # a subword fit also: live group ids gathered, centres formed;
             # a CBOW fit: live bag slots, positions trained; a subword
@@ -891,12 +892,14 @@ class Word2Vec:
                 # tests/test_stall.py pins it). A group dispatched
                 # entirely past the corpus end (the deferred schedule's
                 # one possible phantom tail group) records nothing and
-                # does NOT advance the step counter: its steps were all
-                # zero-pair no-ops, and the epoch-end ``dstep = step``
-                # reset drops its fold_in keys so the next epoch's key
-                # schedule matches the synchronous loop bitwise.
+                # does NOT advance the step counter: the device ran none
+                # of its steps (the scan stops at the corpus end, as it
+                # does in the last group's tail), and the epoch-end
+                # ``dstep = step`` reset drops its fold_in keys so the
+                # next epoch's key schedule matches the synchronous loop
+                # bitwise.
                 nonlocal step, epoch_wd
-                nonlocal packed_pairs, packed_slots, packed_groups
+                nonlocal packed_pairs, packed_slots, packed_groups, steps_run
                 (losses, pair_counts, pos_ends, alphas_d, written,
                  start_h) = pend
                 # The three children part the host BLOCKED on the device
@@ -919,7 +922,11 @@ class Word2Vec:
                     # advance, so the first start past the corpus end
                     # makes all later steps no-ops.
                     n_real = int((starts < n_pos).sum())
-                    hspan.update(n=n_real)
+                    # The scan stops at the corpus end and leaves alpha 0
+                    # where it ran no step; a step that ran wrote at least
+                    # the rule's floor.
+                    ran = int((alphas_h > 0).sum())
+                    hspan.update(n=n_real, ran=ran)
                     with obs_run.span("harvest_account", n=n_real):
                         for i in range(n_real):
                             step += 1
@@ -948,6 +955,7 @@ class Word2Vec:
                     step += spc - n_real  # tail no-ops consumed keys
                 packed_pairs += int(pairs_h[:n_real].sum())
                 packed_slots += n_real * pair_batch
+                steps_run += ran
                 written_h = written_h[:n_real].sum(axis=0)
                 rows_written[:written_h.size] += written_h
                 packed_groups += 1
@@ -1284,6 +1292,8 @@ class Word2Vec:
             # position slots (packed_pairs still counts the live bag
             # slots, the rows the bags gathered).
             model.training_metrics.update(
+                steps_dispatched=packed_groups * spc,
+                steps_run=steps_run,
                 packed_pairs=packed_pairs,
                 packed_mask_density=round(
                     (rows_written[5] if cbow else packed_pairs)

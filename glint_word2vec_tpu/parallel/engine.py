@@ -1224,6 +1224,52 @@ class EmbeddingEngine:
                     step_size * 1e-4,
                 )
 
+            def steps_to_corpus_end(body, syn0_l, syn1_l, pstart, n_valid,
+                                    counts):
+                # Steps 0..K-1 of ``body``, as ``lax.scan`` would run
+                # them, but only those that START inside the view
+                # (``pos < n_valid``): a step past the corpus end trains
+                # nothing and costs the device what a live one does, and
+                # an epoch ends with most of a group of them and, under
+                # the deferred schedule, one whole phantom group. The
+                # condition reads replicated values alone, so every chip
+                # makes the same trips around the step's all-reduces.
+                # What the loop did not reach reads as the host's
+                # accounting expects of a step that consumed nothing:
+                # ``pos_ends`` the final position, every count and loss
+                # 0, and alpha 0, which no step that ran writes (the
+                # rule's floor is ``step_size * 1e-4``): the host counts
+                # the steps the device ran from it. ``counts`` is the
+                # width of the body's fifth output.
+                bufs = (
+                    jnp.zeros(K, jnp.float32), jnp.zeros(K, jnp.int32),
+                    jnp.zeros(K, jnp.int32), jnp.zeros(K, jnp.float32),
+                    jnp.zeros((K, counts), jnp.int32),
+                )
+
+                def live(state):
+                    i, (_, _, pos), _ = state
+                    return (i < K) & (pos < n_valid)
+
+                def step(state):
+                    i, carry, bufs = state
+                    carry, ys = body(carry, i)
+                    return i + 1, carry, tuple(
+                        lax.dynamic_update_index_in_dim(b, y, i, 0)
+                        for b, y in zip(bufs, ys)
+                    )
+
+                ran, (syn0_l, syn1_l, pos), bufs = lax.while_loop(
+                    live, step,
+                    (jnp.uint32(0), (syn0_l, syn1_l, pstart), bufs),
+                )
+                losses, n_pairs, pos_ends, alphas, written = bufs
+                pos_ends = jnp.where(
+                    jnp.arange(K, dtype=jnp.uint32) < ran, pos_ends, pos
+                )
+                return (syn0_l, syn1_l, losses, n_pairs, pos_ends, alphas,
+                        written)
+
             def local_bag_packed_scan(syn0_l, syn1_l, noise, ids, sent_of,
                                       soffs, orig_offs, n_valid, pstart,
                                       base_key, step0, grid_step0,
@@ -1315,12 +1361,9 @@ class EmbeddingEngine:
                         jnp.concatenate([written, formed]),
                     )
 
-                (syn0_l, syn1_l, _), ys = lax.scan(
-                    body,
-                    (syn0_l, syn1_l, pstart),
-                    jnp.arange(K, dtype=jnp.uint32),
+                return steps_to_corpus_end(
+                    body, syn0_l, syn1_l, pstart, n_valid, 9 if G else 6
                 )
-                return (syn0_l, syn1_l) + ys
 
             def local_packed_scan(syn0_l, syn1_l, noise, ids, sent_of, soffs,
                                   orig_offs, n_valid, pstart, base_key,
@@ -1374,12 +1417,9 @@ class EmbeddingEngine:
                         loss, n_pairs, pos_end, alpha, written
                     )
 
-                (syn0_l, syn1_l, _), ys = lax.scan(
-                    body,
-                    (syn0_l, syn1_l, pstart),
-                    jnp.arange(K, dtype=jnp.uint32),
+                return steps_to_corpus_end(
+                    body, syn0_l, syn1_l, pstart, n_valid, 6 if G else 4
                 )
-                return (syn0_l, syn1_l) + ys
 
             return jax.jit(
                 self._shard_map(
@@ -2205,6 +2245,14 @@ class EmbeddingEngine:
         formed.
         The caller reads ``pos_ends[-1]`` to schedule the next dispatch
         (one scalar readback per K steps).
+
+        The group stops at the corpus end ON THE DEVICE: a step runs while
+        ``i < K`` and its start lies inside the view. Steps the device did
+        not run (the tail of an epoch's last group, the whole of a group
+        started past the end) come back as ``alphas`` 0, which no step
+        that ran writes, ``losses`` / ``pair_counts`` / ``rows_written`` 0
+        and ``pos_ends`` the final position, and leave the tables as they
+        were; the caller's key schedule still counts K steps a dispatch.
 
         A CBOW engine trains POSITIONS, not pairs: ``pair_batch`` is then
         the consecutive centre positions of a step (each with its bag of

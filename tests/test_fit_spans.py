@@ -5,6 +5,8 @@ host's own accounting; an epoch on every dispatch.
 Contracts pinned here:
   * the three children lie inside their ``readback_harvest``, in order,
     once a harvested group, and fill it;
+  * ``readback_harvest`` says the steps the device ran (``ran``, ISSUE
+    44) beside the live ones (``n``), and they are the same;
   * ``run_end`` follows the last harvest and closes the ring (the
     benchmark's ``fit.tail_ms`` starts at the one and passes the other);
   * the children are no step-time ledger phases: ``readback_harvest``
@@ -84,6 +86,18 @@ def test_children_lie_inside_their_harvest_in_order_once_a_group(traced):
     # what the parent keeps for itself: the spans' own bookkeeping and the
     # count of live steps, well under a millisecond a group
     assert min(rests) >= -1.0 and statistics.median(rests) < 1000.0
+
+
+def test_every_harvest_says_the_steps_the_device_ran(traced):
+    # ISSUE 44: ``ran`` beside ``n``, counted from what the device wrote.
+    # The scan stops at the corpus end, so the device runs a group's live
+    # steps and no other; the fit's totals are the spans' sums.
+    events, metrics = traced
+    args = [a for _, _, a in _spans(events, "readback_harvest")]
+    assert all(0 <= a["n"] == a["ran"] <= 4 for a in args)
+    assert {a["n"] for a in args} >= {0, 4}  # phantom groups, full groups
+    assert metrics["steps_run"] == sum(a["ran"] for a in args)
+    assert metrics["steps_dispatched"] == 4 * len(args)
 
 
 def test_run_end_follows_the_last_harvest_and_closes_the_ring(traced):

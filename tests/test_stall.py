@@ -238,6 +238,16 @@ def test_packed_deferred_readback_bitwise_parity(monkeypatch):
         m_def.training_metrics["packed_pairs"]
         == m_sync.training_metrics["packed_pairs"]
     )
+    # The scan stops at the corpus end (ISSUE 44): under either schedule
+    # the device ran the live steps and no other, so what was dispatched
+    # and not run is the tail's count, a phantom group an epoch longer
+    # under the deferred schedule.
+    tails = []
+    for tm in (m_def.training_metrics, m_sync.training_metrics):
+        assert tm["steps_run"] == tm["steps"]
+        assert tm["steps_dispatched"] % 4 == 0
+        tails.append(tm["steps_dispatched"] - tm["steps_run"])
+    assert 0 <= tails[1] < 2 * 4 and tails[0] == tails[1] + 2 * 4
 
 
 @pytest.mark.parametrize("subsample_ratio", [0.0, 0.01])

@@ -341,6 +341,34 @@ def test_packed_corpus_scan_keeps_the_tables_as_they_rest(packed_scans, name):
     )
 
 
+def _stops_at_the_corpus_end(compiled, eng) -> None:
+    """ISSUE 44: the group's loop runs ``i < K and pos < n_valid`` trips, a
+    count the data decides. The chip's compiler must still keep both
+    tables in place through the loop's carry: no ``copy`` of anything
+    with a table's rows (the callers hold the donation to its aliasing)."""
+    import re
+
+    text = compiled.as_text()
+    assert re.search(r'op_name="jit\(local_(bag_)?packed_scan\)/'
+                     r'(shard_map/)?while/cond/and"', text)
+    rows = re.findall(
+        rf"= \w+\[{eng.rows_per_shard},\d+\]\S* (?:copy|transpose)\(", text)
+    assert not rows, rows
+
+
+@pytest.mark.parametrize("name", ["2m-1chip", "10m-4chips"])
+def test_packed_corpus_scan_stops_at_the_corpus_end(packed_scans, name):
+    import inspect
+
+    eng, compiled = packed_scans(name)
+    _stops_at_the_corpus_end(compiled, eng)
+    # ... and it is one program a (P, W, B, S, K, G), as it was: where the
+    # corpus ends is a traced argument, not a key of the cache.
+    assert list(inspect.signature(
+        eng._make_packed_corpus_scan).parameters) == [
+            "P", "W", "B_grid", "S", "K", "G"]
+
+
 def _scatter_holds_no_slot_buffer(compiled, eng, source_rows=0) -> list:
     """The lines of the compiled program under ``glint.scatter``, after
     asserting what PR 35 took out of them: no XLA scatter at all (the run
@@ -454,6 +482,7 @@ def test_subword_packed_scan_at_the_cell_size(engines):
     for table in ("syn0", "syn1"):
         assert any(f"glint.scatter/{table}" in k for k in kernels), table
     _scatter_holds_no_slot_buffer(compiled, eng)  # f32[360448,384] was one
+    _stops_at_the_corpus_end(compiled, eng)
     assert "glint.compose" in text and "glint.gather/syn0" in text
     # fastText's cc.en.300 shape, 2M words + 2M buckets, which ISSUE 31
     # reckoned too large for one chip: compiled once by hand it FITS, at
@@ -504,6 +533,7 @@ def test_cbow_packed_scan_at_the_cell_size(cbow_scan):
     for table in ("syn0", "syn1"):
         assert any(f"glint.scatter/{table}" in k for k in kernels), table
     _scatter_holds_no_slot_buffer(compiled, eng)  # f32[81920,384] was one
+    _stops_at_the_corpus_end(compiled, eng)
     for scope in ("glint.batch", "glint.sample", "glint.compose/group",
                   "glint.compose/bag", "glint.gather/syn0",
                   "glint.gather/syn1", "glint.grads"):
@@ -566,6 +596,7 @@ def test_subword_cbow_packed_scan_at_the_cell_size(engines):
     for table in ("syn0", "syn1"):
         assert any(f"glint.scatter/{table}" in k for k in kernels), table
     _scatter_holds_no_slot_buffer(compiled, eng)
+    _stops_at_the_corpus_end(compiled, eng)
     for scope in ("glint.batch", "glint.sample", "glint.compose/group",
                   "glint.compose/bag", "glint.gather/syn0",
                   "glint.gather/syn1", "glint.grads"):
