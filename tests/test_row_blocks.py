@@ -115,10 +115,13 @@ def test_no_traced_row_tensor_has_a_small_axis_beside_d(architecture):
     eng = engine(architecture)
     d = eng.padded_dim
     rows = []  # float32 values of rank >= 3 with d minor
+    # ... but a table as the slab writer is handed it (ISSUE 45): its own
+    # bytes seen as (tile rows, 8, d), whole tiles and no copy
+    slabs = (eng.syn0.shape[0] // 8, 8, d)
     for eqn in equations(traced_packed_scan(eng)):
         for var in eqn.outvars:
             shape = getattr(var.aval, "shape", ())
-            if (len(shape) >= 3 and shape[-1] == d
+            if (len(shape) >= 3 and shape[-1] == d and shape != slabs
                     and var.aval.dtype == jnp.float32):
                 rows.append((eqn.primitive.name, shape))
     # no axis of 5 negatives, 10 bag slots, 1 context or 205 pairs is ever

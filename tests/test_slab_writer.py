@@ -90,12 +90,59 @@ RUN_CASES = [
     "cut_slab_is_the_last",
 ]
 
+# What the kernel's unrolled loop can get wrong. These cases pass few
+# buffers, so that a chunk of CH slots has slabs for the pipeline's every
+# phase: TRIP["slots"] - TRIP["ahead"] slabs one by one while the buffers
+# are fresh, then trips of ``slab_writer.UNROLL`` and what is left of one,
+# then the last TRIP["ahead"] with nothing more to read.
+TRIP = {"slots": 16, "ahead": 8}
+U, A, S = slab_writer.UNROLL, TRIP["ahead"], TRIP["slots"]
+SLAB_COUNTS = sorted({0, 1, U - 1, U, U + 1, A - 1, A + 1, S, S + U - 1,
+                      S + U, S + U + 1, 3 * S})
+# slabs a chunk moves whole before the one its edge cuts: the cut slab
+# would have been a trip's first, or its last
+CUT_AT = {"trip_first": S + 2 * U, "trip_last": S + 2 * U + U - 1}
+TRIP_CASES = (
+    [f"slabs={n}" for n in SLAB_COUNTS]
+    + [f"slab_of={k}" for k in (1, 2, 3, 40)]
+    + [f"{what}_cut_at_{at}" for what in ("run", "slab") for at in CUT_AT]
+    + ["dead_chunk_behind_trips", "shard_owns_none"]
+)
 
-def _slots(live, rng):
+
+def _trips(case, sub):
+    """``(live rows, dead slots, chunk)`` of a case of ``TRIP_CASES``."""
+    lone = np.arange(3, 63) * sub + 5  # 60 slabs of one slot each
+    if case.startswith("slabs="):  # so many slabs in the one chunk
+        return lone[:int(case[6:])], 3, CH
+    if case.startswith("slab_of="):
+        # inside a trip (slab S - A + 2 * U + 1 of the chunk, not a
+        # trip's first), a slab with so many slots over three of its rows
+        k = int(case[8:])
+        at = S - A + 2 * U + 1
+        big = 70 * sub + np.sort(np.arange(k) % 3)
+        return np.concatenate([lone[:at], big, lone[at:2 * at] + 60 * sub]
+                              ), 3, 2 * CH
+    if "_cut_at_" in case:
+        what, at = case.split("_cut_at_")
+        m = CUT_AT[at]
+        # m lone slots, then a slab on slots m .. CH + 9: one row's run, or
+        # two rows whose runs meet at the chunk's edge
+        over = np.full(CH + 10 - m, 100 * sub + 1)
+        if what == "slab":
+            over[CH - m:] += 1
+        return np.concatenate([lone[:m], over, [V - 1]]), 3, CH
+    if case == "dead_chunk_behind_trips":  # CH live slots, then 70 dead
+        return lone[:CH - 4].tolist() + [V - 4, V - 3, V - 2, V - 1], 70, CH
+    assert case == "shard_owns_none"
+    return np.zeros(0, np.int64), 200, CH
+
+
+def _slots(live, rng, dead=3):
     """``(sid, coefs, src, hidx)`` as ``_scatter_rows`` hands them to its
-    writers: the batch's slots (``live`` in a random order among a few
+    writers: the batch's slots (``live`` in a random order among ``dead``
     slots of other shards' rows) sorted by row."""
-    ids = rng.permutation(np.concatenate([live, [V, V, V]]))
+    ids = rng.permutation(np.concatenate([live, np.full(dead, V)]))
     src = rng.normal(0, 1, (32, D)).astype(np.float32)
     hidx = rng.integers(0, 32, ids.size).astype(np.int32)
     coefs = rng.normal(0, 0.05, ids.size).astype(np.float32)
@@ -108,21 +155,25 @@ def _slots(live, rng):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("case", ROW_CASES + RUN_CASES)
+@pytest.mark.parametrize("case", ROW_CASES + RUN_CASES + TRIP_CASES)
 def test_slab_writer_is_bit_equal_to_xla_writer(case, dtype):
     rng = np.random.default_rng(len(case))
     sub = slab_writer.slab_rows(dtype)
+    dead, buffers = 3, {}
     if case in ROW_CASES:
         rows, chunk = _rows(case, sub, rng)
         live = np.concatenate([rows, rows])
-    else:
+    elif case in RUN_CASES:
         live, chunk = _runs(case, sub), CH
+    else:
+        (live, dead, chunk), buffers = _trips(case, sub), TRIP
+        live = np.asarray(live, np.int64)
     table = jnp.asarray(rng.normal(0, 0.5, (V, D)), dtype)
-    slots = _slots(live, rng)
+    slots = _slots(live, rng, dead)
 
     want, none = engine._write_rows(table, *slots)
     got, moved = slab_writer.write(
-        table, *slots, chunk=chunk, interpret=True
+        table, *slots, chunk=chunk, interpret=True, **buffers
     )
 
     assert got.dtype == table.dtype and int(none) == 0
@@ -144,26 +195,36 @@ def test_slab_writer_is_bit_equal_to_xla_writer(case, dtype):
     cut = [k for k in range(chunk, live.size, chunk)
            if slabs[k] == slabs[k - 1]]
     if case in ("slab_across_chunks", "run_of_600", "run_cut_by_chunk_edge",
-                "run_ends_at_chunk_edge", "cut_slab_is_the_last"):
+                "run_ends_at_chunk_edge", "cut_slab_is_the_last"
+                ) or "_cut_at_" in case:
         assert cut, case  # the case is what its name says
+    if "_cut_at_" in case:  # and so is where the cut slab stands
+        whole = np.unique(slabs[:chunk]).size - 1
+        assert (whole - (S - A)) % U == (0 if "first" in case else U - 1)
     if case == "run_of_600":
         assert len(cut) >= 9
     if case == "dense_head":
         assert int(moved) * sub == rows.size
 
 
-@pytest.mark.parametrize("slots,ahead", [(2, 1), (4, 3), (8, 2), (32, 16)])
-def test_slab_writer_whatever_the_buffers(slots, ahead):
-    """More slabs than buffers, fewer, and as many: the four phases of
-    the kernel's pipeline each run dry in one of these."""
+@pytest.mark.parametrize("slots,ahead,unroll", [
+    (2, 1, 1), (4, 3, 1), (8, 2, 2), (32, 16, 4),
+    (8, 4, 4), (16, 8, 8), (64, 24, 4), (64, 32, 8),
+])
+def test_slab_writer_whatever_the_buffers(slots, ahead, unroll):
+    """More slabs than buffers, fewer, and as many, and a trip more or
+    less: the phases of the kernel's pipeline each run dry in one of
+    these."""
     rng = np.random.default_rng(slots)
     table = jnp.asarray(rng.normal(0, 0.5, (V, D)), jnp.float32)
-    for n in sorted({1, ahead, slots - ahead, slots, slots + 1, 3 * slots}):
+    for n in sorted({1, ahead, slots - ahead, slots, slots + 1,
+                     slots + unroll - 1, slots + unroll, 3 * slots}):
         rows = np.sort(rng.permutation(V // 8)[:n]) * 8 + rng.integers(0, 8, n)
         args = _slots(np.concatenate([rows, rows]), rng)
         want, _ = engine._write_rows(table, *args)
         got, moved = slab_writer.write(
-            table, *args, chunk=CH, slots=slots, ahead=ahead, interpret=True,
+            table, *args, chunk=max(CH, 8 * slots), slots=slots, ahead=ahead,
+            unroll=unroll, interpret=True,
         )
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         assert int(moved) == n

@@ -427,10 +427,16 @@ def test_packed_corpus_scan_writes_rows_by_slabs(packed_scans, name):
     assert kernels and all("glint.scatter/syn" in k for k in kernels), kernels
     for table in ("syn0", "syn1"):
         assert any(f"glint.scatter/{table}" in k for k in kernels), table
-        # one sort a table: the second, which brought the distinct rows to
-        # the front, went with the totals
-        assert len([line for line in lines if " sort(" in line
-                    and f"glint.scatter/{table}" in line]) == 1, table
+        # one sort of the slots a table (the second, which brought the
+        # distinct rows to the front, went with the totals), and since
+        # ISSUE 45 one of each chunk's slab keys with their tile rows, a
+        # row of 4,096 a chunk: what lays the kernel's tables down a slab
+        sorts = [line.split(" sort(")[0] for line in lines if " sort(" in line
+                 and f"glint.scatter/{table}" in line]
+        assert len(sorts) == 2, sorts
+        assert len([r for r in sorts if ",4096]" not in r]) == 1, sorts
+        assert len([r for r in sorts
+                    if r.count("[") == 2 == r.count(",4096]")]) == 1, sorts
     assert _fits(compiled, SCANS[name][0])["temp"] < PARENT_TEMP[name]
 
 
@@ -633,6 +639,51 @@ def test_slab_writer_compiles_for_bfloat16(topo):
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes == 2_000_000 * D_REST * 2
     assert m.temp_size_in_bytes < 10**6, m
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("width", [128, 384, 1024, 2048])
+def test_slab_writer_compiles_at_width(topo, width, dtype):
+    # The kernel alone over a 3 GB table (1.5 in bfloat16) of each width
+    # the engine can rest a table in, with the tables ISSUE 45 lays down a
+    # slab in SMEM: a chunk's tile rows, first slots and sublanes, three
+    # int32 a slot (48 KB at the 4,096 slots a 384-column table takes, 6
+    # KB at the 512 of 2,048 columns), where the parent's rows and hops
+    # were two. The table goes in and comes out as (tile rows, sub, width)
+    # and no copy of it is made on the way.
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from jax.sharding import SingleDeviceSharding
+
+    from glint_word2vec_tpu.ops import slab_writer
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n = 157_290  # syn1's update slots of the benchmark's step
+    rows = 2_000_000 * D_REST // width // 16 * 16
+    itemsize = jnp.dtype(dtype).itemsize
+    compiled = jax.jit(slab_writer.write, donate_argnums=0).lower(
+        sds((rows, width), dtype), sds((n,), jnp.int32),
+        sds((n,), jnp.float32), sds((26_215, width), jnp.float32),
+        sds((n,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == rows * width * itemsize
+    # a chunk's payload and the bookkeeping of every chunk, no more
+    assert m.temp_size_in_bytes < 2 * slab_writer.PAYLOAD_BYTES, m
+    sub = slab_writer.slab_rows(dtype)
+    table = (rf"(?:f32|bf16)\[(?:{rows},{width}|{rows // sub},{sub},{width})\]")
+    assert not [line[:200] for line in text.splitlines()
+                if re.search(rf"= {table}\S* (?:copy|transpose)\(", line)]
 
 
 def test_subsample_compact_compiles(engines):
