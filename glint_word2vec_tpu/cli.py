@@ -48,9 +48,6 @@ def _add_train(sub):
                    default=None,
                    help="MXU operand dtype for the step's dense "
                         "contractions (f32 accumulation either way)")
-    p.add_argument("--layout", choices=["rows", "dims"], default="rows",
-                   help="model-axis table partitioning: vocab rows or "
-                        "embedding dims (CIKM column sharding)")
     p.add_argument("--steps-per-call", type=int, default=16,
                    help="minibatches per device dispatch (on-device scan)")
     p.add_argument("--architecture", choices=["skipgram", "cbow"],
@@ -1438,7 +1435,6 @@ def _run(args) -> int:
             num_shards=args.num_shards,
             dtype=args.dtype,
             compute_dtype=args.compute_dtype,
-            layout=args.layout,
             steps_per_call=args.steps_per_call,
             architecture=args.architecture,
             shared_negatives=args.shared_negatives,

@@ -111,7 +111,6 @@ class FastTextWord2Vec(Word2Vec):
             extra_rows=p.bucket,
             shared_negatives=p.shared_negatives,
             compute_dtype=p.compute_dtype,
-            layout=p.layout,
             architecture=p.architecture,
         )
 

@@ -293,11 +293,6 @@ class Word2Vec:
         either way)."""
         return self._set(compute_dtype=v)
 
-    def set_layout(self, v: str) -> "Word2Vec":
-        """Model-axis table partitioning: "rows" (default) or "dims"
-        (CIKM'16 column sharding — scalar-logit model-axis traffic)."""
-        return self._set(layout=v)
-
     def set_steps_per_call(self, v: int) -> "Word2Vec":
         return self._set(steps_per_call=v)
 
@@ -1775,7 +1770,6 @@ class Word2Vec:
             dtype=p.dtype,
             shared_negatives=p.shared_negatives,
             compute_dtype=p.compute_dtype,
-            layout=p.layout,
             architecture=p.architecture,
         )
 

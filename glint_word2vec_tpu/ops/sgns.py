@@ -33,11 +33,9 @@ from glint_word2vec_tpu.ops.sampling import sample_negatives_per_row
 
 
 class SgnsCoefs(NamedTuple):
-    """Logit-stage outputs shared by every sharding layout: the scalar SGD
-    coefficients (the reference's gPlus/gMinus wire format, mllib:422-425)
-    plus the monitoring loss. Computed from FULL logits — under dim/column
-    sharding (parallel/engine.py layout="dims") each shard psums its
-    partial dot products first, then evaluates this identically."""
+    """Logit-stage outputs: the scalar SGD coefficients (the reference's
+    gPlus/gMinus wire format, mllib:422-425) plus the monitoring loss,
+    computed from FULL logits."""
 
     c_pos: jax.Array  # (B, C)
     c_neg: jax.Array  # (B, C, n)
@@ -51,7 +49,7 @@ def sgns_coefs(
     neg_mask: jax.Array,  # (B, C, n) float32
     alpha: jax.Array,  # () float32
 ) -> SgnsCoefs:
-    """Coefficients + loss from already-reduced logits (layout-agnostic)."""
+    """Coefficients + loss from full logits."""
     s_pos = jax.nn.sigmoid(f_pos)
     s_neg = jax.nn.sigmoid(f_neg)
     c_pos = alpha * (1.0 - s_pos) * mask
@@ -102,9 +100,7 @@ def row_dots(h: jax.Array, u, compute_dtype=jnp.float32) -> jax.Array:
 
 def row_sums(c: jax.Array, u, compute_dtype=jnp.float32) -> jax.Array:
     """``sum_k c[b, k] * u[k][b, :]``, ``(B, d)`` float32: the blocks of
-    :func:`row_dots`, each row weighted, summed over the major axis.
-    Columnwise-independent, so a dim-sharded shard passes its local column
-    slices and gets its local slice: no communication."""
+    :func:`row_dots`, each row weighted, summed over the major axis."""
     cc = _rounded(c, compute_dtype)
     terms = [
         cc[:, k, None] * _rounded(block, compute_dtype)
@@ -150,8 +146,8 @@ def sgns_grads(
 def sgns_d_center(
     c_pos: jax.Array,  # (B, C)
     c_neg: jax.Array,  # (B, C, n)
-    u_pos,  # C blocks of (B, dl) — full d or a column slice of it
-    u_neg,  # C * n blocks of (B, dl)
+    u_pos,  # C blocks of (B, d)
+    u_neg,  # C * n blocks of (B, d)
     compute_dtype=jnp.float32,
 ) -> jax.Array:
     """d L/d h with the learning rate folded in (pure SGD step direction)."""
@@ -254,8 +250,8 @@ def shared_sgns_grads(
 
 
 class SharedSgnsCoefs(NamedTuple):
-    """Logit-stage outputs of the shared-pool estimator (layout-agnostic;
-    see :class:`SgnsCoefs`)."""
+    """Logit-stage outputs of the shared-pool estimator (see
+    :class:`SgnsCoefs`)."""
 
     c_pos: jax.Array  # (B, C)
     c_pool: jax.Array  # (B, S)
@@ -289,13 +285,12 @@ def shared_sgns_coefs(
 def shared_sgns_updates(
     c_pos: jax.Array,  # (B, C)
     c_pool: jax.Array,  # (B, S)
-    h: jax.Array,  # (B, dl) — full d or a column slice
-    u_pos,  # C blocks of (B, dl)
-    u_pool: jax.Array,  # (S, dl)
+    h: jax.Array,  # (B, d)
+    u_pos,  # C blocks of (B, d)
+    u_pool: jax.Array,  # (S, d)
     compute_dtype=jnp.float32,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(d_center, d_pool) from coefficients — columnwise-independent, so a
-    dim-sharded shard passes local column slices (see :func:`sgns_d_center`)."""
+    """(d_center, d_pool) from coefficients."""
     cpool_c = c_pool.astype(compute_dtype)
     upool_c = u_pool.astype(compute_dtype)
     d_center = row_sums(c_pos, u_pos, compute_dtype) + jnp.dot(

@@ -74,11 +74,10 @@ from glint_word2vec_tpu.parallel.mesh import (
     MODEL_AXIS,
     pad_to_multiple,
     table_sharding,
-    table_sharding_dims,
 )
 
 
-#: A ``rows`` table keeps each row in whole lanes of the device's
+#: A table keeps each row in whole lanes of the device's
 #: (8, 128) tile: ``dim`` columns rest in ``pad_to_multiple(dim,
 #: TABLE_LANES)``, the rest zero for good (a zero column adds nothing to
 #: a dot product and its gradient is zero). The TPU's default layout for
@@ -277,21 +276,21 @@ def _scatter_rows(table_l, idx, coefs, src, hidx, start):
     (the servers' half of ``adjust``, SURVEY.md §2.2): slot k adds
     ``coefs[k] * src[hidx[k]]`` to global row ``idx[k]``, where ``start``
     is the global id of local row 0. Updates of rows another shard owns
-    are dropped, not walked. Every table dtype and layout takes this one
-    path: the slots are sorted by row (:func:`_sort_slots`), each row's
-    run is summed once in float32 in the order it stood in the batch, and
-    each distinct row is written once, its total rounded once to the
-    table's dtype.
+    are dropped, not walked. Every table dtype takes this one path: the
+    slots are sorted by row (:func:`_sort_slots`), each row's run is
+    summed once in float32 in the order it stood in the batch, and each
+    distinct row is written once, its total rounded once to the table's
+    dtype.
 
     Two writers share the sorted slots and the count of their runs, and
     which one runs follows from what is observed, never from an option:
     where the program is lowered for a TPU and the table's rows can be
-    addressed by whole tile rows (``slab_writer.fits``: every ``rows``
-    table whose shard is a multiple of 16 rows), ``ops/slab_writer.py``'s
-    kernel moves each touched slab once and totals the runs of its rows
-    while it holds it; everywhere else (CPU, GPU, a ``dims`` shard of 75
-    columns) :func:`_write_rows` totals them into a buffer and hands XLA's
-    scatter the distinct rows. The two give the same table bit for bit.
+    addressed by whole tile rows (``slab_writer.fits``: every table whose
+    shard is a multiple of 16 rows), ``ops/slab_writer.py``'s kernel moves
+    each touched slab once and totals the runs of its rows while it holds
+    it; everywhere else (CPU, GPU) :func:`_write_rows` totals them into a
+    buffer and hands XLA's scatter the distinct rows. The two give the
+    same table bit for bit.
     Returns ``(table_l, rows written, slabs moved)``, the last 0 from
     XLA's writer."""
     Vs = table_l.shape[0]
@@ -423,8 +422,7 @@ def _pair_payload(ctx_g, negs_g, cpos_g, cneg_g, h_g):
     """``(ids, coefs, src, hidx)`` of the per-pair syn1 update for
     :func:`_scatter_rows`: slot order [contexts.flat | negs.flat]
     (rank-major batch axis), each slot its coefficient times its
-    centre's row of ``h_g``. Shared by both layouts' step bodies — the
-    ordering contract lives in exactly one place."""
+    centre's row of ``h_g``."""
     B, C = cpos_g.shape
     n = cneg_g.shape[-1]
     rows = jnp.arange(B, dtype=jnp.int32)
@@ -483,7 +481,6 @@ class EmbeddingEngine:
         extra_rows: int = 0,
         shared_negatives: int = 0,
         compute_dtype: Optional[str] = None,
-        layout: str = "rows",
         architecture: str = "skipgram",
     ):
         """``architecture`` is the model's (``Word2VecParams.architecture``):
@@ -497,38 +494,19 @@ class EmbeddingEngine:
         noise table spans the vocab only) and never surface from the query
         ops (top-k masks them; norms/multiply callers slice).
 
-        ``layout`` selects the model-axis partitioning:
-          * "rows" (default): vocab rows split 1/n per shard, full width.
-            Pulls psum whole rows over the model axis.
-          * "dims": every shard holds ALL rows x 1/n of the columns — the
-            CIKM'16 column partitioning the reference's servers use
-            (SURVEY.md §2.2): gathers/scatters are shard-local, and the
-            ONLY model-axis exchange in the train step is the psum of
-            scalar logit partials (the dot products the reference's
-            ``dotprod`` servers return). Per-chip HBM traffic for the
-            sparse row accesses divides by the model-axis size.
-
-        Guidance: per-chip table memory is V*d/n either way, except that
-        "rows" keeps each row in whole 128-column lanes (``TABLE_LANES``:
-        d = 300 rests in 384 columns, so that the device's default layout
-        keeps rows contiguous and no program copies a table to reach a
-        few rows); "dims" pads the columns to the shard count only.
-        For TRAINING at num_model > 1, "dims" is the better default —
-        its model-axis collectives are ~d/(1+overlap) times smaller and
-        its sparse HBM traffic scales down with the axis. "rows" wins
-        for query-heavy serving at huge vocab (top-k batch scores stay
-        (Q, V/n) per shard instead of (Q, V)) and when d is too small to
-        split usefully (d < 128 * num_model leaves sublane-starved
-        slices). Both train bit-equivalently up to reduction order, and
-        checkpoints re-home across layouts, so the choice is reversible.
+        The tables rest split by ROWS over the model axis, 1/n of the rows
+        a shard, each row in whole 128-column lanes (``TABLE_LANES``: d =
+        300 rests in 384 columns, so that the device's default layout keeps
+        rows contiguous and no program copies a table to reach a few
+        rows). A pull psums whole rows over the model axis; a top-k scores
+        ``(Q, V/n)`` a shard.
         """
         self._configure(
             mesh, vocab_size, dim, num_negatives=num_negatives,
             unigram_power=unigram_power,
             unigram_table_size=unigram_table_size, seed=seed, dtype=dtype,
             extra_rows=extra_rows, shared_negatives=shared_negatives,
-            compute_dtype=compute_dtype, layout=layout,
-            architecture=architecture,
+            compute_dtype=compute_dtype, architecture=architecture,
         )
         if counts.shape != (vocab_size,):
             raise ValueError("counts must have shape (vocab_size,)")
@@ -544,8 +522,7 @@ class EmbeddingEngine:
         # Initialize tables directly sharded on-device (no host round-trip):
         # syn0 ~ U[-0.5/d, 0.5/d), syn1 = 0 (word2vec standard, ops/sgns.py).
         # Randoms are drawn for the unpadded rows/cols only, then
-        # zero-padded, so initial values are layout- and mesh-shape-
-        # invariant (a "dims" engine starts bitwise-equal to a "rows" one).
+        # zero-padded, so initial values are mesh-shape-invariant.
         # That rests on partitionable threefry (JAX's default): the legacy
         # lowering produces sharding-DEPENDENT random values when GSPMD
         # partitions the draw.
@@ -579,8 +556,7 @@ class EmbeddingEngine:
         self, mesh, vocab_size: int, dim: int, *, num_negatives: int,
         unigram_power: float, unigram_table_size: Optional[int], seed: int,
         dtype: str, extra_rows: int, shared_negatives: int,
-        compute_dtype: Optional[str], layout: str,
-        architecture: str = "skipgram",
+        compute_dtype: Optional[str], architecture: str = "skipgram",
     ) -> None:
         """The host-only half of construction: validate, and derive every
         attribute the jitted closures capture (geometry, dtypes, step
@@ -589,8 +565,6 @@ class EmbeddingEngine:
         (tests/test_tpu_compile.py)."""
         if vocab_size <= 0 or dim <= 0:
             raise ValueError("vocab_size and dim must be > 0")
-        if layout not in ("rows", "dims"):
-            raise ValueError("layout must be 'rows' or 'dims'")
         if extra_rows < 0:
             raise ValueError("extra_rows must be >= 0")
         if shared_negatives < 0:
@@ -628,40 +602,30 @@ class EmbeddingEngine:
         )
         self.num_data = mesh.shape[DATA_AXIS]
         self.num_model = mesh.shape[MODEL_AXIS]
-        self.layout = layout
-        if layout == "rows":
-            self.padded_vocab = pad_to_multiple(self.num_rows, self.num_model)
-            self.rows_per_shard = self.padded_vocab // self.num_model
-            self.padded_dim = pad_to_multiple(self.dim, TABLE_LANES)
-            self.cols_per_shard = self.padded_dim
-        else:  # dims
-            self.padded_vocab = self.num_rows  # no row padding needed
-            self.rows_per_shard = self.num_rows
-            self.padded_dim = pad_to_multiple(self.dim, self.num_model)
-            self.cols_per_shard = self.padded_dim // self.num_model
+        self.padded_vocab = pad_to_multiple(self.num_rows, self.num_model)
+        self.rows_per_shard = self.padded_vocab // self.num_model
+        self.padded_dim = pad_to_multiple(self.dim, TABLE_LANES)
 
     @property
     def step_body(self) -> str:
         """Which step body this engine traces and which writer its
         scatters end in (:func:`_scatter_rows`), recorded in
-        ``training_metrics``: ``<layout>/<per_pair|shared_pool>/
-        <slab|xla>``. The writer is named by the rule :func:`_scatter_rows`
-        applies when the program is lowered for this mesh's devices."""
+        ``training_metrics``: ``rows/<per_pair|shared_pool>/<slab|xla>``
+        (``rows``: how the tables are split over the model axis; readers
+        of saved records expect the three parts). The writer is named by
+        the rule :func:`_scatter_rows` applies when the program is lowered
+        for this mesh's devices."""
         estimator = "shared_pool" if self.shared_negatives else "per_pair"
         slab = (
             self.mesh.devices.flat[0].platform == "tpu"
             and slab_writer.fits(
-                (self.rows_per_shard, self.cols_per_shard), self._dtype
+                (self.rows_per_shard, self.padded_dim), self._dtype
             )
         )
-        return f"{self.layout}/{estimator}/{'slab' if slab else 'xla'}"
+        return f"rows/{estimator}/{'slab' if slab else 'xla'}"
 
     def _table_sharding(self):
-        return (
-            table_sharding(self.mesh)
-            if self.layout == "rows"
-            else table_sharding_dims(self.mesh)
-        )
+        return table_sharding(self.mesh)
 
     # ------------------------------------------------------------------
     # Jitted SPMD program construction
@@ -681,10 +645,7 @@ class EmbeddingEngine:
         # (_put_alias_table); its rows do not say where their padding
         # starts, so the programs close over the vocabulary's size.
         V = self.vocab_size
-        tspec = (
-            P(MODEL_AXIS, None) if self.layout == "rows"
-            else P(None, MODEL_AXIS)
-        )
+        tspec = P(MODEL_AXIS, None)
         rep = P()
 
         def step_body_rows(syn0_l, syn1_l, noise, centers, cmask,
@@ -724,11 +685,10 @@ class EmbeddingEngine:
             start = lax.axis_index(MODEL_AXIS) * Vs
             drank = lax.axis_index(DATA_AXIS)
 
-            # The glint.* scopes (here, in step_body_dims, in _pull_rows
-            # and in the packed scan's body) name the step's five phases
-            # and its model-axis exchange in every op's metadata, and
-            # nothing else: the device trace is split by them
-            # (benchmark/program_trace.py). A fusion is filed under its
+            # The glint.* scopes (here, in _pull_rows and in the packed
+            # scan's body) name the step's five phases and its model-axis
+            # exchange in every op's metadata, and nothing else: the
+            # device trace is split by them (benchmark/program_trace.py). A fusion is filed under its
             # root's scope, an op under its OUTERMOST one: _pull_rows
             # opens its own two and is called outside any other.
             #
@@ -880,153 +840,6 @@ class EmbeddingEngine:
                 )
             return syn0_l, syn1_l, loss, written
 
-        def step_body_dims(syn0_l, syn1_l, noise, centers, cmask,
-                           contexts, mask, key, alpha, pair_run=None,
-                           mean_gradient=True, lanes=None):
-            # Column-sharded step (CIKM'16 partitioning, SURVEY.md §2.2):
-            # tables are (V, dl) local column slices with EVERY row
-            # resident, so gathers and scatter-adds are shard-local. The
-            # only model-axis communication is the psum of scalar logit
-            # partials — exactly the partial dot products the reference's
-            # servers return from ``dotprod``. The data-axis exchange is
-            # the same scalars+h contract as the rows layout, with h now
-            # a (B, dl) column slice (1/n the bytes per chip).
-            # Groups, ``pair_run``, ``mean_gradient``, ``lanes`` and the
-            # compose scope as in step_body_rows; a padding id (-1) reads a
-            # row the mask drops.
-            Rl, S = centers.shape
-            Bl, C = contexts.shape
-            drank = lax.axis_index(DATA_AXIS)
-            cd = self._compute_dtype
-            compose = (
-                "glint.compose" if S > 1 or lanes else "glint.gather"
-            )
-
-            with jax.named_scope("glint.gather"), jax.named_scope("syn0"):
-                h_rows = sgns.gather_blocks(syn0_l, centers)
-            with jax.named_scope(compose):
-                if lanes is None:
-                    cnt = jnp.maximum(cmask.sum(axis=1, keepdims=True), 1.0)
-                    h = sgns.row_sums(cmask, h_rows) / cnt  # (Rl, dl)
-                else:
-                    with jax.named_scope("group"):
-                        cnt = cmask.sum(axis=1, keepdims=True)
-                        h = sgns.row_sums(cmask, h_rows)
-                    with jax.named_scope("bag"):
-                        cnt = jnp.maximum(_bag_sums(lanes, cnt), 1.0)
-                        h = _bag_sums(lanes, h) / cnt  # (Bl, dl)
-                if pair_run is not None:
-                    h = h[pair_run]  # (Bl, dl)
-            with jax.named_scope("glint.gather"):
-                with jax.named_scope("syn1"):
-                    u_pos = sgns.gather_blocks(syn1_l, contexts)
-
-                h_g = lax.all_gather(h, DATA_AXIS, tiled=True)  # (B, dl)
-
-            if self.shared_negatives:
-                with jax.named_scope("glint.sample"):
-                    pool = sample_negatives_packed(
-                        key, noise, V, (self.shared_negatives,)
-                    )
-                with jax.named_scope("glint.gather"), jax.named_scope("syn1"):
-                    u_pool = syn1_l[pool].astype(jnp.float32)  # (S, dl)
-                with jax.named_scope("glint.sample"):
-                    collide = sgns.pool_collision_mask(pool, contexts, mask)
-                with jax.named_scope("glint.grads"):
-                    f_pos = sgns.row_dots(h, u_pos, cd)
-                    f_pool = jnp.dot(
-                        h.astype(cd), u_pool.astype(cd).T,
-                        preferred_element_type=jnp.float32,
-                    )
-                f_pos, f_pool = _exchange_sum(f_pos), _exchange_sum(f_pool)
-                with jax.named_scope("glint.grads"):
-                    co = sgns.shared_sgns_coefs(
-                        f_pos, f_pool, mask, collide,
-                        alpha.astype(jnp.float32), n,
-                    )
-                    d_center_l, d_pool_l = sgns.shared_sgns_updates(
-                        co.c_pos, co.c_pool, h, u_pos, u_pool, cd
-                    )
-                    d_pool_g = lax.psum(d_pool_l, DATA_AXIS)  # (S, dl)
-                    ids1 = lax.all_gather(
-                        contexts.reshape(-1), DATA_AXIS, tiled=True
-                    )
-                    cpos_g = lax.all_gather(co.c_pos, DATA_AXIS, tiled=True)
-                with (jax.named_scope("glint.scatter"),
-                      jax.named_scope("syn1")):
-                    scat1 = _pool_payload(ids1, pool, cpos_g, h_g, d_pool_g)
-                loss_local = co.loss
-            else:
-                with jax.named_scope("glint.sample"):
-                    rows_g = drank * Bl + jnp.arange(Bl, dtype=jnp.int32)
-                    negs = sample_negatives_per_row_packed(
-                        key, noise, V, rows_g, (C, n)
-                    )
-                with jax.named_scope("glint.gather"), jax.named_scope("syn1"):
-                    u_neg = sgns.gather_blocks(syn1_l, negs)
-                with jax.named_scope("glint.sample"):
-                    nmask = sgns.negative_mask(negs, contexts, mask)
-                with jax.named_scope("glint.grads"):
-                    f_pos = sgns.row_dots(h, u_pos, cd)
-                    f_neg = sgns.row_dots(h, u_neg, cd).reshape(Bl, C, n)
-                f_pos, f_neg = _exchange_sum(f_pos), _exchange_sum(f_neg)
-                with jax.named_scope("glint.grads"):
-                    co = sgns.sgns_coefs(
-                        f_pos, f_neg, mask, nmask, alpha.astype(jnp.float32)
-                    )
-                    d_center_l = sgns.sgns_d_center(
-                        co.c_pos, co.c_neg, u_pos, u_neg, cd
-                    )
-                    ctx_g = lax.all_gather(contexts, DATA_AXIS, tiled=True)
-                    negs_g = lax.all_gather(negs, DATA_AXIS, tiled=True)
-                    cpos_g = lax.all_gather(co.c_pos, DATA_AXIS, tiled=True)
-                    cneg_g = lax.all_gather(co.c_neg, DATA_AXIS, tiled=True)
-                with (jax.named_scope("glint.scatter"),
-                      jax.named_scope("syn1")):
-                    scat1 = _pair_payload(ctx_g, negs_g, cpos_g, cneg_g, h_g)
-                loss_local = co.loss
-
-            if pair_run is not None:
-                with jax.named_scope(compose):
-                    d_center_l = jnp.zeros(
-                        (Rl, d_center_l.shape[1]), jnp.float32
-                    ).at[pair_run].add(d_center_l, indices_are_sorted=True)
-            if lanes is not None:
-                with jax.named_scope(compose), jax.named_scope("bag"):
-                    d_center_l = _bag_spread(lanes, d_center_l, Rl)
-            with jax.named_scope("glint.grads"):
-                dcen_g = lax.all_gather(
-                    d_center_l / cnt if mean_gradient else d_center_l,
-                    DATA_AXIS, tiled=True,
-                )
-                cmask_g = lax.all_gather(cmask, DATA_AXIS, tiled=True)
-                ids0_g = lax.all_gather(
-                    centers.reshape(-1), DATA_AXIS, tiled=True
-                )
-            # Every row is local (start 0, no row is another shard's), and
-            # every shard writes the same rows of its own columns.
-            with jax.named_scope("glint.scatter"):
-                with jax.named_scope("syn0"):
-                    syn0_l, w0, m0 = _scatter_rows(
-                        syn0_l, ids0_g, cmask_g.reshape(-1), dcen_g,
-                        jnp.repeat(jnp.arange(dcen_g.shape[0]), S), 0,
-                    )
-                with jax.named_scope("syn1"):
-                    syn1_l, w1, m1 = _scatter_rows(syn1_l, *scat1, 0)
-                    written = jnp.stack([w0, w1, m0, m1])
-
-            with jax.named_scope("glint.grads"):
-                denom = mask.sum()
-                loss_sum = loss_local * jnp.maximum(denom, 1.0)
-                loss = lax.psum(loss_sum, DATA_AXIS) / jnp.maximum(
-                    lax.psum(denom, DATA_AXIS), 1.0
-                )
-            return syn0_l, syn1_l, loss, written
-
-        step_body = (
-            step_body_rows if self.layout == "rows" else step_body_dims
-        )
-
         # A CBOW engine's one-step program is the role-swapped form: the
         # groups are the positions' bags as ``bag_window_batch`` names
         # them, each row taking the whole gradient. Its fits train through
@@ -1034,7 +847,7 @@ class EmbeddingEngine:
         # holds that scan's span form to this one.
         self._train_step = jax.jit(
             self._shard_map(
-                lambda *a: step_body(
+                lambda *a: step_body_rows(
                     *a, mean_gradient=self.architecture != "cbow"
                 )[:3],
                 in_specs=(tspec, tspec, rep, P(DATA_AXIS, None),
@@ -1057,7 +870,7 @@ class EmbeddingEngine:
                 s0, s1 = carry
                 centers, cmask, contexts, mask, i, alpha = xs
                 key = jax.random.fold_in(base_key, step0 + i)
-                s0, s1, loss, _ = step_body(
+                s0, s1, loss, _ = step_body_rows(
                     s0, s1, noise, centers, cmask, contexts, mask,
                     key, alpha,
                 )
@@ -1137,7 +950,7 @@ class EmbeddingEngine:
                     else:
                         cmask = jnp.ones((Bl, 1), jnp.float32)
                         grp = centers[:, None]
-                    s0, s1, loss, _ = step_body(
+                    s0, s1, loss, _ = step_body_rows(
                         s0, s1, noise, grp, cmask,
                         contexts, mask, key, alpha,
                     )
@@ -1351,7 +1164,7 @@ class EmbeddingEngine:
                                     input_rows.astype(jnp.int32),
                                 ]), DATA_AXIS,
                             )])
-                    s0, s1, loss, written = step_body(
+                    s0, s1, loss, written = step_body_rows(
                         s0, s1, noise, words, cmask,
                         c_l[:, None], live[:, None], key, alpha,
                         mean_gradient=False, lanes=lanes,
@@ -1406,7 +1219,7 @@ class EmbeddingEngine:
                             )
                     else:
                         grp, pair_run = c_l[:, None], None
-                    s0, s1, loss, written = step_body(
+                    s0, s1, loss, written = step_body_rows(
                         s0, s1, noise, grp, cmask,
                         x_l[:, None], m_l[:, None], key, alpha,
                         pair_run=pair_run,
@@ -1433,8 +1246,6 @@ class EmbeddingEngine:
 
         self._make_packed_corpus_scan = make_packed_corpus_scan
 
-        dims = self.layout == "dims"
-        dcols = self.cols_per_shard
         dim_real = self.dim
 
         def shared_query_program(op, build):
@@ -1450,12 +1261,6 @@ class EmbeddingEngine:
             return fn
 
         def local_pull(table_l, idx):
-            if dims:
-                rows = table_l[idx].astype(jnp.float32)  # (L, dl)
-                full = lax.all_gather(
-                    rows, MODEL_AXIS, tiled=True, axis=1
-                )  # (L, padded_dim)
-                return full[:, :dim_real]
             start = lax.axis_index(MODEL_AXIS) * Vs
             return _pull_rows(table_l, idx, start, Vs)[:, :dim_real]
 
@@ -1466,14 +1271,6 @@ class EmbeddingEngine:
         def local_pull_average(table_l, idx, m):
             # idx/m: (S, L) padded sentence word-indices + validity mask.
             S, L = idx.shape
-            if dims:
-                rows = table_l[idx.reshape(-1)].astype(jnp.float32)
-                rows = rows.reshape(S, L, -1) * m[..., None]
-                mean_l = rows.sum(axis=1) / jnp.maximum(
-                    m.sum(axis=1)[:, None], 1.0
-                )  # (S, dl): the server-side partial mean
-                full = lax.all_gather(mean_l, MODEL_AXIS, tiled=True, axis=1)
-                return full[:, :dim_real]
             start = lax.axis_index(MODEL_AXIS) * Vs
             rows = _pull_rows(table_l, idx.reshape(-1), start, Vs)
             rows = rows[:, :dim_real].reshape(S, L, -1) * m[..., None]
@@ -1491,11 +1288,6 @@ class EmbeddingEngine:
         )
 
         def local_norms(table_l):
-            if dims:
-                # Partial sum of squares over local columns, reduced over
-                # the model axis; output replicated.
-                sq = (table_l.astype(jnp.float32) ** 2).sum(axis=1)
-                return jnp.sqrt(lax.psum(sq, MODEL_AXIS))
             # Shard-local, no communication: output stays model-sharded.
             return jnp.sqrt(
                 (table_l.astype(jnp.float32) ** 2).sum(axis=1)
@@ -1503,22 +1295,11 @@ class EmbeddingEngine:
 
         self._norms = shared_query_program("norms", lambda: jax.jit(
             self._shard_map(
-                local_norms, in_specs=(tspec,),
-                out_specs=rep if dims else P(MODEL_AXIS),
+                local_norms, in_specs=(tspec,), out_specs=P(MODEL_AXIS),
             )
         ))
 
-        def _local_cols(v):
-            # Slice the replicated padded query vector down to this
-            # shard's column block.
-            mrank = lax.axis_index(MODEL_AXIS)
-            return lax.dynamic_slice_in_dim(v, mrank * dcols, dcols)
-
         def local_multiply(table_l, v):
-            if dims:
-                # Partial dot over local columns -> psum: exactly the
-                # reference servers' partial-dot-product contract.
-                return lax.psum(_score(table_l, _local_cols(v)), MODEL_AXIS)
             # Distributed matvec: each shard scores its own rows (the TP
             # matvec noted in SURVEY.md §2.3); output model-sharded.
             return _score(table_l, v)
@@ -1526,11 +1307,9 @@ class EmbeddingEngine:
         self._multiply = shared_query_program("multiply", lambda: jax.jit(
             self._shard_map(
                 local_multiply, in_specs=(tspec, rep),
-                out_specs=rep if dims else P(MODEL_AXIS),
+                out_specs=P(MODEL_AXIS),
             )
         ))
-
-        norms_spec = rep if dims else P(MODEL_AXIS)
 
         def _mask_terms(norms_l, start, n_queryable):
             # Cosine masking as one multiply + one add instead of a
@@ -1557,18 +1336,6 @@ class EmbeddingEngine:
 
         def make_topk(k: int):
             def local_topk(table_l, v, norms_l, nq):
-                if dims:
-                    # Partial scores over local columns, psum'd to full
-                    # cosine scores (replicated), then ranked. The psum
-                    # moves V floats of scalars — never rows.
-                    scores = lax.psum(
-                        _score(table_l, _local_cols(v)), MODEL_AXIS
-                    )  # (V,)
-                    inv, neg = _mask_terms(norms_l, 0, nq)
-                    val, idx = lax.top_k(
-                        scores * inv + neg, min(k, scores.shape[0])
-                    )
-                    return val, idx
                 # Cosine top-k without materializing all V scores on one
                 # device: local top-k per shard, all_gather the M*k
                 # candidates, merge. Replaces the reference's full-vocab
@@ -1586,7 +1353,7 @@ class EmbeddingEngine:
             return jax.jit(
                 self._shard_map(
                     local_topk,
-                    in_specs=(tspec, rep, norms_spec, rep),
+                    in_specs=(tspec, rep, P(MODEL_AXIS), rep),
                     out_specs=(rep, rep),
                 )
             )
@@ -1597,23 +1364,6 @@ class EmbeddingEngine:
                 # the tall-skinny orientation streams the row-major table
                 # once (bandwidth-bound like the single-query matvec) —
                 # 2x faster for small Q buckets on CPU, a wash at Q=16+.
-                if dims:
-                    # q arrives padded to (Q, padded_dim); each shard
-                    # scores its column block, psum -> full scores. The
-                    # public method chunks Q so (Q, V) stays bounded.
-                    mrank = lax.axis_index(MODEL_AXIS)
-                    q_l = lax.dynamic_slice_in_dim(
-                        q, mrank * dcols, dcols, axis=1
-                    )
-                    scores = lax.psum(
-                        _score(table_l, q_l.T).T, MODEL_AXIS
-                    )  # (Q, V)
-                    inv, neg = _mask_terms(norms_l, 0, nq)
-                    val, idx = lax.top_k(
-                        scores * inv[None, :] + neg[None, :],
-                        min(k, scores.shape[1]),
-                    )
-                    return val, idx
                 # q: (Q, d) replicated query batch. Same candidate-merge
                 # scheme as the single-vector kernel, vectorized over Q —
                 # one matmul scores all queries against this shard.
@@ -1638,7 +1388,7 @@ class EmbeddingEngine:
             return jax.jit(
                 self._shard_map(
                     local_topk_batch,
-                    in_specs=(tspec, rep, norms_spec, rep),
+                    in_specs=(tspec, rep, P(MODEL_AXIS), rep),
                     out_specs=(rep, rep),
                 )
             )
@@ -2133,29 +1883,28 @@ class EmbeddingEngine:
             tuple(d.id for d in self.mesh.devices.flat),
             self.mesh.axis_names,
             tuple(self.mesh.shape.items()),
-            self.layout, self.architecture,
+            self.architecture,
             str(self._dtype), str(self._compute_dtype),
             self.num_negatives, self.shared_negatives,
-            self.rows_per_shard, self.cols_per_shard,
+            self.rows_per_shard,
             self.padded_vocab, self.padded_dim, self.vocab_size,
             *shape_key,
         )
 
     def _query_memo_key(self, op):
         """Memo key for :data:`_QUERY_MEMO`: the mesh geometry plus
-        ONLY the attributes the query closures capture — layout,
-        storage dtype, shard geometry. Training attributes (negatives,
-        compute dtype) are excluded on purpose:
-        they never reach a query program, so models that differ only in
-        how they were trained still share the whole warm family."""
+        ONLY the attributes the query closures capture — storage dtype,
+        shard geometry. Training attributes (negatives, compute dtype)
+        are excluded on purpose: they never reach a query program, so
+        models that differ only in how they were trained still share the
+        whole warm family."""
         return (
             "query", op,
             tuple(d.id for d in self.mesh.devices.flat),
             self.mesh.axis_names,
             tuple(self.mesh.shape.items()),
-            self.layout,
             str(self._dtype),
-            self.rows_per_shard, self.cols_per_shard,
+            self.rows_per_shard,
             self.padded_vocab, self.padded_dim, self.dim,
         )
 
@@ -2361,16 +2110,12 @@ class EmbeddingEngine:
                               window: Optional[int] = None) -> int:
         """Bytes one device hands the model-axis collectives of one packed
         step (the psums under ``glint.exchange``), from shapes alone: the
-        float32 rows it pulls in the ``rows`` layout (a centre, a context
-        and the negatives, or the shared pool once, for each of its
-        pairs; rows as they rest, ``padded_dim`` wide), the logit partials
-        in ``dims``; 0 where the model axis has one shard."""
+        float32 rows it pulls (a centre, a context and the negatives, or
+        the shared pool once, for each of its pairs; rows as they rest,
+        ``padded_dim`` wide); 0 where the model axis has one shard."""
         if self.num_model == 1:
             return 0
         pairs = pair_batch // self.num_data
-        if self.layout == "dims":
-            return 4 * pairs * (
-                1 + (self.shared_negatives or self.num_negatives))
         centers = self._packed_center_slots(pair_batch, window)
         rows = centers // self.num_data + (
             pairs + self.shared_negatives if self.shared_negatives
@@ -2417,24 +2162,20 @@ class EmbeddingEngine:
         map: MERGE into the existing map, never narrow it — ``None``
         (everything dirty, the state every unknown mutation restores)
         stays ``None`` until a committed save re-establishes clean
-        bits. Column-sharded (dims) layouts always go all-dirty: every
-        column block spans every row."""
+        bits."""
         if touched_ids is None:
             self._shard_dirty = None
             return
         if self._shard_dirty is None:
             return  # already all-dirty; a narrower mark must not undo it
-        axis, per_shard, real_extent = self._shard_geometry()
-        if axis != "rows":
-            self._shard_dirty = None
-            return
+        per_shard = self._save_block_rows()
         starts = np.unique(
             # graftlint: ignore[sync-point] touched_ids is a host id array
             (np.asarray(touched_ids, dtype=np.int64) // per_shard)
             * per_shard
         )
         for start in starts:
-            if 0 <= start < real_extent:
+            if 0 <= start < self.num_rows:
                 for name in ("syn0", "syn1"):
                     self._shard_dirty[f"{name}.r{start:012d}.npy"] = True
 
@@ -2837,36 +2578,24 @@ class EmbeddingEngine:
         if k_b not in self._topk_batch_cache:
             self._topk_batch_cache[k_b] = self._make_topk_batch(k_b)
         fn = self._topk_batch_cache[k_b]
-        # Dims layout materializes full (Q, V) scores per shard; chunk Q
-        # to a ~256 MB score-matrix budget so the intermediate stays
-        # bounded at any vocab size (10M rows -> 6-query chunks).
-        if self.layout == "dims":
-            chunk = max(1, int(256e6 // (4 * self.padded_vocab)))
-        else:
-            chunk = q.shape[0]
-        vals, idxs = [], []
-        for s in range(0, q.shape[0], chunk):
-            qc = q[s : s + chunk]
-            n = qc.shape[0]
-            # Pad Q up to its bucket (power of two, floored at
-            # TOPK_MIN_Q_BUCKET) so concurrency jitter (every distinct
-            # coalesced batch size) maps onto a small compiled family.
-            # Zero-vector padding rows score 0 for real words and are
-            # sliced off; they can never perturb a real row's top-k
-            # (each query row ranks independently).
-            q_b = self._q_bucket(n)
-            if q_b != n:
-                qc = np.concatenate(
-                    [qc, np.zeros((q_b - n, qc.shape[1]), np.float32)]
-                )
-            self._count_query_shape("topk_batch", q_b, k_b)
-            val, idx = fn(
-                self.syn0, self._pad_query(qc), self.norms(),
-                jnp.int32(self.queryable_rows),
+        n = q.shape[0]
+        # Pad Q up to its bucket (power of two, floored at
+        # TOPK_MIN_Q_BUCKET) so concurrency jitter (every distinct
+        # coalesced batch size) maps onto a small compiled family.
+        # Zero-vector padding rows score 0 for real words and are
+        # sliced off; they can never perturb a real row's top-k
+        # (each query row ranks independently).
+        q_b = self._q_bucket(n)
+        if q_b != n:
+            q = np.concatenate(
+                [q, np.zeros((q_b - n, q.shape[1]), np.float32)]
             )
-            vals.append(np.asarray(val)[:n, :kk])
-            idxs.append(np.asarray(idx)[:n, :kk])
-        return np.concatenate(vals), np.concatenate(idxs)
+        self._count_query_shape("topk_batch", q_b, k_b)
+        val, idx = fn(
+            self.syn0, self._pad_query(q), self.norms(),
+            jnp.int32(self.queryable_rows),
+        )
+        return np.asarray(val)[:n, :kk], np.asarray(idx)[:n, :kk]
 
     # ------------------------------------------------------------------
     # Approximate top-k (device-resident ANN index, ISSUE 12)
@@ -3434,56 +3163,46 @@ class EmbeddingEngine:
         """Configure the replica save split (ISSUE 15): sharded saves
         slice the (replicated) tables into ``world`` row blocks and this
         engine writes only block ``rank`` — N replica ranks checkpoint
-        one table in parallel, each copying/hashing 1/N of it. Rows
-        layout only (column blocks span every row, so a row-replica
-        split has nothing to divide). ``world == 1`` clears the split."""
+        one table in parallel, each copying/hashing 1/N of it.
+        ``world == 1`` clears the split."""
         if world <= 1:
             self._save_split = None
             return
-        if self.layout != "rows":
-            raise ValueError("save split requires the rows layout")
         if not 0 <= rank < world:
             raise ValueError(f"rank {rank} not in [0, {world})")
         self._save_split = (int(rank), int(world))  # graftlint: ignore[sync-point] host config
         self._shard_dirty = None  # file geometry changed: all dirty
 
-    def _shard_geometry(self):
-        """(axis, per_shard, real_extent) of the sharded-save layout —
-        the one place the manifest and the block producers agree on.
-        Under a replica save split the block size comes from the split
-        world, not the mesh model axis (every rank addresses every
-        row)."""
-        axis = "rows" if self.layout == "rows" else "cols"
-        if self._save_split is not None and axis == "rows":
+    def _save_block_rows(self) -> int:
+        """Rows a block of the sharded save holds — the one place the
+        manifest and the block producers agree on. Under a replica save
+        split the block size comes from the split world, not the mesh
+        model axis (every rank addresses every row)."""
+        if self._save_split is not None:
             _, world = self._save_split
-            return axis, max(1, -(-self.padded_vocab // world)), self.num_rows
-        per_shard = (
-            self.rows_per_shard if axis == "rows" else self.cols_per_shard
-        )
-        real_extent = self.num_rows if axis == "rows" else self.dim
-        return axis, per_shard, real_extent
+            return max(1, -(-self.padded_vocab // world))
+        return self.rows_per_shard
 
     def _shard_manifest(self) -> dict:
         """Deterministic (mesh-geometry-only) shard-file manifest shared
         by the single-process snapshot and the multi-host in-place save
         — identical producers, so checkpoints from either path re-load
         interchangeably."""
-        axis, per_shard, real_extent = self._shard_geometry()
+        per_shard = self._save_block_rows()
         n_blocks = (
-            self._save_split[1]
-            if self._save_split is not None and axis == "rows"
+            self._save_split[1] if self._save_split is not None
             else self.num_model
         )
         shard_files = {"syn0": [], "syn1": []}
         for name in ("syn0", "syn1"):
             for k in range(n_blocks):
                 start = k * per_shard
-                stop = min(start + per_shard, real_extent)
+                stop = min(start + per_shard, self.num_rows)
                 if start >= stop:
                     continue  # pure-padding block
                 shard_files[name].append({
-                    "file": f"{name}.{axis[0]}{start:012d}.npy",
-                    "start": start, "stop": stop, "axis": axis,
+                    "file": f"{name}.r{start:012d}.npy",
+                    "start": start, "stop": stop, "axis": "rows",
                 })
         return shard_files
 
@@ -3496,33 +3215,29 @@ class EmbeddingEngine:
         (:meth:`set_save_split`, tables replicated across ranks) — the
         rank's own row block, device-sliced so no producer ever copies
         more than one block."""
-        axis, per_shard, real_extent = self._shard_geometry()
-        if self._save_split is not None and axis == "rows":
+        per_shard = self._save_block_rows()
+        if self._save_split is not None:
             rank, world = self._save_split
             start = rank * per_shard
-            stop = min(start + per_shard, real_extent)
+            stop = min(start + per_shard, self.num_rows)
             if start < stop:
                 yield (
                     f"{name}.r{start:012d}.npy",
                     lambda: np.asarray(table[start:stop, : self.dim]),
                 )
             return
-        ix = 0 if axis == "rows" else 1
         for shard in table.addressable_shards:
             if shard.replica_id != 0:
                 continue
-            start = shard.index[ix].start or 0
-            if start >= real_extent:
+            start = shard.index[0].start or 0
+            if start >= self.num_rows:
                 continue
-            stop = min(start + per_shard, real_extent)
+            stop = min(start + per_shard, self.num_rows)
 
             def produce(shard=shard, start=start, stop=stop):
-                data = np.asarray(shard.data)
-                if axis == "rows":
-                    return data[: stop - start]
-                return data[: self.num_rows, : stop - start]
+                return np.asarray(shard.data)[: stop - start]
 
-            yield f"{name}.{axis[0]}{start:012d}.npy", produce
+            yield f"{name}.r{start:012d}.npy", produce
 
     def _iter_owned_blocks(self, name: str, table):
         """Materialized form of :meth:`_iter_owned_block_producers`:
@@ -3537,7 +3252,6 @@ class EmbeddingEngine:
     def _save_meta(self, mode: str) -> dict:
         return {
             "format": mode,
-            "layout": self.layout,
             "vocab_size": self.vocab_size,
             "dim": self.dim,
             "num_negatives": self.num_negatives,
@@ -3770,9 +3484,7 @@ class EmbeddingEngine:
             # The manifest is deterministic from mesh geometry (identical on
             # every process); files are written only by a process that can
             # address the block, each block by exactly one process. Blocks
-            # are row ranges under the rows layout and column ranges under
-            # the dims layout ("axis" in each manifest entry; absent =
-            # rows, for round-2 checkpoints).
+            # are row ranges.
             shard_files = self._shard_manifest()
             for name, table in (("syn0", self.syn0), ("syn1", self.syn1)):
                 for fname, produce in self._iter_owned_block_producers(
@@ -3882,7 +3594,6 @@ class EmbeddingEngine:
             meta["vocab_size"],
             meta["dim"],
             counts,
-            layout=overrides.get("layout", meta.get("layout", "rows")),
             num_negatives=overrides.get("num_negatives", meta["num_negatives"]),
             unigram_power=overrides.get(
                 "unigram_power", meta.get("unigram_power", 0.75)
@@ -3953,10 +3664,12 @@ class EmbeddingEngine:
         tsh = self._table_sharding()
         staged = {"meta": meta}
         for name in ("syn0", "syn1"):
-            # Source blocks as (row range, col range, data), covering any
-            # mix of row-block (rows layout), col-block (dims layout), or
-            # whole-table files — so checkpoints re-home across BOTH mesh
-            # shapes and layouts.
+            # Source blocks as (row range, col range, data), covering
+            # row-block files (what :meth:`save` writes; a manifest entry
+            # with no "axis" is one too), whole-table files and the
+            # col-block files ("axis": "cols") that the column-sharded
+            # ``dims`` engines of PRs before 46 wrote — so every
+            # checkpoint on disk re-homes onto any mesh shape.
             if fmt == "sharded":
                 blocks = []
                 for b in meta["shards"][name]:

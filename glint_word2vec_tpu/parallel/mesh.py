@@ -66,15 +66,6 @@ def table_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P(MODEL_AXIS, None))
 
 
-def table_sharding_dims(mesh: Mesh) -> NamedSharding:
-    """Dim/column sharding for syn0/syn1: every shard holds ALL vocab rows
-    x 1/n of the embedding dimensions — the CIKM'16 partitioning the
-    reference's parameter servers use (SURVEY.md §2.2 sharding note:
-    servers compute *partial* dot products the client sums). Model-axis
-    traffic becomes scalar logit partials instead of full rows."""
-    return NamedSharding(mesh, P(None, MODEL_AXIS))
-
-
 def batch_sharding(mesh: Mesh) -> NamedSharding:
     """Minibatch rows split over "data", replicated over "model"."""
     return NamedSharding(mesh, P(DATA_AXIS))

@@ -194,7 +194,6 @@ class StreamTrainer:
             extra_rows=self.extra_rows,
             shared_negatives=p.shared_negatives,
             compute_dtype=p.compute_dtype,
-            layout=p.layout,
         )
 
     # -- the loop -------------------------------------------------------

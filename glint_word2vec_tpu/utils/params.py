@@ -21,7 +21,10 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import logging
 from dataclasses import dataclass
+
+logger = logging.getLogger(__name__)
 
 
 def _require(cond: bool, msg: str) -> None:
@@ -113,11 +116,6 @@ class Word2VecParams:
     #: the engine's GLINT_W2V_MATMUL_DTYPE env default (so the env knob
     #: works through the model/CLI path too).
     compute_dtype: str | None = None
-    #: Model-axis table partitioning: "rows" (vocab rows split 1/n) or
-    #: "dims" (every shard holds all rows x 1/n of the columns — the
-    #: CIKM'16 column partitioning; model-axis traffic becomes scalar
-    #: logit partials). See parallel/engine.py.
-    layout: str = "rows"
     steps_per_call: int = 16
     shared_negatives: int = 0
     #: Device-resident corpus dispatch shape: "dense" (the default since
@@ -204,9 +202,6 @@ class Word2VecParams:
             self.compute_dtype in (None, "float32", "bfloat16"),
             "compute_dtype must be float32|bfloat16|None",
         )
-        _require(
-            self.layout in ("rows", "dims"), "layout must be rows|dims"
-        )
         _require(self.steps_per_call > 0, "steps_per_call must be > 0")
         _require(self.shared_negatives >= 0, "shared_negatives must be >= 0")
         _require(
@@ -260,4 +255,16 @@ class Word2VecParams:
 
     @classmethod
     def from_json(cls, s: str) -> "Word2VecParams":
-        return cls(**json.loads(s))
+        """Read :meth:`to_json` output. A blob written before PR 46 carries
+        ``"layout"``: the tables now rest split by rows whatever it says
+        (a column-sharded checkpoint is re-homed as it loads,
+        ``EmbeddingEngine.stage_tables``)."""
+        d = json.loads(s)
+        layout = d.pop("layout", "rows")
+        _require(layout in ("rows", "dims"), "layout must be rows|dims")
+        if layout == "dims":
+            logger.warning(
+                "params say layout=dims (column sharding, removed): the "
+                "tables are re-homed by rows"
+            )
+        return cls(**d)

@@ -110,17 +110,6 @@ def main() -> int:
         loaded.transform("w0"), ref_vec, rtol=1e-5, atol=1e-6
     )
 
-    # --- dims (column-sharded) layout on the same global mesh ---------
-    # Same seed + per-global-row draws => the dims run must reproduce the
-    # rows run's vectors up to float reduction order, across processes.
-    model_dims = Word2Vec(**common, layout="dims").fit(sentences)
-    np.testing.assert_allclose(
-        model_dims.transform("w0"), ref_vec, rtol=1e-4, atol=1e-5
-    )
-    syn_d = model_dims.find_synonyms("w0", 5)
-    assert len(syn_d) == 5 and all(np.isfinite(s) for _, s in syn_d)
-    multihost_utils.sync_global_devices("dims_done")
-
     # --- fit_file under multi-host: the native scanner + flat-corpus
     # process sharding path. Process 0 writes the corpus; both read it
     # (the shared-filesystem contract). Must reproduce fit(sentences)

@@ -8,7 +8,8 @@
   makes for the same positions (``grid_window_shrink``), beside that
   stream's own pairs under the same draws.
 * The engine's CBOW packed scan against the reference, on the batches the
-  scan drew, at 1x1, 1x2 and one ``dims`` mesh; 1x1 against 1x2.
+  scan drew, at 1x1, 1x2, 2x2 and the four-chip cell's 1x4; 1x1 against
+  1x2.
 * The scan's span form (each span row gathered once, ISSUE 42) against the
   role-swapped form it replaced and ``word2vec.c``'s loop, on those meshes.
 * Bags of one context each are the skip-gram step on the swapped pair.
@@ -198,16 +199,14 @@ def test_bags_of_an_epoch_are_word2vecs_window_under_the_skipgram_draws(
             lambda o, bp: o in lanes and -bp <= o <= bp - 1)
 
 
-# The meshes and layouts the CBOW scan is held on.
-MESHES = [((1, 1), "rows"), ((1, 2), "rows"), ((2, 2), "rows"),
-          ((1, 2), "dims")]
+# The meshes the CBOW scan is held on; the last is the four-chip cell's.
+MESHES = [(1, 1), (1, 2), (2, 2), (1, 4)]
 
 
-def engine(shape, layout="rows", architecture="cbow", seed=3):
+def engine(shape, architecture="cbow", seed=3):
     counts = np.arange(V, 0, -1).astype(np.int64) * 3
     return EmbeddingEngine(make_mesh(*shape), V, D, counts, num_negatives=NEG,
-                           seed=seed, layout=layout,
-                           architecture=architecture)
+                           seed=seed, architecture=architecture)
 
 
 def tables(eng):
@@ -246,9 +245,9 @@ def gaps(prog, ref, init):
     return np.abs(prog - ref).max() / change, abs(d_prog - d_ref) / d_ref
 
 
-@pytest.mark.parametrize("shape,layout", MESHES)
-def test_packed_cbow_scan_is_the_reference(shape, layout):
-    eng = engine(shape, layout)
+@pytest.mark.parametrize("shape", MESHES)
+def test_packed_cbow_scan_is_the_reference(shape):
+    eng = engine(shape)
     (init0, init1), out = run_packed(eng, zipf_corpus())
     losses, counts, pos_ends, _, written = (np.asarray(a) for a in out)
     ref0, ref1, ref_losses = jnp.asarray(init0), jnp.asarray(init1), []
@@ -296,19 +295,19 @@ def test_packed_cbow_scan_is_the_reference(shape, layout):
     assert gaps(prog0, np.asarray(div0), init0)[0] > 10 * GAP
 
 
-@pytest.mark.parametrize("shape,layout", MESHES)
-def test_the_span_form_is_the_role_swapped_form(shape, layout):
+@pytest.mark.parametrize("shape", MESHES)
+def test_the_span_form_is_the_role_swapped_form(shape):
     """ISSUE 42: the scan names a bag's words by where they stand in the
     step's span, gathers each span row once and sums a row's gradient over
     its bags before the scatter. Held here to the form it replaced, the
     step body with each position's bag (``bag_window_batch``) as its group
     and no ``lanes``, and to ``word2vec.c``'s loop in numpy, on the same
     draws. Only the order of two float32 sums differs."""
-    span = engine(shape, layout)
+    span = engine(shape)
     (init0, init1), out = run_packed(span, zipf_corpus())
     losses, counts, _, alphas, written = (np.asarray(a) for a in out)
     batches = captured(span)
-    swapped = engine(shape, layout)
+    swapped = engine(shape)
     for a, b in zip(tables(swapped), (init0, init1)):
         np.testing.assert_array_equal(a, b)
     c0, c1 = init0.astype(np.float32), init1.astype(np.float32)
@@ -568,28 +567,32 @@ def lowered(eng):
 
 
 # sha256[:16] of the lowered word-level CBOW scan's StableHLO text, by mesh
-# and layout, as tests/test_subword_packed.py holds the skip-gram scans':
+# and split, as tests/test_subword_packed.py holds the skip-gram scans':
 # taken in ISSUE 42, which gave this scan the span form (CHANGES.md has the
 # role-swapped form's); the skip-gram scans' and fastText's CBOW scan's
 # stayed; taken again in ISSUE 44 with every packed scan's (a group's steps
 # run in a `while` that stops at the corpus end; CHANGES.md has ISSUE 42's).
-# A word-level CBOW fit must lower to the program it lowered to.
+# ISSUE 46 deleted the `dims` engine and its 1 x 2 entry; the 1 x 4 mesh's
+# was taken in its place on that issue's parent (7daf58c) and on its tree:
+# the same. A word-level CBOW fit must lower to the program it lowered to.
 CBOW_PROGRAMS = {
     ((1, 1), "rows"): "c8731cfde68643af",
     ((1, 2), "rows"): "57c62a0ec090466a",
     ((2, 2), "rows"): "baf8f88f96d832cf",
-    ((1, 2), "dims"): "4424233919252aa1",
+    ((1, 4), "rows"): "8d25f942b997ec84",
 }
 
 
-@pytest.mark.parametrize("shape,layout", sorted(CBOW_PROGRAMS))
+@pytest.mark.parametrize("shape,split", sorted(CBOW_PROGRAMS))
 def test_a_word_level_cbow_fit_lowers_to_the_program_it_lowered_to(
-        shape, layout):
+        shape, split):
     import hashlib
 
-    low = lowered(engine(shape, layout))
+    eng = engine(shape)
+    assert eng.step_body.split("/")[0] == split
+    low = lowered(eng)
     assert (hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
-            == CBOW_PROGRAMS[(shape, layout)])
+            == CBOW_PROGRAMS[(shape, split)])
 
 
 def test_the_cbow_scan_keeps_the_programs_name_and_scopes():

@@ -199,11 +199,10 @@ def test_corpus_words_done_compacted_matches_host_accounting():
         assert got == expect, (end, got, expect)
 
 
-def _mk_engine(shape, V_, seed=11, layout="rows"):
+def _mk_engine(shape, V_, seed=11):
     counts = np.arange(V_, 0, -1).astype(np.int64) * 3
     return EmbeddingEngine(
         make_mesh(*shape), V_, D, counts, num_negatives=3, seed=seed,
-        layout=layout,
     )
 
 
@@ -254,27 +253,6 @@ def test_corpus_scan_tail_positions_are_noop():
     )
     assert float(np.asarray(m).sum()) == 0.0
     assert np.asarray(c).sum() == 0
-
-
-def test_corpus_scan_dims_layout_matches_rows():
-    # The corpus-resident scan is layout-agnostic: the dims (CIKM column-
-    # partitioned) engine must produce the same tables as the rows engine
-    # for the same corpus schedule, up to reduction order — BOTH tables
-    # (syn1 scatter bugs would not reliably show through syn0 alone).
-    ids, offsets, _ = _corpus()
-    rows_eng = _mk_engine((2, 2), V)
-    dims_eng = _mk_engine((2, 2), V, layout="dims")
-    key = jax.random.PRNGKey(5)
-    alphas = np.array([0.05, 0.04, 0.04, 0.03], np.float32)
-    for e in (rows_eng, dims_eng):
-        e.upload_corpus(ids, offsets)
-        e.train_steps_corpus(0, 8, 3, key, alphas, step0=2)
-    for table in ("syn0", "syn1"):
-        np.testing.assert_allclose(
-            np.asarray(getattr(dims_eng, table), np.float32)[:V, :D],
-            np.asarray(getattr(rows_eng, table), np.float32)[:V, :D],
-            rtol=2e-5, atol=1e-7, err_msg=table,
-        )
 
 
 def test_upload_corpus_validates():

@@ -134,7 +134,8 @@ def test_train_step_matches_single_device_reference():
     assert float(loss) == pytest.approx(float(exp_loss), rel=1e-5)
 
 
-@pytest.mark.parametrize("shape", [(1, 1), (8, 1), (1, 8), (4, 2)])
+@pytest.mark.parametrize(
+    "shape", [(1, 1), (8, 1), (1, 8), (4, 2), (1, 4), (2, 4)])
 def test_train_step_mesh_invariance(shape):
     # Identical seeds and batches must produce identical tables on every
     # mesh shape (up to float reduction order).
@@ -158,6 +159,12 @@ def test_train_step_mesh_invariance(shape):
         np.asarray(eng.syn1, np.float32)[:V],
         rtol=1e-5, atol=1e-6,
     )
+
+
+def test_topk_batch_empty_query_batch():
+    eng = _mk_engine(2, 4)
+    sims, idx = eng.top_k_cosine_batch(np.zeros((0, D), np.float32), 5)
+    assert sims.shape == (0, 5) and idx.shape == (0, 5)
 
 
 def test_train_step_batch_divisibility_guard():
@@ -477,7 +484,7 @@ def test_device_resident_inputs_no_host_bounce():
     "kw,want",
     [
         ({}, "rows/per_pair/xla"),
-        ({"shared_negatives": 8, "layout": "dims"}, "dims/shared_pool/xla"),
+        ({"shared_negatives": 8}, "rows/shared_pool/xla"),
     ],
 )
 def test_step_body_names_what_runs(kw, want):
@@ -486,16 +493,16 @@ def test_step_body_names_what_runs(kw, want):
 
 
 @pytest.mark.parametrize(
-    "layout,negatives,dtype,form",
+    "mesh,negatives,dtype,form",
     list(itertools.product(
-        ["rows", "dims"], ["per_pair", "shared_pool"],
+        [(2, 4), (1, 1)], ["per_pair", "shared_pool"],
         ["float32", "bfloat16"], ["grid", "pair"],
     )),
 )
-def test_engine_step_matches_numpy_oracle(layout, negatives, dtype, form):
-    """One step of every body the engine can trace (layout x negatives x
-    storage dtype x grid or pair form), on a 2 x 4 mesh, against a numpy
-    oracle that is handed the step's own draws: the net under ROADMAP
+def test_engine_step_matches_numpy_oracle(mesh, negatives, dtype, form):
+    """One step of every body the engine can trace (negatives x storage
+    dtype x grid or pair form), on a 2 x 4 mesh and on one device, against
+    a numpy oracle that is handed the step's own draws: the net under ROADMAP
     D1's matrix. float32 tables within reduction order; bfloat16 ones
     within the README's mixed-precision bound (a row's float32 batch
     total rounded once, then one bfloat16 add)."""
@@ -508,10 +515,10 @@ def test_engine_step_matches_numpy_oracle(layout, negatives, dtype, form):
     C = 5 if form == "grid" else 1
     counts = np.arange(V, 0, -1).astype(np.int64) * 10
     eng = EmbeddingEngine(
-        make_mesh(2, 4), V, D, counts, num_negatives=n, seed=3, dtype=dtype,
-        layout=layout, shared_negatives=S if negatives == "shared_pool" else 0,
+        make_mesh(*mesh), V, D, counts, num_negatives=n, seed=3, dtype=dtype,
+        shared_negatives=S if negatives == "shared_pool" else 0,
     )
-    assert eng.step_body == f"{layout}/{negatives}/xla"  # a CPU mesh
+    assert eng.step_body == f"rows/{negatives}/xla"  # a CPU mesh
     rng = np.random.default_rng(12)
     eng.set_tables(
         rng.normal(0, 0.3, (V, D)).astype(np.float32),

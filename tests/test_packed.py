@@ -7,7 +7,7 @@ Contracts pinned here:
     draws (the grid position->draw mapping pack_window_pairs reproduces).
   * MESH INVARIANCE — packed assembly, negative draws (keyed by global
     pair row), and the resulting tables are identical on every shape of
-    the virtual 8-device mesh, and across the rows/dims layouts.
+    the virtual 8-device mesh.
   * UPDATE DECOMPOSITION — feeding a grid batch's pairs through the
     pair-form step applies the identical table update (scatter-adds sum).
   * LR/ACCOUNTING — the traced consumed-position words_done rule matches
@@ -188,11 +188,10 @@ def test_device_words_done_matches_host_rules():
         ) == corpus_words_done_compacted(offsets, offsets_c, end, n_kept)
 
 
-def _mk_engine(shape, seed=11, layout="rows"):
+def _mk_engine(shape, seed=11):
     counts = np.arange(V, 0, -1).astype(np.int64) * 3
     return EmbeddingEngine(
         make_mesh(*shape), V, D, counts, num_negatives=3, seed=seed,
-        layout=layout,
     )
 
 
@@ -221,21 +220,6 @@ def test_packed_scan_mesh_invariance(shape):
         np.testing.assert_allclose(
             np.asarray(getattr(eng, table), np.float32)[:V],
             np.asarray(getattr(ref, table), np.float32)[:V],
-            rtol=2e-5, atol=1e-7, err_msg=table,
-        )
-
-
-def test_packed_scan_dims_layout_matches_rows():
-    ids, offsets, _ = _corpus()
-    key = jax.random.PRNGKey(5)
-    rows_eng = _mk_engine((2, 2))
-    dims_eng = _mk_engine((2, 2), layout="dims")
-    _run_packed(rows_eng, ids, offsets, key)
-    _run_packed(dims_eng, ids, offsets, key)
-    for table in ("syn0", "syn1"):
-        np.testing.assert_allclose(
-            np.asarray(getattr(dims_eng, table), np.float32)[:V, :D],
-            np.asarray(getattr(rows_eng, table), np.float32)[:V, :D],
             rtol=2e-5, atol=1e-7, err_msg=table,
         )
 

@@ -14,13 +14,15 @@ from glint_word2vec_tpu.parallel.mesh import make_mesh
 from glint_word2vec_tpu.serving import ModelServer
 
 
-@pytest.fixture(scope="module", params=["rows", "dims"])
+@pytest.fixture(scope="module", params=[(1, 2), (1, 1)],
+                ids=["1x2", "1x1"])
 def served(request, tiny_corpus):
-    # Both model-axis layouts behind the same HTTP surface: every serving
-    # test (coalescing, error paths, num semantics) runs against each.
+    # A model axis of two and the one device both serving cells run on,
+    # behind the same HTTP surface: every serving test (coalescing, error
+    # paths, num semantics) runs against each.
     model = Word2Vec(
-        mesh=make_mesh(1, 2), vector_size=16, min_count=5, batch_size=128,
-        seed=2, num_iterations=2, layout=request.param,
+        mesh=make_mesh(*request.param), vector_size=16, min_count=5,
+        batch_size=128, seed=2, num_iterations=2,
     ).fit(tiny_corpus)
     server = ModelServer(model, port=0)  # ephemeral port
     server.start_background()

@@ -115,10 +115,10 @@ def corpus(seed=1, sentences=60):
     return ids, np.concatenate([[0], np.cumsum(lens)]).astype(np.int64)
 
 
-def engine(shape, layout="rows", seed=3):
+def engine(shape, seed=3):
     counts = np.arange(V, 0, -1).astype(np.int64) * 3
     return EmbeddingEngine(make_mesh(*shape), V, D, counts,
-                           num_negatives=NEG, seed=seed, layout=layout)
+                           num_negatives=NEG, seed=seed)
 
 
 def test_packed_scan_on_a_1x4_mesh_is_the_sharded_reference():
@@ -157,13 +157,13 @@ def test_packed_scan_on_a_1x4_mesh_is_the_sharded_reference():
 
 
 @functools.lru_cache(maxsize=None)
-def all_reduces(shape, layout="rows"):
+def all_reduces(shape):
     """(shape text, op name, replica groups) of every all-reduce of the
     packed scan an engine on a mesh of ``shape`` compiles."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    eng = engine(shape, layout)
+    eng = engine(shape)
 
     def sds(shape, dtype, *spec):
         return jax.ShapeDtypeStruct(
@@ -192,13 +192,11 @@ def all_reduces(shape, layout="rows"):
 D_REST = -(-D // TABLE_LANES) * TABLE_LANES
 
 
-@pytest.mark.parametrize(
-    "layout,moved", [("rows", f",{D_REST}]"), ("dims", "f32[")])
-def test_the_exchange_has_its_own_scope(layout, moved):
-    found = all_reduces((1, 4), layout)
+def test_the_exchange_has_its_own_scope():
+    found = all_reduces((1, 4))
     across = [f for f in found if f[2] == "{{0,1,2,3}}"]
-    data = [f for f in across if moved in f[0] and "f32[]" not in f[0]]
-    assert data, found  # the rows (or, by columns, the logit partials)
+    data = [f for f in across if f",{D_REST}]" in f[0]]
+    assert data, found  # the rows
     for shape, op_name, _ in data:
         assert "/glint.exchange/" in op_name, (shape, op_name)
     for shape, op_name, _ in across:
@@ -215,25 +213,22 @@ def test_one_shard_exchanges_nothing():
     assert found and {f[2] for f in found} == {"{{0}}"}, found
 
 
-@pytest.mark.parametrize("shape,layout", [
-    ((1, 4), "rows"), ((2, 2), "rows"), ((1, 1), "rows"), ((4, 1), "rows"),
-    ((1, 4), "dims")])
-def test_exchange_bytes_is_what_the_shapes_say(shape, layout):
-    eng = engine(shape, layout)
+@pytest.mark.parametrize(
+    "shape", [(1, 4), (2, 2), (1, 1), (4, 1), (2, 4)])
+def test_exchange_bytes_is_what_the_shapes_say(shape):
+    eng = engine(shape)
     n_data, n_model = shape
     # one data rank's pairs are the benchmark's, whose mesh has no data axis
     counted = eng.packed_exchange_bytes(n_data * PAIRS)
     if n_model == 1:
         assert counted == 0
         assert bytes_sharded.exchange_bytes(BATCH, WINDOW, NEG, D, 1) == 0
-    elif layout == "dims":
-        assert counted == 4 * PAIRS * (1 + NEG)
     else:
         assert counted == bytes_sharded.exchange_bytes(
             BATCH, WINDOW, NEG, D_REST, n_model)
-    if shape == (1, 4) and layout == "rows":
+    if shape == (1, 4):
         # ... and is what the compiled step hands its row all-reduces
-        rows = sum(int(n) for f in all_reduces(shape, layout)
+        rows = sum(int(n) for f in all_reduces(shape)
                    if "glint.exchange" in f[1]
                    for n in re.findall(r"f32\[(\d+),%d\]" % D_REST, f[0]))
         assert 4 * rows * D_REST == counted

@@ -231,7 +231,7 @@ def test_slab_writer_whatever_the_buffers(slots, ahead, unroll):
 
 
 @pytest.mark.parametrize("shape,dtype,why", [
-    ((6000, 75), "float32", "a dims shard: no whole lanes"),
+    ((6000, 75), "float32", "no whole lanes"),
     ((6004, 384), "float32", "rows not a multiple of 8"),
     ((6008, 384), "bfloat16", "rows not a multiple of 16"),
     ((6000, 384), "float32", "whole slabs, but the mesh is the CPU's"),

@@ -136,10 +136,10 @@ def random_groups(seed=4):
     return groups
 
 
-def engine(shape, groups=None, bucket=BUCKET, layout="rows", seed=3):
+def engine(shape, groups=None, bucket=BUCKET, seed=3):
     counts = np.arange(V, 0, -1).astype(np.int64) * 3
     eng = EmbeddingEngine(make_mesh(*shape), V, D, counts, num_negatives=NEG,
-                          seed=seed, extra_rows=bucket, layout=layout)
+                          seed=seed, extra_rows=bucket)
     eng.upload_center_groups(groups)
     return eng
 
@@ -290,7 +290,8 @@ def test_the_grid_scan_forms_its_centres_from_the_group_table():
 
 
 # sha256[:16] of the lowered word-level scans' StableHLO text, by mesh and
-# layout: (packed, grid). Taken on the tree of ISSUE 33, which meant to
+# how the tables are split over it (the first part of `engine.step_body`):
+# (packed, grid). Taken on the tree of ISSUE 33, which meant to
 # change the word-level programs (the batch read from the view's
 # per-position record, the negatives from the packed alias table); between
 # ISSUE 31's parent (7dbd80a) and that tree they had not moved. Taken again
@@ -308,11 +309,15 @@ def test_the_grid_scan_forms_its_centres_from_the_group_table():
 # change them all: a group's steps run in a `while` that stops at the corpus
 # end where they ran in a `scan` of 32 (the step inside is the parent's:
 # `tests/test_corpus_end.py`; the grid scans did not move).
+# ISSUE 46 deleted the column-sharded `dims` engine and with it that
+# layout's 1 x 2 entries; the 1 x 4 mesh (the four-chip cell's) was taken
+# in their place, on ISSUE 46's PARENT (7daf58c) and again on its tree: the
+# same. The other entries are untouched.
 WORD_LEVEL_PROGRAMS = {
     ((1, 1), "rows"): ("84c02214c885c063", "124ae8075d865073"),
     ((1, 2), "rows"): ("533004cc2ecc2727", "0550e22a6e53853a"),
     ((2, 2), "rows"): ("25864a7ff37a7e92", "086f184fadd4d1d0"),
-    ((1, 2), "dims"): ("5436e107881f23b0", "16b11e49808ec3ed"),
+    ((1, 4), "rows"): ("fe92f6c6fbdd4385", "e78d0e7452c56bdf"),
 }
 
 
@@ -340,12 +345,13 @@ def lowered(eng, groups_width=0):
     return packed, grid
 
 
-@pytest.mark.parametrize("shape,layout", sorted(WORD_LEVEL_PROGRAMS))
-def test_a_word_level_fit_lowers_to_the_program_it_lowered_to(shape, layout):
-    eng = engine(shape, bucket=0, layout=layout)
+@pytest.mark.parametrize("shape,split", sorted(WORD_LEVEL_PROGRAMS))
+def test_a_word_level_fit_lowers_to_the_program_it_lowered_to(shape, split):
+    eng = engine(shape, bucket=0)
+    assert eng.step_body.split("/")[0] == split
     got = tuple(hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
                 for low in lowered(eng))
-    assert got == WORD_LEVEL_PROGRAMS[(shape, layout)]
+    assert got == WORD_LEVEL_PROGRAMS[(shape, split)]
 
 
 # The same for the SUBWORD scans (a (V, G) group table on the device), taken
@@ -358,16 +364,17 @@ SUBWORD_PROGRAMS = {
     ((1, 1), "rows"): ("b4cb57206511569c", "5e7e6d3939851660"),
     ((1, 2), "rows"): ("206aa68d18533c20", "2dbc1d5a91874ef4"),
     ((2, 2), "rows"): ("1b6c7b6b8cb67547", "653f273411458e62"),
-    ((1, 2), "dims"): ("f4430457ab05294e", "16aaa556b419b3b6"),
+    ((1, 4), "rows"): ("5bdee85a1f7ce2e7", "0cb6acd97129bf63"),
 }
 
 
-@pytest.mark.parametrize("shape,layout", sorted(SUBWORD_PROGRAMS))
-def test_a_subword_fit_lowers_to_the_program_it_lowered_to(shape, layout):
-    eng = engine(shape, random_groups(), layout=layout)
+@pytest.mark.parametrize("shape,split", sorted(SUBWORD_PROGRAMS))
+def test_a_subword_fit_lowers_to_the_program_it_lowered_to(shape, split):
+    eng = engine(shape, random_groups())
+    assert eng.step_body.split("/")[0] == split
     got = tuple(hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
                 for low in lowered(eng, G))
-    assert got == SUBWORD_PROGRAMS[(shape, layout)]
+    assert got == SUBWORD_PROGRAMS[(shape, split)]
 
 
 def test_the_subword_scan_keeps_its_name_and_scopes():

@@ -1,6 +1,6 @@
 """How the tables rest on the device (``engine.TABLE_LANES``, PR 28).
 
-A ``rows`` table keeps each row in whole 128-column lanes: ``dim`` columns
+A table keeps each row in whole 128-column lanes: ``dim`` columns
 rest in ``padded_dim`` and the rest is zero. The device's default layout
 for such a shape is row-major, so no program copies a whole table at its
 edge to reach a few rows, and nothing has to be pinned. What has to hold
@@ -23,11 +23,11 @@ from glint_word2vec_tpu.parallel.mesh import make_mesh
 V, D, EXTRA = 50, 16, 8
 
 
-def _engine(layout="rows", shape=(2, 4)):
+def _engine(shape=(2, 4)):
     counts = np.arange(V, 0, -1).astype(np.int64) * 10
     return EmbeddingEngine(
         make_mesh(*shape), V, D, counts, num_negatives=3, seed=3,
-        extra_rows=EXTRA, layout=layout,
+        extra_rows=EXTRA,
     )
 
 
@@ -118,8 +118,8 @@ PRODUCERS = {
     "set_tables": (_set_tables, (), {}),
     "exchange_sparse": (_exchange, (), {}),
     "exchange_dense": (_exchange_dense, (), {}),
-    "dims_step": (_step, (0, 1), {"layout": "dims"}),
-    "dims_packed_scan": (_packed, (0, 1), {"layout": "dims"}),
+    "four_chip_step": (_step, (0, 1), {"shape": (1, 4)}),
+    "four_chip_packed_scan": (_packed, (0, 1), {"shape": (1, 4)}),
     "one_device_packed_scan": (_packed, (0, 1), {"shape": (1, 1)}),
 }
 
@@ -162,9 +162,7 @@ def test_every_producer_returns_the_tables_as_they_rest(
     name, tmp_path, monkeypatch, forced_setup
 ):
     eng, donated, values = _run(name, tmp_path)
-    rows_layout = eng.layout == "rows"
-    if rows_layout:
-        assert eng.padded_dim == TABLE_LANES  # 16 columns, one lane
+    assert eng.padded_dim == TABLE_LANES  # 16 columns, one lane
     for table, value in zip((eng.syn0, eng.syn1), values):
         assert table.shape == (eng.padded_vocab, eng.padded_dim)
         assert table.format.layout.major_to_minor == (0, 1)
@@ -180,17 +178,16 @@ def test_every_producer_returns_the_tables_as_they_rest(
     _fresh_programs(monkeypatch)
     monkeypatch.setattr(engine_mod, "TABLE_LANES", 1)
     bare, _, parent = _run(name, tmp_path / "parent")
-    assert bare.padded_dim == D or not rows_layout
+    assert bare.padded_dim == D
     for got, ref in zip(values, parent):
         np.testing.assert_allclose(
             got[:, :D], ref[:, :D], rtol=2e-6, atol=1e-9
         )
 
 
-def test_rows_rest_in_whole_lanes_and_dims_in_whole_shards():
+def test_rows_rest_in_whole_lanes():
     assert TABLE_LANES == 128
-    assert _engine("rows").padded_dim == 128
-    assert _engine("dims", (2, 4)).padded_dim == D  # 16: a multiple of 4
+    assert _engine().padded_dim == 128
 
 
 def test_readers_hand_back_the_real_columns_and_compile_once(monkeypatch):

@@ -99,7 +99,7 @@ def engines(topo):
                 mesh, vocab, D, num_negatives=negatives, unigram_power=0.75,
                 unigram_table_size=None, seed=1, dtype="float32",
                 extra_rows=extra_rows, shared_negatives=shared_negatives,
-                compute_dtype=None, layout="rows",
+                compute_dtype=None,
                 architecture=architecture,
             )
             eng._build_jitted_fns()

@@ -798,7 +798,7 @@ def test_recorded_span_in_a_process_without_jax():
     assert out.stdout.strip() == "['device_steps', 'req.dispatch']"
 
 
-def _untrained_model(vocab_size=64, dim=8, **engine_kw):
+def _untrained_model(vocab_size=64, dim=8, mesh=(1, 1), **engine_kw):
     import numpy as np
 
     from glint_word2vec_tpu.corpus.vocab import Vocabulary
@@ -812,7 +812,7 @@ def _untrained_model(vocab_size=64, dim=8, **engine_kw):
         [f"w{i}" for i in range(vocab_size)], counts
     )
     engine = EmbeddingEngine(
-        make_mesh(1, 1), vocab_size, dim, counts, num_negatives=2, seed=3,
+        make_mesh(*mesh), vocab_size, dim, counts, num_negatives=2, seed=3,
         **engine_kw,
     )
     return Word2VecModel(vocab, engine, Word2VecParams(
@@ -878,19 +878,18 @@ def test_new_request_spans_leave_graftlint_clean():
 
 
 _PACKED_CASES = pytest.mark.parametrize(
-    "shared_negatives,layout", [(0, "rows"), (16, "rows"), (0, "dims")],
-    ids=["per_pair", "shared_pool", "per_pair-dims"],
+    "shared_negatives,mesh", [(0, (1, 1)), (16, (1, 1)), (0, (1, 4))],
+    ids=["per_pair", "shared_pool", "per_pair-1x4"],
 )
 
 
-def _lowered_packed_scan(shared_negatives, layout):
-    """(text, table type) of the packed scan lowered on the CPU, with the
-    ops' locations in the text."""
+def _lowered_packed_scan(shared_negatives, mesh):
+    """(text, a shard's table type) of the packed scan lowered on the CPU,
+    with the ops' locations in the text."""
     import jax
     import jax.numpy as jnp
 
-    model = _untrained_model(shared_negatives=shared_negatives,
-                             layout=layout)
+    model = _untrained_model(shared_negatives=shared_negatives, mesh=mesh)
     eng = model.engine
     try:
         sds = jax.ShapeDtypeStruct
@@ -907,15 +906,14 @@ def _lowered_packed_scan(shared_negatives, layout):
         ).as_text(debug_info=True)
     finally:
         model.stop()
-    rows, cols = table.shape
-    return text, f"tensor<{rows}x{cols}xf32>"
+    return text, f"tensor<{eng.rows_per_shard}x{eng.padded_dim}xf32>"
 
 
 @_PACKED_CASES
-def test_packed_scan_ops_carry_the_phase_scopes(shared_negatives, layout):
+def test_packed_scan_ops_carry_the_phase_scopes(shared_negatives, mesh):
     import re
 
-    text, _ = _lowered_packed_scan(shared_negatives, layout)
+    text, _ = _lowered_packed_scan(shared_negatives, mesh)
     scopes = set(re.findall(r"glint\.\w+(?:/syn[01])?", text))
     # the gathers name their table since ISSUE 31, as the scatters do; what
     # else lies under glint.gather (reshapes, the all-gather of h) does not
@@ -927,7 +925,7 @@ def test_packed_scan_ops_carry_the_phase_scopes(shared_negatives, layout):
 
 
 @_PACKED_CASES
-def test_packed_scan_writes_each_distinct_row_once(shared_negatives, layout):
+def test_packed_scan_writes_each_distinct_row_once(shared_negatives, mesh):
     """What the step's scatters promise the compiler, and where the work
     that earns the promise is filed: each table is written by ONE scatter,
     told its rows are distinct (``unique_indices``; not that they are
@@ -937,7 +935,7 @@ def test_packed_scan_writes_each_distinct_row_once(shared_negatives, layout):
     ``step.scatter_ms`` counts them."""
     import re
 
-    text, table = _lowered_packed_scan(shared_negatives, layout)
+    text, table = _lowered_packed_scan(shared_negatives, mesh)
     names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
     ops = re.findall(
         r'"stablehlo\.(scatter|sort)"\(.*?\) <\{(.*?)\}> \(\{.*?\n\s*\}\) : '
