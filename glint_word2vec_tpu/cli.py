@@ -349,6 +349,19 @@ def _add_query(sub):
              "(--fasttext) model, both on the coalesced, cached, "
              "pre-warmed path: a subword model also answers words "
              "that are not in its dictionary, from their n-gram rows",
+        description=(
+            "Serve a saved model over HTTP. A model saved with "
+            "--num-shards n is served where it lies: the saved topology "
+            "is re-homed on the live devices (the model axis clamped to "
+            "their number), each holds 1/n of the rows of both tables, "
+            "and every query is answered by all of them (a shard's own "
+            "top-k, merged over the model axis) with no option here. "
+            "GET /metrics reports, under the model's entry, `shards`, "
+            "`rows_per_shard`, `resident_bytes` (the model's total over "
+            "ALL its devices, which --model-memory-budget is held "
+            "against) and `resident_bytes_per_device` (what the fullest "
+            "device holds of it)."
+        ),
     )
     p.add_argument("--model", default=None,
                    help="saved model directory (optional when "
@@ -427,7 +440,8 @@ def _add_query(sub):
                          "committed publish generation")
     mm.add_argument("--model-memory-budget", default=None,
                     metavar="BYTES",
-                    help="device-memory budget for resident tables "
+                    help="device-memory budget for resident tables, "
+                         "a model's total over ALL its devices "
                          "(suffixes kb/mb/gb); over budget, the "
                          "least-recently-used unpinned model is staged "
                          "out to its committed snapshot and staged "
@@ -620,7 +634,8 @@ def _add_query(sub):
                           "pins a boot point)")
     fmm.add_argument("--model-memory-budget", default=None,
                      metavar="BYTES",
-                     help="per-replica resident-table budget "
+                     help="per-replica resident-table budget, a "
+                          "model's total over all its devices "
                           "(suffixes kb/mb/gb); LRU stage-out beyond "
                           "it")
 

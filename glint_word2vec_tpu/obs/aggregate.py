@@ -498,6 +498,10 @@ def merge_serving_snapshots(snaps: Iterable[dict]) -> Optional[dict]:
         for k in ("resident_bytes", "stage_ins_total",
                   "evictions_total"):
             m[k] = sum(int(g.get(k) or 0) for g in group)
+        # How one replica's copy lies on its devices: not additive.
+        for k in ("shards", "rows_per_shard",
+                  "resident_bytes_per_device"):
+            m[k] = max(int(g.get(k) or 0) for g in group)
         models[mid] = m
     # Catalog block: LRU churn counters sum across replicas; the
     # membership/budget numbers are per-replica configuration, folded

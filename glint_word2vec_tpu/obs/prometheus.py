@@ -1036,6 +1036,24 @@ def serving_to_prometheus(snap: dict) -> str:
         for mid, m in sorted(models.items()):
             p.sample("glint_model_resident_bytes", {"model": mid},
                      m.get("resident_bytes", 0))
+        p.head("glint_model_resident_bytes_per_device", "gauge",
+               "What the fullest device holds of "
+               "glint_model_resident_bytes.")
+        for mid, m in sorted(models.items()):
+            p.sample("glint_model_resident_bytes_per_device",
+                     {"model": mid},
+                     m.get("resident_bytes_per_device", 0))
+        p.head("glint_model_shards", "gauge",
+               "Devices the model's rows are split over (the model "
+               "axis' size).")
+        for mid, m in sorted(models.items()):
+            p.sample("glint_model_shards", {"model": mid},
+                     m.get("shards", 1))
+        p.head("glint_model_rows_per_shard", "gauge",
+               "Table rows one shard holds.")
+        for mid, m in sorted(models.items()):
+            p.sample("glint_model_rows_per_shard", {"model": mid},
+                     m.get("rows_per_shard", 0))
         p.head("glint_model_pinned", "gauge",
                "Whether the model is pinned against LRU eviction "
                "(default model, mid-rollout holds).")

@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import contextlib
 import json
+import math
 import os
 import time
 from typing import List, Optional, Sequence, Tuple
@@ -128,6 +129,70 @@ def _score(table_l, q):
     return jnp.matmul(
         table_l.astype(jnp.float32), q, precision=lax.Precision.HIGHEST
     )
+
+
+def _mask_terms(norms_l, start, n_queryable):
+    """Cosine masking as one multiply + one add instead of a division plus
+    two (.., V)-wide boolean selects: ``inv`` is the reciprocal norm (0 on
+    masked rows), ``neg`` pins masked rows at -inf. Zero-norm rows must
+    never outrank a real word with negative cosine (the reference's
+    zero-norm guard at mllib:603-609 only had to avoid a 0/0); likewise
+    rows at or past ``n_queryable`` (padding / subword buckets / spare
+    extra rows not yet assigned a streaming word): only real words may
+    surface from similarity search. ``n_queryable`` is a TRACED scalar —
+    vocab_size + assigned extra rows — so online vocab growth (streaming
+    hot-swap, ISSUE 10) widens the mask without recompiling any warmed
+    top-k program. Both vectors are (V,) so the per-score work is a fused
+    multiply-add — on the serving path this cut batch top-k time ~30%
+    (SERVING_BENCH)."""
+    ok = (norms_l > 0) & (
+        start + jnp.arange(norms_l.shape[0]) < n_queryable
+    )
+    inv = jnp.where(ok, 1.0 / jnp.where(norms_l > 0, norms_l, 1.0), 0.0)
+    neg = jnp.where(ok, 0.0, -jnp.inf)
+    return inv, neg
+
+
+def _shard_topk(table_l, q, norms_l, nq, start, kk):
+    """One shard's candidates for a query ``(d,)`` or a batch ``(Q, d)``:
+    the ``kk`` best masked cosines over ITS rows and their LOCAL row ids
+    (``start`` is the shard's first row). Two sibling scopes, neither
+    inside the other (a device trace files an op under its outermost
+    ``glint.`` scope, as in the step body): ``glint.score``, the pass over
+    the shard's rows and the mask terms, and ``glint.topk``, the local
+    top-k. Scores are ``(table @ q.T).T``, not ``q @ table.T``: the
+    tall-skinny orientation streams the row-major table once
+    (bandwidth-bound like the single-query matvec) — 2x faster for small
+    Q buckets on CPU, a wash at Q=16+."""
+    with jax.named_scope("glint.score"):
+        # two spellings, so that each lowers to the text it always had
+        if q.ndim == 1:
+            scores = _score(table_l, q)
+            inv, neg = _mask_terms(norms_l, start, nq)
+            masked = scores * inv + neg
+        else:
+            scores = _score(table_l, q.T).T  # (Q, Vs)
+            inv, neg = _mask_terms(norms_l, start, nq)
+            masked = scores * inv[None, :] + neg[None, :]
+    with jax.named_scope("glint.topk"):
+        return lax.top_k(masked, kk)  # (.., kk) values, local rows
+
+
+def _merge_topk(val, idx, start, k):
+    """What exists only across shards, under ``glint.merge``: the shards'
+    candidates all-gathered over the model axis (values, then the rows as
+    global ids), the second top-k over the M * kk of them, and the take of
+    the ids."""
+    with jax.named_scope("glint.merge"):
+        axis = val.ndim - 1
+        cand_val = lax.all_gather(val, MODEL_AXIS, tiled=True, axis=axis)
+        cand_idx = lax.all_gather(
+            idx + start, MODEL_AXIS, tiled=True, axis=axis
+        )
+        mval, mpos = lax.top_k(cand_val, min(k, cand_val.shape[axis]))
+        if axis == 0:
+            return mval, cand_idx[mpos]
+        return mval, jnp.take_along_axis(cand_idx, mpos, axis=1)
 
 
 def _pull_rows(table_l, idx, start, rows_per_shard, table=None):
@@ -1311,29 +1376,6 @@ class EmbeddingEngine:
             )
         ))
 
-        def _mask_terms(norms_l, start, n_queryable):
-            # Cosine masking as one multiply + one add instead of a
-            # division plus two (.., V)-wide boolean selects: inv is the
-            # reciprocal norm (0 on masked rows), neg pins masked rows
-            # at -inf. Zero-norm rows must never outrank a real word
-            # with negative cosine (the reference's zero-norm guard at
-            # mllib:603-609 only had to avoid a 0/0); likewise rows at or
-            # past ``n_queryable`` (padding / subword buckets / spare
-            # extra rows not yet assigned a streaming word): only real
-            # words may surface from similarity search. ``n_queryable``
-            # is a TRACED scalar — vocab_size + assigned extra rows —
-            # so online vocab growth (streaming hot-swap, ISSUE 10)
-            # widens the mask without recompiling any warmed top-k
-            # program. Both vectors are (V,) so the per-score work is a
-            # fused multiply-add — on the serving path this cut batch
-            # top-k time ~30% (SERVING_BENCH).
-            ok = (norms_l > 0) & (
-                start + jnp.arange(norms_l.shape[0]) < n_queryable
-            )
-            inv = jnp.where(ok, 1.0 / jnp.where(norms_l > 0, norms_l, 1.0), 0.0)
-            neg = jnp.where(ok, 0.0, -jnp.inf)
-            return inv, neg
-
         def make_topk(k: int):
             def local_topk(table_l, v, norms_l, nq):
                 # Cosine top-k without materializing all V scores on one
@@ -1341,14 +1383,10 @@ class EmbeddingEngine:
                 # candidates, merge. Replaces the reference's full-vocab
                 # driver-side scan (mllib:601-617).
                 start = lax.axis_index(MODEL_AXIS) * Vs
-                kk = min(k, Vs)
-                scores = _score(table_l, v)
-                inv, neg = _mask_terms(norms_l, start, nq)
-                val, idx = lax.top_k(scores * inv + neg, kk)
-                cand_val = lax.all_gather(val, MODEL_AXIS, tiled=True)
-                cand_idx = lax.all_gather(idx + start, MODEL_AXIS, tiled=True)
-                mval, mpos = lax.top_k(cand_val, min(k, cand_val.shape[0]))
-                return mval, cand_idx[mpos]
+                val, idx = _shard_topk(
+                    table_l, v, norms_l, nq, start, min(k, Vs)
+                )
+                return _merge_topk(val, idx, start, k)
 
             return jax.jit(
                 self._shard_map(
@@ -1360,30 +1398,14 @@ class EmbeddingEngine:
 
         def make_topk_batch(k: int):
             def local_topk_batch(table_l, q, norms_l, nq):
-                # Scores are computed as (table @ q.T).T, not q @ table.T:
-                # the tall-skinny orientation streams the row-major table
-                # once (bandwidth-bound like the single-query matvec) —
-                # 2x faster for small Q buckets on CPU, a wash at Q=16+.
                 # q: (Q, d) replicated query batch. Same candidate-merge
                 # scheme as the single-vector kernel, vectorized over Q —
                 # one matmul scores all queries against this shard.
                 start = lax.axis_index(MODEL_AXIS) * Vs
-                kk = min(k, Vs)
-                scores = _score(table_l, q.T).T  # (Q, Vs)
-                inv, neg = _mask_terms(norms_l, start, nq)
-                val, idx = lax.top_k(
-                    scores * inv[None, :] + neg[None, :], kk
-                )  # (Q, kk)
-                cand_val = lax.all_gather(
-                    val, MODEL_AXIS, tiled=True, axis=1
+                val, idx = _shard_topk(
+                    table_l, q, norms_l, nq, start, min(k, Vs)
                 )
-                cand_idx = lax.all_gather(
-                    idx + start, MODEL_AXIS, tiled=True, axis=1
-                )
-                mval, mpos = lax.top_k(
-                    cand_val, min(k, cand_val.shape[1])
-                )
-                return mval, jnp.take_along_axis(cand_idx, mpos, axis=1)
+                return _merge_topk(val, idx, start, k)
 
             return jax.jit(
                 self._shard_map(
@@ -2289,16 +2311,53 @@ class EmbeddingEngine:
     def _row_writer(self):
         """Lazily-built jitted row-block writer shared by
         :meth:`write_rows` and the extra-row assignment path: one
-        compiled program per block shape, start row traced."""
+        compiled program per block shape, start row traced.
+
+        On a model axis of one it is a ``dynamic_update_slice``. Over
+        several shards that op's traced start makes XLA's partitioner
+        gather the WHOLE table on every device first (at 10M x 300 over
+        four v5e chips the chip's compiler refuses the program: 14.66 GB
+        of temporaries beside a 3.84 GB shard; PERF.md, PR 47), so there
+        each shard writes its own rows of the block into a window of its
+        own table, as the servers each take their slice of a push, and
+        nothing of the table crosses chips."""
         if not hasattr(self, "_write_rows_fn"):
+            if self.num_model == 1:
+                def write(table, block, s):
+                    return lax.dynamic_update_slice(
+                        table, block.astype(table.dtype), (s, 0)
+                    )
+            else:
+                write = self._shard_map(
+                    self._write_own_rows,
+                    in_specs=(P(MODEL_AXIS, None), P(), P()),
+                    out_specs=P(MODEL_AXIS, None),
+                )
             self._write_rows_fn = jax.jit(
-                lambda table, block, s: jax.lax.dynamic_update_slice(
-                    table, block.astype(table.dtype), (s, 0)
-                ),
+                write,
                 out_shardings=self._table_sharding(),
                 donate_argnums=(0,),
             )
         return self._write_rows_fn
+
+    def _write_own_rows(self, table_l, block, s):
+        """One shard's share of :meth:`write_rows`: the rows of ``block``
+        (global rows ``s ..``) that this shard owns, written in place.
+        The window is as many rows as the block has (or the whole shard,
+        if the block is longer), laid where the block meets the shard; a
+        window row the block does not reach keeps what it held."""
+        Vs = self.rows_per_shard
+        n, cols = block.shape
+        m = min(n, Vs)
+        loc = s - lax.axis_index(MODEL_AXIS) * Vs  # local row of block[0]
+        w = jnp.clip(loc, 0, Vs - m)
+        held = lax.dynamic_slice(table_l, (w, 0), (m, cols))
+        i = w + jnp.arange(m) - loc  # the block row a window row takes
+        rows = jnp.where(
+            ((i >= 0) & (i < n))[:, None],
+            block[jnp.clip(i, 0, n - 1)].astype(table_l.dtype), held,
+        )
+        return lax.dynamic_update_slice(table_l, rows, (w, 0))
 
     def write_rows(self, start_row: int, rows: jax.Array) -> None:
         """Overwrite ``rows.shape[0]`` consecutive syn0 rows starting at
@@ -3760,24 +3819,45 @@ class EmbeddingEngine:
         self.syn0, self.syn1 = put(syn0), put(syn1)
         self._tick_tables("set_tables")
 
-    def resident_bytes(self) -> int:
-        """Device bytes the live tables (+ adopted ANN index) hold —
-        the per-model cost the serving catalog's memory budget accounts
-        (ISSUE 20). Zero after :meth:`release_tables`."""
-        n = 0
+    def _resident_arrays(self):
+        """The live tables and the adopted ANN index's arrays."""
         for a in (self.syn0, self.syn1):
             if a is not None:
-                # graftlint: ignore[sync-point] .size is array metadata
-                n += int(a.size) * a.dtype.itemsize
+                yield a
         idx = self._ann
         if idx is not None:
             for name in ("centroids", "members", "member_invn",
                          "member_rows"):
                 a = getattr(idx, name, None)
                 if a is not None and hasattr(a, "size"):
-                    # graftlint: ignore[sync-point] .size is metadata
-                    n += int(a.size) * a.dtype.itemsize
-        return n
+                    yield a
+
+    def resident_bytes(self) -> int:
+        """Device bytes the live tables (+ adopted ANN index) hold —
+        the per-model cost the serving catalog's memory budget accounts
+        (ISSUE 20). Zero after :meth:`release_tables`.
+
+        The model's total over ALL its devices (an array's ``size`` is
+        the global array's): a 10M x 300 f32 pair split by rows over
+        four chips is 30.7 GB here and 7.68 GB to a chip.
+        :meth:`resident_bytes_per_device` is what one chip holds."""
+        # graftlint: ignore[sync-point] .size is array metadata
+        return sum(int(a.size) * a.dtype.itemsize
+                   for a in self._resident_arrays())
+
+    def resident_bytes_per_device(self) -> int:
+        """What the FULLEST device holds of :meth:`resident_bytes`, from
+        the arrays' shardings alone (a replicated array counts whole on
+        every device it lies on)."""
+        held: dict = {}
+        for a in self._resident_arrays():
+            sharding = getattr(a, "sharding", None)
+            if sharding is None:  # a host array: no device holds it
+                continue
+            shard = math.prod(sharding.shard_shape(a.shape))
+            for dev in sharding.device_set:
+                held[dev] = held.get(dev, 0) + shard * a.dtype.itemsize
+        return max(held.values(), default=0)
 
     @property
     def tables_resident(self) -> bool:

@@ -584,6 +584,7 @@ class _SynonymCoalescer:
         t_dis0 = time.perf_counter()
         with obs_events.phase_span(
             "req.dispatch", batch=len(chunk), mode=mode,
+            shards=int(getattr(m.engine, "num_model", 1)),
             traces=[r["trace"] for r in chunk if r.get("trace")],
         ):
             # The table a word's row is pulled from is the table its
@@ -933,18 +934,29 @@ class ServedModel:
             and type(self.model) is Word2VecModel
         )
 
+    def _engines(self):
+        """The entry's engines with tables on the device: the training
+        engine and, for the subword family, the composed query engine
+        that rests beside it."""
+        if not self.resident:
+            return []
+        engines = (getattr(self.model, "engine", None),
+                   getattr(self.model, "_qeng", None))
+        return [e for e in engines if hasattr(e, "resident_bytes")]
+
     def resident_bytes(self) -> int:
         """Device bytes this entry holds right now (0 when staged
-        out)."""
-        eng = getattr(self.model, "engine", None)
-        fn = getattr(eng, "resident_bytes", None)
-        if fn is None or not self.resident:
-            return 0
-        # The subword family's composed query engine rests beside the
-        # training tables: the budget counts both.
-        qeng = getattr(self.model, "_qeng", None)
-        return int(fn()) + (
-            int(qeng.resident_bytes()) if qeng is not None else 0
+        out), the subword family's composed query engine counted beside
+        its training tables. The total over ALL the model's devices,
+        which is what ``--model-memory-budget`` is held against: a
+        model split by rows over four chips counts its whole 30.7 GB,
+        not a chip's 7.68 GB (:meth:`resident_bytes_per_device`)."""
+        return sum(int(e.resident_bytes()) for e in self._engines())
+
+    def resident_bytes_per_device(self) -> int:
+        """What the fullest device holds of :meth:`resident_bytes`."""
+        return sum(
+            int(e.resident_bytes_per_device()) for e in self._engines()
         )
 
 
@@ -1920,6 +1932,15 @@ class ModelServer:
         snap["resident_replicas"] = 1 if entry.resident else 0
         snap["pinned"] = entry.pins > 0
         snap["resident_bytes"] = entry.resident_bytes()
+        # How the model lies on its devices: the model axis' size, the
+        # table rows a shard holds, and the fullest device's bytes of
+        # the total above.
+        eng = getattr(entry.model, "engine", None)
+        snap["shards"] = int(getattr(eng, "num_model", 1))
+        snap["rows_per_shard"] = int(getattr(eng, "rows_per_shard", 0))
+        snap["resident_bytes_per_device"] = (
+            entry.resident_bytes_per_device()
+        )
         snap["stage_ins_total"] = entry.stage_ins
         snap["evictions_total"] = entry.evictions
         return snap

@@ -775,6 +775,55 @@ def test_query_program_at_the_benchmark_size_copies_no_table(
     assert mem["temp"] < temp_ceiling, mem
 
 
+# The four-chip served cell (benchmark/configs/w2v-nn-300-10m-x4.json: 10M x
+# 300 by rows over four chips, 16 callers, num 10 -> the k bucket 16): a
+# round's pull and batch top-k, and the program that fills the table a block
+# of 250,000 rows at a time (``write_rows``: each shard writes its own rows;
+# as one ``dynamic_update_slice`` the partitioner gathered the whole table on
+# every chip, 14.66 GB of temporaries, and the compiler refused it: PR 47).
+# ISSUE 47's sizes: two tables, 30.72 GB at rest, 7.68 GB a chip; a program
+# is handed ONE of them, 3.84 GB a chip, and copies none of it.
+X4_TABLE_BYTES_A_CHIP = 10_000_000 * D_REST * 4 // 4
+
+
+@pytest.mark.parametrize(
+    "op,shape,temp_ceiling",
+    [("pull", (16,), 1 * 10**6),
+     ("topk_batch", (16, 16), 200 * 10**6),
+     ("topk_batch", (64, 32), 1400 * 10**6),
+     ("norms", (), 1 * 10**6),
+     ("write_rows", (250_000,), 800 * 10**6)],
+    ids=["pull", "topk_batch-16x16", "topk_batch-64x32", "norms",
+         "write_rows"],
+)
+def test_query_program_at_the_four_chip_served_cell_size(
+    engines, op, shape, temp_ceiling
+):
+    import jax.numpy as jnp
+
+    eng = engines(4, vocab=10_000_000)
+    assert eng.rows_per_shard == 2_500_000
+    if op == "write_rows":
+        sds = _shapes(eng)
+        lowered = eng._row_writer().lower(
+            _table(eng), sds((shape[0], D), jnp.float32), sds((), jnp.int32)
+        )
+    else:
+        lowered = _lower_query(eng, op, shape)
+    compiled = lowered.compile()
+    mem = _fits(compiled, 4)
+    assert _rests(compiled.input_formats[0][0], eng)
+    assert not _whole_table_copies(compiled, eng)
+    assert mem["temp"] < temp_ceiling, mem
+    # a device holds its quarter of the one table the program is handed
+    # (half of the issue's 7.68 GB of tables a chip) and little else
+    assert 2 * X4_TABLE_BYTES_A_CHIP == 7_680_000_000
+    assert X4_TABLE_BYTES_A_CHIP <= mem["args"] < (
+        X4_TABLE_BYTES_A_CHIP + 320 * 10**6), mem
+    if op == "write_rows":  # in place: the shard that comes back is the
+        assert mem["aliased"] == X4_TABLE_BYTES_A_CHIP, mem  # one handed in
+
+
 # The served subword cell's compose (benchmark/configs/ft-nn-300-1m-2mb.json:
 # 1M words + 2M bucket rows, groups 16 wide): a coalesced round's
 # out-of-dictionary words are ONE pull-average at their power-of-two bucket
