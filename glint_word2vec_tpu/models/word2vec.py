@@ -1931,12 +1931,16 @@ class Word2VecModel:
         return self._decode_hits(sims, idx)
 
     def find_synonyms_batch(
-        self, vectors: np.ndarray, num: int, *, approximate: bool = False
+        self, vectors: Optional[np.ndarray], num: int, *,
+        approximate: bool = False, ids=None,
     ) -> List[List[Tuple[str, float]]]:
         """Top-``num`` neighbors for a whole (Q, d) query batch in one
         distributed dispatch — the batch form of
         :meth:`find_synonyms_vector` (the reference answers findSynonyms
         for arrays by looping single queries, ml:375-420).
+        ``ids`` (the exact path's alone) names the queries that are rows
+        of the queried table, which the program then gathers for itself:
+        see ``EmbeddingEngine.top_k_cosine_batch``.
         ``approximate=True`` rides the engine's two-stage coarse index
         (ISSUE 12) instead of the exact masked GEMM — requires an
         adopted index; the serving layer owns the recall gate. A
@@ -1960,9 +1964,7 @@ class Word2VecModel:
                 np.asarray(vectors, np.float32), num
             )
         else:
-            sims, idx = eng.top_k_cosine_batch(
-                np.asarray(vectors, np.float32), num
-            )
+            sims, idx = eng.top_k_cosine_batch(vectors, num, ids=ids)
         return [self._decode_hits(s, i) for s, i in zip(sims, idx)]
 
     def analogy(

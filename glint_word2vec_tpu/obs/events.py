@@ -62,9 +62,14 @@ REQUEST_SPANS = {
     "req.hop": "balancer -> replica proxy attempt (one per retry hop)",
     "req.grace": "coalescer leader's straggler-absorbing sleeps before a "
                  "dispatch (args: batch before and after)",
-    "req.dispatch": "warm-bucket device dispatch of one coalesced batch",
-    "req.pull": "the dispatch's row pull, device program and read-back "
-                "(child of req.dispatch; args: rows)",
+    "req.dispatch": "warm-bucket device dispatch of one coalesced batch "
+                    "(args: batch, mode, shards, traces, and `programs`, "
+                    "the query programs the round launched)",
+    "req.pull": "what is left of the dispatch's row pull on the host: the "
+                "words' row ids built, padded to their bucket and put on "
+                "the device for the top-k to gather from (the approximate "
+                "path: the pull's program and read-back) (child of "
+                "req.dispatch; args: rows)",
     "req.compose": "the dispatch's compose of its out-of-dictionary "
                    "query words (subword family): host n-gram hashing, "
                    "one bucketed pull_average and its read-back (child "

@@ -1397,11 +1397,22 @@ class EmbeddingEngine:
             )
 
         def make_topk_batch(k: int):
-            def local_topk_batch(table_l, q, norms_l, nq):
-                # q: (Q, d) replicated query batch. Same candidate-merge
-                # scheme as the single-vector kernel, vectorized over Q —
-                # one matmul scores all queries against this shard.
+            def local_topk_batch(table_l, q, ids, norms_l, nq):
+                # q: (Q, padded_dim) replicated query batch, ids: (Q,).
+                # A query whose id is a row of the table is gathered here,
+                # at the table's resting width, and divided by its norm as
+                # the host divides the vectors it sends (a zero row stays
+                # zero): a served round's rows never visit the host. An
+                # id of -1 takes its row of ``q``. Then the same
+                # candidate-merge scheme as the single-vector kernel,
+                # vectorized over Q — one matmul scores all queries
+                # against this shard.
                 start = lax.axis_index(MODEL_AXIS) * Vs
+                rows = _pull_rows(table_l, ids, start, Vs)
+                with jax.named_scope("glint.query"):
+                    nrm = jnp.sqrt((rows * rows).sum(axis=1, keepdims=True))
+                    rows = rows / jnp.where(nrm > 0, nrm, 1.0)
+                    q = jnp.where((ids >= 0)[:, None], rows, q)
                 val, idx = _shard_topk(
                     table_l, q, norms_l, nq, start, min(k, Vs)
                 )
@@ -1410,13 +1421,16 @@ class EmbeddingEngine:
             return jax.jit(
                 self._shard_map(
                     local_topk_batch,
-                    in_specs=(tspec, rep, P(MODEL_AXIS), rep),
+                    in_specs=(tspec, rep, rep, P(MODEL_AXIS), rep),
                     out_specs=(rep, rep),
                 )
             )
 
         self._topk_cache: dict = {}
         self._topk_batch_cache: dict = {}
+        #: Q bucket -> the zero query block of a batch that is all ids
+        #: (:meth:`_query_block`): put on the device once.
+        self._zero_queries: dict = {}
         # The per-k factories consult the process memo first: a
         # same-geometry engine's k-bucket family is the SAME jitted
         # callable (tables/norms/queryable are traced arguments), so a
@@ -1436,6 +1450,10 @@ class EmbeddingEngine:
         # growing — the /metrics zero-compile contract (ISSUE 2).
         self._query_shapes: set = set()
         self.query_compiles: int = 0
+        #: Query programs this engine has launched (every dispatch of a
+        #: query op counts its shape once): what a served round reads
+        #: before and after itself for its span's ``programs``.
+        self.query_dispatches: int = 0
         #: First-seen shapes on THIS engine whose program was already
         #: compiled process-wide by a same-geometry engine (the shared
         #: warm family, ISSUE 20): a ``query_compiles`` tick that cost
@@ -2259,6 +2277,7 @@ class EmbeddingEngine:
         """Record one query-op dispatch shape; a first-seen shape is one
         jit compile (jit specializes per shape). Callers hold the query
         lock on the serving path; elsewhere races only over-count."""
+        self.query_dispatches += 1
         if key not in self._query_shapes:
             self._query_shapes.add(key)
             self.query_compiles += 1
@@ -2615,44 +2634,84 @@ class EmbeddingEngine:
         )
         return np.asarray(val)[:k], np.asarray(idx)[:k]
 
+    def _put_replicated(self, a: np.ndarray) -> jax.Array:
+        """A host block on every device of the mesh, as the query programs
+        take their replicated arguments. Always the same placement, so a
+        program warmed with one block runs the next without a new
+        executable."""
+        return jax.device_put(a, NamedSharding(self.mesh, P()))
+
+    @staticmethod
+    def _query_ids(ids, q_b: int) -> np.ndarray:
+        """A batch's query ids padded with -1 to its Q bucket, as the
+        batch top-k takes them: an id >= 0 names the row of syn0 that IS
+        the query, -1 a query the host sends as a vector. A host block:
+        its few bytes go to the device inside the program's launch, with
+        no dispatch of their own."""
+        block = np.full(q_b, -1, np.int32)
+        if ids is not None:
+            block[: len(ids)] = ids
+        return block
+
+    def _query_block(self, vecs, q_b: int) -> jax.Array:
+        """The ``(q_b, padded_dim)`` block the batch top-k reads where an
+        id is -1: ``vecs`` normalized (the reference normalizes with BLAS
+        snrm2/sscal before ``multiply``, mllib:593-595), zero rows and
+        columns up to the bucket and the tables' resting width. With no
+        vector to send it is a block of zeros that stays on the device."""
+        if vecs is None:
+            if q_b not in self._zero_queries:
+                self._zero_queries[q_b] = self._put_replicated(
+                    np.zeros((q_b, self.padded_dim), np.float32)
+                )
+            return self._zero_queries[q_b]
+        nrm = np.linalg.norm(vecs, axis=1, keepdims=True)
+        q = np.zeros((q_b, self.padded_dim), np.float32)
+        q[: vecs.shape[0], : self.dim] = vecs / np.where(nrm > 0, nrm, 1.0)
+        return self._put_replicated(q)
+
     def top_k_cosine_batch(
-        self, vecs, k: int
+        self, vecs, k: int, ids=None
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Batched :meth:`top_k_cosine`: (Q, d) queries -> ((Q, k) sims,
         (Q, k) indices) in one distributed dispatch. The batch analogue of
         the reference's findSynonyms(Array) delegation loop
-        (ml:375-420), scored as one sharded matmul per call."""
+        (ml:375-420), scored as one sharded matmul per call.
+
+        ``ids`` (Q row ids) says which queries are rows of syn0: the
+        program gathers and normalizes those itself, so they never visit
+        the host, and reads ``vecs`` only where an id is -1. With every
+        id >= 0 ``vecs`` may be None."""
         if not 0 < k <= self.padded_vocab:
             raise ValueError(f"k must be in [1, {self.padded_vocab}]")
-        q = np.asarray(vecs, dtype=np.float32)
-        if q.ndim != 2 or q.shape[1] != self.dim:
-            raise ValueError(f"vecs must have shape (Q, {self.dim})")
-        nrm = np.linalg.norm(q, axis=1, keepdims=True)
-        q = q / np.where(nrm > 0, nrm, 1.0)
+        if vecs is not None:
+            vecs = np.asarray(vecs, dtype=np.float32)
+            if vecs.ndim != 2 or vecs.shape[1] != self.dim:
+                raise ValueError(f"vecs must have shape (Q, {self.dim})")
+        elif ids is None:
+            raise ValueError("top_k_cosine_batch needs vecs or ids")
+        n = vecs.shape[0] if ids is None else len(ids)
+        if vecs is not None and vecs.shape[0] != n:
+            raise ValueError(f"vecs has {vecs.shape[0]} rows for {n} ids")
         kk = min(k, self.padded_vocab)
-        if q.shape[0] == 0:
+        if n == 0:
             empty = np.zeros((0, kk))
             return empty.astype(np.float32), empty.astype(np.int64)
         k_b = self._k_bucket(k)
         if k_b not in self._topk_batch_cache:
             self._topk_batch_cache[k_b] = self._make_topk_batch(k_b)
-        fn = self._topk_batch_cache[k_b]
-        n = q.shape[0]
         # Pad Q up to its bucket (power of two, floored at
         # TOPK_MIN_Q_BUCKET) so concurrency jitter (every distinct
         # coalesced batch size) maps onto a small compiled family.
-        # Zero-vector padding rows score 0 for real words and are
-        # sliced off; they can never perturb a real row's top-k
+        # The padding rows (id -1, a zero vector) score 0 for real words
+        # and are sliced off; they can never perturb a real row's top-k
         # (each query row ranks independently).
         q_b = self._q_bucket(n)
-        if q_b != n:
-            q = np.concatenate(
-                [q, np.zeros((q_b - n, q.shape[1]), np.float32)]
-            )
         self._count_query_shape("topk_batch", q_b, k_b)
-        val, idx = fn(
-            self.syn0, self._pad_query(q), self.norms(),
-            jnp.int32(self.queryable_rows),
+        val, idx = self._topk_batch_cache[k_b](
+            self.syn0, self._query_block(vecs, q_b),
+            self._query_ids(ids, q_b), self.norms(),
+            np.int32(self.queryable_rows),
         )
         return np.asarray(val)[:n, :kk], np.asarray(idx)[:n, :kk]
 
@@ -2911,17 +2970,11 @@ class EmbeddingEngine:
             qc = qvecs[s : s + q_chunk]
             ic = qids[s : s + q_chunk]
             n = qc.shape[0]
-            nrm = np.linalg.norm(qc, axis=1, keepdims=True)
-            qn = qc / np.where(nrm > 0, nrm, 1.0)
             q_b = self._q_bucket(n)
-            qp = qn
-            if q_b != n:
-                qp = np.concatenate(
-                    [qn, np.zeros((q_b - n, qn.shape[1]), np.float32)]
-                )
             self._count_query_shape("topk_batch", q_b, k_b)
             ex_val, ex_idx = exact_fn(
-                syn0, self._pad_query(qp), norms, jnp.int32(queryable)
+                syn0, self._query_block(qc, q_b),
+                self._query_ids(None, q_b), norms, np.int32(queryable),
             )
             ex_val = np.asarray(ex_val)[:n]
             ex_idx = np.asarray(ex_idx)[:n]
@@ -2970,9 +3023,11 @@ class EmbeddingEngine:
         for q in sorted({next_pow2(int(q)) for q in q_buckets}):
             self.pull(np.zeros(q, np.int32))
         for q in sorted({self._q_bucket(int(q)) for q in q_buckets}):
-            zq = np.zeros((q, d), np.float32)
+            # by ids, so that the zero block of an all-ids round is on
+            # the device too; a round that sends vectors runs the same
+            # program
             for k in ks:
-                self.top_k_cosine_batch(zq, k)
+                self.top_k_cosine_batch(None, k, ids=np.zeros(q, np.int32))
         for s in sorted({next_pow2(int(s)) for s in sentence_rows}):
             for L in sorted({next_pow2(int(L)) for L in sentence_lens}):
                 self.pull_average(

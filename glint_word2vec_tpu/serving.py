@@ -50,8 +50,9 @@ compiled program is reused — zero post-warmup compiles across swaps.
 Every device dispatch on the hot path belongs to a small, pre-warmed
 shape family: coalesced batches pad to power-of-two Q buckets (capped at
 ``max_batch``), top-k requests round up to k buckets
-(engine.TOPK_MIN_K_BUCKET), the coalesced word pull chunks at
-``MAX_QUERY_ROWS`` exactly like ``transform_words``, and ``ModelServer``
+(engine.TOPK_MIN_K_BUCKET), the approximate path's coalesced word pull
+chunks at ``MAX_QUERY_ROWS`` exactly like ``transform_words``, and
+``ModelServer``
 compiles the whole family BEFORE binding the port — so the first real
 request (and every later one inside the family) never pays a jit compile.
 
@@ -248,9 +249,10 @@ class _SynonymCoalescer:
     single-query dispatches (QPS flat in N). Here every waiting request
     lands in a pending list; whichever thread next wins the device lock
     becomes leader, drains the list, answers ALL of them with ONE
-    ``engine.pull`` + ONE ``find_synonyms_batch`` dispatch per
-    ``max_batch`` chunk (the batch top-k the reference lacks — it loops
-    findSynonyms, ml:375-420), and wakes the waiters. Exclusion
+    ``find_synonyms_batch`` dispatch per ``max_batch`` chunk (the batch
+    top-k the reference lacks — it loops findSynonyms, ml:375-420; a
+    dictionary word rides it as its row id, which the program gathers
+    for itself, so no row visits the host), and wakes the waiters. Exclusion
     semantics match find_synonyms exactly (fetch num+1, drop the query
     word, truncate). Dispatches are shape-bucketed: the engine pads Q to
     powers of two and rounds k up to its bucket, so every chunk reuses a
@@ -567,10 +569,11 @@ class _SynonymCoalescer:
 
     def _dispatch(self, chunk, mode: str = "exact") -> None:
         """Answer one <= max_batch slice of the drained batch with one
-        bucketed pull + one bucketed batch top-k dispatch (exact masked
-        GEMM, or the two-stage coarse+rerank when ``mode == "ann"``);
-        the subword family's out-of-dictionary words add one bucketed
-        compose in between."""
+        bucketed batch top-k dispatch: the exact masked GEMM, which
+        gathers its dictionary words' rows itself, or, when ``mode ==
+        "ann"``, one bucketed pull and the two-stage coarse+rerank. The
+        subword family's out-of-dictionary words add one bucketed
+        compose before it."""
         faults.fire("serving.dispatch")
         m = self.model
         # Version BEFORE the reads: if a table mutation lands mid-
@@ -586,33 +589,56 @@ class _SynonymCoalescer:
             "req.dispatch", batch=len(chunk), mode=mode,
             shards=int(getattr(m.engine, "num_model", 1)),
             traces=[r["trace"] for r in chunk if r.get("trace")],
-        ):
-            # The table a word's row is pulled from is the table its
+        ) as span:
+            # The table a word's row comes from is the table its
             # neighbours are scored against: the training table at word
             # level, the composed one for the subword family (composed
             # anew here, first, if the training tables have moved).
             qeng = m._query_engine()
-            word_rows = [r for r in chunk if "idx" in r]
-            if word_rows:
-                with obs_events.phase_span("req.pull", rows=len(word_rows)):
-                    pulled = _pull_coalesced(
-                        qeng,
-                        np.asarray([r["idx"] for r in word_rows], np.int32),
-                    )
-                for r, v in zip(word_rows, pulled):
-                    r["vec"] = v
+            engines = {qeng, m.engine}
+            launched = sum(e.query_dispatches for e in engines)
+            n_words = sum("idx" in r for r in chunk)
             if self.composes:
-                chunk = self._compose_round(chunk, len(word_rows))
+                chunk = self._compose_round(chunk, n_words)
                 if not chunk:
                     return
+            # What a request carries decides how it enters the top-k: a
+            # dictionary word as its row id, which the exact program
+            # gathers for itself (nothing of the row visits the host); a
+            # raw vector and a composed one as the vector. The
+            # approximate search takes vectors alone, so there the words'
+            # rows are pulled first.
+            ids = None
+            if n_words:
+                with obs_events.phase_span("req.pull", rows=n_words):
+                    word_ids = np.asarray(
+                        [r.get("idx", -1) for r in chunk], np.int32
+                    )
+                    if mode == "ann":
+                        rows = iter(
+                            _pull_coalesced(qeng, word_ids[word_ids >= 0])
+                        )
+                        for r in chunk:
+                            if "idx" in r:
+                                r["vec"] = next(rows)
+                    else:
+                        ids = word_ids
+            vectors = None
+            if ids is None or n_words < len(chunk):
+                vectors = np.zeros((len(chunk), m.vector_size), np.float32)
+                for row, r in zip(vectors, chunk):
+                    if "vec" in r:
+                        row[:] = r["vec"]
             k = max(
                 r["num"] + (1 if r["word"] is not None else 0)
                 for r in chunk
             )
             hits = m.find_synonyms_batch(
-                np.stack([r["vec"] for r in chunk]),
-                min(k, m.vocab.size),
-                approximate=(mode == "ann"),
+                vectors, min(k, m.vocab.size),
+                approximate=(mode == "ann"), ids=ids,
+            )
+            span.update(
+                programs=sum(e.query_dispatches for e in engines) - launched
             )
         t_dis1 = time.perf_counter()
         if self.metrics is not None:

@@ -235,6 +235,65 @@ def test_coalescer_chunks_at_max_batch(served):
     assert sizes == {"1": 1, "2": 2}
 
 
+@pytest.mark.parametrize("kind", ["words", "vectors", "mixed"])
+def test_an_exact_round_is_one_query_program(served, monkeypatch, kind):
+    # What a request carries decides how it enters the top-k: a dictionary
+    # word goes in as its row id and the program gathers the row itself,
+    # so an exact round launches ONE query program and never calls
+    # ``engine.pull``; a raw vector goes in as the vector. After the
+    # warm-up no such round compiles anything.
+    import threading
+
+    from glint_word2vec_tpu.obs import events as obs_events
+
+    server, model = served
+    co = server._coalescer
+    words = [model.vocab.words[i] for i in (3, 1, 4, 15, 9)]
+    vecs = [np.asarray(model.transform(w), np.float32) for w in words]
+    by_vector = {"words": [False] * 5, "vectors": [True] * 5,
+                 "mixed": [False, True, False, False, True]}[kind]
+    batch = [
+        {"word": None if v else w, "vector": vec.tolist() if v else None,
+         "num": 4, "event": threading.Event(), "result": None,
+         "error": None}
+        for w, vec, v in zip(words, vecs, by_vector)
+    ]
+
+    def no_pull(*a, **k):
+        raise AssertionError("an exact round pulled rows to the host")
+
+    monkeypatch.setattr(model.engine, "pull", no_pull)
+    compiled_before = model.engine.query_compiles
+    recorder = obs_events.EventRecorder(capacity=4096)
+    prev = obs_events.get_recorder()
+    obs_events.set_recorder(recorder)
+    try:
+        with co.device_lock:
+            co._process(batch)
+    finally:
+        obs_events.set_recorder(prev)
+    monkeypatch.undo()
+    for r, w, v in zip(batch, words, by_vector):
+        assert r["event"].is_set() and r["error"] is None
+        want = (model.find_synonyms_vector(model.transform(w), 4) if v
+                else model.find_synonyms(w, 4))
+        assert [x[0] for x in r["result"]] == [x[0] for x in want]
+        np.testing.assert_allclose(
+            [x[1] for x in r["result"]], [x[1] for x in want], atol=2e-6)
+    spans = recorder.events()
+    rounds = [e for e in spans if e["name"] == "req.dispatch"]
+    assert len(rounds) == 1
+    assert rounds[0]["args"]["programs"] == 1
+    assert rounds[0]["args"]["batch"] == 5
+    pulls = [e for e in spans if e["name"] == "req.pull"]
+    assert len(pulls) == (0 if kind == "vectors" else 1)
+    if pulls:
+        assert pulls[0]["args"]["rows"] == by_vector.count(False)
+    # warmed shapes only (an earlier test of this file may have asked
+    # for a bucket past the warm-up's: count from here)
+    assert model.engine.query_compiles == compiled_before
+
+
 def test_smoke_every_endpoint_zero_post_warmup_compiles(served):
     # The CI serving smoke (ISSUE 2): a freshly warmed ModelServer
     # answers every endpoint once plus a concurrent coalesced burst

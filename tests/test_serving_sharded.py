@@ -271,9 +271,11 @@ def test_a_model_saved_over_four_shards_loads_a_quarter_on_each_device(
 
 
 #: sha256 of the lowered text (no debug info, so no scope names) of the
-#: one-device programs of ``_lowered`` at commit 3e53f4c, PR 47's parent.
+#: one-device programs of ``_lowered`` at commit 3e53f4c, PR 47's parent;
+#: the batch top-k's is PR 48's, which gathers its own query rows from
+#: the ids it is handed (the parent's read aec390e826e93b37).
 PARENT_PROGRAMS = {
-    "topk_batch": "aec390e826e93b37",
+    "topk_batch": "7802c35ced09e595",
     "topk": "3ee5ab35d74818d8",
     "pull": "87f1cec0cba1736c",
 }
@@ -283,8 +285,8 @@ def _lowered(eng):
     pad = eng.padded_dim
     return {
         "topk_batch": eng._make_topk_batch(16).lower(
-            eng.syn0, jnp.zeros((16, pad), jnp.float32), eng.norms(),
-            jnp.int32(1000)),
+            eng.syn0, jnp.zeros((16, pad), jnp.float32),
+            jnp.zeros((16,), jnp.int32), eng.norms(), jnp.int32(1000)),
         "topk": eng._make_topk(16).lower(
             eng.syn0, jnp.zeros((pad,), jnp.float32), eng.norms(),
             jnp.int32(1000)),
@@ -370,3 +372,48 @@ def test_the_round_span_says_how_many_shards_it_launched_on():
         model.stop()
     rounds = [e for e in recorder.events() if e["name"] == "req.dispatch"]
     assert rounds and all(e["args"]["shards"] == 4 for e in rounds)
+
+
+@pytest.mark.parametrize("chunk", ["ids", "vectors", "mixed"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (1, 4)], ids=["1x1", "1x4"])
+def test_the_round_by_ids_is_the_pull_and_then_the_top_k(
+        mesh_shape, dtype, chunk):
+    """ONE program a round: the batch top-k handed row ids (and vectors
+    where an id is -1) answers as the pull of those rows followed by the
+    top-k of what was pulled: the same rows, similarities to ``TOL``. Q is
+    5 (its bucket is 8) and 13 (16); row 5 has norm zero; ids fall on both
+    sides of every shard edge."""
+    V = 1003
+    table = _table(V, 23)
+    table[5] = 0.0
+    counts = np.ones(V, np.int64)
+    eng = EmbeddingEngine(make_mesh(*mesh_shape), V, D, counts,
+                          num_negatives=2, seed=1, dtype=dtype)
+    eng.write_rows(0, jnp.asarray(table))
+    per_shard = eng.rows_per_shard
+    rng = np.random.default_rng(5)
+    for rows in ([0, 5, per_shard - 1, per_shard % V, V - 1],
+                 list(rng.choice(V, 12, replace=False)) + [5]):
+        rows = np.asarray(rows, np.int32)
+        pulled = np.asarray(eng.pull(rows), np.float32)
+        sent = rng.normal(0.0, 0.1, pulled.shape).astype(np.float32)
+        if chunk == "ids":
+            ids, vecs, queries = rows, None, pulled
+        elif chunk == "vectors":
+            ids, vecs, queries = np.full_like(rows, -1), sent, sent
+        else:
+            by_vector = np.arange(rows.shape[0]) % 3 == 1
+            ids = np.where(by_vector, -1, rows)
+            vecs = np.where(by_vector[:, None], sent, 0.0)
+            queries = np.where(by_vector[:, None], sent, pulled)
+        before = eng.query_dispatches
+        val, idx = eng.top_k_cosine_batch(vecs, 10, ids=ids)
+        assert eng.query_dispatches == before + 1
+        want_val, want_idx = eng.top_k_cosine_batch(queries, 10)
+        assert val.shape == (rows.shape[0], 10)
+        live = np.isfinite(want_val)
+        np.testing.assert_array_equal(np.isfinite(val), live)
+        np.testing.assert_array_equal(idx[live], want_idx[live])
+        np.testing.assert_allclose(val[live], want_val[live],
+                                   rtol=0, atol=TOL)

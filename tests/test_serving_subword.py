@@ -191,6 +191,51 @@ def test_served_answer_equals_the_reference(ft, ref, kind):
     assert snap["compose"]["group_slots_total"] == 4 * WIDTH
 
 
+@pytest.mark.parametrize("kind", ["dictionary", "mixed"])
+def test_a_round_hands_its_dictionary_words_to_the_top_k_as_ids(
+        ft, ref, monkeypatch, kind):
+    """A dictionary word enters the top-k as its row id of the COMPOSED
+    table and is gathered there: the query engine's ``pull`` is not
+    called and the round is ONE query program, TWO when it composes
+    out-of-dictionary words first (their vectors, and a raw one, enter as
+    vectors). ``req.pull`` and ``req.dispatch`` are still recorded, and
+    after the warm-up the round compiles nothing."""
+    server, model = ft
+    words = model.vocab.words
+    lock = threading.Lock()
+    co = _SynonymCoalescer(model, lock, metrics=ServingMetrics())
+    vec = np.random.default_rng(8).normal(size=D).astype(np.float32)
+    jobs = [dict(word=words[i], num=3 + i % 4) for i in (2, 11, 40, 77, 5)]
+    if kind == "mixed":
+        jobs += ([dict(word=w, num=6) for w in OOV]
+                 + [dict(vector=[float(x) for x in vec], num=5)])
+
+    def no_pull(*a, **k):
+        raise AssertionError("an exact round pulled rows to the host")
+
+    monkeypatch.setattr(model._query_engine(), "pull", no_pull)
+    recorder = obs_events.EventRecorder(capacity=4096)
+    prev = obs_events.get_recorder()
+    obs_events.set_recorder(recorder)
+    try:
+        results, errors = _round(co, lock, jobs)
+    finally:
+        obs_events.set_recorder(prev)
+    assert errors == [None] * len(jobs)
+    for kw, got in zip(jobs, results):
+        _same(got, ref.nn(kw["word"], kw["num"]) if "word" in kw
+              else ref.nn_of_vector(vec, kw["num"]))
+    spans = recorder.events()
+    rounds = [e for e in spans if e["name"] == "req.dispatch"]
+    assert [e["args"]["batch"] for e in rounds] == [len(jobs)]
+    assert rounds[0]["args"]["programs"] == (2 if kind == "mixed" else 1)
+    assert [e["args"]["rows"] for e in spans
+            if e["name"] == "req.pull"] == [5]
+    assert len([e for e in spans if e["name"] == "req.compose"]) == (
+        kind == "mixed")
+    assert _metrics(server)["compiles"]["post_warmup"] == 0
+
+
 def test_oov_word_sharing_every_ngram_is_not_the_dictionary_word(ft, ref):
     """"aaaaa" (outside) has exactly the n-grams of "aaaa" (inside): its
     vector is its bucket rows' mean with no word row in it, it is not

@@ -740,7 +740,8 @@ def _lower_query(eng, op, shape):
         )
     elif op == "topk_batch":
         lowered = eng._make_topk_batch(shape[1]).lower(
-            table, sds((shape[0], eng.padded_dim), jnp.float32), norms, nq
+            table, sds((shape[0], eng.padded_dim), jnp.float32),
+            sds((shape[0],), jnp.int32), norms, nq
         )
     elif op == "pull":
         lowered = eng._pull.lower(table, sds(shape, jnp.int32))
