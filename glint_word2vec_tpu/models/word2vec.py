@@ -1295,6 +1295,8 @@ class Word2Vec:
                     / packed_slots, 4),
                 exchange_bytes_per_step=engine.packed_exchange_bytes(
                     pair_batch, p.window),
+                exchange_send_bytes_per_step=(
+                    engine.packed_exchange_send_bytes(pair_batch, p.window)),
             )
             # Live steps, and the update slots one hands the scatters.
             steps = packed_slots // pair_batch

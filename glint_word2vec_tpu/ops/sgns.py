@@ -24,7 +24,7 @@ padded entries contribute exactly zero to every scatter-add.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +40,7 @@ class SgnsCoefs(NamedTuple):
     c_pos: jax.Array  # (B, C)
     c_neg: jax.Array  # (B, C, n)
     loss: jax.Array  # ()
+    pair_loss: jax.Array  # (B, C) the masked terms ``loss`` is the mean of
 
 
 def sgns_coefs(
@@ -59,7 +60,7 @@ def sgns_coefs(
         log_sig(-f_neg) * neg_mask, axis=-1
     ) * mask
     loss = pair_loss.sum() / jnp.maximum(mask.sum(), 1.0)
-    return SgnsCoefs(c_pos=c_pos, c_neg=c_neg, loss=loss)
+    return SgnsCoefs(c_pos=c_pos, c_neg=c_neg, loss=loss, pair_loss=pair_loss)
 
 
 class SgnsGrads(NamedTuple):
@@ -75,6 +76,9 @@ class SgnsGrads(NamedTuple):
     c_neg: jax.Array  # (B, C, n) alpha * (0 - sigmoid(f_neg)) * mask
     d_center: jax.Array  # (B, d)  gradient w.r.t. syn0[centers]
     loss: jax.Array  # () masked-mean SGNS loss (monitoring only)
+    # (B, C) its terms: what a shard that did a slice of the pairs ships
+    # (parallel/engine.py), so the mean is one sum in one order on any mesh
+    pair_loss: Optional[jax.Array] = None
 
 
 def _rounded(x: jax.Array, compute_dtype) -> jax.Array:
@@ -139,7 +143,8 @@ def sgns_grads(
     co = sgns_coefs(f_pos, f_neg, mask, neg_mask, alpha)
     d_center = sgns_d_center(co.c_pos, co.c_neg, u_pos, u_neg, compute_dtype)
     return SgnsGrads(
-        c_pos=co.c_pos, c_neg=co.c_neg, d_center=d_center, loss=co.loss
+        c_pos=co.c_pos, c_neg=co.c_neg, d_center=d_center, loss=co.loss,
+        pair_loss=co.pair_loss,
     )
 
 

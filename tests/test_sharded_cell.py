@@ -115,33 +115,48 @@ def corpus(seed=1, sentences=60):
     return ids, np.concatenate([[0], np.cumsum(lens)]).astype(np.int64)
 
 
-def engine(shape, seed=3):
+def engine(shape, seed=3, architecture="skipgram", groups=None):
     counts = np.arange(V, 0, -1).astype(np.int64) * 3
-    return EmbeddingEngine(make_mesh(*shape), V, D, counts,
-                           num_negatives=NEG, seed=seed)
+    eng = EmbeddingEngine(make_mesh(*shape), V, D, counts,
+                          num_negatives=NEG, seed=seed,
+                          architecture=architecture,
+                          extra_rows=0 if groups is None else BUCKET)
+    eng.upload_center_groups(groups)
+    return eng
 
 
-def test_packed_scan_on_a_1x4_mesh_is_the_sharded_reference():
+# A data rank's pairs (205, 101, 101) are no multiple of the model axis:
+# the cell's 26,215 over four. The batch of 63 positions packs 202 pairs on
+# a mesh with one data rank or two, so the reference replays what two ranks
+# drew.
+@pytest.mark.parametrize(
+    "shape,batch", [((1, 4), BATCH), ((2, 2), 63), ((2, 4), 63)])
+def test_packed_scan_on_a_mesh_is_the_sharded_reference(shape, batch):
     from benchmark.kinds.train import capture_batches
 
     seed, total_words = 3, 5000
-    eng = engine((1, 4), seed=seed)
+    n_data, n_model = shape
+    pairs = packed_pair_batch(batch, WINDOW, n_data)
+    assert pairs == packed_pair_batch(batch, WINDOW, 1)
+    assert (pairs // n_data) % n_model
+    eng = engine(shape, seed=seed)
     ids, offsets = corpus()
     eng.upload_corpus(ids, offsets)
     eng.set_keep_probs(np.ones(V, np.float32))
     eng.compact_corpus(jax.random.PRNGKey(9))
     losses = eng.train_steps_corpus_packed(
-        0, PAIRS, WINDOW, BATCH, jax.random.PRNGKey(seed), K,
+        0, pairs, WINDOW, batch, jax.random.PRNGKey(seed), K,
         step_size=0.025, total_words=total_words)[0]
     cfg = {"model": {"window": WINDOW, "negatives": NEG, "step_size": 0.025},
-           "run": {"batch_size": BATCH}}
+           "run": {"batch_size": batch}}
     batches = capture_batches(eng, cfg, seed, K, total_words)
     rows = touched(batches)
     # as benchmark/kinds/train_sharded.py reads them: the D real columns
     # of rows that rest in whole lanes
     prog0 = np.asarray(eng.syn0, np.float32)[rows][:, :D]
     prog1 = np.asarray(eng.syn1, np.float32)[rows][:, :D]
-    assert {s.data.shape[0] for s in eng.syn0.addressable_shards} == {V // 4}
+    assert {s.data.shape[0] for s in eng.syn0.addressable_shards} == {
+        V // n_model}
     gaps = reference_sharded.replay_gaps(
         seed, V, D, rows, batches, prog0, prog1,
         np.asarray(losses, np.float32), devices4())
@@ -156,10 +171,65 @@ def test_packed_scan_on_a_1x4_mesh_is_the_sharded_reference():
     assert low["replay.syn1_gap"] > 10 * GAP, low
 
 
+BUCKET, G = 300, 8
+
+
+def random_groups(seed=4):
+    """A seeded group table (tests/test_subword_packed.py's): the word's
+    own row, then 0 to G - 1 bucket rows, -1 padded."""
+    rng = np.random.default_rng(seed)
+    groups = V + rng.integers(0, BUCKET, (V, G)).astype(np.int32)
+    groups[np.arange(G)[None, :] > rng.integers(0, G, V)[:, None]] = -1
+    groups[:, 0] = np.arange(V)
+    return groups
+
+
+def fit_on(shape, family):
+    """(tables before, tables after, losses) of K packed steps of one
+    ``family``'s scan on a mesh of ``shape``; a step's pairs (205; CBOW:
+    60 positions) are no multiple of four shards' sublanes, so the pair
+    slices are padded."""
+    cbow = family == "cbow"
+    eng = engine(shape, architecture="cbow" if cbow else "skipgram",
+                 groups=random_groups() if family == "subword" else None)
+
+    def tables():
+        return (np.asarray(eng.syn0, np.float32)[:, :D],
+                np.asarray(eng.syn1, np.float32)[:, :D])
+
+    before = tables()
+    eng.upload_corpus(*corpus())
+    eng.set_keep_probs(np.full(V, 0.8 if cbow else 1.0, np.float32))
+    eng.compact_corpus(jax.random.PRNGKey(9))
+    batch = 60 if cbow else BATCH
+    losses = eng.train_steps_corpus_packed(
+        0, batch if cbow else PAIRS, WINDOW, batch, jax.random.PRNGKey(3), K,
+        step_size=0.05 if cbow else 0.025, total_words=5000)[0]
+    return before, tables(), np.asarray(losses)
+
+
+@pytest.mark.parametrize("family", ["word", "subword", "cbow"])
+def test_a_1x4_fit_is_the_one_device_fit(family):
+    # Each shard does the pair math of a quarter of the pairs; the tables
+    # and losses are the one device's at the replay's limits (on the CPU
+    # they are its bits: the same float32 terms in the same order).
+    befores, ones, one_losses = fit_on((1, 1), family)
+    _, fours, four_losses = fit_on((1, 4), family)
+    for before, one, four in zip(befores, ones, fours):
+        change = np.abs(one - before).max()
+        assert change > 0
+        assert np.abs(four - one).max() / change < GAP
+        d_one = np.sqrt(np.square((one - before).astype(np.float64)).sum())
+        d_four = np.sqrt(np.square((four - before).astype(np.float64)).sum())
+        assert abs(d_four - d_one) / d_one < DNORM_GAP
+    np.testing.assert_allclose(four_losses, one_losses, rtol=LOSS_GAP)
+
+
 @functools.lru_cache(maxsize=None)
-def all_reduces(shape):
-    """(shape text, op name, replica groups) of every all-reduce of the
-    packed scan an engine on a mesh of ``shape`` compiles."""
+def collectives(shape):
+    """(shape text, op name, replica groups, opcode) of every all-reduce,
+    reduce-scatter and all-gather of the packed scan an engine on a mesh
+    of ``shape`` compiles."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -180,11 +250,13 @@ def all_reduces(shape):
         sds((2,), jnp.uint32), u32, u32, f32, f32, f32).compile().as_text()
     found = []
     for line in text.splitlines():
-        m = re.search(r"= (.*?) all-reduce(?:-start)?\(", line)
+        m = re.search(r"= (.*?) (all-reduce|reduce-scatter|all-gather)"
+                      r"(?:-start)?\(", line)
         if m:
             found.append((
                 m.group(1), re.search(r'op_name="([^"]*)"', line).group(1),
-                re.search(r"replica_groups=(\{\{.*?\}\})", line).group(1)))
+                re.search(r"replica_groups=(\{\{.*?\}\})", line).group(1),
+                m.group(2)))
     return found
 
 
@@ -193,24 +265,44 @@ D_REST = -(-D // TABLE_LANES) * TABLE_LANES
 
 
 def test_the_exchange_has_its_own_scope():
-    found = all_reduces((1, 4))
+    found = collectives((1, 4))
     across = [f for f in found if f[2] == "{{0,1,2,3}}"]
     data = [f for f in across if f",{D_REST}]" in f[0]]
     assert data, found  # the rows
-    for shape, op_name, _ in data:
+    for shape, op_name, _, _ in data:
         assert "/glint.exchange/" in op_name, (shape, op_name)
-    for shape, op_name, _ in across:
+    for shape, op_name, _, _ in across:
         assert "glint.gather" not in op_name, (shape, op_name)
         # what else crosses the model axis is the scatters' counts: rows
         # written and slabs moved, of each table
         assert "glint.exchange" in op_name or shape.startswith("s32[4]")
+    # The centre side alone is all-reduced (every shard's syn1 scatter
+    # wants every pair's h); the pair side, a context and NEG negatives a
+    # pair, is reduce-scattered over the pairs, and what the scatters need
+    # of the pair math comes back by all-gather: d_center and, a pair, its
+    # 1 + NEG coefficients and its loss term.
+    slots = engine((1, 4)).packed_pair_slots(PAIRS)
+    assert slots % 4 == 0 and PAIRS < slots < PAIRS + 4 * 8
+    by_kind = {kind: sorted(f[0] for f in data if f[3] == kind)
+               for kind in ("all-reduce", "reduce-scatter", "all-gather")}
+    assert by_kind["all-reduce"] == [f"f32[{PAIRS},{D_REST}]{{1,0}}"]
+    assert by_kind["reduce-scatter"] == (
+        [f"f32[{slots // 4},{D_REST}]{{1,0}}"] * (1 + NEG))
+    assert by_kind["all-gather"] == [f"f32[{slots},{D_REST}]{{1,0}}"]
+    # (the loss terms apart, a vector, so that their sum is the one-shard
+    # program's reduction over the pairs)
+    scalars = [f for f in across if f[3] == "all-gather" and f not in data]
+    assert sorted(f[0] for f in scalars) == sorted(
+        [f"f32[{slots},{1 + NEG}]{{1,0}}", f"f32[{slots}]{{0}}"])
 
 
 def test_one_shard_exchanges_nothing():
     # The CPU's compiler keeps a psum over one device as an all-reduce
     # among {0} alone (the chip's removes it: tests/test_tpu_compile.py).
-    found = all_reduces((1, 1))
+    found = collectives((1, 1))
     assert found and {f[2] for f in found} == {"{{0}}"}, found
+    assert {f[3] for f in found if "glint.exchange" in f[1]} == {
+        "all-reduce"}, found
 
 
 @pytest.mark.parametrize(
@@ -220,18 +312,43 @@ def test_exchange_bytes_is_what_the_shapes_say(shape):
     n_data, n_model = shape
     # one data rank's pairs are the benchmark's, whose mesh has no data axis
     counted = eng.packed_exchange_bytes(n_data * PAIRS)
+    sent = eng.packed_exchange_send_bytes(n_data * PAIRS)
     if n_model == 1:
         assert counted == 0
         assert bytes_sharded.exchange_bytes(BATCH, WINDOW, NEG, D, 1) == 0
+        assert sent == {"all_reduce": 0, "reduce_scatter": 0,
+                        "all_gather": 0}
     else:
         assert counted == bytes_sharded.exchange_bytes(
             BATCH, WINDOW, NEG, D_REST, n_model)
+        # what a chip must send at least: an all-reduce of S bytes twice
+        # (n - 1) / n x S, a reduce-scatter of S handed, or an all-gather
+        # of S gathered, once
+        slots = eng.packed_pair_slots(n_data * PAIRS)
+        others = n_model - 1
+        assert sent == {
+            "all_reduce": 2 * others * 4 * PAIRS * D_REST // n_model,
+            "reduce_scatter": (
+                others * 4 * slots * (1 + NEG) * D_REST // n_model),
+            "all_gather": others * 4 * slots * (D_REST + 2 + NEG) // n_model,
+        }
+        assert sum(sent.values()) < bytes_sharded.all_reduce_wire_bytes(
+            counted, n_model)
     if shape == (1, 4):
-        # ... and is what the compiled step hands its row all-reduces
-        rows = sum(int(n) for f in all_reduces(shape)
-                   if "glint.exchange" in f[1]
-                   for n in re.findall(r"f32\[(\d+),%d\]" % D_REST, f[0]))
-        assert 4 * rows * D_REST == counted
+        # ... and is what the compiled step hands its row collectives: the
+        # all-reduce its rows, a reduce-scatter n times the rows it hands
+        # back, of which the slots that pad a pair slice name no row
+        found = [f for f in collectives(shape) if "glint.exchange" in f[1]]
+
+        def rows(kind):
+            return sum(int(n) for f in found if f[3] == kind
+                       for n in re.findall(r"f32\[(\d+),%d\]" % D_REST, f[0]))
+
+        handed = rows("all-reduce") + n_model * rows("reduce-scatter")
+        padding = (1 + NEG) * (slots - PAIRS)
+        assert 4 * (handed - padding) * D_REST == counted
+        assert others * 4 * rows("all-gather") * D_REST // n_model == (
+            sent["all_gather"] - others * 4 * slots * (2 + NEG) // n_model)
 
 
 def test_fit_reports_the_exchange(tmp_path):
@@ -242,15 +359,22 @@ def test_fit_reports_the_exchange(tmp_path):
     path.write_text("\n".join(
         " ".join(f"w{i}" for i in rng.integers(0, 200, 12))
         for _ in range(300)) + "\n")
-    seen = {}
+    seen, sent = {}, {}
     for shards in (1, 4):
         model = Word2Vec(vector_size=D, window=WINDOW, num_negatives=NEG,
                          min_count=1, batch_size=BATCH, steps_per_call=2,
                          num_shards=shards, num_iterations=1, seed=1,
                          subsample_ratio=1e-3).fit_file(str(path))
         seen[shards] = model.training_metrics["exchange_bytes_per_step"]
+        sent[shards] = model.training_metrics[
+            "exchange_send_bytes_per_step"]
         model.stop()
-    assert seen[1] == 0
+    assert seen[1] == 0 and set(sent[1].values()) == {0}
+    # the step's collectives by kind, and under seven all-reduces' bytes
+    assert set(sent[4]) == {"all_reduce", "reduce_scatter", "all_gather"}
+    assert 0 < min(sent[4].values())
+    assert sum(sent[4].values()) < bytes_sharded.all_reduce_wire_bytes(
+        seen[4], 4)
     assert seen[4] == bytes_sharded.exchange_bytes(
         BATCH, WINDOW, NEG, D_REST, 4)
     assert bytes_sharded.all_reduce_wire_bytes(seen[4], 4) == 1.5 * seen[4]

@@ -313,11 +313,19 @@ def test_the_grid_scan_forms_its_centres_from_the_group_table():
 # layout's 1 x 2 entries; the 1 x 4 mesh (the four-chip cell's) was taken
 # in their place, on ISSUE 46's PARENT (7daf58c) and again on its tree: the
 # same. The other entries are untouched.
+# ISSUE 49 meant to change the step wherever the model axis has several
+# shards (the pair side's pulls end in a reduce-scatter over the pairs, a
+# shard does the pair math of its slice, `d_center` and the scalars come
+# back by all-gather): the 1 x 2, 2 x 2 and 1 x 4 entries, here and in
+# SUBWORD_PROGRAMS, were taken again on its tree (CHANGES.md keeps the old
+# ones). The (1, 1) entries are AS THEY WERE: the one-shard program is
+# emitted by the parent's very ops, which is the proof that the one-chip
+# cells run what they ran.
 WORD_LEVEL_PROGRAMS = {
     ((1, 1), "rows"): ("84c02214c885c063", "124ae8075d865073"),
-    ((1, 2), "rows"): ("533004cc2ecc2727", "0550e22a6e53853a"),
-    ((2, 2), "rows"): ("25864a7ff37a7e92", "086f184fadd4d1d0"),
-    ((1, 4), "rows"): ("fe92f6c6fbdd4385", "e78d0e7452c56bdf"),
+    ((1, 2), "rows"): ("3bf83a49f1fd39f3", "d8ee8b0c469fb202"),
+    ((2, 2), "rows"): ("16db240ffed84230", "8669dfc535929469"),
+    ((1, 4), "rows"): ("7501689733c401d8", "af1b9eaf8b029b7a"),
 }
 
 
@@ -362,9 +370,9 @@ def test_a_word_level_fit_lowers_to_the_program_it_lowered_to(shape, split):
 # ISSUE 38's, as above.
 SUBWORD_PROGRAMS = {
     ((1, 1), "rows"): ("b4cb57206511569c", "5e7e6d3939851660"),
-    ((1, 2), "rows"): ("206aa68d18533c20", "2dbc1d5a91874ef4"),
-    ((2, 2), "rows"): ("1b6c7b6b8cb67547", "653f273411458e62"),
-    ((1, 4), "rows"): ("5bdee85a1f7ce2e7", "0cb6acd97129bf63"),
+    ((1, 2), "rows"): ("46356c178d068fa3", "41acdfbc78de7b1c"),
+    ((2, 2), "rows"): ("094df8e3e0c63b1d", "f9007ed7535872a0"),
+    ((1, 4), "rows"): ("aaf3d6a14819355a", "125b6a0dc0d5b32f"),
 }
 
 

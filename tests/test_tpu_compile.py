@@ -271,17 +271,59 @@ def test_packed_corpus_scan_at_the_four_chip_cell_size(packed_scans):
     # The sharded cell's step (benchmark/configs/w2v-300-10m-x4.json): 10M
     # x 300 f32 is 24 GB of tables, 6 GB a chip over the host's four (7.68
     # at rest, in rows of 384 columns), and its corpus is resident and
-    # replicated. The tables are donated, the rows cross chips through an
-    # all-reduce, and the whole must fit one chip's 16 GB: what is left
-    # over is all the room a later PR has for the step's temporaries.
+    # replicated. The tables are donated and the whole must fit one chip's
+    # 16 GB: what is left over is all the room a later PR has for the
+    # step's temporaries.
+    import re
+
+    from glint_word2vec_tpu.corpus.batching import packed_pair_batch
+
     eng, compiled = packed_scans("10m-4chips")
     mem = _fits(compiled, 4)
     assert compiled.memory_analysis().alias_size_in_bytes >= (
         2 * eng.rows_per_shard * D * 4
     ), mem
     assert mem["args"] < _table_args_ceiling(10_000_000, 4, 256 * 10**6), mem
-    assert mem["temp"] < 1.5e9, mem
-    assert "all-reduce" in compiled.as_text()
+    # ISSUE 49's parent held 303,918,592 B of temporaries here (seven
+    # all-reduced blocks of 26,215 rows); a shard now holds a quarter of
+    # the six pair-side blocks.
+    assert mem["temp"] < 1.5e9 and mem["temp"] <= 303_918_592, mem
+    # What crosses the model axis (ISSUE 49). Of the rows a step pulls only
+    # the centre side, h, is all-reduced: every shard's syn1 scatter wants
+    # every pair's h.
+    pairs = packed_pair_batch(BATCH, WINDOW, 1)
+    slots = eng.packed_pair_slots(pairs)
+    text = compiled.as_text()
+    rows = rf"f32\[\d+,{D_REST}\]"
+    exchange = [line for line in text.splitlines()
+                if "glint.exchange" in line]
+    reduced = [re.search(rf"= ({rows})", line).group(1) for line in exchange
+               if re.search(rf"= {rows}\S* all-reduce(-start)?\(", line)]
+    assert reduced == [f"f32[{pairs},{D_REST}]"], reduced
+    # The pair side, a context and five negatives a pair, is
+    # reduce-scattered over the pairs, a block at a time, each op under
+    # the scope the program gave it: the slots are whole spans of the
+    # chip's reduce-scatter (engine._pair_slots), so the compiler pads
+    # none of them (it would re-cut the padded shards with
+    # collective-permutes, in ops that carry no scope, and a trace would
+    # file the exchange under nothing).
+    assert slots == 26_880
+    scattered = [re.search(rf"= ({rows})", line).group(1)
+                 for line in exchange
+                 if re.search(rf"= {rows}\S* reduce-scatter(-start)?\(", line)]
+    assert scattered == [f"f32[{slots // 4},{D_REST}]"] * (1 + NEG), scattered
+    assert "formatting steps: (pad" not in text
+    assert "collective-permute" not in text
+    # d_center comes back by all-gather, and a pair's coefficients with
+    # it; its loss term in a vector of its own (which this compiler
+    # gathers by an all-reduce of zero-filled slots).
+    gathered = [re.search(r"= (f32\[[\d,]+\])", line).group(1)
+                for line in exchange
+                if re.search(r"\S* all-gather(-start)?\(", line)]
+    assert sorted(gathered) == sorted(
+        [f"f32[{slots},{D_REST}]", f"f32[4,{slots // 4},{1 + NEG}]"]
+    ), gathered
+    assert re.search(rf"= f32\[{slots}\]\S* all-(reduce|gather)", text)
 
 
 def test_packed_corpus_scan_at_three_million_rows_fits_one_chip(packed_scans):
