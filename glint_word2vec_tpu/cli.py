@@ -199,6 +199,16 @@ def _add_fit_stream(sub):
              "stream: online vocab growth, adaptive distributions, and "
              "committed generation publishing a `serve "
              "--watch-checkpoint` fleet hot-swaps under load",
+        description="Incremental (ISGNS, arXiv:1704.03956) training on a "
+                    "sentence stream it sees once. Run on one v5e chip at "
+                    "two f32 tables of 2,065,536 x 300 (2M base words and "
+                    "65,536 spare rows, --buffer-words 1048576): "
+                    "about 1.1M raw words/s (PERF.md, the cell "
+                    "w2v-stream-300-2m.train): a round of 1M kept words "
+                    "is 1.06 s of the device's work, and the host's half "
+                    "of a round (0.55 s: fill, promotion, refresh) runs "
+                    "up to two rounds ahead, behind it, so the device "
+                    "sets the pace and idles 4% of the time.",
     )
     p.add_argument("--corpus", default="-",
                    help="sentence source, one per line: a file path, or "
@@ -233,7 +243,15 @@ def _add_fit_stream(sub):
     p.add_argument("--refresh-words", type=int, default=None,
                    help="kept-word cadence for recomputing the adaptive "
                         "noise/subsample distributions (default: one "
-                        "buffer)")
+                        "buffer, so every round; a promotion refreshes "
+                        "at once whatever this says). A refresh re-derives "
+                        "an alias table for EVERY base word: about 0.13 s "
+                        "of host time at 2M words, behind the device's "
+                        "work (PERF.md, the cell w2v-stream-300-2m.train: "
+                        "the host's half of a round of 1M kept words is "
+                        "0.55 s, the device's 1.06 s); raise it when the "
+                        "vocabulary is large and the buffer small, so that "
+                        "the host, not the device, would set the pace")
     p.add_argument("--max-words", type=int, default=None,
                    help="stop after training this many words (bounded "
                         "runs/smokes; default: run until the stream ends)")

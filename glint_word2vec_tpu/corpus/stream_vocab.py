@@ -34,11 +34,13 @@ tests/test_stream_vocab.py pins down.
 from __future__ import annotations
 
 import heapq
+from itertools import chain, repeat
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from glint_word2vec_tpu.corpus.vocab import Vocabulary
+from glint_word2vec_tpu.corpus.word_index import WordIndex
 
 
 class SpaceSavingSketch:
@@ -171,7 +173,20 @@ class StreamVocab:
                  max_size: Optional[int] = None):
         self.words: List[str] = list(base.words)
         self.word_index: Dict[str, int] = dict(base.word_index)
-        self._counts: List[int] = [int(c) for c in base.counts]
+        # Live counts: an int64 array with spare room behind ``size``
+        # (promotion appends), and the sentences observed since the last
+        # read, whose ids are folded in by ONE bincount when the counts
+        # are next read (:meth:`_live_counts`). A Python list of 2M ints
+        # was copied whole three times a refresh; and the pending ids are
+        # held as arrays, which the cyclic collector does not track: as
+        # 30,000 lists a round they brought on a full collection over the
+        # 2M-entry dictionary every second round (PERF.md, PR 50).
+        self._counts = np.array(base.counts, dtype=np.int64)
+        # The chunked look-ups' index (corpus/word_index.py), built when
+        # the first chunk asks for it; ``word_index`` stays the statement.
+        self._chunk_index: Optional[WordIndex] = None
+        self._pending: List[np.ndarray] = []
+        self._pending_words = 0
         #: Engine ``vocab_size``: rows below this came from the
         #: bootstrap scan; rows at or above it are promoted words on
         #: extra rows.
@@ -195,32 +210,54 @@ class StreamVocab:
     def __contains__(self, word: str) -> bool:
         return word in self.word_index
 
-    def counts_array(self) -> np.ndarray:
-        """Live counts snapshot aligned with ``words`` (int64)."""
-        return np.asarray(self._counts, dtype=np.int64)
+    def _live_counts(self) -> np.ndarray:
+        """The counts of ``words`` as a VIEW of the live array, with
+        every observed sentence folded in."""
+        n = len(self.words)
+        if self._pending:
+            self._counts[:n] += np.bincount(
+                np.concatenate(self._pending), minlength=n
+            )
+            self._pending, self._pending_words = [], 0
+        return self._counts[:n]
 
-    def observe(self, sentence: Sequence[str]) -> List[int]:
-        """Count one sentence and encode its in-vocabulary words.
+    def counts_array(self) -> np.ndarray:
+        """Live counts snapshot aligned with ``words`` (int64): one copy
+        of ``size`` counts, 16 MB at 2M words (milliseconds), after one
+        bincount of what was observed since the last read (about a
+        tenth of a second a million words)."""
+        return self._live_counts().copy()
+
+    #: Observed words held back before they are folded into the counts
+    #: unasked: bounds the memory of a run that never reads them.
+    _PENDING_MAX_WORDS = 1 << 22
+
+    def observe(self, sentence: Sequence[str]) -> np.ndarray:
+        """Count one sentence and encode its in-vocabulary words (an
+        int32 array of row indices).
 
         Admitted words get an exact count increment and their row index
-        in the output; OOV words feed the candidate sketch (and are
-        dropped from the encoding, exactly as batch training drops OOV
-        — until promotion admits them, from which point on they train).
+        in the output; OOV words feed the candidate sketch, in arrival
+        order (and are dropped from the encoding, exactly as batch
+        training drops OOV — until promotion admits them, from which
+        point on they train). The returned array is also what the counts
+        are folded from: the caller must not change it in place.
         """
-        ids: List[int] = []
-        wi = self.word_index
-        counts = self._counts
-        kept = 0
-        for w in sentence:
-            i = wi.get(w)
-            if i is None:
-                self.sketch.add(w)
-                self.oov_words_seen += 1
-            else:
-                counts[i] += 1
-                kept += 1
-                ids.append(i)
-        self.train_words_count += kept
+        ids = list(map(self.word_index.get, sentence))
+        if None in ids:
+            add = self.sketch.add
+            for w, i in zip(sentence, ids):
+                if i is None:
+                    add(w)
+            n = len(ids)
+            ids = [i for i in ids if i is not None]
+            self.oov_words_seen += n - len(ids)
+        ids = np.array(ids, np.int32)
+        self._pending.append(ids)
+        self._pending_words += len(ids)
+        self.train_words_count += len(ids)
+        if self._pending_words >= self._PENDING_MAX_WORDS:
+            self._live_counts()
         return ids
 
     def encode(self, sentence: Sequence[str]) -> List[int]:
@@ -230,6 +267,61 @@ class StreamVocab:
         OOV words are dropped, not sketched."""
         wi = self.word_index
         return [i for w in sentence if (i := wi.get(w)) is not None]
+
+    #: Chunks under this many tokens go through the dictionary: the
+    #: vector index's fixed cost a call is a few dozen numpy calls.
+    _INDEX_MIN_TOKENS = 64
+
+    def _rows_of(self, tokens: List[str]) -> np.ndarray:
+        """int32 rows of a chunk's tokens, -1 for a word outside the
+        vocabulary."""
+        n = len(tokens)
+        if n >= self._INDEX_MIN_TOKENS:
+            if self._chunk_index is None:
+                self._chunk_index = WordIndex(self.words)
+            rows = self._chunk_index.lookup(tokens)
+            if rows is not None:
+                return rows
+        return np.fromiter(
+            map(self.word_index.get, tokens, repeat(-1)), np.int32, n
+        )
+
+    def _encode_chunk(self, sentences: Sequence[Sequence[str]], count: bool):
+        tokens = list(chain.from_iterable(sentences))
+        lens = np.fromiter(map(len, sentences), np.int64, len(sentences))
+        rows = self._rows_of(tokens)
+        known = rows >= 0
+        if not known.all():
+            unknown = np.flatnonzero(~known)
+            if count:
+                add = self.sketch.add
+                for i in unknown.tolist():
+                    add(tokens[i])
+                self.oov_words_seen += unknown.size
+            sentence_of = np.repeat(np.arange(lens.size), lens)
+            lens = np.bincount(sentence_of[known], minlength=lens.size)
+            rows = rows[known]
+        if count:
+            self._pending.append(rows)
+            self._pending_words += rows.size
+            self.train_words_count += rows.size
+            if self._pending_words >= self._PENDING_MAX_WORDS:
+                self._live_counts()
+        return rows, lens
+
+    def observe_many(self, sentences: Sequence[Sequence[str]]):
+        """:meth:`observe` over a chunk of sentences in one pass: the
+        in-vocabulary rows of all of them, concatenated (int32), and how
+        many each sentence contributed (int64). The same counts, the same
+        sketch (OOV words are added in arrival order) as a call a
+        sentence; the rows are what the counts are folded from, so the
+        caller must not change them in place."""
+        return self._encode_chunk(sentences, count=True)
+
+    def encode_many(self, sentences: Sequence[Sequence[str]]):
+        """:meth:`encode` over a chunk of sentences: rows and lengths as
+        :meth:`observe_many` gives them, nothing counted or sketched."""
+        return self._encode_chunk(sentences, count=False)
 
     def promotable(self, min_count: int,
                    limit: Optional[int] = None) -> List[Tuple[str, int]]:
@@ -268,9 +360,15 @@ class StreamVocab:
         if word in self.sketch:
             self.sketch.pop(word)
         idx = len(self.words)
+        if idx == self._counts.shape[0]:
+            self._counts = np.concatenate(
+                [self._counts, np.zeros(max(idx, 1024), np.int64)]
+            )
         self.words.append(word)
         self.word_index[word] = idx
-        self._counts.append(int(count))
+        if self._chunk_index is not None:
+            self._chunk_index.add(word, idx)
+        self._counts[idx] = int(count)
         # A promoted word's pre-promotion occurrences were counted by
         # the sketch, not train_words_count; fold the estimate in so
         # the subsample normalizer reflects what the counts claim.
@@ -288,29 +386,36 @@ class StreamVocab:
         while filling each round's buffer."""
         if subsample_ratio <= 0:
             return np.ones(self.size, dtype=np.float64)
-        counts = self.counts_array()
-        pcn = counts.astype(np.float64) / float(
-            max(self.train_words_count, 1)
-        )
-        with np.errstate(divide="ignore", invalid="ignore"):
-            ran = (np.sqrt(pcn / subsample_ratio) + 1.0) * (
-                subsample_ratio / pcn
-            )
-        ran = np.where(counts > 0, ran, 0.0)
-        return np.clip(ran, 0.0, 1.0)
+        counts = self._live_counts()
+        total = float(max(self.train_words_count, 1))
+        # The rule reads 1 or more (clipped to 1) wherever a word's share
+        # of the stream is at most 2.618 times the ratio, so only the few
+        # words over that get the arithmetic: tens of words of 2M, where
+        # the whole array was 45 ms a refresh (PERF.md, PR 50). The bound
+        # used, 2.5, leaves the rule 3% over 1 at the edge: no rounding
+        # brings a skipped word under 1.
+        keep = (counts > 0).astype(np.float64)
+        often = np.flatnonzero(counts > 2.5 * subsample_ratio * total)
+        pcn = counts[often].astype(np.float64) / total
+        ran = (np.sqrt(pcn / subsample_ratio) + 1.0) * (subsample_ratio / pcn)
+        keep[often] = np.clip(ran, 0.0, 1.0)
+        return keep
 
     def noise_counts(self) -> np.ndarray:
         """Live counts over the BASE vocabulary only — the adaptive
         negative-sampling distribution (``engine.set_noise_counts``
         keeps the alias shapes fixed at vocab_size; promoted words are
-        never negative-sampled, like fastText bucket rows)."""
-        return np.asarray(self._counts[: self.base_size], dtype=np.int64)
+        never negative-sampled, like fastText bucket rows). One copy of
+        ``base_size`` counts: 16 MB at 2M words, milliseconds."""
+        return self._live_counts()[: self.base_size].copy()
 
     def noise_weights(self, power: float = 0.75) -> np.ndarray:
         """Normalized ``count^power`` noise distribution over the base
         vocab — what :meth:`noise_counts` induces; used for the
         distribution-drift gauge."""
-        w = np.power(self.noise_counts().astype(np.float64), power)
+        w = np.power(
+            self._live_counts()[: self.base_size].astype(np.float64), power
+        )
         s = w.sum()
         return w / s if s > 0 else w
 

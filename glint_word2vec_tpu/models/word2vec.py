@@ -45,7 +45,7 @@ from glint_word2vec_tpu.corpus.vocab import (
 )
 from glint_word2vec_tpu.obs import TrainingDiverged, start_run
 from glint_word2vec_tpu.utils import faults, next_pow2
-from glint_word2vec_tpu.utils.metrics import TrainingMetrics
+from glint_word2vec_tpu.utils.metrics import TrainingMetrics, scatter_summary
 from glint_word2vec_tpu.utils.params import Word2VecParams
 from glint_word2vec_tpu.utils.prefetch import prefetch
 
@@ -1340,31 +1340,9 @@ class Word2Vec:
                     subword_rows_per_center=round(
                         rows_written[4] / rows_written[5], 4),
                 )
-            if rows_written[:4].any():
-                # Rows the scatters wrote over the update slots they were
-                # handed (the step sums a row's duplicates before it
-                # writes): both tables, then each.
-                rows, slabs = rows_written[:2], rows_written[2:4]
-                model.training_metrics.update(
-                    scatter_distinct_share=round(
-                        sum(rows) / (steps * sum(slots)), 4),
-                    scatter_distinct_share_syn0=round(
-                        rows[0] / (steps * slots[0]), 4),
-                    scatter_distinct_share_syn1=round(
-                        rows[1] / (steps * slots[1]), 4),
-                )
-                if slabs.all():
-                    # The slab writer ran (ops/slab_writer.py): distinct
-                    # rows written over the slabs it moved, 1 to 8 (16 in
-                    # bfloat16). XLA's writer moves none.
-                    model.training_metrics.update(
-                        scatter_rows_per_slab=round(
-                            sum(rows) / sum(slabs), 4),
-                        scatter_rows_per_slab_syn0=round(
-                            rows[0] / slabs[0], 4),
-                        scatter_rows_per_slab_syn1=round(
-                            rows[1] / slabs[1], 4),
-                    )
+            model.training_metrics.update(
+                scatter_summary(rows_written[:4], steps, slots)
+            )
         return model
 
     # -- multi-host helpers (SURVEY.md §2.3 DP row; VERDICT.md missing #1) --

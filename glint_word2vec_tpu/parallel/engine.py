@@ -234,6 +234,9 @@ def _pull_rows(table_l, idx, start, rows_per_shard, table=None):
     return _exchange_sum(_own_rows(table_l, idx, start, rows_per_shard, table))
 
 
+#: Rows one dispatch of ``EmbeddingEngine.assign_extra_rows`` claims.
+_EXTRA_ROW_BLOCK = 256
+
 #: A float32 array is tiled (8, 128) on a TPU: its second-minor axis is laid
 #: down in blocks of 8. An axis of 5 (the negatives) or 10 (a CBOW bag)
 #: beside d therefore pays for 8 or 16, and a reshape that puts it there
@@ -2661,43 +2664,76 @@ class EmbeddingEngine:
         costs a compile — the streaming hot-swap contract (ISSUE 10)."""
         return self.vocab_size + self.extra_rows_assigned
 
-    def _extra_row_init(self, start: int, m: int) -> jax.Array:
-        """Fresh syn0 init for rows ``[start, start+m)``: the word2vec
-        ``U[-0.5/d, 0.5/d)`` draw, keyed per GLOBAL row by the engine
-        seed — so batched and single assignment produce identical
-        values and repeated runs draw identically. Compiled once per
-        block size ``m``; ``start`` is traced."""
-        if not hasattr(self, "_extra_init_fn"):
-            d = self.dim
+    def _extra_row_writer(self):
+        """The ONE program a promotion runs, whatever its size: rows
+        ``[s, s + m)`` of ``syn0`` take the word2vec ``U[-0.5/d, 0.5/d)``
+        draw, keyed per GLOBAL row by the engine seed (so a row's values
+        do not depend on the burst it arrived in, and repeated runs draw
+        identically), and the same rows of ``syn1`` take zeros. A call
+        handles ``m <= _EXTRA_ROW_BLOCK`` rows; ``s`` and ``m`` are
+        traced, the block is a static shape, and each shard writes its
+        own rows of it into a window of its own table
+        (:meth:`_write_own_rows`' layout), the rows past ``m`` keeping
+        what they held. Blocks cut to the burst's size, a power of two
+        each, compiled two programs a size: a stream's bursts differ
+        round by round, and a trainer an hour old still met new ones
+        (PERF.md, PR 50)."""
+        if not hasattr(self, "_extra_rows_fn"):
+            d, dp, Vs = self.dim, self.padded_dim, self.rows_per_shard
+            B = min(_EXTRA_ROW_BLOCK, Vs)
             base = jax.random.PRNGKey(self._seed)
+            sharded = self.num_model > 1
 
-            def _block(start, rel):
+            def fresh(s):
                 keys = jax.vmap(
                     lambda r: jax.random.fold_in(base, (1 << 30) + r)
-                )(start + rel)
+                )(s + jnp.arange(B, dtype=jnp.int32))
                 blk = jax.vmap(
                     lambda k: jax.random.uniform(
-                        k, (self.padded_dim,), jnp.float32,
+                        k, (dp,), jnp.float32,
                         minval=-0.5 / d, maxval=0.5 / d,
                     )
                 )(keys)
-                if self.padded_dim > d:
-                    blk = blk.at[:, d:].set(0.0)
-                return blk
+                return blk.at[:, d:].set(0.0) if dp > d else blk
 
-            self._extra_init_fn = jax.jit(_block)
-        return self._extra_init_fn(
-            jnp.int32(start), jnp.arange(m, dtype=jnp.int32)
-        )
+            def write(table_l, block, loc, m):
+                w = jnp.clip(loc, 0, Vs - B)
+                held = lax.dynamic_slice(table_l, (w, 0), (B, dp))
+                i = w + jnp.arange(B) - loc  # the block row a window row takes
+                rows = jnp.where(
+                    ((i >= 0) & (i < m))[:, None],
+                    block[jnp.clip(i, 0, B - 1)].astype(table_l.dtype),
+                    held,
+                )
+                return lax.dynamic_update_slice(table_l, rows, (w, 0))
+
+            def local(syn0_l, syn1_l, s, m):
+                loc = s - (lax.axis_index(MODEL_AXIS) * Vs if sharded else 0)
+                return (
+                    write(syn0_l, fresh(s), loc, m),
+                    write(syn1_l, jnp.zeros((B, dp), jnp.float32), loc, m),
+                )
+
+            tsh = self._table_sharding()
+            self._extra_rows_fn = (B, jax.jit(
+                self._shard_map(
+                    local,
+                    in_specs=(P(MODEL_AXIS, None),) * 2 + (P(), P()),
+                    out_specs=(P(MODEL_AXIS, None),) * 2,
+                ) if sharded else local,
+                out_shardings=(tsh, tsh),
+                donate_argnums=(0, 1),
+            ))
+        return self._extra_rows_fn
 
     def assign_extra_rows(self, words: Sequence[Optional[str]]) -> List[int]:
         """Claim ``len(words)`` consecutive spare extra rows in one
         batched mutation: the promotion-burst path (a vocabulary shift
         can promote thousands of words between two mini-epochs, and
         per-word writes would issue thousands of serialized single-row
-        dispatches). The block is written in power-of-two chunks, so a
-        lifetime of arbitrary burst sizes compiles at most
-        ``log2(extra_rows_total)`` distinct block shapes, and the whole
+        dispatches). The burst is written ``_EXTRA_ROW_BLOCK`` rows a
+        dispatch by one program (:meth:`_extra_row_writer`), so a
+        lifetime of arbitrary burst sizes compiles once, and the whole
         burst costs ONE ``table_version`` tick.
 
         Each claimed syn0 row gets the word2vec ``U[-0.5/d, 0.5/d)``
@@ -2722,19 +2758,12 @@ class EmbeddingEngine:
                 "headroom"
             )
         start = self.vocab_size + self.extra_rows_assigned
-        fn = self._row_writer()
-        s, left = start, n
-        while left:
-            m = 1 << (left.bit_length() - 1)
-            self.syn0 = fn(
-                self.syn0, self._extra_row_init(s, m), jnp.int32(s)
+        block, fn = self._extra_row_writer()
+        for s in range(start, start + n, block):
+            self.syn0, self.syn1 = fn(
+                self.syn0, self.syn1, jnp.int32(s),
+                jnp.int32(min(block, start + n - s)),
             )
-            self.syn1 = fn(
-                self.syn1, jnp.zeros((m, self.padded_dim), jnp.float32),
-                jnp.int32(s),
-            )
-            s += m
-            left -= m
         self.extra_rows_assigned += n
         self._tick_tables("assign_extra_row")
         self._ann_touch_rows(range(start, start + n))
@@ -2798,19 +2827,13 @@ class EmbeddingEngine:
         _ann_mod.update_rows(self._ann, self.syn0, self.norms(), rows)
         self._ann.table_version = self.table_version
 
-    def set_noise_counts(self, counts: np.ndarray) -> None:
-        """Install updated per-word corpus counts and rebuild the
-        negative-sampling alias table from them — the ISGNS adaptive
-        unigram distribution (arXiv:1704.03956): a long-lived streaming
-        trainer re-derives the noise distribution from the counts it
-        has actually observed, on a cadence, instead of freezing the
-        bootstrap distribution forever.
-
-        Shapes are invariant (``prob``/``alias`` stay ``(vocab_size,)``
-        arrays), so every compiled train program keeps running warm —
-        the refresh is two replicated device_puts. Spare extra rows are
-        never negative-sampled (the table spans the base vocab only, as
-        for fastText buckets); checkpoints carry the updated counts."""
+    def noise_table(self, counts: np.ndarray):
+        """The alias table :meth:`set_noise_counts` would install for
+        ``counts``: host work only (two ``pow`` and the alias build over
+        ``vocab_size`` counts, tens of milliseconds at 2M with
+        native/host_ops.cpp), touching nothing of the engine, so a
+        streaming trainer builds the next round's table while the device
+        drains this one and hands it to :meth:`set_noise_counts`."""
         # graftlint: ignore[sync-point] counts arrive as a host numpy array
         c = np.asarray(counts, dtype=np.int64)
         if c.shape != (self.vocab_size,):
@@ -2819,10 +2842,29 @@ class EmbeddingEngine:
             )
         if c.sum() <= 0:
             raise ValueError("counts must sum to > 0")
-        table = build_unigram_alias(
+        return build_unigram_alias(
             c, power=self.unigram_power, table_size=self.unigram_table_size
         )
-        self._counts = c.copy()
+
+    def set_noise_counts(self, counts: np.ndarray, table=None) -> None:
+        """Install updated per-word corpus counts and rebuild the
+        negative-sampling alias table from them — the ISGNS adaptive
+        unigram distribution (arXiv:1704.03956): a long-lived streaming
+        trainer re-derives the noise distribution from the counts it
+        has actually observed, on a cadence, instead of freezing the
+        bootstrap distribution forever. ``table`` is
+        :meth:`noise_table` of the same counts where the caller built it
+        ahead; left out, it is built here.
+
+        Shapes are invariant (``prob``/``alias`` stay ``(vocab_size,)``
+        arrays), so every compiled train program keeps running warm —
+        the refresh is two replicated device_puts. Spare extra rows are
+        never negative-sampled (the table spans the base vocab only, as
+        for fastText buckets); checkpoints carry the updated counts."""
+        if table is None:
+            table = self.noise_table(counts)
+        # graftlint: ignore[sync-point] counts arrive as a host numpy array
+        self._counts = np.array(counts, dtype=np.int64)
         self._put_alias_table(table)
 
     def norms(self) -> jax.Array:

@@ -174,6 +174,36 @@ class TrainingMetrics:
         })
 
 
+def scatter_summary(rows_written, steps: int, slots) -> dict:
+    """``training_metrics`` keys of the packed steps' scatters, from the
+    scans' summed ``rows_written[:4]`` (distinct rows written into syn0 and
+    syn1, then the slabs the slab writer moved for them), the live steps
+    and the update slots one step hands the scatters (syn0, syn1:
+    ``EmbeddingEngine.packed_scatter_slots``). Rows written over slots
+    handed (the step sums a row's duplicates before it writes), both
+    tables and each; where the slab writer ran (ops/slab_writer.py),
+    distinct rows over the slabs it moved, 1 to 8 (16 in bfloat16): XLA's
+    writer moves none. {} for a fit whose steps wrote nothing."""
+    rows, slabs = rows_written[:2], rows_written[2:4]
+    if not (any(rows) or any(slabs)) or not steps:
+        return {}
+    out = {
+        "scatter_distinct_share": round(
+            sum(rows) / (steps * sum(slots)), 4),
+        "scatter_distinct_share_syn0": round(
+            rows[0] / (steps * slots[0]), 4),
+        "scatter_distinct_share_syn1": round(
+            rows[1] / (steps * slots[1]), 4),
+    }
+    if all(slabs):
+        out.update(
+            scatter_rows_per_slab=round(sum(rows) / sum(slabs), 4),
+            scatter_rows_per_slab_syn0=round(rows[0] / slabs[0], 4),
+            scatter_rows_per_slab_syn1=round(rows[1] / slabs[1], 4),
+        )
+    return out
+
+
 class LatencyHistogram:
     """Fixed log-spaced latency histogram: O(1) memory per endpoint,
     quantiles by linear interpolation inside the winning bucket.

@@ -113,7 +113,7 @@ def test_observe_counts_and_encodes():
     sv = _bootstrap([["a", "b", "a"], ["a", "b", "c", "c"]], min_count=2)
     # a(3), b(2), c(2) admitted; encode returns row ids, OOV sketched.
     ids = sv.observe(["a", "c", "newword", "b"])
-    assert ids == [sv.word_index["a"], sv.word_index["c"], sv.word_index["b"]]
+    assert ids.tolist() == [sv.word_index["a"], sv.word_index["c"], sv.word_index["b"]]
     assert sv.oov_words_seen == 1
     assert "newword" in sv.sketch
     assert sv.counts_array()[sv.word_index["a"]] == 4  # 3 bootstrap + 1
@@ -271,3 +271,88 @@ def test_snapshot_vocabulary_is_aligned():
     assert v.word_index == sv.word_index
     assert v.counts.tolist() == sv.counts_array().tolist()
     assert v.size == sv.base_size + 1
+
+
+# ----------------------------------------------------------------------
+# The chunked look-ups (PR 50): corpus/word_index.py behind
+# observe_many / encode_many, held to the dictionary and to a call a
+# sentence
+# ----------------------------------------------------------------------
+
+_ODD_WORDS = ["", "a b", "é", "日本語", "sixteenbyteslong", "seventeenbyteslon",
+              "averyveryverylongwordindeed", "ab", "ab\0", "x\0y"]
+
+
+@pytest.mark.parametrize("odd", [False, True])
+def test_word_index_is_the_dictionary(odd):
+    from glint_word2vec_tpu.corpus.word_index import WordIndex
+
+    rng = np.random.default_rng(3)
+    words = [f"w{i:05d}" for i in range(3000)] + (_ODD_WORDS if odd else [])
+    index, rows = WordIndex(words), {w: i for i, w in enumerate(words)}
+    # grown one word at a time, past the table it was built with
+    for i in range(4000):
+        w = f"g{i}" if i % 7 else "long" + "x" * 20 + str(i)
+        index.add(w, len(rows))
+        rows[w] = len(rows)
+    asked = [list(rows)[i] for i in rng.integers(0, len(rows), 20000)]
+    asked += ["nope", "w", "w000001", "ée", "sixteenbyteslonG", "g"]
+    nul = [t for t in asked if "\0" in t]
+    if nul:  # a NUL would read as a key's padding: the caller's dictionary
+        assert index.lookup(asked) is None
+        asked = [t for t in asked if "\0" not in t]
+    got = index.lookup(asked)
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, [rows.get(t, -1) for t in asked])
+
+
+@pytest.mark.parametrize("chunk", [1, 3, 50])
+def test_observe_many_is_observe_a_sentence(chunk):
+    rng = np.random.default_rng(11)
+    known = [f"k{i}" for i in range(300)] + ["é", "x\0y", "w" * 30]
+    base = build_vocab([known], min_count=1)
+    one = StreamVocab(base, sketch_capacity=16)  # evictions in the sketch
+    many = StreamVocab(base, sketch_capacity=16)
+    pool = known + [f"new{i}" for i in range(40)]
+    sents = [list(rng.choice(pool, size=rng.integers(1, 90)))
+             for _ in range(120)]
+    for lo in range(0, len(sents), chunk):
+        part = sents[lo:lo + chunk]
+        want = [one.observe(s) for s in part]
+        rows, lens = many.observe_many(part)
+        np.testing.assert_array_equal(rows, np.concatenate(want))
+        assert rows.dtype == np.int32
+        assert lens.tolist() == [len(w) for w in want]
+        enc, enc_lens = many.encode_many(part)  # counts nothing
+        np.testing.assert_array_equal(enc, rows)
+        np.testing.assert_array_equal(enc_lens, lens)
+        if lo == 60:  # a promotion reaches the index too
+            w = next(iter(one.sketch._counts))
+            assert one.promote(w, 3) == many.promote(w, 3)
+    np.testing.assert_array_equal(one.counts_array(), many.counts_array())
+    assert one.train_words_count == many.train_words_count
+    assert one.oov_words_seen == many.oov_words_seen > 0
+    assert one.sketch._counts == many.sketch._counts
+    assert one.sketch._errors == many.sketch._errors
+    assert one.sketch.items_seen == many.sketch.items_seen
+
+
+def test_keep_probabilities_are_the_rule_over_every_word():
+    """Only the words the rule can bring under 1 get its arithmetic; the
+    rest read what the whole-array form gave, bit for bit."""
+    rng = np.random.default_rng(5)
+    counts = np.concatenate([rng.zipf(1.3, 5000).astype(np.int64),
+                             [0, 0, 1, 10**7]])
+    base = build_vocab([["a"]], min_count=1)
+    sv = StreamVocab(base)
+    sv.words = [f"w{i}" for i in range(counts.size)]
+    sv._counts = counts.copy()
+    sv.train_words_count = int(counts.sum())
+    for ratio in (1e-3, 1e-5, 0.3):
+        pcn = counts.astype(np.float64) / float(counts.sum())
+        with np.errstate(divide="ignore", invalid="ignore"):
+            ran = (np.sqrt(pcn / ratio) + 1.0) * (ratio / pcn)
+        want = np.clip(np.where(counts > 0, ran, 0.0), 0.0, 1.0)
+        got = sv.keep_probabilities(ratio)
+        np.testing.assert_array_equal(got, want)
+        assert 0 < (got < 1).sum() < counts.size
