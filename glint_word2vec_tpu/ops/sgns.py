@@ -24,7 +24,7 @@ padded entries contribute exactly zero to every scatter-add.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -76,9 +76,6 @@ class SgnsGrads(NamedTuple):
     c_neg: jax.Array  # (B, C, n) alpha * (0 - sigmoid(f_neg)) * mask
     d_center: jax.Array  # (B, d)  gradient w.r.t. syn0[centers]
     loss: jax.Array  # () masked-mean SGNS loss (monitoring only)
-    # (B, C) its terms: what a shard that did a slice of the pairs ships
-    # (parallel/engine.py), so the mean is one sum in one order on any mesh
-    pair_loss: Optional[jax.Array] = None
 
 
 def _rounded(x: jax.Array, compute_dtype) -> jax.Array:
@@ -143,8 +140,7 @@ def sgns_grads(
     co = sgns_coefs(f_pos, f_neg, mask, neg_mask, alpha)
     d_center = sgns_d_center(co.c_pos, co.c_neg, u_pos, u_neg, compute_dtype)
     return SgnsGrads(
-        c_pos=co.c_pos, c_neg=co.c_neg, d_center=d_center, loss=co.loss,
-        pair_loss=co.pair_loss,
+        c_pos=co.c_pos, c_neg=co.c_neg, d_center=d_center, loss=co.loss
     )
 
 

@@ -20,11 +20,11 @@ maps to a jitted SPMD function:
 
 Communication design: a sum over the "model" axis replaces the
 client<->server pull round-trip (each shard contributes its owned rows,
-zeros elsewhere): a ``psum`` where every shard needs the rows (a serving
-pull; the step's centre side), a ``psum_scatter`` over the pairs for the
-step's pair side, whose rows only make a pair's scalars and ``d_center``:
-a shard does the pair math of its slice and the shards ``all_gather``
-those (:func:`_pull_pair_slices`). An ``all_gather`` over the "data" axis
+zeros elsewhere): a ``psum`` of the rows where every shard needs them (a
+serving pull; the step's centre side). The step's pair side sends no row:
+a ``syn1`` row has one owner, whose ``h . u`` is the pair's logit, so the
+shards ``psum`` the logit partials and their partial ``d_center``
+(``step_body_rows``). An ``all_gather`` over the "data" axis
 replaces the async gradient push. The data-axis exchange carries ONLY the batch's
 center representations ``h`` (B x d), the scalar gradient coefficients
 (the reference's gPlus/gMinus payload, mllib:422-425), and int32 indices —
@@ -204,7 +204,7 @@ def _own_rows(table_l, idx, start, rows_per_shard, table=None):
     for every row another shard holds, under ``glint.gather`` (the step
     bodies name the ``table``, ``syn0`` or ``syn1``, as an inner scope, as
     their scatters do). An id no shard owns (the -1 that pads a subword
-    group or a pair slice) is zeros on every shard."""
+    group) is zeros on every shard."""
     with jax.named_scope("glint.gather"), (
         jax.named_scope(table) if table else contextlib.nullcontext()
     ):
@@ -222,9 +222,9 @@ def _pull_rows(table_l, idx, start, rows_per_shard, table=None):
     axis, so EVERY shard holds every row: what the serving pulls and the
     step's centre side want (each shard's ``syn1`` scatter needs every
     pair's ``h``). The TPU analogue of the servers each answering a pull
-    with their slice (SURVEY.md §2.2 pull). The step's pair side, whose
-    rows only ever make a pair's scalars and its ``d_center``, ends in a
-    reduce-scatter instead (:func:`_pull_pair_slices`).
+    with their slice (SURVEY.md §2.2 pull). With several shards the
+    step's pair side, whose rows only ever make a pair's scalars and its
+    ``d_center``, stops at :func:`_own_rows` and sums those instead.
 
     Opens its own scopes, so callers keep it OUT of theirs (a device trace
     files an op under its outermost ``glint.`` scope): the own-row gather
@@ -262,137 +262,21 @@ def _pull_blocks(table_l, ids, start, rows_per_shard, table=None):
             for c in _id_columns(ids)]
 
 
+def _own_blocks(table_l, ids, start, rows_per_shard, table=None):
+    """:func:`_pull_blocks` without the all-reduce: this shard's OWN rows
+    (:func:`_own_rows`), zeros where another shard holds the row, so
+    nothing crosses chips."""
+    return [_own_rows(table_l, c, start, rows_per_shard, table)
+            for c in _id_columns(ids)]
+
+
 def _exchange_sum(x):
-    """The model-axis all-reduce of pulled rows, under ``glint.exchange``
-    (``EmbeddingEngine.packed_exchange_bytes`` counts what it and
-    :func:`_pull_pair_slices`' reduce-scatter are handed)."""
+    """The model-axis all-reduce, under ``glint.exchange``: of pulled
+    rows, and of the pair side's logit partials and partial ``d_center``
+    (``EmbeddingEngine.packed_exchange_bytes`` counts what it is
+    handed)."""
     with jax.named_scope("glint.exchange"):
         return lax.psum(x, MODEL_AXIS)
-
-
-#: The chip's reduce-scatter (v5e, the compiler this repo is built against)
-#: moves a block in equal spans of at most 9,216 granules of 512 B (a row of
-#: 128 float32 lanes), each a whole number of 256 granules, and PADS a block
-#: that is not so many whole spans (26,240 rows of 384 columns to 26,880),
-#: re-cutting the shards after it with collective-permutes and slices of
-#: its own. Those ops, and the reduce-scatter they replace, carry no
-#: ``glint.`` scope, so a trace files the exchange under no scope at all
-#: (PERF.md, PR 49). Handing it whole spans costs the same bytes and keeps
-#: the op the program named.
-_SPAN_GRANULES, _SPAN_ALIGN = 9216, 256
-
-
-def _pair_slots(pairs: int, n: int, cols: int) -> int:
-    """Slots ``pairs`` batch rows are dealt into over ``n`` shards for
-    reduce-scatters of ``(slots, cols)`` float32 blocks: the pairs padded
-    so every shard takes whole sublanes, and on to whole spans of the
-    chip's reduce-scatter where that is a whole number of rows and under
-    an eighth more of them (a guess that misses costs nothing but the
-    scope: the compiler pads instead)."""
-    unit = n * _SUBLANES
-    slots = -(-pairs // unit) * unit
-    per_row = -(-cols // TABLE_LANES)
-    spans = -(-slots * per_row // _SPAN_GRANULES)
-    whole = -(-slots * per_row // (spans * _SPAN_ALIGN)) * spans * _SPAN_ALIGN
-    if whole % (per_row * unit) == 0 and whole // per_row <= slots + slots // 8:
-        return whole // per_row
-    return slots
-
-
-class _PairSlices:
-    """How ``pairs`` batch rows are dealt over the ``n`` shards of the
-    model axis for the pair math on blocks of ``cols`` columns: shard j
-    takes rows ``[j * width, (j + 1) * width)``, ``width`` a whole number
-    of sublanes (:func:`_pair_slots`). Where the last shards would run
-    past the batch they read the ``width`` rows that END it instead, and
-    the rows at the front of that slice, a neighbour's, are no slot of
-    theirs: the slice of a row tensor is then always inside it (no row is
-    ever padded), and only the ids are."""
-
-    def __init__(self, pairs: int, n: int, cols: int):
-        self.pairs, self.n = pairs, n
-        self.width = min(_pair_slots(pairs, n, cols) // n, pairs)
-        # rows shard j owns, which END its slice
-        self.owned = [
-            min(max(pairs - j * self.width, 0), self.width) for j in range(n)
-        ]
-
-    @property
-    def padded(self) -> int:
-        return self.n * self.width
-
-    def pad_ids(self, col):
-        """``(n * width,)``: shard j's slots in turn, -1 (a row no shard
-        owns) in the slots before its own rows."""
-        if self.padded == self.pairs:
-            return col
-        pieces = []
-        for j, own in enumerate(self.owned):
-            if own < self.width:
-                pieces.append(jnp.full(self.width - own, -1, col.dtype))
-            if own:
-                pieces.append(col[j * self.width:j * self.width + own])
-        return jnp.concatenate(pieces)
-
-    def mine(self):
-        """``(first row, live (width,))`` of this shard's slice."""
-        j = lax.axis_index(MODEL_AXIS)
-        own = jnp.clip(self.pairs - j * self.width, 0, self.width)
-        first = jnp.minimum(
-            j * self.width + own - self.width, self.pairs - self.width
-        )
-        return first, jnp.arange(self.width) >= self.width - own
-
-    def take(self, x, first):
-        """This shard's ``width`` rows of a ``(pairs, ...)`` array."""
-        return lax.dynamic_slice_in_dim(x, first, self.width)
-
-    def gathered(self, x):
-        """``(pairs, ...)``: the shards' slices, all-gathered over the
-        model axis under ``glint.exchange``, cut back to the batch: batch
-        row r lies ``offset`` slots on, the dead slots before it, so the
-        batch is a choice a row among a few shifted views of the slots,
-        one pass and no copy of a part."""
-        with jax.named_scope("glint.exchange"):
-            x = lax.all_gather(x, MODEL_AXIS, tiled=True)
-        if self.padded == self.pairs:
-            return x
-        with jax.named_scope("glint.grads"):
-            row = jnp.arange(self.pairs).reshape((-1,) + (1,) * (x.ndim - 1))
-            out = None
-            for j, own in enumerate(self.owned):
-                # shard j's rows start at batch row j * width, slot
-                # (j + 1) * width - own
-                offset = self.width - own
-                if own and out is None:
-                    out = x[offset:offset + self.pairs]
-                elif own and offset:
-                    out = jnp.where(
-                        row >= j * self.width,
-                        x[offset:offset + self.pairs], out,
-                    )
-            return out
-
-
-def _pull_pair_slices(table_l, ids, start, rows_per_shard, slices, table):
-    """The step's pair-side pull where the model axis has several shards:
-    the K blocks of :func:`_pull_blocks`, but ``(slices.width, d)`` each,
-    this shard's slice of the pairs. Every shard gathers its own rows of
-    every pair as before (the ids padded to the shards' slots, never the
-    rows), and the sum over the model axis is a reduce-scatter over the
-    pairs: a shard receives the summed rows of the pairs whose math it
-    does, a quarter of an all-reduce's bytes on four chips, and nobody
-    receives rows it would only repeat the others' arithmetic on."""
-    blocks = []
-    for col in _id_columns(ids):
-        with jax.named_scope("glint.gather"):
-            col = slices.pad_ids(col)
-        rows = _own_rows(table_l, col, start, rows_per_shard, table)
-        with jax.named_scope("glint.exchange"):
-            blocks.append(lax.psum_scatter(
-                rows, MODEL_AXIS, scatter_dimension=0, tiled=True
-            ))
-    return blocks
 
 
 #: Update slots one trip of the row writer walks. XLA's TPU scatter into a
@@ -715,8 +599,8 @@ class EmbeddingEngine:
         300 rests in 384 columns, so that the device's default layout keeps
         rows contiguous and no program copies a table to reach a few
         rows). A pull sums whole rows over the model axis (``_pull_rows``;
-        the step's pair side a slice of the pairs a shard,
-        ``_pull_pair_slices``); a top-k scores ``(Q, V/n)`` a shard.
+        the step's pair side sums logits and ``d_center``, no ``syn1``
+        row); a top-k scores ``(Q, V/n)`` a shard.
         """
         self._configure(
             mesh, vocab_size, dim, num_negatives=num_negatives,
@@ -921,19 +805,14 @@ class EmbeddingEngine:
                 h_rows = _pull_rows(
                     syn0_l, centers.reshape(-1), start, Vs, "syn0"
                 )
-            # With several model shards the per-pair step pulls the pair
-            # side (syn1) a slice of the pairs a shard (_pull_pair_slices).
-            slices = (
-                _PairSlices(Bl, self.num_model, self.padded_dim)
-                if self.num_model > 1 and not self.shared_negatives
-                else None
-            )
-            if slices is None:
-                u_pos = _pull_blocks(syn1_l, contexts, start, Vs, "syn1")
-            else:
-                u_pos = _pull_pair_slices(
-                    syn1_l, contexts, start, Vs, slices, "syn1"
-                )
+            # With several model shards the per-pair step sends no syn1
+            # row: a row has one owner, whose h . u is the pair's logit
+            # (every other shard's term is a product with zeros), so the
+            # pair side stops at a shard's own rows and only the logits
+            # and d_center cross (below).
+            own_pairs = self.num_model > 1 and not self.shared_negatives
+            pull_pairs = _own_blocks if own_pairs else _pull_blocks
+            u_pos = pull_pairs(syn1_l, contexts, start, Vs, "syn1")
             with jax.named_scope(compose):
                 with (jax.named_scope("group") if lanes
                       else contextlib.nullcontext()):
@@ -1006,10 +885,10 @@ class EmbeddingEngine:
                     negs = sample_negatives_per_row_packed(
                         key, noise, V, rows_g, (C, n)
                     )
-                if slices is None:
-                    u_neg = _pull_blocks(syn1_l, negs, start, Vs, "syn1")
-                    with jax.named_scope("glint.sample"):
-                        nmask = sgns.negative_mask(negs, contexts, mask)
+                u_neg = pull_pairs(syn1_l, negs, start, Vs, "syn1")
+                with jax.named_scope("glint.sample"):
+                    nmask = sgns.negative_mask(negs, contexts, mask)
+                if not own_pairs:
                     with jax.named_scope("glint.grads"):
                         g = sgns.sgns_grads(
                             h, u_pos, u_neg, mask, nmask,
@@ -1017,49 +896,44 @@ class EmbeddingEngine:
                             compute_dtype=self._compute_dtype,
                         )
                 else:
-                    # Several model shards: each does the pair math of its
-                    # slice of the pairs alone, on the summed syn1 rows the
-                    # reduce-scatter hands it, and what the scatters need
-                    # of it comes back by all-gather: ``d_center`` and the
-                    # scalars, the data axis's ship-scalars rule above.
-                    u_neg = _pull_pair_slices(
-                        syn1_l, negs, start, Vs, slices, "syn1"
+                    # The K = C * (1 + n) logit partials cross with the
+                    # small axis MAJOR, (K, Bl): a float32 (Bl, K) rests
+                    # in 128 lanes a row (PERF.md, PR 38). One shard's
+                    # term is not zero, so the sum is the owner's logit to
+                    # the bit, and the coefficients and the loss are the
+                    # one-shard program's, formed on every shard over the
+                    # whole batch. d_center's K terms are summed by owner
+                    # first and across the shards second: that order is
+                    # what differs from one shard.
+                    with jax.named_scope("glint.grads"):
+                        f_part = sgns.row_dots(
+                            h, u_pos + u_neg, self._compute_dtype
+                        ).T
+                    f = _exchange_sum(f_part)
+                    with jax.named_scope("glint.grads"):
+                        f = f.T
+                        co = sgns.sgns_coefs(
+                            f[:, :C], f[:, C:].reshape(nmask.shape), mask,
+                            nmask, alpha.astype(jnp.float32),
+                        )
+                        d_part = sgns.sgns_d_center(
+                            co.c_pos, co.c_neg, u_pos, u_neg,
+                            self._compute_dtype,
+                        )
+                        # The loss's terms are summed as a VECTOR of their
+                        # own, a pair's in one, standing in memory: fused
+                        # with the logits that lie pairs-minor the chip
+                        # reduced them tile by tile of (1, 128), 1.26e-6
+                        # off the replay's loss (PERF.md, PR 51; PR 49 met
+                        # the same).
+                        pair_loss = lax.optimization_barrier(
+                            co.pair_loss.sum(axis=1)
+                        )
+                        loss = pair_loss.sum() / jnp.maximum(mask.sum(), 1.0)
+                    g = sgns.SgnsGrads(
+                        c_pos=co.c_pos, c_neg=co.c_neg,
+                        d_center=_exchange_sum(d_part), loss=loss,
                     )
-                    with jax.named_scope("glint.sample"):
-                        first, live = slices.mine()
-                        mask_s = slices.take(mask, first) * live[:, None]
-                        nmask_s = sgns.negative_mask(
-                            slices.take(negs, first),
-                            slices.take(contexts, first), mask_s,
-                        )
-                    with jax.named_scope("glint.grads"):
-                        g = sgns.sgns_grads(
-                            slices.take(h, first), u_pos, u_neg, mask_s,
-                            nmask_s, alpha.astype(jnp.float32),
-                            compute_dtype=self._compute_dtype,
-                        )
-                        coefs_s = jnp.concatenate(
-                            [g.c_pos, g.c_neg.reshape(slices.width, -1)],
-                            axis=1,
-                        )
-                    d_center = slices.gathered(g.d_center)
-                    coefs = slices.gathered(coefs_s)
-                    # The loss terms travel as a vector of their own, a
-                    # pair's in one, so their sum is the reduction the
-                    # one-shard program makes, over the same shape in the
-                    # same order (as a column of ``coefs`` the chip summed
-                    # them another way, 1.4e-6 off the replay's loss:
-                    # PERF.md, PR 49).
-                    pair_loss = slices.gathered(g.pair_loss.sum(axis=1))
-                    with jax.named_scope("glint.grads"):
-                        g = sgns.SgnsGrads(
-                            c_pos=coefs[:, :C],
-                            c_neg=coefs[:, C:].reshape(Bl, C, n),
-                            d_center=d_center,
-                            loss=pair_loss.sum() / jnp.maximum(
-                                mask.sum(), 1.0
-                            ),
-                        )
                 with jax.named_scope("glint.grads"):
                     ctx_g = lax.all_gather(contexts, DATA_AXIS, tiled=True)
                     negs_g = lax.all_gather(negs, DATA_AXIS, tiled=True)
@@ -2359,56 +2233,34 @@ class EmbeddingEngine:
 
     def packed_exchange_bytes(self, pair_batch: int,
                               window: Optional[int] = None) -> int:
-        """Bytes one device hands the model-axis pulls of one packed step
-        (the all-reduce and the reduce-scatters under ``glint.exchange``),
-        from shapes alone: the float32 rows it pulls (a centre, a context
-        and the negatives, or the shared pool once, for each of its pairs;
-        rows as they rest, ``padded_dim`` wide; the slots that pad a pair
-        slice name no row and are not counted); 0 where the model axis
-        has one shard."""
+        """Bytes one device hands the model-axis all-reduces of one packed
+        step (``glint.exchange``), from shapes alone, float32 rows as they
+        rest, ``padded_dim`` wide: the centre rows it pulls and, with a
+        shared pool, a context a pair and the pool once; with per-pair
+        negatives no ``syn1`` row but a pair's ``d_center`` and its
+        ``1 + num_negatives`` logits. 0 where the model axis has one
+        shard."""
         if self.num_model == 1:
             return 0
         pairs = pair_batch // self.num_data
         centers = self._packed_center_slots(pair_batch, window)
-        rows = centers // self.num_data + (
-            pairs + self.shared_negatives if self.shared_negatives
-            else pairs * (1 + self.num_negatives))
-        return 4 * rows * self.padded_dim
-
-    def packed_pair_slots(self, pair_batch: int) -> int:
-        """Slots one data rank's pairs (a CBOW engine's positions) of a
-        packed step are dealt into over the model axis: the pairs, padded
-        so every shard takes a slice of whole sublanes."""
-        return _PairSlices(
-            pair_batch // self.num_data, self.num_model, self.padded_dim
-        ).padded
+        rows = centers // self.num_data + pairs
+        if self.shared_negatives:
+            return 4 * (rows + self.shared_negatives) * self.padded_dim
+        return 4 * (rows * self.padded_dim
+                    + pairs * (1 + self.num_negatives))
 
     def packed_exchange_send_bytes(self, pair_batch: int,
                                    window: Optional[int] = None) -> dict:
         """Bytes one device must SEND over the model axis in one packed
         step at least, by collective, from shapes alone: among n shards an
-        all-reduce of S bytes sends 2 (n - 1) / n x S (the centre side;
-        with a shared pool, every pull), a reduce-scatter of S handed
-        (the pair side's rows, padding slots and all) or an all-gather of
-        S gathered (``d_center``, and a pair's coefficients and loss
-        term) (n - 1) / n x S. All 0 where the model axis has one shard."""
+        all-reduce of S bytes sends 2 (n - 1) / n x S, and all-reduces
+        are all the step has (:meth:`packed_exchange_bytes`): the other
+        two keys read 0. All 0 where the model axis has one shard."""
         n = self.num_model
-        sent = {"all_reduce": 0, "reduce_scatter": 0, "all_gather": 0}
-        if n == 1:
-            return sent
         handed = self.packed_exchange_bytes(pair_batch, window)
-        if self.shared_negatives:
-            sent["all_reduce"] = 2 * (n - 1) * handed // n
-            return sent
-        k = 1 + self.num_negatives
-        pairs = pair_batch // self.num_data
-        slots = self.packed_pair_slots(pair_batch)
-        centre = handed - 4 * pairs * k * self.padded_dim
-        sent["all_reduce"] = 2 * (n - 1) * centre // n
-        sent["reduce_scatter"] = (n - 1) * 4 * slots * k * self.padded_dim // n
-        sent["all_gather"] = (
-            (n - 1) * 4 * slots * (self.padded_dim + k + 1) // n)
-        return sent
+        return {"all_reduce": 2 * (n - 1) * handed // n,
+                "reduce_scatter": 0, "all_gather": 0}
 
     # ------------------------------------------------------------------
     # Serving ops (the BigWord2VecMatrix query surface)

@@ -215,15 +215,15 @@ def tables(eng):
 
 
 def run_packed(eng, corpus, seed=3, total_words=5000, window=WINDOW,
-               batch=BATCH, keep=0.8):
-    """K CBOW steps from the seed's tables over the compacted view; returns
-    (tables before, the scan's per-step outputs)."""
+               batch=BATCH, keep=0.8, steps=K):
+    """``steps`` CBOW steps from the seed's tables over the compacted view;
+    returns (tables before, the scan's per-step outputs)."""
     before = tables(eng)
     eng.upload_corpus(*corpus)
     eng.set_keep_probs(np.full(V, keep, np.float32))
     eng.compact_corpus(jax.random.PRNGKey(9))
     out = eng.train_steps_corpus_packed(
-        0, batch, window, batch, jax.random.PRNGKey(seed), K,
+        0, batch, window, batch, jax.random.PRNGKey(seed), steps,
         step_size=0.05, total_words=total_words)
     return before, out
 
@@ -378,14 +378,50 @@ def test_the_benchmarks_enumeration_holds_the_bags_and_the_device_counts():
     assert bag_faults(eng, batches, WINDOW, off) == (0, 1)
 
 
+def seed_syn1(eng):
+    """Seeded output rows in place of the zeros a fit starts from, under
+    which the first step's logits are all 0 and its ``d_center`` too."""
+    rows = np.random.default_rng(6).normal(0, 0.3, (eng.num_rows, D))
+    eng.set_tables(tables(eng)[0], rows.astype(np.float32))
+
+
+def assert_two_shards_fit_as_one(fit, steps):
+    """``fit(shape, steps, seeded)`` runs packed steps on a mesh of
+    ``shape`` (from :func:`seed_syn1`'s rows where ``seeded``) and returns
+    (tables before, tables after, the scan's outputs, the losses first).
+
+    On several shards a pair's logit is its owner's ``h . u`` (the others'
+    terms are products with zeros), so the logits, the coefficients, the
+    loss and what a step writes to ``syn1`` are the one shard's BITS for as
+    long as the tables are: step 0. ``d_center``'s terms are summed by
+    owner first and across the shards second (ISSUE 51: no ``syn1`` row
+    crosses the model axis), so ``syn0``, and every later step with it, is
+    the one shard's at the replay's limits, no longer to the bit."""
+    for n, seeded in ((1, True), (steps, False)):
+        (init, one, one_out), (_, two, two_out) = (
+            fit(shape, n, seeded) for shape in ((1, 1), (1, 2)))
+        losses, *rest = zip(one_out, two_out)
+        assert losses[0][0] == losses[1][0]
+        np.testing.assert_allclose(losses[1], losses[0], rtol=LOSS_GAP)
+        for a, b in rest:  # positions and counts: whole numbers
+            np.testing.assert_array_equal(a, b)
+        if n == 1:
+            np.testing.assert_array_equal(one[1], two[1])
+        for before, a, b in zip(init, one, two):
+            gap, dnorm = gaps(b, a, before)
+            assert gap < GAP and dnorm < DNORM_GAP, (n, gap, dnorm)
+        assert np.abs(one[0] - init[0]).max() > 0
+
+
 def test_one_by_one_equals_one_by_two():
-    seen = []
-    for shape in ((1, 1), (1, 2)):
+    def fit(shape, steps, seeded):
         eng = engine(shape)
-        _, out = run_packed(eng, zipf_corpus())
-        seen.append(tables(eng) + tuple(np.asarray(a) for a in out[:4]))
-    for a, b in zip(*seen):
-        np.testing.assert_array_equal(a, b)
+        if seeded:
+            seed_syn1(eng)
+        before, out = run_packed(eng, zipf_corpus(), steps=steps)
+        return before, tables(eng), [np.asarray(a) for a in out[:4]]
+
+    assert_two_shards_fit_as_one(fit, K)
 
 
 def test_one_context_bags_are_the_skipgram_step_on_the_swapped_pair():
@@ -577,12 +613,14 @@ def lowered(eng):
 # the same. ISSUE 49 changed the step on meshes whose model axis has several
 # shards (tests/test_subword_packed.py says how): those three entries were
 # taken again on its tree (CHANGES.md has the old ones), (1, 1) is as it was.
+# ISSUE 51 changed the same three again (the pair side sends logits and
+# d_center, no syn1 row): taken again on its tree, (1, 1) as it was.
 # A word-level CBOW fit must lower to the program it lowered to.
 CBOW_PROGRAMS = {
     ((1, 1), "rows"): "c8731cfde68643af",
-    ((1, 2), "rows"): "6abae65acce40f32",
-    ((2, 2), "rows"): "ccc8c03a9b8f3ffd",
-    ((1, 4), "rows"): "e58186426a840b53",
+    ((1, 2), "rows"): "5ba78a0944e12514",
+    ((2, 2), "rows"): "79c54474240d0be5",
+    ((1, 4), "rows"): "bb443c9d949746cd",
 }
 
 

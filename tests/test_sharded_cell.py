@@ -187,8 +187,7 @@ def random_groups(seed=4):
 def fit_on(shape, family):
     """(tables before, tables after, losses) of K packed steps of one
     ``family``'s scan on a mesh of ``shape``; a step's pairs (205; CBOW:
-    60 positions) are no multiple of four shards' sublanes, so the pair
-    slices are padded."""
+    60 positions) are no multiple of four shards' sublanes."""
     cbow = family == "cbow"
     eng = engine(shape, architecture="cbow" if cbow else "skipgram",
                  groups=random_groups() if family == "subword" else None)
@@ -210,9 +209,10 @@ def fit_on(shape, family):
 
 @pytest.mark.parametrize("family", ["word", "subword", "cbow"])
 def test_a_1x4_fit_is_the_one_device_fit(family):
-    # Each shard does the pair math of a quarter of the pairs; the tables
-    # and losses are the one device's at the replay's limits (on the CPU
-    # they are its bits: the same float32 terms in the same order).
+    # A pair's logit is its owner's h . u and the coefficients and the
+    # loss are formed whole on every shard; d_center's terms are summed by
+    # owner first and across the shards second. The tables and losses are
+    # the one device's at the replay's limits.
     befores, ones, one_losses = fit_on((1, 1), family)
     _, fours, four_losses = fit_on((1, 4), family)
     for before, one, four in zip(befores, ones, fours):
@@ -226,10 +226,9 @@ def test_a_1x4_fit_is_the_one_device_fit(family):
 
 
 @functools.lru_cache(maxsize=None)
-def collectives(shape):
-    """(shape text, op name, replica groups, opcode) of every all-reduce,
-    reduce-scatter and all-gather of the packed scan an engine on a mesh
-    of ``shape`` compiles."""
+def compiled_text(shape):
+    """The compiled text of the packed scan an engine on a mesh of
+    ``shape`` builds: ``rank_pairs(shape)`` pairs a data rank a step."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -239,24 +238,38 @@ def collectives(shape):
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(eng.mesh, P(*spec)))
 
-    span = -(-3 * PAIRS // context_width(WINDOW))
-    fn = eng._make_packed_corpus_scan(PAIRS, WINDOW, BATCH, span, K)
+    pairs = packed_pair_batch(BATCH, WINDOW, eng.num_data)
+    span = -(-3 * pairs // context_width(WINDOW))
+    fn = eng._make_packed_corpus_scan(pairs, WINDOW, BATCH, span, K)
     table = sds(eng.syn0.shape, jnp.float32, *eng.syn0.sharding.spec)
     offs = sds((61,), jnp.int32)
     i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
-    text = fn.lower(
+    return fn.lower(
         table, table, sds((-(-V // 64), 128), jnp.int32),
         sds((900,), jnp.int32), sds((900,), jnp.int32), offs, offs, i32, i32,
         sds((2,), jnp.uint32), u32, u32, f32, f32, f32).compile().as_text()
+
+
+def rank_pairs(shape):
+    return packed_pair_batch(BATCH, WINDOW, shape[0]) // shape[0]
+
+
+def collectives(shape):
+    """(shape text, op name, replica groups, opcode, operand text) of every
+    all-reduce, reduce-scatter, all-gather, all-to-all and
+    collective-permute of the packed scan an engine on a mesh of ``shape``
+    compiles."""
     found = []
-    for line in text.splitlines():
-        m = re.search(r"= (.*?) (all-reduce|reduce-scatter|all-gather)"
-                      r"(?:-start)?\(", line)
+    for line in compiled_text(shape).splitlines():
+        m = re.search(r"= (.*?) (all-reduce|reduce-scatter|all-gather|"
+                      r"all-to-all|collective-permute)(?:-start)?\((.*?)\)",
+                      line)
         if m:
+            groups = re.search(
+                r"(?:replica_groups|source_target_pairs)=(\{\{.*?\}\})", line)
             found.append((
                 m.group(1), re.search(r'op_name="([^"]*)"', line).group(1),
-                re.search(r"replica_groups=(\{\{.*?\}\})", line).group(1),
-                m.group(2)))
+                groups.group(1), m.group(2), m.group(3)))
     return found
 
 
@@ -269,31 +282,60 @@ def test_the_exchange_has_its_own_scope():
     across = [f for f in found if f[2] == "{{0,1,2,3}}"]
     data = [f for f in across if f",{D_REST}]" in f[0]]
     assert data, found  # the rows
-    for shape, op_name, _, _ in data:
+    for shape, op_name, *_ in data:
         assert "/glint.exchange/" in op_name, (shape, op_name)
-    for shape, op_name, _, _ in across:
+    for shape, op_name, *_ in across:
         assert "glint.gather" not in op_name, (shape, op_name)
         # what else crosses the model axis is the scatters' counts: rows
         # written and slabs moved, of each table
         assert "glint.exchange" in op_name or shape.startswith("s32[4]")
-    # The centre side alone is all-reduced (every shard's syn1 scatter
-    # wants every pair's h); the pair side, a context and NEG negatives a
-    # pair, is reduce-scattered over the pairs, and what the scatters need
-    # of the pair math comes back by all-gather: d_center and, a pair, its
-    # 1 + NEG coefficients and its loss term.
-    slots = engine((1, 4)).packed_pair_slots(PAIRS)
-    assert slots % 4 == 0 and PAIRS < slots < PAIRS + 4 * 8
-    by_kind = {kind: sorted(f[0] for f in data if f[3] == kind)
-               for kind in ("all-reduce", "reduce-scatter", "all-gather")}
-    assert by_kind["all-reduce"] == [f"f32[{PAIRS},{D_REST}]{{1,0}}"]
-    assert by_kind["reduce-scatter"] == (
-        [f"f32[{slots // 4},{D_REST}]{{1,0}}"] * (1 + NEG))
-    assert by_kind["all-gather"] == [f"f32[{slots},{D_REST}]{{1,0}}"]
-    # (the loss terms apart, a vector, so that their sum is the one-shard
-    # program's reduction over the pairs)
-    scalars = [f for f in across if f[3] == "all-gather" and f not in data]
-    assert sorted(f[0] for f in scalars) == sorted(
-        [f"f32[{slots},{1 + NEG}]{{1,0}}", f"f32[{slots}]{{0}}"])
+    # No syn1 row crosses: the centre side is all-reduced (every shard's
+    # syn1 scatter wants every pair's h), the pair side's 1 + NEG logit
+    # partials a pair cross with the small axis major (a float32 (pairs,
+    # 6) would rest in 128 lanes a pair), the coefficients and the loss are
+    # formed on every shard, and the partial d_center is all-reduced. All
+    # that crosses is all-reduced.
+    assert {f[3] for f in across} == {"all-reduce"}, across
+    assert sorted(f[0] for f in data) == [f"f32[{PAIRS},{D_REST}]{{1,0}}"] * 2
+    logits = [f[0] for f in across
+              if "glint.exchange" in f[1] and f not in data]
+    assert logits == [f"f32[{1 + NEG},{PAIRS}]{{1,0}}"], logits
+    # ... at the cell's 26,215 pairs under 1 MB as the chip tiles it,
+    # (8, 128): 13.4 MB the other way round
+    assert 8 * -(-26_215 // 128) * 128 * 4 < 1e6 < 26_215 * 128 * 4
+    assert [f[0] for f in across if f[0].startswith("s32")] == ["s32[4]{0}"]
+
+
+@pytest.mark.parametrize("shape", [(1, 4), (2, 2)])
+def test_no_syn1_row_crosses_the_model_axis(shape):
+    # Over the model axis the compiled packed scan has two collectives
+    # whose operand is d-wide, h's and d_center's, and every one sums
+    # (nothing is gathered, scattered or permuted); the d-wide
+    # all-gathers of h_g and dcen_g are the data axis's.
+    n_data, n_model = shape
+    ranks = np.arange(n_data * n_model).reshape(n_data, n_model)
+    model_axis = "{" + ",".join(
+        "{" + ",".join(map(str, row)) + "}" for row in ranks) + "}"
+    data_axis = "{" + ",".join(
+        "{" + ",".join(map(str, col)) + "}" for col in ranks.T) + "}"
+    found = collectives(shape)
+    assert {f[2] for f in found} <= {model_axis, data_axis}, found
+    over = [f for f in found if f[2] == model_axis]
+    assert over and {f[3] for f in over} == {"all-reduce"}, over
+    wide = [f for f in over if f",{D_REST}]" in f[0]]
+    assert [f[0] for f in wide] == (
+        [f"f32[{rank_pairs(shape)},{D_REST}]{{1,0}}"] * 2)
+    # the first is handed a gather of syn0 (h), the second a sum formed
+    # under glint.grads (d_center); no operand is a gather of syn1
+    text = compiled_text(shape)
+    producers = [re.search(r'%s = .*?op_name="([^"]*)"' % re.escape(
+        f[4].split(" ")[-1]), text).group(1) for f in wide]
+    assert "glint.gather/syn0" in producers[0], producers
+    assert "glint.grads" in producers[1], producers
+    if n_data > 1:
+        gathered = [f for f in found if f[2] == data_axis
+                    and f[3] == "all-gather" and f",{D_REST}]" in f[0]]
+        assert len(gathered) == 2, gathered  # h_g, dcen_g
 
 
 def test_one_shard_exchanges_nothing():
@@ -319,36 +361,28 @@ def test_exchange_bytes_is_what_the_shapes_say(shape):
         assert sent == {"all_reduce": 0, "reduce_scatter": 0,
                         "all_gather": 0}
     else:
-        assert counted == bytes_sharded.exchange_bytes(
+        # a pair's centre row (h) and its d_center, rows as they rest, and
+        # its 1 + NEG logits: no syn1 row, so under a third of the seven
+        # row blocks the benchmark's numerator still counts
+        assert counted == 4 * PAIRS * (2 * D_REST + 1 + NEG)
+        assert 3 * counted < bytes_sharded.exchange_bytes(
             BATCH, WINDOW, NEG, D_REST, n_model)
-        # what a chip must send at least: an all-reduce of S bytes twice
-        # (n - 1) / n x S, a reduce-scatter of S handed, or an all-gather
-        # of S gathered, once
-        slots = eng.packed_pair_slots(n_data * PAIRS)
-        others = n_model - 1
+        # what a chip must send at least: all the step has is all-reduces,
+        # of S bytes twice (n - 1) / n x S
         assert sent == {
-            "all_reduce": 2 * others * 4 * PAIRS * D_REST // n_model,
-            "reduce_scatter": (
-                others * 4 * slots * (1 + NEG) * D_REST // n_model),
-            "all_gather": others * 4 * slots * (D_REST + 2 + NEG) // n_model,
-        }
-        assert sum(sent.values()) < bytes_sharded.all_reduce_wire_bytes(
+            "all_reduce": 2 * (n_model - 1) * counted // n_model,
+            "reduce_scatter": 0, "all_gather": 0}
+        assert sent["all_reduce"] == bytes_sharded.all_reduce_wire_bytes(
             counted, n_model)
-    if shape == (1, 4):
-        # ... and is what the compiled step hands its row collectives: the
-        # all-reduce its rows, a reduce-scatter n times the rows it hands
-        # back, of which the slots that pad a pair slice name no row
+    if n_model > 1 and n_data == 1:
+        # ... and is what the compiled step hands its collectives under
+        # the scope, shape by shape
         found = [f for f in collectives(shape) if "glint.exchange" in f[1]]
-
-        def rows(kind):
-            return sum(int(n) for f in found if f[3] == kind
-                       for n in re.findall(r"f32\[(\d+),%d\]" % D_REST, f[0]))
-
-        handed = rows("all-reduce") + n_model * rows("reduce-scatter")
-        padding = (1 + NEG) * (slots - PAIRS)
-        assert 4 * (handed - padding) * D_REST == counted
-        assert others * 4 * rows("all-gather") * D_REST // n_model == (
-            sent["all_gather"] - others * 4 * slots * (2 + NEG) // n_model)
+        assert {f[3] for f in found} == {"all-reduce"}
+        assert 4 * sum(
+            int(np.prod([int(n) for n in dims.split(",")]))
+            for f in found for dims in re.findall(r"f32\[([\d,]+)\]", f[0])
+        ) == counted
 
 
 def test_fit_reports_the_exchange(tmp_path):
@@ -370,11 +404,10 @@ def test_fit_reports_the_exchange(tmp_path):
             "exchange_send_bytes_per_step"]
         model.stop()
     assert seen[1] == 0 and set(sent[1].values()) == {0}
-    # the step's collectives by kind, and under seven all-reduces' bytes
-    assert set(sent[4]) == {"all_reduce", "reduce_scatter", "all_gather"}
-    assert 0 < min(sent[4].values())
-    assert sum(sent[4].values()) < bytes_sharded.all_reduce_wire_bytes(
-        seen[4], 4)
-    assert seen[4] == bytes_sharded.exchange_bytes(
+    # the step's collectives by kind: all-reduces alone, of h, d_center and
+    # the logits, which is under a third of seven row blocks' bytes
+    assert seen[4] == 4 * PAIRS * (2 * D_REST + 1 + NEG)
+    assert sent[4] == {"all_reduce": 3 * seen[4] // 2, "reduce_scatter": 0,
+                       "all_gather": 0}
+    assert 3 * seen[4] < bytes_sharded.exchange_bytes(
         BATCH, WINDOW, NEG, D_REST, 4)
-    assert bytes_sharded.all_reduce_wire_bytes(seen[4], 4) == 1.5 * seen[4]

@@ -25,6 +25,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from test_cbow import assert_two_shards_fit_as_one, seed_syn1  # noqa: E402
+
 from glint_word2vec_tpu.corpus.batching import (  # noqa: E402
     context_width,
     packed_pair_batch,
@@ -144,16 +146,16 @@ def engine(shape, groups=None, bucket=BUCKET, seed=3):
     return eng
 
 
-def run_packed(eng, corpus, seed=3, total_words=5000):
-    """K packed steps from the seed's tables; returns (tables before, the
-    scan's per-step outputs)."""
+def run_packed(eng, corpus, seed=3, total_words=5000, steps=K):
+    """``steps`` packed steps from the seed's tables; returns (tables
+    before, the scan's per-step outputs)."""
     before = (np.asarray(eng.syn0, np.float32)[:, :D],
               np.asarray(eng.syn1, np.float32)[:, :D])
     eng.upload_corpus(*corpus)
     eng.set_keep_probs(np.ones(V, np.float32))
     eng.compact_corpus(jax.random.PRNGKey(9))
     out = eng.train_steps_corpus_packed(
-        0, PAIRS, WINDOW, BATCH, jax.random.PRNGKey(seed), K,
+        0, PAIRS, WINDOW, BATCH, jax.random.PRNGKey(seed), steps,
         step_size=0.025, total_words=total_words)
     return before, out
 
@@ -218,13 +220,15 @@ def test_packed_subword_scan_is_the_grouped_reference(shape):
 
 def test_one_by_one_equals_one_by_two():
     groups = random_groups()
-    seen = []
-    for shape in ((1, 1), (1, 2)):
+
+    def fit(shape, steps, seeded):
         eng = engine(shape, groups)
-        _, out = run_packed(eng, zipf_corpus())
-        seen.append(tables(eng) + (np.asarray(out[0]),))
-    for a, b in zip(*seen):
-        np.testing.assert_array_equal(a, b)
+        if seeded:
+            seed_syn1(eng)
+        before, out = run_packed(eng, zipf_corpus(), steps=steps)
+        return before, tables(eng), [np.asarray(out[0])]
+
+    assert_two_shards_fit_as_one(fit, K)
 
 
 def test_groups_of_one_word_are_the_word_level_scan_bit_for_bit():
@@ -321,11 +325,17 @@ def test_the_grid_scan_forms_its_centres_from_the_group_table():
 # ones). The (1, 1) entries are AS THEY WERE: the one-shard program is
 # emitted by the parent's very ops, which is the proof that the one-chip
 # cells run what they ran.
+# ISSUE 51 meant to change the same entries again (no syn1 row crosses the
+# model axis: the shards all-reduce the pairs' logit partials and their
+# partial `d_center`; ISSUE 49's slices are deleted): the 1 x 2, 2 x 2 and
+# 1 x 4 entries, here and in SUBWORD_PROGRAMS, were taken again on its tree
+# (CHANGES.md keeps ISSUE 49's), and the (1, 1) entries are, once more,
+# untouched.
 WORD_LEVEL_PROGRAMS = {
     ((1, 1), "rows"): ("84c02214c885c063", "124ae8075d865073"),
-    ((1, 2), "rows"): ("3bf83a49f1fd39f3", "d8ee8b0c469fb202"),
-    ((2, 2), "rows"): ("16db240ffed84230", "8669dfc535929469"),
-    ((1, 4), "rows"): ("7501689733c401d8", "af1b9eaf8b029b7a"),
+    ((1, 2), "rows"): ("a1fd7f3b69139e67", "4bc8b68e6e898927"),
+    ((2, 2), "rows"): ("0fd535cd1ee4a741", "259b4ce3ff2db77d"),
+    ((1, 4), "rows"): ("b1351a93da7d9703", "c561b672f436946e"),
 }
 
 
@@ -370,9 +380,9 @@ def test_a_word_level_fit_lowers_to_the_program_it_lowered_to(shape, split):
 # ISSUE 38's, as above.
 SUBWORD_PROGRAMS = {
     ((1, 1), "rows"): ("b4cb57206511569c", "5e7e6d3939851660"),
-    ((1, 2), "rows"): ("46356c178d068fa3", "41acdfbc78de7b1c"),
-    ((2, 2), "rows"): ("094df8e3e0c63b1d", "f9007ed7535872a0"),
-    ((1, 4), "rows"): ("aaf3d6a14819355a", "125b6a0dc0d5b32f"),
+    ((1, 2), "rows"): ("2e048c3abec31589", "70321b9355fd3a3a"),
+    ((2, 2), "rows"): ("309ddb9e453d6de3", "0787886b4b610cbb"),
+    ((1, 4), "rows"): ("0572d737a6ecac22", "bfb34fd4d3e5b9a4"),
 }
 
 

@@ -317,45 +317,61 @@ def test_packed_corpus_scan_at_the_four_chip_cell_size(packed_scans):
     ), mem
     assert mem["args"] < _table_args_ceiling(10_000_000, 4, 256 * 10**6), mem
     # ISSUE 49's parent held 303,918,592 B of temporaries here (seven
-    # all-reduced blocks of 26,215 rows); a shard now holds a quarter of
-    # the six pair-side blocks.
+    # all-reduced blocks of 26,215 rows); the six own-row blocks of the
+    # pair side still stand, ahead of the logits and d_center.
     assert mem["temp"] < 1.5e9 and mem["temp"] <= 303_918_592, mem
-    # What crosses the model axis (ISSUE 49). Of the rows a step pulls only
-    # the centre side, h, is all-reduced: every shard's syn1 scatter wants
-    # every pair's h.
+    # What crosses the model axis (ISSUE 51): no syn1 row. Two row blocks
+    # are all-reduced, h (every shard's syn1 scatter wants every pair's)
+    # and the partial d_center, and between them the pairs' logit
+    # partials; each op under the scope the program gave it. Nothing is
+    # reduce-scattered, gathered or permuted, so the compiler has nothing
+    # to pad and re-cut in ops that carry no scope.
     pairs = packed_pair_batch(BATCH, WINDOW, 1)
-    slots = eng.packed_pair_slots(pairs)
+    assert pairs == 26_215
     text = compiled.as_text()
-    rows = rf"f32\[\d+,{D_REST}\]"
-    exchange = [line for line in text.splitlines()
-                if "glint.exchange" in line]
-    reduced = [re.search(rf"= ({rows})", line).group(1) for line in exchange
-               if re.search(rf"= {rows}\S* all-reduce(-start)?\(", line)]
-    assert reduced == [f"f32[{pairs},{D_REST}]"], reduced
-    # The pair side, a context and five negatives a pair, is
-    # reduce-scattered over the pairs, a block at a time, each op under
-    # the scope the program gave it: the slots are whole spans of the
-    # chip's reduce-scatter (engine._pair_slots), so the compiler pads
-    # none of them (it would re-cut the padded shards with
-    # collective-permutes, in ops that carry no scope, and a trace would
-    # file the exchange under nothing).
-    assert slots == 26_880
-    scattered = [re.search(rf"= ({rows})", line).group(1)
-                 for line in exchange
-                 if re.search(rf"= {rows}\S* reduce-scatter(-start)?\(", line)]
-    assert scattered == [f"f32[{slots // 4},{D_REST}]"] * (1 + NEG), scattered
-    assert "formatting steps: (pad" not in text
-    assert "collective-permute" not in text
-    # d_center comes back by all-gather, and a pair's coefficients with
-    # it; its loss term in a vector of its own (which this compiler
-    # gathers by an all-reduce of zero-filled slots).
-    gathered = [re.search(r"= (f32\[[\d,]+\])", line).group(1)
-                for line in exchange
-                if re.search(r"\S* all-gather(-start)?\(", line)]
-    assert sorted(gathered) == sorted(
-        [f"f32[{slots},{D_REST}]", f"f32[4,{slots // 4},{1 + NEG}]"]
-    ), gathered
-    assert re.search(rf"= f32\[{slots}\]\S* all-(reduce|gather)", text)
+    for op in ("reduce-scatter", "all-gather", "collective-permute",
+               "all-to-all", "formatting steps: (pad"):
+        assert op not in text, op
+    reduced = [line for line in text.splitlines()
+               if re.search(r" all-reduce(-start)?\(", line)]
+    exchange = [re.search(r"= (\w+)\[([\d,]+)\]\{([\d,]+)", line).groups()
+                for line in reduced if "glint.exchange" in line]
+    assert len(exchange) == len(reduced) - 1  # the scatters' s32 counts
+    assert [e for e in exchange if e[1].endswith(f",{D_REST}")] == [
+        ("f32", f"{pairs},{D_REST}", "1,0")] * 2, exchange
+    # The logits cross with the pairs MINOR, as (6, 26215) lies in
+    # memory, whatever order the shape is printed in: tiled (8, 128)
+    # under 1 MB, where pairs-major each pair's six would pay for 128
+    # lanes, 13.4 MB (ISSUE 38's rule).
+    (dtype, dims, minor_to_major), = [
+        e for e in exchange if not e[1].endswith(f",{D_REST}")]
+    dims = [int(n) for n in dims.split(",")]
+    minor, second = (dims[int(i)] for i in minor_to_major.split(","))
+    assert (dtype, minor, second) == ("f32", pairs, 1 + NEG)
+    assert 4 * -(-minor // 128) * 128 * -(-second // 8) * 8 < 1e6
+    # The loss's order of summation (ISSUE 51's first build read
+    # replay.loss_gap 1.26e-6 on the chip, ISSUE 49's the same): the step's
+    # sums to a scalar under glint.grads, the loss's terms and the mask's
+    # count, run over a VECTOR of the pairs in the tiles the one-chip
+    # program's run over, never over a (26215, 1) column that lies in
+    # tiles of (1, 128), which the chip sums in another order.
+    assert _scalar_sums(text) == _scalar_sums(
+        packed_scans("2m-1chip")[1].as_text()
+    ) == [f"f32[{pairs}]{{0:T(1024)}}"] * 2
+
+
+def _scalar_sums(text: str) -> list:
+    """The operands' shapes and tiles (less the memory space the compiler
+    chose for them) of a compiled packed scan's ``reduce`` ops to a float32
+    scalar under ``glint.grads``."""
+    import re
+
+    shape = dict(re.findall(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\]\{[^}]*\})", text, re.M))
+    return sorted(
+        re.sub(r"S\(\d+\)", "", shape[m.group(1)])
+        for m in re.finditer(
+            r"= f32\[\]\S* reduce\((%[\w.\-]+),.*glint\.grads", text))
 
 
 def test_packed_corpus_scan_at_three_million_rows_fits_one_chip(packed_scans):
