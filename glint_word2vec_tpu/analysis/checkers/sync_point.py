@@ -109,9 +109,9 @@ SYNC_SEAMS: Dict[str, str] = {
         "model query surface: stages the host query vector, returns "
         "host (word, score) pairs",
     "glint_word2vec_tpu/models/word2vec.py::"
-    "Word2VecModel.find_synonyms_batch":
-        "model query surface: stages host query vectors, returns host "
-        "(word, score) pairs",
+    "Word2VecModel.top_k_batch":
+        "model query surface (find_synonyms_batch up to its decode): "
+        "stages host query vectors, returns host scores and ids",
     "glint_word2vec_tpu/models/word2vec.py::Word2VecModel.transform":
         "model query surface: returns host vector by contract",
     "glint_word2vec_tpu/models/word2vec.py::"

@@ -175,18 +175,23 @@ class FastTextModel(Word2VecModel):
         power of two. One word costs a ``(1, max_subwords)`` block, a
         coalesced round its Q bucket's, and no call compiles a shape
         :meth:`warm_compose` has not."""
-        n = groups.shape[0]
+        out = np.empty((groups.shape[0], self.vector_size), np.float32)
+        for s, k, g, m in self._compose_blocks(groups, gmask):
+            out[s : s + k] = np.asarray(self._compose_device(g, m))[:k]
+        return out
+
+    def _compose_blocks(self, groups: np.ndarray, gmask: np.ndarray):
+        """``(first row, rows, ids, mask)`` of each block :meth:`_compose`
+        dispatches, the last padded to its power of two."""
         B = self.COMPOSE_BLOCK
-        out = np.empty((n, self.vector_size), np.float32)
-        for s in range(0, n, B):
+        for s in range(0, groups.shape[0], B):
             g, m = groups[s : s + B], gmask[s : s + B]
             k = g.shape[0]
             pad = next_pow2(k) - k
             if pad:
                 g = np.pad(g, ((0, pad), (0, 0)))
                 m = np.pad(m, ((0, pad), (0, 0)))
-            out[s : s + k] = np.asarray(self._compose_device(g, m))[:k]
-        return out
+            yield s, k, g, m
 
     def warm_compose(self, max_rows: Optional[int] = None) -> int:
         """Compile every block shape :meth:`_compose` can dispatch for
@@ -253,7 +258,19 @@ class FastTextModel(Word2VecModel):
         ]
         n = int(ok.sum())
         if n:
-            vecs = self._compose(g[ok], m[ok])
+            # :meth:`_compose`, with the launch and the read-back of its
+            # block as the round's spans (no-ops without a recorder).
+            vecs = np.empty((n, self.vector_size), np.float32)
+            for s, k, gb, mb in self._compose_blocks(g[ok], m[ok]):
+                with obs_events.phase_span(
+                    "req.enqueue", program="pull_average", q=gb.shape[0],
+                    shards=self.engine.num_model,
+                ):
+                    block = self._compose_device(gb, mb)
+                with obs_events.phase_span(
+                    "req.result", program="pull_average"
+                ):
+                    vecs[s : s + k] = np.asarray(block)[:k]
             for i, v in zip(np.flatnonzero(ok), vecs):
                 vectors[i] = v
         return (

@@ -1927,6 +1927,20 @@ class Word2VecModel:
         ``num`` beyond the index's probe capacity (nprobe x member
         slots — thousands at the default geometry) silently routes to
         the exact path: correctness outranks the speedup there."""
+        return [
+            self._decode_hits(s, i)
+            for s, i in zip(*self.top_k_batch(
+                vectors, num, approximate=approximate, ids=ids
+            ))
+        ]
+
+    def top_k_batch(
+        self, vectors: Optional[np.ndarray], num: int, *,
+        approximate: bool = False, ids=None,
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """:meth:`find_synonyms_batch` up to its decode: the ``(Q, num)``
+        scores and row ids on the host. A served round decodes them
+        itself, under a span of its own."""
         if num <= 0:
             raise ValueError("num must be > 0")
         num = min(num, self.vocab.size)
@@ -1940,12 +1954,8 @@ class Word2VecModel:
             )
             approximate = num <= cap
         if approximate:
-            sims, idx = eng.ann_top_k_batch(
-                np.asarray(vectors, np.float32), num
-            )
-        else:
-            sims, idx = eng.top_k_cosine_batch(vectors, num, ids=ids)
-        return [self._decode_hits(s, i) for s, i in zip(sims, idx)]
+            return eng.ann_top_k_batch(np.asarray(vectors, np.float32), num)
+        return eng.top_k_cosine_batch(vectors, num, ids=ids)
 
     def analogy(
         self, positive: Sequence[str], negative: Sequence[str], num: int

@@ -56,8 +56,18 @@ logger = logging.getLogger(__name__)
 #: CI stitch assertions can rely on the vocabulary.
 REQUEST_SPANS = {
     "req.accept": "per-process root span of one request hop "
-                  "(balancer or replica handler, accept to response)",
+                  "(balancer or replica handler, accept to response; the "
+                  "replica's carries `cache`, hit or miss, of a word "
+                  "query and `cpu_ms`, the handler thread's own CPU time "
+                  "inside the span: the wall less it is the time the "
+                  "thread did not run)",
+    "req.head": "http.server's parse of the request line and headers: "
+                "the request line read off the connection to the "
+                "handler's entry, which lies before req.accept",
+    "req.parse": "the body's read off the socket and its json.loads",
     "req.admission": "admission gate: inflight-slot acquire or shed",
+    "req.lookup": "the coalescer's result-cache probe, the wait for its "
+                  "lock included (args: hit)",
     "req.queue": "coalescer queue wait, enqueue to leader drain",
     "req.hop": "balancer -> replica proxy attempt (one per retry hop)",
     "req.grace": "coalescer leader's straggler-absorbing sleeps before a "
@@ -74,8 +84,26 @@ REQUEST_SPANS = {
                    "query words (subword family): host n-gram hashing, "
                    "one bucketed pull_average and its read-back (child "
                    "of req.dispatch; args: words, oov, slots, rows)",
-    "req.query": "engine query path (args carry mode=ann|exact)",
-    "req.readback": "device result harvest / host materialization",
+    "req.enqueue": "one query program of a round put on the device's "
+                   "queue: its arguments built, the jitted call made and "
+                   "returned, the device not yet done (child of "
+                   "req.dispatch; args: program, q, shards)",
+    "req.result": "that program's outputs read back: from the enqueue's "
+                  "return to the results held by the host, which is the "
+                  "device's run, the transfer and the leader's turn at "
+                  "the interpreter lock (child of req.dispatch; args: "
+                  "program)",
+    "req.decode": "the round's pure-Python tail: scores and ids on the "
+                  "host to the results set on the requests, the "
+                  "self-word filter included (child of req.dispatch; "
+                  "args: batch)",
+    "req.query": "engine query path up to the round's last enqueue "
+                 "(args carry mode=ann|exact)",
+    "req.readback": "device result harvest / host materialization: the "
+                    "round's last enqueue returned to its results set",
+    "req.wake": "a finished answer waiting for its handler thread: the "
+                "leader's stamp before it sets the batch's events to the "
+                "waiter's return from its wait",
     "req.serialize": "response serialization + socket write",
 }
 
@@ -202,6 +230,10 @@ class NullRequestTrace:
 
     __slots__ = ("trace_id", "kept")
 
+    #: Nothing is buffered: a site whose reading costs something (the
+    #: thread's CPU clock) takes it on a live trace alone.
+    live = False
+
     def __init__(self, trace_id: str):
         self.trace_id = trace_id
         self.kept = False
@@ -210,6 +242,9 @@ class NullRequestTrace:
         return NULL_SPAN
 
     def add_phase(self, name: str, t0: float, dur: float, **args) -> None:
+        pass
+
+    def annotate(self, **args) -> None:
         pass
 
     def finish(self, status: int = 200, *, force: bool = False) -> bool:
@@ -234,12 +269,15 @@ class RequestTrace:
     ``cli trace-merge`` can stitch hops across processes by id.
     """
 
-    __slots__ = ("trace_id", "_rec", "_spans", "_t0", "kept")
+    __slots__ = ("trace_id", "_rec", "_spans", "_root", "_t0", "kept")
+
+    live = True
 
     def __init__(self, trace_id: str, rec: "EventRecorder"):
         self.trace_id = trace_id
         self._rec = rec
         self._spans: list = []
+        self._root: dict = {}
         self._t0 = time.perf_counter()
         self.kept = False
 
@@ -252,6 +290,12 @@ class RequestTrace:
         coalescer leader stamps perf_counter() pairs into the request
         dict; the waiter thread converts them here)."""
         self._spans.append((name, t0, dur, args))
+
+    def annotate(self, **args) -> None:
+        """Attributes of the hop's root span known only below it (the
+        coalescer knows whether the cache answered, the handler owns
+        ``req.accept``): merged into the root at :meth:`finish`."""
+        self._root.update(args)
 
     def finish(self, status: int = 200, *, force: bool = False) -> bool:
         """Apply tail sampling; flush buffered spans if kept. Returns
@@ -268,7 +312,9 @@ class RequestTrace:
         if not keep:
             return False
         # The root span (req.accept closes last, so it is the final
-        # buffered entry) carries the response status.
+        # buffered entry) carries the response status and what the
+        # layers below noted for it.
+        spans[-1][3].update(self._root)
         spans[-1][3].setdefault("status", int(status))
         for name, t0, dur, args in spans:
             a = dict(args)

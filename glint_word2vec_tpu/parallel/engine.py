@@ -1539,6 +1539,10 @@ class EmbeddingEngine:
         #: query op counts its shape once): what a served round reads
         #: before and after itself for its span's ``programs``.
         self.query_dispatches: int = 0
+        #: ``perf_counter()`` at which the last batch top-k's launch
+        #: returned: where a served round's read-back begins
+        #: (``req.readback`` of the requests it carried).
+        self.query_enqueued_at: float = 0.0
         #: First-seen shapes on THIS engine whose program was already
         #: compiled process-wide by a same-geometry engine (the shared
         #: warm family, ISSUE 20): a ``query_compiles`` tick that cost
@@ -2847,12 +2851,24 @@ class EmbeddingEngine:
         # (each query row ranks independently).
         q_b = self._q_bucket(n)
         self._count_query_shape("topk_batch", q_b, k_b)
-        val, idx = self._topk_batch_cache[k_b](
-            self.syn0, self._query_block(vecs, q_b),
-            self._query_ids(ids, q_b), self.norms(),
-            np.int32(self.queryable_rows),
-        )
-        return np.asarray(val)[:n, :kk], np.asarray(idx)[:n, :kk]
+        # The launch and the read-back are two spans of a served round
+        # (no-ops without a recorder): the first is what the host pays to
+        # put the program on the device's queue, the second the device's
+        # run, the transfer and this thread's turn at the interpreter
+        # lock.
+        with obs_events.phase_span(
+            "req.enqueue", program="topk_batch", q=q_b,
+            shards=self.num_model,
+        ):
+            val, idx = self._topk_batch_cache[k_b](
+                self.syn0, self._query_block(vecs, q_b),
+                self._query_ids(ids, q_b), self.norms(),
+                np.int32(self.queryable_rows),
+            )
+        self.query_enqueued_at = time.perf_counter()
+        with obs_events.phase_span("req.result", program="topk_batch"):
+            val, idx = np.asarray(val), np.asarray(idx)
+        return val[:n, :kk], idx[:n, :kk]
 
     # ------------------------------------------------------------------
     # Approximate top-k (device-resident ANN index, ISSUE 12)

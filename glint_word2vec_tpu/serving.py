@@ -249,7 +249,8 @@ class _SynonymCoalescer:
     single-query dispatches (QPS flat in N). Here every waiting request
     lands in a pending list; whichever thread next wins the device lock
     becomes leader, drains the list, answers ALL of them with ONE
-    ``find_synonyms_batch`` dispatch per ``max_batch`` chunk (the batch
+    ``top_k_batch`` dispatch (``find_synonyms_batch`` up to its decode,
+    which the round does itself) per ``max_batch`` chunk (the batch
     top-k the reference lacks — it loops findSynonyms, ml:375-420; a
     dictionary word rides it as its row id, which the program gathers
     for itself, so no row visits the host), and wakes the waiters. Exclusion
@@ -399,9 +400,12 @@ class _SynonymCoalescer:
         # accounting never saw.
         mode = "exact" if (exact or not self.ann_active()) else "ann"
         if word is not None and self.cache_size:
-            with self._mu:
-                self._cache_sync_locked()
-                hit = self._cache.get((word, num, mode))
+            with tr.phase("req.lookup") as lookup:
+                with self._mu:
+                    self._cache_sync_locked()
+                    hit = self._cache.get((word, num, mode))
+                lookup.update(hit=hit is not None)
+            tr.annotate(cache="miss" if hit is None else "hit")
             if self.metrics is not None:
                 self.metrics.record_cache(hit is not None)
             if hit is not None:
@@ -476,14 +480,20 @@ class _SynonymCoalescer:
             req["event"].wait()
         if req.get("t_dis0") is not None:
             # Leader-stamped dispatch window -> this request's phases:
-            # queue wait (enqueue to leader drain), the device query
-            # window, and the host materialization tail.
+            # queue wait (enqueue to leader drain), the query up to the
+            # round's last launch, the read-back and decode of its
+            # results, and how long the finished answer then waited for
+            # this thread to run.
+            t_woken = time.perf_counter()
             tr.add_phase("req.queue", req["t_enq"],
                          req["t_dis0"] - req["t_enq"])
             tr.add_phase("req.query", req["t_dis0"],
-                         req["t_dis1"] - req["t_dis0"], mode=mode)
-            tr.add_phase("req.readback", req["t_dis1"],
-                         req["t_rb1"] - req["t_dis1"])
+                         req["t_rb0"] - req["t_dis0"], mode=mode)
+            tr.add_phase("req.readback", req["t_rb0"],
+                         req["t_rb1"] - req["t_rb0"])
+            if req.get("t_wake") is not None:
+                tr.add_phase("req.wake", req["t_wake"],
+                             t_woken - req["t_wake"])
         if req["error"] is not None:
             raise req["error"]
         return req["result"]
@@ -564,7 +574,11 @@ class _SynonymCoalescer:
                 if r["error"] is None and r["result"] is None:
                     r["error"] = e
         finally:
+            # One stamp a batch: a waiter's ``req.wake`` runs from here
+            # to its return from ``event.wait()``.
+            t_wake = time.perf_counter()
             for r in live:
+                r["t_wake"] = t_wake
                 r["event"].set()
 
     def _dispatch(self, chunk, mode: str = "exact") -> None:
@@ -633,14 +647,26 @@ class _SynonymCoalescer:
                 r["num"] + (1 if r["word"] is not None else 0)
                 for r in chunk
             )
-            hits = m.find_synonyms_batch(
+            sims, idx = m.top_k_batch(
                 vectors, min(k, m.vocab.size),
                 approximate=(mode == "ann"), ids=ids,
             )
+            # The read-back began where the batch top-k's launch returned
+            # (the approximate search stamps none: there it is the
+            # decode alone).
+            t_rb0 = qeng.query_enqueued_at
+            if t_rb0 < t_dis0:
+                t_rb0 = time.perf_counter()
+            with obs_events.phase_span("req.decode", batch=len(chunk)):
+                for r, sc, ix in zip(chunk, sims, idx):
+                    hs = m._decode_hits(sc, ix)
+                    if r["word"] is not None:
+                        hs = [(w, s) for w, s in hs if w != r["word"]]
+                    r["result"] = hs[: r["num"]]
+            t_rb1 = time.perf_counter()
             span.update(
                 programs=sum(e.query_dispatches for e in engines) - launched
             )
-        t_dis1 = time.perf_counter()
         if self.metrics is not None:
             self.metrics.record_batch(len(chunk))
             if mode == "ann":
@@ -661,16 +687,11 @@ class _SynonymCoalescer:
                     self.metrics.record_exact_fallback(
                         len(chunk) - n_req, "gate"
                     )
-        for r, hs in zip(chunk, hits):
-            if r["word"] is not None:
-                hs = [(w, s) for w, s in hs if w != r["word"]]
-            r["result"] = hs[: r["num"]]
-        t_rb1 = time.perf_counter()
         for r in chunk:
             # Dispatch-window stamps the waiter threads convert into
             # their own queue/query/readback phases (single-writer per
             # trace: only the owning waiter touches its RequestTrace).
-            r["t_dis0"], r["t_dis1"], r["t_rb1"] = t_dis0, t_dis1, t_rb1
+            r["t_dis0"], r["t_rb0"], r["t_rb1"] = t_dis0, t_rb0, t_rb1
         if self.cache_size:
             with self._mu:
                 if self._cache_sync_locked() != ver:
@@ -1419,6 +1440,13 @@ class ModelServer:
             def log_message(self, fmt, *args):  # route to logging, not stderr
                 logger.debug("serve: " + fmt, *args)
 
+            def parse_request(self):
+                # The request line has just arrived on the keep-alive
+                # connection: what http.server does from here to
+                # do_POST's entry is the request's ``req.head``.
+                self._t_head = time.perf_counter()
+                return super().parse_request()
+
             def _send(self, code: int, obj, headers=None) -> None:
                 tr = getattr(self, "_trace", None) or obs_events.NULL_TRACE
                 with tr.phase("req.serialize"):
@@ -1577,9 +1605,24 @@ class ModelServer:
                         time.perf_counter() - t0, 404,
                     )
                     return
+                if tr.live:
+                    tr.add_phase("req.head", self._t_head,
+                                 time.perf_counter() - self._t_head)
                 try:
                     with tr.phase("req.accept", path=path):
-                        self._handle_post(path, entry)
+                        # The handler thread's own CPU clock, read on a
+                        # live trace alone: the span's wall less its
+                        # ``cpu_ms`` is the time this thread did not run
+                        # (the interpreter lock, the coalescer's lock,
+                        # the socket).
+                        cpu0 = time.thread_time() if tr.live else None
+                        try:
+                            self._handle_post(path, entry)
+                        finally:
+                            if cpu0 is not None:
+                                tr.annotate(cpu_ms=round(
+                                    (time.thread_time() - cpu0) * 1e3, 4
+                                ))
                 finally:
                     kept = tr.finish(self._status)
                     server._observe_request(
@@ -1590,8 +1633,9 @@ class ModelServer:
 
             def _handle_post(self, path, entry):
                 try:
-                    n = int(self.headers.get("Content-Length", 0))
-                    req = json.loads(self.rfile.read(n) or b"{}")
+                    with self._trace.phase("req.parse"):
+                        n = int(self.headers.get("Content-Length", 0))
+                        req = json.loads(self.rfile.read(n) or b"{}")
                 except (ValueError, json.JSONDecodeError) as e:
                     return self._send(400, {"error": f"bad request: {e}"})
                 if path in _DEVICE_PATHS:

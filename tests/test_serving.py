@@ -443,8 +443,8 @@ def test_synonym_cache_hit_invalidation_and_bound(served):
     )
     w = model.vocab.words[0]
     dispatches = []
-    orig = model.find_synonyms_batch
-    model.find_synonyms_batch = (
+    orig = model.top_k_batch
+    model.top_k_batch = (
         lambda *a, **k: dispatches.append(1) or orig(*a, **k)
     )
     try:
@@ -469,7 +469,7 @@ def test_synonym_cache_hit_invalidation_and_bound(served):
             co.query(word=model.vocab.words[i], num=3)
         assert len(co._cache) <= 2
     finally:
-        model.find_synonyms_batch = orig
+        model.top_k_batch = orig
 
 
 def test_cache_disabled_always_dispatches(served):
@@ -481,8 +481,8 @@ def test_cache_disabled_always_dispatches(served):
     co = _SynonymCoalescer(model, threading.Lock(), cache_size=0)
     w = model.vocab.words[1]
     dispatches = []
-    orig = model.find_synonyms_batch
-    model.find_synonyms_batch = (
+    orig = model.top_k_batch
+    model.top_k_batch = (
         lambda *a, **k: dispatches.append(1) or orig(*a, **k)
     )
     try:
@@ -490,7 +490,7 @@ def test_cache_disabled_always_dispatches(served):
         co.query(word=w, num=4)
         assert len(dispatches) == 2 and not co._cache
     finally:
-        model.find_synonyms_batch = orig
+        model.top_k_batch = orig
 
 
 def test_num_zero_and_negative_match_single_query_semantics(served):

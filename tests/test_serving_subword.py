@@ -491,3 +491,38 @@ def test_compose_span_is_on_the_ring(ft):
     assert (args["oov"], args["words"], args["slots"]) == (1, 0, WIDTH)
     assert 0 < args["rows"] <= WIDTH
     assert "req.compose" in obs_events.REQUEST_SPANS
+
+
+def test_a_composing_round_launches_and_reads_back_twice(ft):
+    """Inside one ``req.dispatch`` the compose's launch and read-back,
+    then the top-k's, then the decode: disjoint, in order, the compose's
+    pair inside ``req.compose``."""
+    server, model = ft
+    recorder = obs_events.EventRecorder(capacity=4096)
+    prev = obs_events.get_recorder()
+    obs_events.set_recorder(recorder)
+    try:
+        _post(server, "/synonyms", {"word": "twiceoutsider", "num": 4})
+    finally:
+        obs_events.set_recorder(prev)
+    events = recorder.events()
+    (dispatch,) = [e for e in events if e["name"] == "req.dispatch"]
+    (compose,) = [e for e in events if e["name"] == "req.compose"]
+    kids = sorted(
+        (e for e in events
+         if e["name"] in ("req.enqueue", "req.result", "req.decode")),
+        key=lambda e: e["ts"])
+    assert [(e["name"], e["args"].get("program")) for e in kids] == [
+        ("req.enqueue", "pull_average"), ("req.result", "pull_average"),
+        ("req.enqueue", "topk_batch"), ("req.result", "topk_batch"),
+        ("req.decode", None)]
+    end = lambda e: e["ts"] + e["dur"]  # noqa: E731
+    assert dispatch["ts"] <= kids[0]["ts"]
+    assert end(kids[-1]) <= end(dispatch) + 0.2
+    for a, b in zip(kids, kids[1:]):
+        assert end(a) <= b["ts"] + 0.2
+    assert compose["ts"] <= kids[0]["ts"]
+    assert end(kids[1]) <= end(compose) + 0.2 <= kids[2]["ts"] + 0.4
+    assert kids[0]["args"]["q"] == 1
+    assert kids[0]["args"]["shards"] == model.engine.num_model
+    assert dispatch["args"]["programs"] == 2

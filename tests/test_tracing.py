@@ -137,6 +137,10 @@ def test_request_span_registry_is_closed():
         "req.accept", "req.admission", "req.queue", "req.hop",
         "req.grace", "req.dispatch", "req.pull", "req.compose",
         "req.query", "req.readback", "req.serialize",
+        # ISSUE 52: the round's launch, read-back and decode; the
+        # request's head, body parse, cache probe and wake
+        "req.enqueue", "req.result", "req.decode",
+        "req.head", "req.parse", "req.lookup", "req.wake",
     }
     assert obs_events.TRACE_HEADER.lower() == "x-glint-trace"
 
@@ -874,7 +878,178 @@ def test_new_request_spans_leave_graftlint_clean():
     assert new == [] and stale == []
     # the two new spans are registered and have call sites, not baselined
     assert not [e for e in entries if e["rule"] == "span-registry"]
-    assert {"req.grace", "req.pull"} <= set(obs_events.REQUEST_SPANS)
+    assert {"req.grace", "req.pull", "req.enqueue", "req.result",
+            "req.decode", "req.head", "req.parse", "req.lookup",
+            "req.wake"} <= set(obs_events.REQUEST_SPANS)
+
+
+# ----------------------------------------------------------------------
+# A served request measured from inside (ISSUE 52): the hit path phase by
+# phase with the thread's CPU time, the round split into its launch, its
+# read-back and its decode
+# ----------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def traced_server():
+    from glint_word2vec_tpu.serving import ModelServer
+
+    model = _untrained_model()
+    server = ModelServer(model, port=0)
+    server.start_background()
+    yield server, model
+    server.stop()
+    model.stop()
+
+
+def _synonyms(server, word, trace_id):
+    req = urllib.request.Request(
+        f"http://{server.host}:{server.port}/synonyms",
+        data=json.dumps({"word": word, "num": 3}).encode(),
+        headers={"Content-Type": "application/json",
+                 obs_events.TRACE_HEADER: trace_id},
+    )
+    with urllib.request.urlopen(req, timeout=60) as r:
+        return json.loads(r.read())
+
+
+def _miss_then_hit(server, monkeypatch, word):
+    """(ring events, phases of the miss, phases of the hit) of one word
+    asked twice with every trace kept; a request's phases by name."""
+    monkeypatch.setattr(obs_events, "_TRACE_SAMPLE_EVERY", 1)
+    rec = obs_events.set_recorder(EventRecorder())
+    first = _synonyms(server, word, "miss-" + word)
+    assert _synonyms(server, word, "hit-" + word) == first
+    # a reply is on the wire before its handler flushes the trace
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline and not any(
+            e["name"] == "req.accept"
+            and e["args"]["trace"] == "hit-" + word for e in rec.events()):
+        time.sleep(0.005)
+    obs_events.set_recorder(None)
+    events = rec.events()
+
+    def phases(trace_id):
+        out = {}
+        for e in events:
+            if e.get("args", {}).get("trace") == trace_id:
+                assert e["name"] not in out, e["name"]
+                out[e["name"]] = e
+        return out
+
+    return events, phases("miss-" + word), phases("hit-" + word)
+
+
+def _end(e):
+    return e["ts"] + e["dur"]
+
+
+def test_kept_hit_holds_its_phases_inside_head_to_accept(
+        traced_server, monkeypatch):
+    server, _ = traced_server
+    _, _, hit = _miss_then_hit(server, monkeypatch, "w5")
+    assert set(hit) == {"req.head", "req.accept", "req.parse",
+                        "req.admission", "req.lookup", "req.serialize"}
+    assert hit["req.lookup"]["args"]["hit"] is True
+    t0, t1 = hit["req.head"]["ts"], _end(hit["req.accept"])
+    for e in hit.values():
+        assert t0 <= e["ts"] and _end(e) <= t1 + 0.2, e["name"]
+    # the head ends where the handler's root span begins
+    assert _end(hit["req.head"]) <= hit["req.accept"]["ts"] + 0.2
+    order = ["req.head", "req.parse", "req.admission", "req.lookup",
+             "req.serialize"]
+    for a, b in zip(order, order[1:]):
+        assert _end(hit[a]) <= hit[b]["ts"] + 0.2, (a, b)
+    accept = hit["req.accept"]["args"]
+    assert accept["cache"] == "hit" and accept["status"] == 200
+    assert 0 <= accept["cpu_ms"] <= hit["req.accept"]["dur"] / 1e3
+
+
+def test_kept_miss_holds_wake_and_a_readback_over_the_result(
+        traced_server, monkeypatch):
+    server, _ = traced_server
+    events, miss, _ = _miss_then_hit(server, monkeypatch, "w6")
+    assert {"req.head", "req.parse", "req.lookup", "req.queue",
+            "req.query", "req.readback", "req.wake",
+            "req.serialize"} <= set(miss)
+    assert miss["req.lookup"]["args"]["hit"] is False
+    assert miss["req.accept"]["args"]["cache"] == "miss"
+    assert miss["req.wake"]["dur"] >= 0
+    # the answer was finished before its handler thread was woken
+    assert _end(miss["req.readback"]) <= miss["req.wake"]["ts"] + 0.2
+    rounds = [e for e in events if e["name"] == "req.dispatch"
+              and "miss-w6" in e["args"]["traces"]]
+    assert len(rounds) == 1
+    result = [e for e in events if e["name"] == "req.result"
+              and rounds[0]["ts"] <= e["ts"] <= _end(rounds[0])]
+    assert len(result) == 1
+    # req.readback means what its registry line says: the round's last
+    # launch returned to the results set, so it covers the read-back
+    rb = miss["req.readback"]
+    assert rb["ts"] <= result[0]["ts"] + 0.2
+    assert _end(result[0]) <= _end(rb) + 0.2
+    assert _end(miss["req.query"]) <= rb["ts"] + 0.2
+    assert _end(rb) <= _end(rounds[0]) + 0.2
+
+
+def _round_children(events, dispatch):
+    """The launch, read-back and decode spans inside one req.dispatch,
+    oldest first."""
+    return sorted(
+        (e for e in events
+         if e["name"] in ("req.enqueue", "req.result", "req.decode")
+         and e["tid"] == dispatch["tid"]
+         and dispatch["ts"] <= e["ts"] and _end(e) <= _end(dispatch) + 0.2),
+        key=lambda e: e["ts"])
+
+
+def test_a_round_is_enqueue_result_decode_disjoint_and_in_order(
+        traced_server, monkeypatch):
+    server, model = traced_server
+    events, _, _ = _miss_then_hit(server, monkeypatch, "w7")
+    (dispatch,) = [e for e in events if e["name"] == "req.dispatch"]
+    kids = _round_children(events, dispatch)
+    assert [e["name"] for e in kids] == [
+        "req.enqueue", "req.result", "req.decode"]
+    for a, b in zip(kids, kids[1:]):
+        assert _end(a) <= b["ts"] + 0.2
+    assert kids[0]["args"] == {"program": "topk_batch", "q": 1,
+                               "shards": model.engine.num_model}
+    assert kids[1]["args"] == {"program": "topk_batch"}
+    assert kids[2]["args"] == {"batch": 1}
+
+
+def test_no_recorder_no_request_trace_and_no_cpu_clock(
+        traced_server, monkeypatch):
+    server, _ = traced_server
+
+    def never(*a, **k):
+        raise AssertionError("called without a recorder")
+
+    obs_events.set_recorder(None)
+    monkeypatch.setattr(time, "thread_time", never)
+    monkeypatch.setattr(obs_events.RequestTrace, "__init__", never)
+    monkeypatch.setattr(obs_events._Span, "__init__", never)
+    first = _synonyms(server, "w8", "a")   # a miss: a whole round
+    assert len(first) == 3 and _synonyms(server, "w8", "b") == first
+
+
+def test_plain_find_synonyms_batch_records_nothing_without_a_recorder():
+    model = _untrained_model()
+    try:
+        obs_events.set_recorder(None)
+        before = model.engine.query_enqueued_at
+        hits = model.find_synonyms_batch(None, 3, ids=[1, 2])
+        assert [len(h) for h in hits] == [3, 3]
+        assert model.engine.query_enqueued_at > before
+        # with one, outside any server, the launch and the read-back are
+        # two spans and nothing else is
+        rec = obs_events.set_recorder(EventRecorder())
+        model.find_synonyms_batch(None, 3, ids=[1, 2])
+        assert [e["name"] for e in rec.events()
+                if e["ph"] == "X"] == ["req.enqueue", "req.result"]
+    finally:
+        model.stop()
 
 
 _PACKED_CASES = pytest.mark.parametrize(
