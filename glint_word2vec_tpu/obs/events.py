@@ -70,11 +70,12 @@ REQUEST_SPANS = {
                   "lock included (args: hit)",
     "req.queue": "coalescer queue wait, enqueue to leader drain",
     "req.hop": "balancer -> replica proxy attempt (one per retry hop)",
-    "req.grace": "coalescer leader's straggler-absorbing sleeps before a "
-                 "dispatch (args: batch before and after)",
     "req.dispatch": "warm-bucket device dispatch of one coalesced batch "
-                    "(args: batch, mode, shards, traces, and `programs`, "
-                    "the query programs the round launched)",
+                    "(args: batch, mode, shards, traces, `programs`, the "
+                    "query programs the round launched, and, only where "
+                    "the round before named this one's leader, "
+                    "`handoff_ms`: that round's events set to this "
+                    "round's drain)",
     "req.pull": "what is left of the dispatch's row pull on the host: the "
                 "words' row ids built, padded to their bucket and put on "
                 "the device for the top-k to gather from (the approximate "

@@ -56,6 +56,14 @@ chunks at ``MAX_QUERY_ROWS`` exactly like ``transform_words``, and
 compiles the whole family BEFORE binding the port — so the first real
 request (and every later one inside the family) never pays a jit compile.
 
+Synonym queries that miss the result cache are coalesced
+(:class:`_SynonymCoalescer`): one request at a time leads a round, drains
+what is pending the moment the device is free, and names the leader of
+the next round from what arrived meanwhile. No round waits for
+stragglers: a round's time is flat in its batch (the score pass over the
+whole table), so the round in flight is the batching window, and a
+waiter waits on its answer, never on the device lock.
+
 Both model families ride that path. A word-level model's query vector is
 a row of its table. A subword (fastText) model's is composed: a
 dictionary word's from the composed word table (``FastTextModel.
@@ -247,17 +255,35 @@ class _SynonymCoalescer:
     Device queries are serialized by the server lock, so under N
     concurrent clients each /synonyms request used to wait for N-1
     single-query dispatches (QPS flat in N). Here every waiting request
-    lands in a pending list; whichever thread next wins the device lock
-    becomes leader, drains the list, answers ALL of them with ONE
-    ``top_k_batch`` dispatch (``find_synonyms_batch`` up to its decode,
-    which the round does itself) per ``max_batch`` chunk (the batch
-    top-k the reference lacks — it loops findSynonyms, ml:375-420; a
-    dictionary word rides it as its row id, which the program gathers
-    for itself, so no row visits the host), and wakes the waiters. Exclusion
-    semantics match find_synonyms exactly (fetch num+1, drop the query
-    word, truncate). Dispatches are shape-bucketed: the engine pads Q to
-    powers of two and rounds k up to its bucket, so every chunk reuses a
-    pre-warmed compiled program.
+    lands in a pending list and waits on its own event; ONE of them at a
+    time leads: it takes the device lock, drains the list, answers ALL
+    of them with ONE ``top_k_batch`` dispatch (``find_synonyms_batch`` up
+    to its decode, which the round does itself) per ``max_batch`` chunk
+    (the batch top-k the reference lacks — it loops findSynonyms,
+    ml:375-420; a dictionary word rides it as its row id, which the
+    program gathers for itself, so no row visits the host), and wakes
+    the waiters. Exclusion semantics match find_synonyms exactly (fetch
+    num+1, drop the query word, truncate). Dispatches are shape-bucketed:
+    the engine pads Q to powers of two and rounds k up to its bucket, so
+    every chunk reuses a pre-warmed compiled program.
+
+    The hand-off between rounds (ISSUE 53). The round in flight IS the
+    batching window: a leader drains what is pending the moment it holds
+    the device, with no wait for stragglers, and whatever arrives during
+    its round rides the next one. The leader is chosen under ``_mu``,
+    never by a race for the device lock: a miss that finds nobody
+    leading leads; a leader that ends its round with requests pending
+    names one of them leader (wakes that thread alone to lead) and
+    returns to its own caller. So a leader never runs a second round
+    with its own answer in hand, and an answered waiter never touches
+    the device lock: one acquisition a round. What the policy rests on:
+    a round's time is flat in its batch (on the chip it is the score
+    pass over the whole table, at Q = 1 or 16 alike), so a request that
+    misses a drain waits at most one round, and holding a round back to
+    fill it costs everybody more than it spares the one. Where a
+    round's cost does grow with its batch (a CPU back end; a table small
+    enough that the top-k and not the score pass sets the time) rounds
+    that queue behind a busy device still fill by themselves.
 
     Two families batch. The base word-level family: a query word's
     vector is its row of the table. The subword family
@@ -285,17 +311,10 @@ class _SynonymCoalescer:
         self.metrics = metrics
         self._mu = threading.Lock()
         self._pending: list = []
-        #: Straggler-consolidation grace (seconds). When a drained batch
-        #: already shows concurrency (>= 2 waiters), the leader briefly
-        #: sleeps — releasing the GIL so handler threads mid-read can
-        #: enqueue — and re-drains before dispatching. Under a closed
-        #: loop of N clients the round otherwise fragments: the leader
-        #: catches the first few arrivals and each straggler serializes
-        #: a full extra device round behind it (a ~2.7x p95/p50 gap at
-        #: 16 clients, SERVING_BENCH). A few ms of grace is noise next
-        #: to the batched dispatch it merges into; batches of 1 (the
-        #: low-concurrency path) never pay it.
-        self.batch_grace = 0.002
+        #: The request that leads now or has been named to lead next
+        #: (under ``_mu``); None where nobody does and the next miss
+        #: leads its own round.
+        self._leader: Optional[dict] = None
         #: Bounded (word, num) -> result cache for the base word family.
         #: Synonym traffic over a vocabulary is zipfian, so a hot set a
         #: tiny fraction of vocab_size absorbs most of the load without
@@ -348,7 +367,7 @@ class _SynonymCoalescer:
         if deadline is None:
             return self.device_lock.acquire()
         return self.device_lock.acquire(
-            timeout=deadline - time.monotonic()
+            timeout=max(0.0, deadline - time.monotonic())
         )
 
     def cache_lookup(self, word, num, exact: bool = False):
@@ -414,6 +433,11 @@ class _SynonymCoalescer:
             "word": word, "vector": vector, "num": int(num),
             "event": threading.Event(), "result": None, "error": None,
             "deadline": deadline, "abandoned": False,
+            # ``lead``: set under ``_mu`` where this request leads a
+            # round, by itself on finding nobody leading or by the leader
+            # that names it; ``handoff_from``: the perf_counter() at
+            # which that leader's round was answered.
+            "lead": False, "handoff_from": None,
             "mode": mode, "exact_requested": bool(exact),
             # Tracing (ISSUE 18): the leader stamps dispatch-window
             # perf_counter() pairs onto the dict; THIS waiter thread
@@ -423,61 +447,33 @@ class _SynonymCoalescer:
         }
         with self._mu:
             self._pending.append(req)
-        # Leaders set every batched event BEFORE releasing the device
-        # lock, so a waiter whose result is already in hand must not
-        # queue behind the next leader's whole dispatch (lock convoy —
-        # it showed up as a 7x p95 inflation at 16 clients).
-        if not req["event"].is_set():
-            if self._acquire_device(deadline):
-                try:
+            if self._leader is None:  # nobody leads: this request does
+                self._leader, req["lead"] = req, True
+        if not req["lead"]:
+            # A waiter waits on its answer, never on the device lock.
+            # Its event fires for one of two reasons: a leader answered
+            # it, or a leader whose round it missed named it to lead
+            # the next one (``lead`` set under ``_mu`` first).
+            if not req["event"].wait(
+                None if deadline is None else deadline - time.monotonic()
+            ):
+                # Timed out waiting for a leader. Mark the request
+                # abandoned AND pull it out of the pending list under
+                # the lock, so the list cannot grow without bound while
+                # the device is wedged and a leader that does run spends
+                # no dispatch work on a client that already got its 504.
+                # If the answer, or the lead, landed in the race, take it.
+                with self._mu:
                     if not req["event"].is_set():
-                        with self._mu:
-                            batch, self._pending = self._pending, []
-                        if len(batch) > 1 and self.batch_grace > 0:
-                            # Concurrency detected: absorb stragglers
-                            # until one quiet grace window (or the chunk
-                            # cap) so the whole round rides one bucketed
-                            # dispatch. A request missing the drain
-                            # costs a FULL extra device round; the
-                            # worst-case grace (16ms) is well under one.
-                            with obs_events.phase_span(
-                                "req.grace", batch=len(batch)
-                            ) as grace:
-                                for _ in range(8):
-                                    n0 = len(batch)
-                                    time.sleep(self.batch_grace)
-                                    with self._mu:
-                                        if self._pending:
-                                            batch += self._pending
-                                            self._pending = []
-                                    if (len(batch) == n0
-                                            or len(batch) >= self.max_batch):
-                                        break
-                                grace.update(batch_after=len(batch))
-                        if batch:
-                            self._process(batch)
-                finally:
-                    self.device_lock.release()
-        if deadline is None:
-            req["event"].wait()
-        elif not req["event"].wait(deadline - time.monotonic()):
-            # Timed out waiting for a leader. Mark the request abandoned
-            # AND pull it out of the pending list under the lock, so the
-            # list cannot grow without bound while the device is wedged
-            # (no future leader may ever drain it) and a future leader
-            # that does run spends no dispatch work on a client that
-            # already got its 504. If the result landed in the race,
-            # serve it.
-            with self._mu:
-                if not req["event"].is_set():
-                    req["abandoned"] = True
-                    try:
-                        self._pending.remove(req)
-                    except ValueError:
-                        pass  # a leader already drained it
-            if req["abandoned"]:
-                raise DeadlineExceeded("deadline waiting for dispatch")
-            req["event"].wait()
+                        req["abandoned"] = True
+                        try:
+                            self._pending.remove(req)
+                        except ValueError:
+                            pass  # a leader already drained it
+                if req["abandoned"]:
+                    raise DeadlineExceeded("deadline waiting for dispatch")
+        if req["lead"]:
+            self._lead(req)
         if req.get("t_dis0") is not None:
             # Leader-stamped dispatch window -> this request's phases:
             # queue wait (enqueue to leader drain), the query up to the
@@ -498,6 +494,41 @@ class _SynonymCoalescer:
             raise req["error"]
         return req["result"]
 
+    def _lead(self, req) -> None:
+        """One round, led by ``req``'s thread: the device lock, ONE drain
+        of the pending list with no wait before it, ``_process``, and the
+        hand-off. ``req`` is still pending (a request leaves the list by
+        a drain or by its own abandonment), so the round answers it."""
+        if not self._acquire_device(req["deadline"]):
+            # The device stayed busy past this request's deadline: its
+            # 504, and the lead to whoever is still waiting behind it.
+            with self._mu:
+                req["abandoned"] = True
+                self._pending.remove(req)
+                self._name_next_locked(req["handoff_from"])
+            raise DeadlineExceeded("deadline waiting for device")
+        answered_at = None
+        try:
+            with self._mu:
+                batch, self._pending = self._pending, []
+            handoff_ms = None
+            if req["handoff_from"] is not None:  # back to back
+                handoff_ms = 1e3 * (time.perf_counter() - req["handoff_from"])
+            answered_at = self._process(batch, handoff_ms)
+        finally:
+            with self._mu:
+                self._name_next_locked(answered_at)
+            self.device_lock.release()
+
+    def _name_next_locked(self, handoff_from: Optional[float]) -> None:
+        """Pass the lead on: to the oldest request that arrived during
+        this round, whose thread alone is woken to lead the next one; to
+        nobody where none is pending. Caller holds ``self._mu``."""
+        nxt = self._leader = self._pending[0] if self._pending else None
+        if nxt is not None:
+            nxt["lead"], nxt["handoff_from"] = True, handoff_from
+            nxt["event"].set()
+
     def _cache_sync_locked(self) -> int:
         """Drop every cached result if the tables moved since they were
         computed; returns the version the cache is now valid for.
@@ -508,7 +539,12 @@ class _SynonymCoalescer:
             self._cache_version = ver
         return ver
 
-    def _process(self, batch) -> None:
+    def _process(self, batch, handoff_ms: Optional[float] = None) -> float:
+        """Answer a drained batch and set its events; returns the
+        perf_counter() at which it set them. ``handoff_ms``: where the
+        round's leader was named by the round before, the ms from that
+        round's events being set to this one's drain; it goes on the
+        round's first ``req.dispatch``."""
         m = self.model
         live = []
         now = time.monotonic()
@@ -568,7 +604,10 @@ class _SynonymCoalescer:
             for mode in ("ann", "exact"):
                 group = [r for r in live if r.get("mode", "exact") == mode]
                 for s in range(0, len(group), self.max_batch):
-                    self._dispatch(group[s : s + self.max_batch], mode)
+                    self._dispatch(
+                        group[s : s + self.max_batch], mode, handoff_ms
+                    )
+                    handoff_ms = None
         except Exception as e:  # pragma: no cover - device failure path
             for r in live:
                 if r["error"] is None and r["result"] is None:
@@ -580,8 +619,10 @@ class _SynonymCoalescer:
             for r in live:
                 r["t_wake"] = t_wake
                 r["event"].set()
+        return t_wake
 
-    def _dispatch(self, chunk, mode: str = "exact") -> None:
+    def _dispatch(self, chunk, mode: str = "exact",
+                  handoff_ms: Optional[float] = None) -> None:
         """Answer one <= max_batch slice of the drained batch with one
         bucketed batch top-k dispatch: the exact masked GEMM, which
         gathers its dictionary words' rows itself, or, when ``mode ==
@@ -604,6 +645,8 @@ class _SynonymCoalescer:
             shards=int(getattr(m.engine, "num_model", 1)),
             traces=[r["trace"] for r in chunk if r.get("trace")],
         ) as span:
+            if handoff_ms is not None:
+                span.update(handoff_ms=handoff_ms)
             # The table a word's row comes from is the table its
             # neighbours are scored against: the training table at word
             # level, the composed one for the subword family (composed

@@ -510,3 +510,293 @@ def test_num_zero_and_negative_match_single_query_semantics(served):
         _post(server, "/synonyms_vector",
               {"vector": [0.0] * model.vector_size, "num": 0})
     assert e.value.code == 400
+
+
+# ----------------------------------------------------------------------
+# The hand-off between rounds (ISSUE 53): what is pending rides the next
+# round, a waiter waits on its answer and not on the device lock
+# ----------------------------------------------------------------------
+
+
+class _DeviceLock:
+    """A device lock that counts the coalescer's acquisitions. The test
+    is the "other endpoint": ``hold`` / ``free`` take and give the lock
+    uncounted, and ``keep_at_release`` has it take the device the moment
+    the round in flight lets go."""
+
+    def __init__(self):
+        import threading
+
+        self._lock = threading.Lock()
+        self.acquisitions = 0
+        self.keep_at_release = False
+        self.hold, self.free = self._lock.acquire, self._lock.release
+
+    def acquire(self, timeout=None):
+        ok = (self._lock.acquire() if timeout is None
+              else self._lock.acquire(timeout=timeout))
+        self.acquisitions += ok
+        return ok
+
+    def release(self):
+        if self.keep_at_release:
+            self.keep_at_release = False
+        else:
+            self._lock.release()
+
+
+class _Rounds:
+    """A coalescer of its own over the served model whose first rounds
+    the test holds inside their dispatch, callers on threads of their
+    own, and every ``req.dispatch`` recorded."""
+
+    def __init__(self, model, gated=0, failing=()):
+        import threading
+
+        from glint_word2vec_tpu.obs import events as obs_events
+        from glint_word2vec_tpu.serving import _SynonymCoalescer
+        from glint_word2vec_tpu.utils.metrics import ServingMetrics
+
+        self.model = model
+        self.lock = _DeviceLock()
+        self.metrics = ServingMetrics()
+        self.co = _SynonymCoalescer(
+            model, self.lock, metrics=self.metrics, cache_size=0)
+        self.entered = [threading.Event() for _ in range(gated)]
+        self.opened = [threading.Event() for _ in range(gated)]
+        self.threads, self.answers = {}, {}
+        dispatch, rounds = self.co._dispatch, iter(range(10**6))
+
+        def gated_dispatch(chunk, mode="exact", handoff_ms=None):
+            i = next(rounds)
+            if i < gated:
+                self.entered[i].set()
+                assert self.opened[i].wait(60)
+            if i in failing:
+                raise RuntimeError("the device fell over")
+            return dispatch(chunk, mode, handoff_ms)
+
+        self.co._dispatch = gated_dispatch
+        self._events = obs_events
+        self._prev = obs_events.get_recorder()
+        self.recorder = obs_events.set_recorder(
+            obs_events.EventRecorder(capacity=4096))
+
+    def close(self):
+        for gate in self.opened:
+            gate.set()
+        self._events.set_recorder(self._prev)
+
+    def ask(self, name, timeout=None, enqueued=True, **kw):
+        """Start a caller; returns once its request is pending (not
+        awaited of one that leads at once: it drains itself)."""
+        import threading
+
+        def call():
+            try:
+                self.answers[name] = self.co.query(deadline=(
+                    None if timeout is None
+                    else time.monotonic() + timeout), **kw)
+            except Exception as e:  # the request's own error
+                self.answers[name] = e
+
+        before = len(self.co._pending)
+        self.threads[name] = threading.Thread(target=call)
+        self.threads[name].start()
+        if enqueued:
+            self.until(lambda: len(self.co._pending) > before
+                       or name in self.answers)
+
+    def ask_word(self, name, i, **kw):
+        self.ask(name, word=self.model.vocab.words[i], num=3 + i % 3, **kw)
+
+    @staticmethod
+    def until(cond):
+        t_end = time.monotonic() + 60
+        while not cond():
+            assert time.monotonic() < t_end
+            time.sleep(0.002)
+
+    def join(self, *names):
+        for name in names:
+            self.threads[name].join(timeout=60)
+            assert not self.threads[name].is_alive(), name
+
+    def right(self, name, i):
+        want = self.model.find_synonyms(self.model.vocab.words[i], 3 + i % 3)
+        got = self.answers[name]
+        assert isinstance(got, list), got
+        assert [w for w, _ in got] == [w for w, _ in want]
+
+    def dispatches(self):
+        return [e for e in self.recorder.events()
+                if e["name"] == "req.dispatch"]
+
+    def idle(self):
+        return self.co._pending == [] and self.co._leader is None
+
+
+def _two_rounds(h):
+    """``a`` leads a round; ``b`` then ``c`` arrive while it is in
+    flight and ride the next one, held in its dispatch until ``a``'s
+    thread has returned."""
+    h.ask_word("a", 0, enqueued=False)
+    assert h.entered[0].wait(60)
+    h.ask_word("b", 1)
+    h.ask_word("c", 2)
+    h.opened[0].set()
+    assert h.entered[1].wait(60)
+    h.join("a")  # back at its caller while the second round is in flight
+    h.opened[1].set()
+    h.join("b", "c")
+    for name, i in (("a", 0), ("b", 1), ("c", 2)):
+        h.right(name, i)
+    return h.dispatches()
+
+
+def _case_rides_next_round(h):
+    first, second = _two_rounds(h)
+    assert first["args"]["batch"] == 1 and second["args"]["batch"] == 2
+    assert "handoff_ms" not in first["args"]
+    assert 0 <= second["args"]["handoff_ms"] < 60e3
+    # the second round is led by one of those who missed the first
+    assert first["tid"] != second["tid"]
+    assert second["ts"] >= first["ts"] + first["dur"]
+    assert h.idle()
+
+
+def _case_one_lock_acquisition_a_round(h):
+    # an answered waiter (c) never touches the device lock, and neither
+    # does a leader with its answer in hand
+    assert len(_two_rounds(h)) == 2
+    assert h.lock.acquisitions == 2
+
+
+def _case_waiter_deadline(h):
+    from glint_word2vec_tpu.serving import DeadlineExceeded
+
+    h.lock.hold()
+    h.ask_word("a", 0)  # leads, and waits for the device
+    h.ask_word("b", 1, timeout=0.2)
+    h.join("b")
+    assert isinstance(h.answers["b"], DeadlineExceeded)
+    assert [r["word"] for r in h.co._pending] == [h.model.vocab.words[0]]
+    h.lock.free()
+    h.join("a")
+    h.right("a", 0)
+    (only,) = h.dispatches()  # no dispatch slot for the 504
+    assert only["args"]["batch"] == 1 and h.idle()
+
+
+def _case_leader_deadline_passes_lead_on(h):
+    from glint_word2vec_tpu.serving import DeadlineExceeded
+
+    h.lock.hold()
+    h.ask_word("a", 0, timeout=0.3)  # leads; the device stays busy
+    h.ask_word("b", 1)
+    h.ask_word("c", 2)
+    h.join("a")
+    assert isinstance(h.answers["a"], DeadlineExceeded)
+    h.until(lambda: h.co._leader is not None and h.co._leader["lead"])
+    assert len(h.co._pending) == 2
+    h.lock.free()
+    h.join("b", "c")
+    h.right("b", 1)
+    h.right("c", 2)
+    (only,) = h.dispatches()
+    # named by a leader that never ran a round: no hand-off to measure
+    assert only["args"]["batch"] == 2 and "handoff_ms" not in only["args"]
+    assert h.lock.acquisitions == 1 and h.idle()
+
+
+def _case_named_leader_deadline_passes_lead_on(h):
+    from glint_word2vec_tpu.serving import DeadlineExceeded
+
+    h.ask_word("a", 0, enqueued=False)
+    assert h.entered[0].wait(60)
+    h.ask_word("b", 1, timeout=1.5)
+    h.ask_word("c", 2)
+    # another endpoint takes the device the moment the round lets go:
+    # b is named, cannot reach the device by its deadline, and passes
+    # the lead on to c
+    h.lock.keep_at_release = True
+    h.opened[0].set()
+    h.join("a", "b")
+    h.right("a", 0)
+    assert isinstance(h.answers["b"], DeadlineExceeded)
+    h.until(lambda: h.co._leader is not None
+            and h.co._leader["word"] == h.model.vocab.words[2])
+    h.lock.free()
+    h.join("c")
+    h.right("c", 2)
+    first, second = h.dispatches()
+    assert second["args"]["batch"] == 1
+    assert second["args"]["handoff_ms"] > 0  # since a's round ended
+    assert h.idle()
+
+
+def _case_validation_error_fails_alone(h):
+    h.lock.hold()
+    h.ask_word("good", 0)
+    h.ask("bad", vector=["a", "b"], num=3)
+    h.ask("oov", word="notaword_xyz", num=5)
+    h.ask_word("fine", 4)
+    h.lock.free()
+    h.join("good", "bad", "oov", "fine")
+    h.right("good", 0)
+    h.right("fine", 4)
+    assert isinstance(h.answers["bad"], ValueError)
+    assert isinstance(h.answers["oov"], KeyError)
+    (only,) = h.dispatches()
+    assert only["args"]["batch"] == 2
+    assert h.lock.acquisitions == 1 and h.idle()
+
+
+def _case_dispatch_error_strands_nobody(h):
+    # the first round's dispatch raises: its requests take the error,
+    # and those that arrived meanwhile are still named a leader and
+    # answered
+    h.ask_word("a", 0, enqueued=False)
+    assert h.entered[0].wait(60)
+    h.ask_word("b", 1)
+    h.ask_word("c", 2)
+    h.opened[0].set()
+    h.join("a", "b", "c")
+    assert isinstance(h.answers["a"], RuntimeError)
+    h.right("b", 1)
+    h.right("c", 2)
+    (only,) = h.dispatches()  # the failed one never opened its span
+    assert only["args"]["batch"] == 2 and "handoff_ms" in only["args"]
+    assert h.lock.acquisitions == 2 and h.idle()
+
+
+def _case_pending_grows_behind_another_endpoint(h):
+    h.lock.hold()  # /vector, /transform, a training step
+    for i in range(6):
+        h.ask_word(f"r{i}", i)
+    assert len(h.co._pending) == 6
+    h.lock.free()
+    h.join(*(f"r{i}" for i in range(6)))
+    for i in range(6):
+        h.right(f"r{i}", i)
+    assert h.metrics.snapshot()["coalesced_batch_sizes"] == {"6": 1}
+    assert h.lock.acquisitions == 1 and h.idle()
+
+
+@pytest.mark.parametrize("case, gated, failing", [
+    (_case_rides_next_round, 2, ()),
+    (_case_one_lock_acquisition_a_round, 2, ()),
+    (_case_waiter_deadline, 0, ()),
+    (_case_leader_deadline_passes_lead_on, 0, ()),
+    (_case_named_leader_deadline_passes_lead_on, 1, ()),
+    (_case_validation_error_fails_alone, 0, ()),
+    (_case_dispatch_error_strands_nobody, 1, (0,)),
+    (_case_pending_grows_behind_another_endpoint, 0, ()),
+], ids=lambda v: v.__name__[6:] if callable(v) else "")
+def test_hand_off_between_rounds(served, case, gated, failing):
+    _, model = served
+    h = _Rounds(model, gated=gated, failing=failing)
+    try:
+        case(h)
+    finally:
+        h.close()

@@ -135,7 +135,7 @@ def test_trace_id_adoption_and_minting():
 def test_request_span_registry_is_closed():
     assert set(obs_events.REQUEST_SPANS) == {
         "req.accept", "req.admission", "req.queue", "req.hop",
-        "req.grace", "req.dispatch", "req.pull", "req.compose",
+        "req.dispatch", "req.pull", "req.compose",
         "req.query", "req.readback", "req.serialize",
         # ISSUE 52: the round's launch, read-back and decode; the
         # request's head, body parse, cache probe and wake
@@ -824,24 +824,41 @@ def _untrained_model(vocab_size=64, dim=8, mesh=(1, 1), **engine_kw):
     ))
 
 
-def test_coalesced_round_records_grace_and_pull_inside_dispatch():
+def test_coalesced_round_is_one_dispatch_with_no_sleep_before_it(
+        monkeypatch):
+    # The hand-off (ISSUE 53): six callers enqueue behind a held device
+    # lock; on release ONE round of batch 6 is dispatched, with no sleep
+    # before it, and ``req.dispatch`` holds ``req.pull`` on one leader's
+    # lane. Nobody named that leader, so the round carries no
+    # ``handoff_ms``.
     import threading
+    import types
 
+    from glint_word2vec_tpu import serving
     from glint_word2vec_tpu.serving import _SynonymCoalescer
+
+    def no_sleep(seconds):
+        raise AssertionError(f"the coalescer slept {seconds}s")
 
     model = _untrained_model()
     rec = obs_events.set_recorder(EventRecorder())
     lock = threading.Lock()
     co = _SynonymCoalescer(model, lock, cache_size=0)
+    monkeypatch.setattr(serving, "time", types.SimpleNamespace(
+        monotonic=time.monotonic, perf_counter=time.perf_counter,
+        sleep=no_sleep))
+    results = [None] * 6
+
+    def call(i):
+        try:
+            results[i] = co.query(word=f"w{i}", num=3)
+        except BaseException as e:
+            results[i] = e
+
     try:
-        # Hold the device while six callers enqueue: whoever leads next
-        # drains a batch that shows concurrency, so the grace loop runs.
         lock.acquire()
-        callers = [
-            threading.Thread(target=co.query, kwargs={"word": f"w{i}",
-                                                      "num": 3})
-            for i in range(6)
-        ]
+        callers = [threading.Thread(target=call, args=(i,))
+                   for i in range(6)]
         for t in callers:
             t.start()
         deadline = time.monotonic() + 30
@@ -853,19 +870,20 @@ def test_coalesced_round_records_grace_and_pull_inside_dispatch():
         assert not any(t.is_alive() for t in callers)
     finally:
         model.stop()
+    assert all(isinstance(r, list) and len(r) == 3 for r in results), results
     spans = {}
     for e in rec.events():
         if e["ph"] == "X":
             spans.setdefault(e["name"], []).append(e)
-    grace, dispatch, pull = (
-        spans[n][0] for n in ("req.grace", "req.dispatch", "req.pull")
-    )
-    assert grace["args"] == {"batch": 6, "batch_after": 6}
+    assert "req.grace" not in spans
+    (dispatch,), (pull,) = spans["req.dispatch"], spans["req.pull"]
     assert dispatch["args"]["batch"] == 6 and pull["args"] == {"rows": 6}
-    # one leader's lane: grace, then the dispatch that holds the pull
-    assert grace["tid"] == dispatch["tid"] == pull["tid"]
-    assert grace["ts"] + grace["dur"] <= dispatch["ts"] <= pull["ts"]
+    assert "handoff_ms" not in dispatch["args"]
+    # one leader's lane: the dispatch holds the pull
+    assert dispatch["tid"] == pull["tid"]
+    assert dispatch["ts"] <= pull["ts"]
     assert pull["ts"] + pull["dur"] <= dispatch["ts"] + dispatch["dur"]
+    assert co._pending == [] and co._leader is None
 
 
 def test_new_request_spans_leave_graftlint_clean():
@@ -878,7 +896,7 @@ def test_new_request_spans_leave_graftlint_clean():
     assert new == [] and stale == []
     # the two new spans are registered and have call sites, not baselined
     assert not [e for e in entries if e["rule"] == "span-registry"]
-    assert {"req.grace", "req.pull", "req.enqueue", "req.result",
+    assert {"req.pull", "req.enqueue", "req.result",
             "req.decode", "req.head", "req.parse", "req.lookup",
             "req.wake"} <= set(obs_events.REQUEST_SPANS)
 
