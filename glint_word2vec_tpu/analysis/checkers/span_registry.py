@@ -32,11 +32,12 @@ EVENTS_REL = "glint_word2vec_tpu/obs/events.py"
 RULE = "span-registry"
 
 
-def declared_spans(cache: ModuleCache) -> Optional[Dict[str, int]]:
-    """Extract the REQUEST_SPANS registry statically: name ->
-    declaration line. Supports the dict (name -> docstring) form;
-    returns None when the registry cannot be found or is not statically
-    evaluable."""
+def declared_spans(cache: ModuleCache,
+                   registry: str = "REQUEST_SPANS") -> Optional[Dict[str, int]]:
+    """Extract a registry of ``obs/events.py`` (REQUEST_SPANS, or
+    DEVICE_SCOPES) statically: name -> declaration line. Supports the
+    dict (name -> docstring) form; returns None when the registry cannot
+    be found or is not statically evaluable."""
     mod = cache.module(EVENTS_REL)
     if mod is None or mod.tree is None:
         return None
@@ -46,7 +47,7 @@ def declared_spans(cache: ModuleCache) -> Optional[Dict[str, int]]:
             targets = node.targets
         elif isinstance(node, ast.AnnAssign) and node.value is not None:
             targets = [node.target]
-        if not any(isinstance(t, ast.Name) and t.id == "REQUEST_SPANS"
+        if not any(isinstance(t, ast.Name) and t.id == registry
                    for t in targets):
             continue
         value = node.value
@@ -136,4 +137,52 @@ def check_span_registry(cache: ModuleCache) -> List[Finding]:
                      "or drop it from REQUEST_SPANS (and the README "
                      "span table)",
             ))
+    return findings
+
+
+SCOPE_RULE = "scope-registry"
+
+
+@checker(SCOPE_RULE,
+         "every jax.named_scope literal is an entry of the obs/events.py "
+         "DEVICE_SCOPES registry")
+def check_scope_registry(cache: ModuleCache) -> List[Finding]:
+    """The device trace is split by the scopes in an op's metadata: a
+    literal outside the registry is a typo or an unrecorded scope, and
+    its time moves to another metric unseen. One direction only: a scope
+    passed through a variable (a table's name, ``compose``) is chosen at
+    its own site, so whether a registered scope is still opened somewhere
+    cannot be told from the literals."""
+    findings: List[Finding] = []
+    scopes = declared_spans(cache, "DEVICE_SCOPES")
+    events_mod = cache.module(EVENTS_REL)
+    if scopes is None:
+        if events_mod is not None:
+            findings.append(events_mod.finding(
+                SCOPE_RULE, 1,
+                "DEVICE_SCOPES registry missing or not statically "
+                "evaluable in obs/events.py",
+                hint="declare DEVICE_SCOPES = {\"glint.x\": "
+                     "\"docstring\", ...} with literal keys",
+            ))
+        return findings
+    for mod in cache.modules():
+        if mod.tree is None:
+            continue
+        for node in ast.walk(mod.tree):
+            if not isinstance(node, ast.Call) or not node.args:
+                continue
+            name = call_name(node)
+            if name is None or name.rsplit(".", 1)[-1] != "named_scope":
+                continue
+            scope = const_str(node.args[0])
+            if scope is not None and scope not in scopes:
+                findings.append(mod.finding(
+                    SCOPE_RULE, node,
+                    f"device scope {scope!r} is not a DEVICE_SCOPES "
+                    f"registry entry",
+                    hint="add it to obs/events.py DEVICE_SCOPES (with a "
+                         "docstring) or fix the typo; valid: "
+                         + ", ".join(sorted(scopes)),
+                ))
     return findings

@@ -60,6 +60,16 @@ def _add_train(sub):
                         "the corpus-resident path only and is refused "
                         "with --shared-negatives, --packing grid or "
                         "--exchange; saved with the model")
+    p.add_argument("--position-weights", action="store_true",
+                   help="CBOW with position weights (Mikolov et al. LREC "
+                        "2018, arXiv:1712.09405 s2.2; the recipe of the "
+                        "published cc.<lang>.300 fastText tables, Grave "
+                        "et al. arXiv:1802.06893): a trained vector for "
+                        "each relative position of the window multiplies "
+                        "the word a bag holds there before the bag is "
+                        "summed; a third table, (2 x window, vector "
+                        "size), saved with the model. Needs "
+                        "--architecture cbow, with or without --fasttext")
     p.add_argument("--shared-negatives", type=int, default=0,
                    help="shared noise-pool size per step "
                         "(0 = per-pair reference semantics)")
@@ -1470,6 +1480,7 @@ def _run(args) -> int:
             compute_dtype=args.compute_dtype,
             steps_per_call=args.steps_per_call,
             architecture=args.architecture,
+            position_weights=args.position_weights,
             shared_negatives=args.shared_negatives,
             batch_packing=args.packing,
             exchange=args.exchange,

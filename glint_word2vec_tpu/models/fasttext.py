@@ -112,6 +112,7 @@ class FastTextWord2Vec(Word2Vec):
             shared_negatives=p.shared_negatives,
             compute_dtype=p.compute_dtype,
             architecture=p.architecture,
+            position_lanes=2 * p.window if p.position_weights else 0,
         )
 
     def _train_batches(self, engine, group, base_key, step0, alphas):

@@ -305,6 +305,10 @@ class Word2Vec:
         """"skipgram" (default) or "cbow" (see Word2VecParams)."""
         return self._set(architecture=v)
 
+    def set_position_weights(self, v: bool) -> "Word2Vec":
+        """CBOW's position weights (see Word2VecParams)."""
+        return self._set(position_weights=v)
+
     def set_batch_packing(self, v: str) -> "Word2Vec":
         """Device-corpus dispatch shape: "dense" (the default — valid
         (center, context) pairs prefix-sum-compacted into dense
@@ -1246,6 +1250,9 @@ class Word2Vec:
             # HUNG writer raises after the bounded wait instead of
             # pinning fit exit forever (GLINT_CKPT_WAIT_TIMEOUT).
             engine.wait_pending_saves(timeout=_ckpt_wait_timeout())
+            position_table = engine.position_table_stats()
+            if position_table:
+                obs_run.note_end(position_table=position_table)
         except TrainingDiverged:
             engine.wait_pending_saves(
                 reraise=False, timeout=_ckpt_wait_timeout()
@@ -1265,6 +1272,8 @@ class Word2Vec:
         model.training_metrics = {
             **metrics.summary(), "pipeline": "device_corpus",
         }
+        if position_table:
+            model.training_metrics["position_table"] = position_table
         # Step-time attribution (ISSUE 8): where the fit thread's wall
         # went, by phase — the breakdown that replaces eyeballing the
         # single device_stall_seconds proxy. None when obs is off.
@@ -1751,6 +1760,7 @@ class Word2Vec:
             shared_negatives=p.shared_negatives,
             compute_dtype=p.compute_dtype,
             architecture=p.architecture,
+            position_lanes=2 * p.window if p.position_weights else 0,
         )
 
     def _train_batches(self, engine, group: BatchGroup, base_key, step0,

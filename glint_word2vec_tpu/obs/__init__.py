@@ -178,6 +178,9 @@ class _NullRun:
     def observe_losses(self, first_step: int, losses, n_real: int) -> None:
         pass
 
+    def note_end(self, **args) -> None:
+        pass
+
     def close(self, failed: bool = False) -> None:
         pass
 
@@ -243,6 +246,7 @@ class ObsRun:
         self._since_check = 0
         self._aborted = False
         self._closed = False
+        self._end_args: dict = {}
         self.status.update(state="running")
         self.event("run_start", pipeline=pipeline, total_epochs=total_epochs)
         self._write_status(force=True)
@@ -355,6 +359,11 @@ class ObsRun:
         if self.recorder is not None:
             self.recorder.flush()
 
+    def note_end(self, **args) -> None:
+        """What the fit knows only at its end (the position table's
+        state): carried by the ``run_end`` event."""
+        self._end_args.update(args)
+
     def close(self, failed: bool = False) -> None:
         """Idempotent teardown: final state, Chrome-trace export, JSONL
         flush/close, recorder uninstall, final status write, server stop.
@@ -376,7 +385,7 @@ class ObsRun:
         else:
             state = "done"
         self.status.update(state=state)
-        self.event("run_end", state=state)
+        self.event("run_end", state=state, **self._end_args)
         self.ledger.finalize()
         if self.config.steptime_path:
             try:

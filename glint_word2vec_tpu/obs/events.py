@@ -47,6 +47,36 @@ from typing import Optional
 
 logger = logging.getLogger(__name__)
 
+#: The device-scope vocabulary: every ``jax.named_scope`` literal of the
+#: package is a key here. An op's metadata carries the scopes it was traced
+#: under, outermost first, and the device trace is split by them
+#: (``benchmark/program_trace.py`` by the outer ``glint.<phase>``, a
+#: per-layer reader by an inner name lifted into it): a scope renamed or
+#: mistyped at one site silently moves its time to another metric.
+#: graftlint's scope-registry rule holds every literal to this registry.
+DEVICE_SCOPES = {
+    "glint.batch": "a step's batch assembly on the device",
+    "glint.sample": "negative sampling and its masks",
+    "glint.gather": "row gathers (inner: the table) and the data-axis "
+                    "gather of the hidden vectors",
+    "glint.compose": "what a grouped centre or a bag adds to a step "
+                     "(inner: group, bag, posgrad)",
+    "glint.grads": "logits, coefficients, d_center, the loss",
+    "glint.scatter": "the row updates (inner: the table)",
+    "glint.exchange": "the model-axis all-reduces of a step",
+    "glint.query": "a serving pull of query rows",
+    "glint.score": "a shard's scores of its rows",
+    "glint.topk": "a shard's own top-k",
+    "glint.merge": "the model-axis merge of the shards' top-k",
+    "syn0": "inner: the input table's rows",
+    "syn1": "inner: the output table's rows",
+    "group": "inner, under glint.compose: a span word's group summed once",
+    "bag": "inner, under glint.compose: the bags' shifted (weighted) adds "
+           "and their transpose",
+    "posgrad": "inner, under glint.compose: the position table's lane "
+               "reductions, their mean and the table's update",
+}
+
 #: The request-path span vocabulary (ISSUE 18). Every distributed-
 #: tracing instrumentation site (``RequestTrace.phase`` /
 #: ``RequestTrace.add_phase`` / module-level ``phase_span``) MUST name

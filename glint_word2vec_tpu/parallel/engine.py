@@ -494,28 +494,54 @@ TOPK_MIN_Q_BUCKET = 8
 ANN_MAX_Q = 16
 
 
-def _bag_sums(lanes, rows):
+def _lane_weighted(weights, k: int, x):
+    """``x`` as lane k carries it: times row k of the position table
+    ``weights (L, d)``, or as it is where there is none."""
+    return x if weights is None else weights[k] * x
+
+
+def _bag_sums(lanes, rows, weights=None):
     """``(Bl, ...)``: row t the masked sum of a bag's lanes over composed
     ``rows (Bl + reach, ...)``. ``lanes`` is ``(starts, lmask (Bl, L))``:
     lane k of batch row t reads ``rows[t + starts[k]]`` where
     ``lmask[t, k]``. Batch rows are consecutive positions, so a lane is
-    the whole array shifted: L masked adds of slices, no gather."""
+    the whole array shifted: L masked adds of slices, no gather.
+    ``weights (L, d)``, the position table, multiplies lane k's slice by
+    its row k, column by column."""
     starts, lmask = lanes
     Bl = lmask.shape[0]
     return sum(
-        lmask[:, k, None] * rows[a:a + Bl] for k, a in enumerate(starts)
+        lmask[:, k, None] * _lane_weighted(weights, k, rows[a:a + Bl])
+        for k, a in enumerate(starts)
     )
 
 
-def _bag_spread(lanes, e, n_rows: int):
+def _bag_spread(lanes, e, n_rows: int, weights=None):
     """The transpose of :func:`_bag_sums`: ``(n_rows, d)``, row p the sum
-    of ``e[t]`` over the batch rows t whose bag holds p."""
+    of ``e[t]`` (times lane k's row of ``weights``) over the batch rows t
+    whose bag holds p (in lane k)."""
     starts, lmask = lanes
     Bl = e.shape[0]
     return sum(
-        jnp.pad(lmask[:, k, None] * e, ((a, n_rows - Bl - a), (0, 0)))
+        jnp.pad(
+            lmask[:, k, None] * _lane_weighted(weights, k, e),
+            ((a, n_rows - Bl - a), (0, 0)),
+        )
         for k, a in enumerate(starts)
     )
+
+
+def _lane_grads(lanes, rows, e):
+    """The position table's gradient, ``(L, d)``: row k the sum over the
+    batch rows t whose bag holds lane k of (the composed word the lane
+    reads x ``e[t]``), column by column, and ``(L, 1)``, how many batch
+    rows that is. Dense: L reductions over the batch, no scatter."""
+    starts, lmask = lanes
+    Bl = e.shape[0]
+    return jnp.stack([
+        (lmask[:, k, None] * (rows[a:a + Bl] * e)).sum(axis=0)
+        for k, a in enumerate(starts)
+    ]), lmask.sum(axis=0)[:, None]
 
 
 def _pair_payload(ctx_g, negs_g, cpos_g, cneg_g, h_g):
@@ -582,11 +608,25 @@ class EmbeddingEngine:
         shared_negatives: int = 0,
         compute_dtype: Optional[str] = None,
         architecture: str = "skipgram",
+        position_lanes: int = 0,
     ):
         """``architecture`` is the model's (``Word2VecParams.architecture``):
         a ``"cbow"`` engine trains through :meth:`train_steps_corpus_packed`
         alone, whose scan then forms bags (``make_packed_corpus_scan``): of
         words or, while the engine holds a group table, of their groups.
+
+        ``position_lanes`` > 0 (a CBOW engine's alone, ``2 * window`` of
+        the fit it trains) gives the engine a THIRD table, ``posw``
+        ``(position_lanes, d)`` float32: row k the vector that multiplies,
+        column by column, the word a bag reads in lane k before the bag is
+        summed (arXiv:1712.09405 section 2.2), its rows in the order of
+        ``ops/device_batching.bag_lanes``. Dense, replicated on every
+        device of the mesh, started at ones and trained by every step of
+        the bag scan, a row by the mean of its gradient over the step's
+        positions that hold its lane (``step_body_rows``). It is a member
+        of :attr:`table_names`, so whatever walks the tables (save, load,
+        checkpoints, donation, byte counts) walks it; 0 and the engine
+        holds, carries and allocates nothing of it.
 
         ``extra_rows`` appends non-vocabulary rows to both tables (e.g.
         fastText char-ngram buckets, models/fasttext.py): they are trained
@@ -608,6 +648,7 @@ class EmbeddingEngine:
             unigram_table_size=unigram_table_size, seed=seed, dtype=dtype,
             extra_rows=extra_rows, shared_negatives=shared_negatives,
             compute_dtype=compute_dtype, architecture=architecture,
+            position_lanes=position_lanes,
         )
         if counts.shape != (vocab_size,):
             raise ValueError("counts must have shape (vocab_size,)")
@@ -638,6 +679,10 @@ class EmbeddingEngine:
         self.syn0, self.syn1 = jax.jit(_init, out_shardings=(tsh, tsh))(
             jax.random.PRNGKey(seed)
         )
+        if self.position_lanes:
+            self.posw = self._put_table(
+                "posw", np.ones((self.position_lanes, d), np.float32)
+            )
         self._build_jitted_fns()
 
     def _put_alias_table(self, table) -> None:
@@ -658,6 +703,7 @@ class EmbeddingEngine:
         unigram_power: float, unigram_table_size: Optional[int], seed: int,
         dtype: str, extra_rows: int, shared_negatives: int,
         compute_dtype: Optional[str], architecture: str = "skipgram",
+        position_lanes: int = 0,
     ) -> None:
         """The host-only half of construction: validate, and derive every
         attribute the jitted closures capture (geometry, dtypes, step
@@ -677,7 +723,19 @@ class EmbeddingEngine:
                 "architecture='cbow' draws its negatives a position: "
                 "shared_negatives must be 0"
             )
+        if position_lanes < 0 or position_lanes % 2:
+            raise ValueError(
+                "position_lanes must be 2 * window of the fit, or 0"
+            )
+        if position_lanes and architecture != "cbow":
+            raise ValueError(
+                "position weights multiply the words of a CBOW bag: "
+                "position_lanes needs architecture='cbow'"
+            )
         self.architecture = architecture
+        #: Rows of the position table ``posw``; 0: the engine has none.
+        self.position_lanes = int(position_lanes)  # graftlint: ignore[sync-point] host config scalar
+        self.posw = None
         self.mesh = mesh
         self.vocab_size = int(vocab_size)
         self._seed = int(seed)  # graftlint: ignore[sync-point] host config scalar
@@ -729,6 +787,62 @@ class EmbeddingEngine:
         return table_sharding(self.mesh)
 
     # ------------------------------------------------------------------
+    # The table set
+    # ------------------------------------------------------------------
+
+    @property
+    def table_names(self) -> Tuple[str, ...]:
+        """The tables this engine owns, by attribute name, in the order
+        the step programs take and return them. Everything that saves,
+        loads, donates, frees or counts tables walks this."""
+        return ("syn0", "syn1") + (("posw",) if self.position_lanes else ())
+
+    def tables(self) -> dict:
+        """``{name: live array}`` of :attr:`table_names`."""
+        return {name: getattr(self, name) for name in self.table_names}
+
+    def _table_layout(self, name: str):
+        """``(rows, padded rows, sharding, dtype)`` of a table as it rests:
+        ``syn0`` / ``syn1`` split by rows over the model axis in the
+        engine's dtype, ``posw`` whole on every device in float32. All rest
+        in ``padded_dim`` columns and speak ``dim``."""
+        if name == "posw":
+            return (self.position_lanes, self.position_lanes,
+                    NamedSharding(self.mesh, P()), jnp.float32)
+        return (self.num_rows, self.padded_vocab, self._table_sharding(),
+                self._dtype)
+
+    def position_table_stats(self) -> Optional[dict]:
+        """``{"rows", "max_abs_dev", "finite"}`` of the position table,
+        read back whole (it is a few rows): how far its furthest entry
+        lies from the ones it started at, and whether every entry is
+        finite. None for an engine without one."""
+        if not self.position_lanes:
+            return None
+        # graftlint: ignore[sync-point] a (2 * window, d) table, at fit end
+        d = np.asarray(self.posw, dtype=np.float32)[:, : self.dim]
+        finite = bool(np.isfinite(d).all())
+        return {
+            "rows": self.position_lanes,
+            # graftlint: ignore[sync-point] d is the host copy above
+            "max_abs_dev": float(np.abs(d - 1.0).max()) if finite else None,
+            "finite": finite,
+        }
+
+    def _put_table(self, name: str, host: np.ndarray) -> jax.Array:
+        """A host table (unpadded: its rows x ``dim``) to the device as
+        the table ``name`` rests, the padding zero."""
+        rows, padded, sharding, dtype = self._table_layout(name)
+        if host.shape != (rows, self.dim):
+            raise ValueError(f"{name} shape mismatch")
+        # Host array straight to its shards: going through jnp.asarray
+        # first would land the whole table on the default device.
+        full = np.pad(
+            host, ((0, padded - rows), (0, self.padded_dim - self.dim))
+        ).astype(np.float32, copy=False)
+        return jax.device_put(full.astype(dtype, copy=False), sharding)
+
+    # ------------------------------------------------------------------
     # Jitted SPMD program construction
     # ------------------------------------------------------------------
 
@@ -751,7 +865,7 @@ class EmbeddingEngine:
 
         def step_body_rows(syn0_l, syn1_l, noise, centers, cmask,
                            contexts, mask, key, alpha, pair_run=None,
-                           mean_gradient=True, lanes=None):
+                           mean_gradient=True, lanes=None, posw=None):
             # Data-sharded inputs: centers/cmask (Rl, S), contexts/mask
             # (Bl, C). S = subword-group width; word-level training is the
             # S=1 specialization. The center representation is the masked
@@ -775,6 +889,17 @@ class EmbeddingEngine:
             # The whole gradient goes back the same way. Without
             # ``lanes`` a CBOW group is a position's whole bag (the form
             # ``_train_step`` keeps for the tests).
+            # ``posw`` (L, d), with ``lanes`` alone: the position table.
+            # Lane k's composed word is multiplied by row k, column by
+            # column, before the bag is summed, and takes its gradient
+            # through the same row; the row takes (composed word x
+            # gradient), from the tables as they stood before the step,
+            # as its MEAN over the lane's live positions of the step
+            # (``posgrad``): a lane has some 4,400 of them in a batch of
+            # 8,192, all pulling one way, and their SUM, which every
+            # other row of the step takes, left the table at 2,000 after
+            # 64 steps and nothing finite after 96 (PERF.md, PR 54). The
+            # new table is then a fifth output.
             Rl, S = centers.shape
             Bl, C = contexts.shape
             # What a grouped centre or a bag adds to the step has a scope
@@ -828,7 +953,8 @@ class EmbeddingEngine:
                 if lanes is not None:
                     with jax.named_scope("bag"):
                         cnt = jnp.maximum(_bag_sums(lanes, cnt), 1.0)
-                        h = _bag_sums(lanes, h)  # (Bl, d)
+                        composed = h  # (Rl, d): the span's words
+                        h = _bag_sums(lanes, h, posw)  # (Bl, d)
                 h = h / cnt
                 if pair_run is not None:
                     h = h[pair_run]  # (Bl, d)
@@ -954,9 +1080,18 @@ class EmbeddingEngine:
                     d_center = jnp.zeros(
                         (Rl, d_center.shape[1]), jnp.float32
                     ).at[pair_run].add(d_center, indices_are_sorted=True)
+            if posw is not None:
+                # Whole on every model shard already (the pull and the
+                # pair side's exchange both are); the data ranks each
+                # hold their own positions' share.
+                with jax.named_scope(compose), jax.named_scope("posgrad"):
+                    total, positions = lax.psum(
+                        _lane_grads(lanes, composed, d_center), DATA_AXIS
+                    )
+                    posw_new = posw + total / jnp.maximum(positions, 1.0)
             if lanes is not None:
                 with jax.named_scope(compose), jax.named_scope("bag"):
-                    d_center = _bag_spread(lanes, d_center, Rl)
+                    d_center = _bag_spread(lanes, d_center, Rl, posw)
             with jax.named_scope("glint.grads"):
                 dcen_g = lax.all_gather(
                     d_center / cnt if mean_gradient else d_center,
@@ -988,6 +1123,8 @@ class EmbeddingEngine:
                 loss = lax.psum(loss_sum, DATA_AXIS) / jnp.maximum(
                     lax.psum(denom, DATA_AXIS), 1.0
                 )
+            if posw is not None:
+                return syn0_l, syn1_l, loss, written, posw_new
             return syn0_l, syn1_l, loss, written
 
         # A CBOW engine's one-step program is the role-swapped form: the
@@ -1187,8 +1324,7 @@ class EmbeddingEngine:
                     step_size * 1e-4,
                 )
 
-            def steps_to_corpus_end(body, syn0_l, syn1_l, pstart, n_valid,
-                                    counts):
+            def steps_to_corpus_end(body, tables, pstart, n_valid, counts):
                 # Steps 0..K-1 of ``body``, as ``lax.scan`` would run
                 # them, but only those that START inside the view
                 # (``pos < n_valid``): a step past the corpus end trains
@@ -1203,7 +1339,9 @@ class EmbeddingEngine:
                 # 0, and alpha 0, which no step that ran writes (the
                 # rule's floor is ``step_size * 1e-4``): the host counts
                 # the steps the device ran from it. ``counts`` is the
-                # width of the body's fifth output.
+                # width of the body's fifth output. The carry is the
+                # ``tables`` (two, or three with ``posw``) and the
+                # position; they come back first, in their order.
                 bufs = (
                     jnp.zeros(K, jnp.float32), jnp.zeros(K, jnp.int32),
                     jnp.zeros(K, jnp.int32), jnp.zeros(K, jnp.float32),
@@ -1211,7 +1349,7 @@ class EmbeddingEngine:
                 )
 
                 def live(state):
-                    i, (_, _, pos), _ = state
+                    i, (*_, pos), _ = state
                     return (i < K) & (pos < n_valid)
 
                 def step(state):
@@ -1222,22 +1360,24 @@ class EmbeddingEngine:
                         for b, y in zip(bufs, ys)
                     )
 
-                ran, (syn0_l, syn1_l, pos), bufs = lax.while_loop(
-                    live, step,
-                    (jnp.uint32(0), (syn0_l, syn1_l, pstart), bufs),
+                ran, (*tables, pos), bufs = lax.while_loop(
+                    live, step, (jnp.uint32(0), (*tables, pstart), bufs),
                 )
                 losses, n_pairs, pos_ends, alphas, written = bufs
                 pos_ends = jnp.where(
                     jnp.arange(K, dtype=jnp.uint32) < ran, pos_ends, pos
                 )
-                return (syn0_l, syn1_l, losses, n_pairs, pos_ends, alphas,
-                        written)
+                return (*tables, losses, n_pairs, pos_ends, alphas, written)
 
-            def local_bag_packed_scan(syn0_l, syn1_l, noise, ids, sent_of,
-                                      soffs, orig_offs, n_valid, pstart,
-                                      base_key, step0, grid_step0,
-                                      step_size, inv_total_words,
-                                      words_base, groups=None):
+            def local_bag_packed_scan(syn0_l, syn1_l, *rest):
+                # ``rest``: the position table where the engine has one
+                # (third, beside the tables it is donated with), then
+                # ``local_packed_scan``'s arguments from ``noise`` on.
+                posw = rest[:1] if self.position_lanes else ()
+                (noise, ids, sent_of, soffs, orig_offs, n_valid, pstart,
+                 base_key, step0, grid_step0, step_size, inv_total_words,
+                 words_base, *groups) = rest[len(posw):]
+                groups = groups[0] if groups else None
                 # CBOW: step i trains the P consecutive positions from
                 # ``pos``, each rank its own Pl of them, and the roles of
                 # the two tables' index sets are swapped: a position's own
@@ -1261,11 +1401,13 @@ class EmbeddingEngine:
                 # trained; with a group table then the live group ids
                 # gathered, the span words composed, and the input rows:
                 # the rows a bag's mean is over, summed over the positions.
+                # ``posw`` is carried and trained beside the two tables:
+                # lane k of ``starts`` reads its row k.
                 drank = lax.axis_index(DATA_AXIS)
                 starts = [W + o for o in bag_lanes(W)]
 
                 def body(carry, i):
-                    s0, s1, pos = carry
+                    s0, s1, *pw, pos = carry
                     with jax.named_scope("glint.batch"):
                         key = jax.random.fold_in(base_key, step0 + i)
                         c_l, span, lmask, live = bag_span_batch(
@@ -1314,18 +1456,20 @@ class EmbeddingEngine:
                                     input_rows.astype(jnp.int32),
                                 ]), DATA_AXIS,
                             )])
-                    s0, s1, loss, written = step_body_rows(
+                    s0, s1, loss, written, *pw = step_body_rows(
                         s0, s1, noise, words, cmask,
                         c_l[:, None], live[:, None], key, alpha,
                         mean_gradient=False, lanes=lanes,
+                        posw=pw[0] if pw else None,
                     )
-                    return (s0, s1, pos_end), (
+                    return (s0, s1, *pw, pos_end), (
                         loss, formed[0], pos_end, alpha,
                         jnp.concatenate([written, formed]),
                     )
 
                 return steps_to_corpus_end(
-                    body, syn0_l, syn1_l, pstart, n_valid, 9 if G else 6
+                    body, (syn0_l, syn1_l, *posw), pstart, n_valid,
+                    9 if G else 6,
                 )
 
             def local_packed_scan(syn0_l, syn1_l, noise, ids, sent_of, soffs,
@@ -1381,17 +1525,27 @@ class EmbeddingEngine:
                     )
 
                 return steps_to_corpus_end(
-                    body, syn0_l, syn1_l, pstart, n_valid, 6 if G else 4
+                    body, (syn0_l, syn1_l), pstart, n_valid, 6 if G else 4
                 )
 
+            if self.position_lanes not in (0, 2 * W):
+                raise ValueError(
+                    f"the engine's position table has "
+                    f"{self.position_lanes} rows: it trains window "
+                    f"{self.position_lanes // 2}, not {W}"
+                )
+            # The tables lead the arguments and the results, and are
+            # donated; every other table than syn0 and syn1 is replicated.
+            others = (rep,) * (len(self.table_names) - 2)
             return jax.jit(
                 self._shard_map(
                     local_bag_packed_scan if self.architecture == "cbow"
                     else local_packed_scan,
-                    in_specs=(tspec, tspec) + (rep,) * (14 if G else 13),
-                    out_specs=(tspec, tspec, rep, rep, rep, rep, rep),
+                    in_specs=(tspec, tspec) + others
+                    + (rep,) * (14 if G else 13),
+                    out_specs=(tspec, tspec) + others + (rep,) * 5,
                 ),
-                donate_argnums=(0, 1),
+                donate_argnums=tuple(range(len(self.table_names))),
             )
 
         self._make_packed_corpus_scan = make_packed_corpus_scan
@@ -2012,7 +2166,7 @@ class EmbeddingEngine:
             tuple(d.id for d in self.mesh.devices.flat),
             self.mesh.axis_names,
             tuple(self.mesh.shape.items()),
-            self.architecture,
+            self.architecture, self.position_lanes,
             str(self._dtype), str(self._compute_dtype),
             self.num_negatives, self.shared_negatives,
             self.rows_per_shard,
@@ -2146,7 +2300,9 @@ class EmbeddingEngine:
         (fastText's CBOW) and ``rows_written`` is ``(K, 9)``: then the
         live group ids the step gathered, the span words they composed
         (less what lies outside the view), and the input rows, the rows a
-        bag's mean is over, summed over the step's positions.
+        bag's mean is over, summed over the step's positions. An engine
+        with ``position_lanes`` trains its position table in the same
+        steps (``window`` must be the one it was built for).
         """
         if getattr(self, "_corpus", None) is None:
             raise ValueError("no corpus uploaded (call upload_corpus first)")
@@ -2194,8 +2350,9 @@ class EmbeddingEngine:
                 )
             sent_of = self._corpus_sent
             n_valid = getattr(self, "_corpus_n_valid", ids.shape[0])
-        self.syn0, self.syn1, *per_step = fn(
-            self.syn0, self.syn1, self._alias_packed, ids, sent_of, soffs,
+        names = self.table_names
+        out = fn(
+            *self.tables().values(), self._alias_packed, ids, sent_of, soffs,
             self._corpus[1], jnp.int32(n_valid),
             jnp.int32(start_position), base_key, jnp.uint32(step0),
             jnp.uint32(grid_step0), jnp.float32(step_size),
@@ -2203,8 +2360,10 @@ class EmbeddingEngine:
             jnp.float32(words_base),
             *((self._center_groups,) if G else ()),
         )
+        for name, table in zip(names, out):
+            setattr(self, name, table)
         self._tick_tables("train_steps_corpus_packed")
-        return tuple(per_step)
+        return tuple(out[len(names):])
 
     def _packed_center_slots(self, pair_batch: int, window) -> int:
         """``syn0`` rows one packed step pulls and update slots it hands the
@@ -3226,8 +3385,7 @@ class EmbeddingEngine:
         # manifest, and dropped before the next one materializes — peak
         # host memory is one shard, never one table.
         files, meta = self._snapshot_host(
-            self.syn0, self.syn1, mode, deep_copy=False,
-            lazy=(mode == "sharded"),
+            self.tables(), mode, deep_copy=False, lazy=(mode == "sharded"),
         )
         self._write_snapshot(path, files, meta,
                              table_version=self.table_version)
@@ -3284,7 +3442,7 @@ class EmbeddingEngine:
         # (counted as back-pressure): transient host memory stays
         # bounded to one extra table pair.
         writer.wait_for_slot()
-        files, meta = self._snapshot_host(self.syn0, self.syn1, mode)
+        files, meta = self._snapshot_host(self.tables(), mode)
         tv = self.table_version
 
         def job():
@@ -3347,9 +3505,9 @@ class EmbeddingEngine:
             ),
         }
 
-    def _snapshot_host(self, syn0, syn1, mode: str, *,
+    def _snapshot_host(self, tables: dict, mode: str, *,
                        deep_copy: bool = True, lazy: bool = False):
-        """Blocking device->host snapshot of the given table pair:
+        """Blocking device->host snapshot of the given :meth:`tables`:
         returns ``(files, meta)`` where ``files`` is a list of
         ``(filename, ndarray)`` blocks and ``meta`` the ``engine.json``
         manifest dict. With ``deep_copy`` (the async path) every block
@@ -3372,7 +3530,7 @@ class EmbeddingEngine:
             # ONLY its own row block), just deferred: each producer
             # copies its one block at write time.
             shard_files = self._shard_manifest()
-            for name, table in (("syn0", syn0), ("syn1", syn1)):
+            for name, table in tables.items():
                 for fname, produce in self._iter_owned_block_producers(
                     name, table
                 ):
@@ -3384,14 +3542,16 @@ class EmbeddingEngine:
                     ])
         elif mode == "sharded":
             shard_files = self._shard_manifest()
-            for name, table in (("syn0", syn0), ("syn1", syn1)):
+            for name, table in tables.items():
                 for fname, block in self._iter_owned_blocks(name, table):
                     files.append([fname, block])
         elif mode == "single":
-            for name, table in (("syn0", syn0), ("syn1", syn1)):
+            for name, table in tables.items():
                 files.append([
                     f"{name}.npy",
-                    np.asarray(table)[: self.num_rows, : self.dim],
+                    np.asarray(table)[
+                        : self._table_layout(name)[0], : self.dim
+                    ],
                 ])
         else:
             raise ValueError("mode must be 'sharded' or 'single'")
@@ -3442,11 +3602,15 @@ class EmbeddingEngine:
         self._save_split = (int(rank), int(world))  # graftlint: ignore[sync-point] host config
         self._shard_dirty = None  # file geometry changed: all dirty
 
-    def _save_block_rows(self) -> int:
+    def _save_block_rows(self, name: str = "syn0") -> int:
         """Rows a block of the sharded save holds — the one place the
         manifest and the block producers agree on. Under a replica save
         split the block size comes from the split world, not the mesh
-        model axis (every rank addresses every row)."""
+        model axis (every rank addresses every row). A table that
+        :meth:`_table_layout` rests whole on every device is one block."""
+        _, padded_rows, sharding, _ = self._table_layout(name)
+        if not any(sharding.spec):  # split over no axis of the mesh
+            return padded_rows
         if self._save_split is not None:
             _, world = self._save_split
             return max(1, -(-self.padded_vocab // world))
@@ -3457,16 +3621,13 @@ class EmbeddingEngine:
         by the single-process snapshot and the multi-host in-place save
         — identical producers, so checkpoints from either path re-load
         interchangeably."""
-        per_shard = self._save_block_rows()
-        n_blocks = (
-            self._save_split[1] if self._save_split is not None
-            else self.num_model
-        )
-        shard_files = {"syn0": [], "syn1": []}
-        for name in ("syn0", "syn1"):
-            for k in range(n_blocks):
+        shard_files = {name: [] for name in self.table_names}
+        for name in self.table_names:
+            per_shard = self._save_block_rows(name)
+            rows = self._table_layout(name)[0]
+            for k in range(-(-rows // per_shard)):
                 start = k * per_shard
-                stop = min(start + per_shard, self.num_rows)
+                stop = min(start + per_shard, rows)
                 if start >= stop:
                     continue  # pure-padding block
                 shard_files[name].append({
@@ -3484,11 +3645,12 @@ class EmbeddingEngine:
         (:meth:`set_save_split`, tables replicated across ranks) — the
         rank's own row block, device-sliced so no producer ever copies
         more than one block."""
-        per_shard = self._save_block_rows()
+        per_shard = self._save_block_rows(name)
+        rows = self._table_layout(name)[0]
         if self._save_split is not None:
             rank, world = self._save_split
             start = rank * per_shard
-            stop = min(start + per_shard, self.num_rows)
+            stop = min(start + per_shard, rows)
             if start < stop:
                 yield (
                     f"{name}.r{start:012d}.npy",
@@ -3499,9 +3661,9 @@ class EmbeddingEngine:
             if shard.replica_id != 0:
                 continue
             start = shard.index[0].start or 0
-            if start >= self.num_rows:
+            if start >= rows:
                 continue
-            stop = min(start + per_shard, self.num_rows)
+            stop = min(start + per_shard, rows)
 
             def produce(shard=shard, start=start, stop=stop):
                 return np.asarray(shard.data)[: stop - start]
@@ -3533,6 +3695,9 @@ class EmbeddingEngine:
             ),
             "shared_negatives": self.shared_negatives,
             "architecture": self.architecture,
+            # Rows of the position table saved beside the two; a snapshot
+            # without the key (every one before PR 54) holds none.
+            "position_lanes": self.position_lanes,
         }
 
     def _write_snapshot(self, path: str, files, meta: dict,
@@ -3745,7 +3910,7 @@ class EmbeddingEngine:
 
         t0 = time.time()
         os.makedirs(path, exist_ok=True)
-        shard_files = {"syn0": [], "syn1": []}
+        shard_files = {name: [] for name in self.table_names}
         written = []
         t_shards = 0.0
         peak = 0
@@ -3755,7 +3920,7 @@ class EmbeddingEngine:
             # address the block, each block by exactly one process. Blocks
             # are row ranges.
             shard_files = self._shard_manifest()
-            for name, table in (("syn0", self.syn0), ("syn1", self.syn1)):
+            for name, table in self.tables().items():
                 for fname, produce in self._iter_owned_block_producers(
                     name, table
                 ):
@@ -3788,14 +3953,13 @@ class EmbeddingEngine:
             if mode != "single":
                 raise ValueError("mode must be 'sharded' or 'single'")
             if jax.process_index() == 0:
-                syn0 = np.asarray(self.syn0, dtype=np.float32)[
-                    : self.num_rows, : self.dim
-                ]
-                syn1 = np.asarray(self.syn1, dtype=np.float32)[
-                    : self.num_rows, : self.dim
-                ]
-                atomic_write_npy(os.path.join(path, "syn0.npy"), syn0)
-                atomic_write_npy(os.path.join(path, "syn1.npy"), syn1)
+                for name, table in self.tables().items():
+                    atomic_write_npy(
+                        os.path.join(path, f"{name}.npy"),
+                        np.asarray(table, dtype=np.float32)[
+                            : self._table_layout(name)[0], : self.dim
+                        ],
+                    )
         if jax.process_index() == 0:
             counts = np.asarray(self._counts_unpadded(), dtype=np.int64)
             atomic_write_npy(os.path.join(path, "counts.npy"), counts)
@@ -3876,6 +4040,7 @@ class EmbeddingEngine:
                 "shared_negatives", meta.get("shared_negatives", 0)
             ),
             architecture=meta.get("architecture", "skipgram"),
+            position_lanes=meta.get("position_lanes", 0),
         )
         eng.load_tables(path)
         return eng
@@ -3929,10 +4094,17 @@ class EmbeddingEngine:
                 f"d={meta['dim']}), engine has (V={self.vocab_size}, "
                 f"extra={self.num_rows - self.vocab_size}, d={self.dim})"
             )
+        if meta.get("position_lanes", 0) != self.position_lanes:
+            raise ValueError(
+                f"checkpoint at {path} holds a position table of "
+                f"{meta.get('position_lanes', 0)} rows, the engine one of "
+                f"{self.position_lanes}: position_weights and window are "
+                "the model's, saved with it"
+            )
         fmt = meta.get("format", "single")
-        tsh = self._table_sharding()
         staged = {"meta": meta}
-        for name in ("syn0", "syn1"):
+        for name in self.table_names:
+            _, padded_rows, sharding, dtype = self._table_layout(name)
             # Source blocks as (row range, col range, data), covering
             # row-block files (what :meth:`save` writes; a manifest entry
             # with no "axis" is one too), whole-table files and the
@@ -3957,12 +4129,12 @@ class EmbeddingEngine:
                 arr = np.load(os.path.join(path, f"{name}.npy"), mmap_mode="r")
                 blocks = [((0, arr.shape[0]), (0, arr.shape[1]), arr)]
 
-            def assemble(index, _blocks=blocks):
+            def assemble(index, _blocks=blocks, _rows=padded_rows,
+                         _dtype=dtype):
                 row_sl, col_sl = index[0], index[1]
                 r0 = row_sl.start or 0
                 r1 = (
-                    row_sl.stop if row_sl.stop is not None
-                    else self.padded_vocab
+                    row_sl.stop if row_sl.stop is not None else _rows
                 )
                 c0 = col_sl.start or 0
                 c1 = (
@@ -3984,10 +4156,10 @@ class EmbeddingEngine:
                 self._stage_peak_block_bytes = max(
                     self._stage_peak_block_bytes, out.nbytes
                 )
-                return out.astype(self._dtype)
+                return out.astype(_dtype)
 
             staged[name] = jax.make_array_from_callback(
-                (self.padded_vocab, self.padded_dim), tsh, assemble
+                (padded_rows, self.padded_dim), sharding, assemble
             )
         return staged
 
@@ -3998,40 +4170,34 @@ class EmbeddingEngine:
         cache + serving result caches drop). Microseconds — the whole
         point of the split is that this is all the serving hot-swap
         holds the device lock for."""
-        self.syn0 = staged["syn0"]
-        self.syn1 = staged["syn1"]
+        for name in self.table_names:
+            setattr(self, name, staged[name])
         # graftlint: ignore[sync-point] meta is the parsed engine.json dict
         self.extra_rows_assigned = int(
             staged["meta"].get("extra_rows_assigned", 0)
         )
         self._tick_tables("load_tables")
 
-    def set_tables(self, syn0: np.ndarray, syn1: np.ndarray) -> None:
-        """Install host table values (unpadded, all num_rows rows),
+    def set_tables(self, syn0: np.ndarray, syn1: np.ndarray,
+                   posw: Optional[np.ndarray] = None) -> None:
+        """Install host table values (unpadded: all num_rows rows of
+        ``syn0`` and ``syn1``; ``posw``, for an engine that has one, its
+        ``position_lanes`` rows, left as it is where not given),
         re-padding and re-sharding."""
-        if syn0.shape != (self.num_rows, self.dim):
-            raise ValueError("syn0 shape mismatch")
-        if syn1.shape != (self.num_rows, self.dim):
-            raise ValueError("syn1 shape mismatch")
-        pad = (
-            (0, self.padded_vocab - self.num_rows),
-            (0, self.padded_dim - self.dim),
-        )
-        tsh = self._table_sharding()
-
-        def put(host):
-            # Host array straight to its shards: going through
-            # jnp.asarray first would land the whole table on the
-            # default device.
-            full = np.pad(host, pad).astype(np.float32, copy=False)
-            return jax.device_put(full.astype(self._dtype, copy=False), tsh)
-
-        self.syn0, self.syn1 = put(syn0), put(syn1)
+        if posw is not None and not self.position_lanes:
+            raise ValueError("this engine has no position table")
+        given = {"syn0": syn0, "syn1": syn1, "posw": posw}
+        placed = {
+            name: self._put_table(name, host)
+            for name, host in given.items() if host is not None
+        }
+        for name, table in placed.items():
+            setattr(self, name, table)
         self._tick_tables("set_tables")
 
     def _resident_arrays(self):
         """The live tables and the adopted ANN index's arrays."""
-        for a in (self.syn0, self.syn1):
+        for a in self.tables().values():
             if a is not None:
                 yield a
         idx = self._ann
@@ -4085,12 +4251,12 @@ class EmbeddingEngine:
         unlike :meth:`destroy` the corpus/training buffers (if any)
         are left alone."""
         self.wait_pending_saves(reraise=False)
-        for a in (self.syn0, self.syn1):
+        for name, a in self.tables().items():
             try:
                 a.delete()
             except Exception:
                 pass
-        self.syn0 = self.syn1 = None
+            setattr(self, name, None)
         self._ann = None
         self._tick_tables("release_tables")
 
@@ -4100,7 +4266,7 @@ class EmbeddingEngine:
         separate buffers, but a half-written checkpoint helps nobody)."""
         self.wait_pending_saves(reraise=False)
         _free(
-            self.syn0, self.syn1, self._prob, self._alias,
+            *self.tables().values(), self._prob, self._alias,
             self._alias_packed, getattr(self, "_keep_prob", None),
             *(getattr(self, "_corpus", None) or ()),
             *(getattr(self, "_corpus_compacted", None) or ()),
@@ -4109,8 +4275,9 @@ class EmbeddingEngine:
             *(getattr(self, "_compact_prefetch", None) or ()),
         )
         self._compact_prefetch = None
-        self.syn0 = self.syn1 = self._prob = self._alias = None
-        self._alias_packed = None
+        for name in self.table_names:
+            setattr(self, name, None)
+        self._prob = self._alias = self._alias_packed = None
         self._corpus = None
         self._corpus_compacted = None
         self._corpus_sent = self._compacted_sent = None

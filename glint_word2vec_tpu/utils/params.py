@@ -93,6 +93,20 @@ class Word2VecParams:
         position, and is refused with a shared pool, grid packing,
         replica exchange, the streaming trainer, or a fit that path does
         not take (there is no host-batcher CBOW).
+      position_weights: CBOW with position-dependent weighting (Mikolov
+        et al., LREC 2018, arXiv:1712.09405 section 2.2; the recipe of
+        the published ``cc.<lang>.300`` fastText tables, Grave et al.,
+        arXiv:1802.06893): a vector ``d_p`` for each relative position
+        p of the window multiplies, column by column, the word a bag
+        holds at p before the bag is summed. The model then owns a third
+        table, ``posw`` ``(2 * window, vector_size)`` float32, started at
+        ones and trained with the two others (every step adds to each row
+        the MEAN of its gradient over the step's positions that hold a
+        word there: their sum, which the rows of ``syn0`` take, does not
+        stay finite at a batch of 8,192), saved with the model and restored by ``load``
+        and by a resumed fit; queries read ``syn0`` alone, as the
+        published ``.vec`` files hold input vectors alone. Valid with
+        ``architecture="cbow"`` only, both families.
     """
 
     vector_size: int = 100
@@ -176,6 +190,9 @@ class Word2VecParams:
     #: Which model is trained: "skipgram" or "cbow" (see the class
     #: docstring). A saved model without the key is a skip-gram.
     architecture: str = "skipgram"
+    #: CBOW's position weights (see the class docstring). A saved model
+    #: without the key has none.
+    position_weights: bool = False
 
     def __post_init__(self) -> None:
         self.validate()
@@ -231,6 +248,15 @@ class Word2VecParams:
         _require(
             self.architecture in ("skipgram", "cbow"),
             "architecture must be skipgram|cbow",
+        )
+        _require(
+            isinstance(self.position_weights, bool),
+            "position_weights must be true or false",
+        )
+        _require(
+            not self.position_weights or self.architecture == "cbow",
+            "position_weights weight the words of a CBOW bag by where they "
+            "stand: architecture must be 'cbow'",
         )
         if self.architecture == "cbow":
             _require(

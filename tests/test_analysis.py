@@ -599,6 +599,65 @@ def test_parse_error_is_a_finding(tmp_path):
 
 
 # ----------------------------------------------------------------------
+# scope-registry
+# ----------------------------------------------------------------------
+
+_SCOPES_FIXTURE = """
+    DEVICE_SCOPES = {
+        "glint.used": "a phase",
+        "inner": "an inner name",
+        "glint.unused": "registered, opened through a variable",
+    }
+"""
+
+
+def test_scope_registry_flags_an_unregistered_literal(tmp_path):
+    findings, _ = run_on(tmp_path, {
+        "glint_word2vec_tpu/obs/events.py": _SCOPES_FIXTURE,
+        "glint_word2vec_tpu/mod.py": """
+            import jax
+
+            def f(x):
+                with jax.named_scope("glint.used"), jax.named_scope("inner"):
+                    with jax.named_scope("glint.typo"):
+                        return x
+        """,
+    }, rules=["scope-registry"])
+    msgs = [f.message for f in findings]
+    # the typo alone: a registered scope that nothing opens is no finding
+    assert len(findings) == 1, msgs
+    assert "glint.typo" in msgs[0] and "not a DEVICE_SCOPES" in msgs[0]
+
+
+def test_scope_registry_clean_and_nonliteral(tmp_path):
+    findings, _ = run_on(tmp_path, {
+        "glint_word2vec_tpu/obs/events.py": _SCOPES_FIXTURE,
+        "glint_word2vec_tpu/mod.py": """
+            import jax
+
+            def f(x, wide):
+                # a scope chosen among registered literals at its own site
+                scope = "glint.unused" if wide else "glint.used"
+                with jax.named_scope(scope), jax.named_scope("inner"):
+                    return x
+        """,
+    }, rules=["scope-registry"])
+    assert findings == []
+
+
+def test_scope_registry_holds_the_engines_scopes():
+    """Every scope a per-layer reader splits the device trace by is a
+    registered literal of the engine, the position table's among them."""
+    from glint_word2vec_tpu.obs.events import DEVICE_SCOPES
+
+    findings, _ = core.run_analysis(REPO, rules=["scope-registry"])
+    assert findings == [], [f.format() for f in findings]
+    for scope in ("glint.compose", "group", "bag", "posgrad", "glint.batch",
+                  "glint.scatter", "syn0", "syn1"):
+        assert DEVICE_SCOPES[scope], scope
+
+
+# ----------------------------------------------------------------------
 # whole-repo smoke: the committed baseline is exactly reproduced
 # ----------------------------------------------------------------------
 
@@ -651,7 +710,8 @@ def test_cli_list_rules():
     )
     assert out.returncode == 0
     for rule in ("sync-point", "atomic-persist", "table-tick",
-                 "fault-point", "prom-consistency", "lock-discipline"):
+                 "fault-point", "prom-consistency", "lock-discipline",
+                 "span-registry", "scope-registry"):
         assert rule in out.stdout
 
 

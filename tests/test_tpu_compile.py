@@ -86,9 +86,9 @@ def engines(topo):
 
     def get(chips: int, shared_negatives: int = 0, vocab: int = V,
             extra_rows: int = 0, architecture: str = "skipgram",
-            negatives: int = NEG):
+            negatives: int = NEG, position_lanes: int = 0):
         key = (chips, shared_negatives, vocab, extra_rows, architecture,
-               negatives)
+               negatives, position_lanes)
         if key not in built:
             mesh = Mesh(
                 np.asarray(topo.devices[:chips]).reshape(1, chips),
@@ -100,7 +100,7 @@ def engines(topo):
                 unigram_table_size=None, seed=1, dtype="float32",
                 extra_rows=extra_rows, shared_negatives=shared_negatives,
                 compute_dtype=None,
-                architecture=architecture,
+                architecture=architecture, position_lanes=position_lanes,
             )
             eng._build_jitted_fns()
             built[key] = eng
@@ -183,7 +183,8 @@ def _compile_packed_scan(eng, words=CORPUS_WORDS, sentences=CORPUS_SENTENCES,
     compiled for the engine's described mesh; with ``group_width`` the
     subword family's, a (vocab, group_width) group table its last
     argument. A CBOW engine's scan trains 8,192 positions a step, each
-    with its bag, and has no span."""
+    with its bag, and has no span; with ``position_lanes`` its position
+    table is the third argument, beside the tables."""
     import jax.numpy as jnp
 
     from glint_word2vec_tpu.corpus.batching import (
@@ -202,10 +203,12 @@ def _compile_packed_scan(eng, words=CORPUS_WORDS, sentences=CORPUS_SENTENCES,
     vocab = eng.vocab_size
     groups = (sds((vocab, group_width), jnp.int32),) if group_width else ()
     table = _table(eng)
+    posw = ((sds((eng.position_lanes, eng.padded_dim), jnp.float32),)
+            if eng.position_lanes else ())
     offs = sds((sentences + 1,), jnp.int32)
     i32, u32, f32 = (sds((), t) for t in (jnp.int32, jnp.uint32, jnp.float32))
     return fn.lower(
-        table, table, sds((-(-vocab // 64), 128), jnp.int32),
+        table, table, *posw, sds((-(-vocab // 64), 128), jnp.int32),
         sds((words,), jnp.int32), sds((words,), jnp.int32), offs, offs,
         i32, i32,
         sds((2,), jnp.uint32), u32, u32, f32, f32, f32, *groups,
@@ -700,6 +703,82 @@ def test_subword_cbow_packed_scan_at_the_cell_size(engines):
     # the bags read the composed words as shifted slices: no (positions x
     # lanes x d) tensor is ever formed
     assert "f32[8192,10,384]" not in text and "f32[10,8192,384]" not in text
+
+
+def _fusion_roots(text: str, scope: str) -> list:
+    """``(the fusion instruction's own op_name, the op_names inside it,
+    joined)`` of every fused kernel of a compiled program that holds an op
+    traced under ``scope``."""
+    import re
+
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            bodies[name.lstrip("%")] = []
+        elif name and line.startswith("}"):
+            name = None
+        elif name:
+            bodies[name.lstrip("%")].append(line)
+    out = []
+    for lines in bodies.values():
+        for line in lines:
+            called = re.search(r" fusion\(.*calls=%?([\w.\-]+)", line)
+            if not called:
+                continue
+            inner = " ".join(re.findall(
+                r'op_name="([^"]*)"', "\n".join(bodies.get(called[1], []))))
+            if scope in inner:
+                own = re.search(r'op_name="([^"]*)"', line)
+                out.append((own[1] if own else "", inner))
+    return out
+
+
+def test_subword_cbow_scan_with_position_weights_at_the_cell_size(engines):
+    # ``ft-cbow-pw-300-1m-2mb`` (ISSUE 54): the scan above with the position
+    # table, f32[10,384], third among its arguments and results, carried
+    # and donated with the two row tables; the weights ride the bags'
+    # shifted adds, and the table's gradient is ten reductions over the
+    # batch under ``glint.compose/posgrad``, no scatter.
+    vocab, words = SUBWORD_SCANS["ft-1m-2mb"]
+    sentences = -(-(words - 8 * 80_000) // 40) + 80_000
+    eng = engines(1, 0, vocab, BUCKET, architecture="cbow", negatives=10,
+                  position_lanes=2 * WINDOW)
+    assert eng.table_names == ("syn0", "syn1", "posw")
+    compiled = _compile_packed_scan(eng, words, sentences, 16)
+    mem = _fits(compiled)
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        2 * eng.rows_per_shard * D_REST * 4 + 2 * WINDOW * D_REST * 4
+    ), mem
+    # 9,696,511,488 B by part (compile check, PR 54): 9,334,804,480 of
+    # arguments (the sibling's and the table's 24,576, its ten rows resting
+    # in sixteen), 361,696,256 of temporaries, 1,300,480 over the sibling's
+    # 9,695,186,432 B program (compile check, PR 39)
+    assert mem["total"] < 10.2e9 and mem["temp"] < 0.6e9, mem
+    assert not _whole_table_copies(compiled, eng)
+    text = compiled.as_text()
+    assert "all-reduce" not in text and "packed_scan" in text
+    _scatter_holds_no_slot_buffer(compiled, eng)
+    _stops_at_the_corpus_end(compiled, eng)
+    for scope in ("glint.compose/group", "glint.compose/bag",
+                  "glint.compose/posgrad", "glint.scatter/syn0",
+                  "glint.scatter/syn1"):
+        assert scope in text, scope
+    assert "f32[8192,10,384]" not in text and "f32[10,8192,384]" not in text
+    # the table's update is dense: no scatter, no custom call under it
+    assert not [line for line in text.splitlines()
+                if "glint.compose/posgrad" in line
+                and ("scatter(" in line or "custom-call(" in line)]
+    # A kernel's time is filed under its ROOT's scope. The kernels that hold
+    # an op of the table's reductions are rooted under glint.compose, so
+    # ``step.compose_ms`` reads all the table costs; the one that forms the
+    # positions' gradient (glint.grads ops, rooted under glint.compose/bag
+    # without the weights) ends in a lane's reduction and is filed under
+    # posgrad: why the cell lists no ``step.bag_ms`` (PERF.md section 3).
+    roots = _fusion_roots(text, "glint.compose/posgrad")
+    assert roots and all("glint.compose" in r for r, _ in roots), roots
+    assert any("glint.compose/posgrad" in r and "glint.grads" in inner
+               for r, inner in roots), roots
 
 
 def test_slab_writer_compiles_for_bfloat16(topo):
